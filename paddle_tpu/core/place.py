@@ -36,8 +36,11 @@ class Place:
     @property
     def jax_device(self) -> jax.Device:
         devs = [d for d in jax.devices() if d.platform == self.platform]
-        if not devs:  # fall back: requested platform absent (e.g. TPU on CI)
-            devs = jax.devices()
+        if not devs:
+            from .enforce import UnavailableError
+            raise UnavailableError(
+                f"{self!r}: JAX has no {self.platform!r} device here "
+                f"(found {sorted({d.platform for d in jax.devices()})})")
         return devs[self.device_id % len(devs)]
 
 
@@ -116,6 +119,36 @@ def get_device() -> str:
 
 def expected_place() -> Place:
     return _expected_place if _expected_place is not None else _default_place()
+
+
+def accelerator_held() -> Optional[str]:
+    """Platform of the accelerator this process holds, or None.
+
+    A chip belongs to one process at a time: the process that has
+    initialised a JAX backend on it keeps it until it exits, and a child
+    that needs the chip then fails or hangs. A process that has only
+    IMPORTED jax holds nothing. Never initialises a backend itself."""
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    plat = jax.default_backend()
+    return None if plat == "cpu" else plat
+
+
+def refuse_chip_contention(child_env, child: str) -> None:
+    """Raise before spawning ``child`` (a process that will run JAX
+    under ``child_env``) when it would need the chip this process
+    already holds: a child not pinned to ``JAX_PLATFORMS=cpu`` opens
+    the accelerator. Failing here is the loud alternative to a child
+    that hangs at start-up."""
+    held = accelerator_held()
+    if held and child_env.get("JAX_PLATFORMS", "").strip() != "cpu":
+        from .enforce import UnavailableError
+        raise UnavailableError(
+            f"this process has initialised JAX on {held!r} and holds the "
+            f"chip; {child} would need it too (one process per chip). "
+            f"Start it from a process that has not touched JAX, or pin "
+            f"it to the CPU with JAX_PLATFORMS=cpu in its environment")
 
 
 def is_compiled_with_tpu() -> bool:

@@ -20,7 +20,6 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from ..compat import axis_size as _compat_axis_size
 import jax.numpy as jnp
 from jax._src import core as _jax_core
 
@@ -165,7 +164,7 @@ def send(tensor, dst: int, group=None, use_calc_stream: bool = True):
     x = _unwrap(tensor)
     if _in_trace():
         axis = _axis(group or "pp")
-        n = _compat_axis_size(axis)
+        n = jax.lax.axis_size(axis)
         out = jax.lax.ppermute(x, axis,
                                [(i, (i + 1) % n) for i in range(n)])
         return _rewrap(tensor, out)
@@ -180,7 +179,7 @@ def p2p_shift(x, axis_name: str = "pp", shift: int = 1):
     """Shift values along a mesh axis (the pipeline hop primitive)."""
     if not _in_trace():
         return x
-    n = _compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(_unwrap(x), axis_name, perm)
 

@@ -41,11 +41,8 @@ def init_parallel_env(coordinator_address: Optional[str] = None,
         # PT_CPU_COLLECTIVES=none opts out.
         impl = os.environ.get("PT_CPU_COLLECTIVES", "gloo")
         if impl and impl != "none":
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  impl)
-            except Exception:
-                pass  # older jax without the option
+            jax.config.update("jax_cpu_collectives_implementation",
+                              impl)
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=nproc, process_id=pid)
     _initialized = True

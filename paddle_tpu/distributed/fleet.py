@@ -358,7 +358,7 @@ class ShardedTrainStep:
             # DDP convention: global grad = MEAN of per-shard grads, so
             # train_fn must return a batch-mean loss; a sum-reduced loss
             # comes out scaled by 1/dp relative to the exact path.
-            from ..compat import shard_map as _shard_map
+            from jax import shard_map as _shard_map
             from .mp_layers import no_sharding_constraints
 
             def vag(params, buffers, key, batch):
@@ -492,8 +492,6 @@ class ShardedTrainStep:
         return cached_lr_device(self, self.optimizer)
 
     def __call__(self, batch):
-        from ..jit import effects_token_guard
-        effects_token_guard(self.mesh.devices.flat)
         batch_raw = jax.tree_util.tree_map(
             lambda t: t.value if isinstance(t, Tensor) else t, batch,
             is_leaf=lambda t: isinstance(t, Tensor))

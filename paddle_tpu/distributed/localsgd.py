@@ -19,7 +19,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..nn.layer import Layer, functional_state

@@ -69,6 +69,21 @@ def no_sharding_constraints():
         _constraints_state.disabled = prev
 
 
+def active_hybrid_mesh():
+    """The global hybrid mesh when the CURRENT trace is partitioned over
+    it by GSPMD (inside a fleet step's jit), else None: no fleet, an
+    eager call, constraints disabled, or a manual (shard_map) context
+    whose body already runs per shard."""
+    hcg = get_hybrid_communicate_group()
+    from jax._src import core as _jax_core
+    if hcg is None or _constraints_disabled() or \
+            _jax_core.trace_state_clean() or hcg.mesh.size == 1:
+        return None
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return hcg.mesh
+
+
 def _constrain(x, *spec):
     """Apply a sharding constraint when a mesh is active (inside pjit).
 

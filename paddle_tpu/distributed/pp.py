@@ -25,7 +25,6 @@ import functools
 from typing import Any, Callable, List, Optional, Sequence
 
 import jax
-from ..compat import axis_size as _compat_axis_size
 import jax.numpy as jnp
 
 from ..core.offload import remat_policy as _remat_policy
@@ -120,7 +119,7 @@ def spmd_pipeline(stage_fn: Callable, stage_params: Any, x_micro,
     Returns [n_micro, mb, ...] outputs valid on the LAST stage (zeros
     elsewhere); reduce with a pp-psum or mask as needed.
     """
-    n_stages = _compat_axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     n_micro = x_micro.shape[0]
     total_steps = n_micro + n_stages - 1
@@ -196,7 +195,7 @@ def spmd_pipeline_1f1b(stage_fn: Callable, stage_params: Any, shared: Any,
       per pp rank (stage-0 holds first_fn grads, last stage holds
       last_fn grads and the loss); psum over the pp axis to combine.
     """
-    n_stages = _compat_axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     fn = jax.checkpoint(stage_fn, policy=_remat_policy()) \
         if remat else stage_fn

@@ -33,7 +33,6 @@ import functools
 from typing import Optional
 
 import jax
-from ..compat import axis_size as _compat_axis_size
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -131,7 +130,7 @@ def _ring_flash_forward(q, k, v, axis_name, causal, scale):
     """Returns (normalized acc f32, global lse) — the flash residuals."""
     from ..ops.pallas.flash_attention import flash_attention_lse
 
-    n = _compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_loc, h, _ = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -203,7 +202,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, res, do):
                                               _resolve_blocks)
 
     q, k, v, out, lse = res
-    n = _compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_loc = q.shape[1]
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -276,7 +275,7 @@ def _zigzag_ring_flash_forward(q, k, v, axis_name, scale):
     contiguous layout's ~2x causal wait disappears)."""
     from ..ops.pallas.flash_attention import flash_attention_lse
 
-    n = _compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_loc, h, _ = q.shape
     c = s_loc // 2
@@ -351,7 +350,7 @@ def _zigzag_flash_vjp_bwd(axis_name, scale, res, do):
                                               _resolve_blocks)
 
     q, k, v, out, lse = res
-    n = _compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_loc = q.shape[1]
     c = s_loc // 2
@@ -465,7 +464,7 @@ def ring_attention(q, k, v, axis_name: str = "sep", causal: bool = False,
             return _zigzag_ring_attention_flash(q, k, v, axis_name,
                                                 scale_f)
         return _ring_attention_flash(q, k, v, axis_name, causal, scale_f)
-    n = _compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -523,7 +522,7 @@ def ulysses_attention(q, k, v, axis_name: str = "sep",
     exchange each device holds [B, S_full, H/N, D] and runs ordinary
     (flash) attention, then exchanges back.
     """
-    n = _compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def seq_to_head(x):
         # [B, S/N, H, D] -> [B, S, H/N, D]
@@ -571,7 +570,7 @@ def ring_schedule_work(n: int, layout: str = "contiguous"):
 
 def _axis_bound(axis_name: str) -> bool:
     try:
-        _compat_axis_size(axis_name)
+        jax.lax.axis_size(axis_name)
         return True
     except NameError:
         return False
@@ -621,7 +620,7 @@ def sequence_parallel_attention(q, k, v, mode: str = "ring",
                 "ulysses redistributes heads over sep: per-mp-shard "
                 f"heads {local_heads} must be divisible by the sep "
                 f"degree {sep}")
-        from ..compat import shard_map
+        from jax import shard_map
         head_axis = "mp" if mp > 1 else None
 
         def sharded(qq, kk, vv):

@@ -358,6 +358,17 @@ def make_mesh(axis_shapes: Dict[str, int], devices=None) -> Mesh:
 SERVING_MODEL_AXIS = "mp"
 
 
+def collectives_in(compiled_text: str) -> Dict[str, int]:
+    """Collective ops in a compiled program's text, by kind (the SPMD
+    partitioner inserts them at compile time, so this reads
+    ``compiled.as_text()``, not the lowering)."""
+    import collections
+    import re
+    return dict(collections.Counter(re.findall(
+        r"\b(all-reduce|all-gather|reduce-scatter|collective-permute|"
+        r"all-to-all)(?:-start)?\(", compiled_text)))
+
+
 def make_serving_mesh(model_parallel: int, devices=None) -> Mesh:
     """1-D tensor-parallel mesh for the decode engine / serving stack:
     ``model_parallel`` devices along the :data:`SERVING_MODEL_AXIS`

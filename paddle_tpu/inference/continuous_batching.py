@@ -80,6 +80,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import re
 import time
 from typing import (Any, Callable, Dict, Hashable, List, Optional,
                     Sequence, Tuple)
@@ -490,7 +491,7 @@ class ContinuousBatchingEngine:
         from ..nn.layer import functional_state
         from ..models.gpt import paged_cache_create
 
-        # env-gated persistent compile cache (PADDLE_TPU_COMPILE_CACHE):
+        # persistent compile cache (core/compile_cache.py places it):
         # the engine's prefill-per-bucket + decode/verify programs are
         # exactly the compiles a restarted server pays again cold
         enable_compile_cache()
@@ -1376,7 +1377,10 @@ class ContinuousBatchingEngine:
     def _capture_cost(self, kind: str, jitfn, args: Tuple) -> None:
         """Capture flops / bytes-accessed estimates for ``kind`` from
         ``jit.lower(...).cost_analysis()`` on stub avals (no compile,
-        no execution) — once per program kind, at (re)trace time, on
+        no execution), and which Pallas kernels the lowered program
+        holds (``pallas_kernels``: name -> call sites; empty where
+        every gate picked its reference) — once per program kind, at
+        (re)trace time, on
         the ENGINE thread (bind_state substitution is process-global,
         so a scrape thread must never trace the model concurrently).
         These feed the serving_program_* gauges that replace the r10
@@ -1413,14 +1417,27 @@ class ContinuousBatchingEngine:
 
         try:
             stubs = jax.tree_util.tree_map(stub, args)
-            ca = jitfn.lower(*stubs).cost_analysis()
+            lowered = jitfn.lower(*stubs)
+            ca = lowered.cost_analysis()
             if isinstance(ca, (list, tuple)):
                 ca = ca[0] if ca else {}
-            self._program_costs[kind] = {
-                "flops": float(ca.get("flops") or 0.0),
-                "bytes_accessed": float(ca.get("bytes accessed")
-                                        or 0.0),
-            }
+            cost: Dict[str, Any] = {
+                "pallas_kernels": dict(collections.Counter(re.findall(
+                    r'kernel_name\s*=\s*"([^"]+)"',
+                    lowered.as_text())))}
+            if ca:  # None on the TPU backend: no cost model there
+                # for a module that is not compiled yet
+                cost["flops"] = float(ca.get("flops") or 0.0)
+                cost["bytes_accessed"] = float(
+                    ca.get("bytes accessed") or 0.0)
+            self._program_costs[kind] = cost
+            if self.mesh is not None:
+                # the partitioner inserts collectives at COMPILE time;
+                # the launch that just ran left this program in the
+                # compile cache, so this is a cache read
+                from ..distributed.topology import collectives_in
+                self._program_costs[kind]["collectives"] = \
+                    collectives_in(lowered.compile().as_text())
         except Exception as e:  # cost capture must never break a step
             self._program_costs[kind] = {
                 "error": f"{type(e).__name__}: {e}"}
@@ -1579,11 +1596,29 @@ class ContinuousBatchingEngine:
         None when single-device, else axis sizes + device count."""
         if self.mesh is None:
             return None
-        return {"axes": {str(a): int(self.mesh.shape[a])
+        info = {"axes": {str(a): int(self.mesh.shape[a])
                          for a in self.mesh.axis_names},
                 "model_parallel": int(self.mesh.shape[self._mesh_axis]),
                 "devices": int(self.mesh.size),
                 "model_axis": self._mesh_axis}
+        if self._state_cache is not None:
+            # where the weights really are (filled at first admission):
+            # `split` counts leaves that are NOT fully replicated,
+            # `pspec_split` those whose pspec names the model axis —
+            # equal when the placement follows the annotations
+            placed = [(v, self._state_shardings[kind][name])
+                      for kind, grp in self._state_cache.items()
+                      for name, v in grp.items()]
+            info["weights"] = {
+                "leaves": len(placed),
+                "split": sum(not v.sharding.is_fully_replicated
+                             for v, _ in placed),
+                "pspec_split": sum(not want.is_fully_replicated
+                                   for _, want in placed),
+                "on_all_devices": all(
+                    len(v.sharding.device_set) == self.mesh.size
+                    for v, _ in placed)}
+        return info
 
     def _decode_body_fn(self):
         """The ONE single-token decode step body: shared verbatim by

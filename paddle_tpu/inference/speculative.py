@@ -1,7 +1,7 @@
 """Speculative decoding over the paged continuous-batching engine.
 
 Decode at b128 runs 1.63x off its own measured streaming floor
-(PROFILE_DECODE.json): every emitted token re-reads the full weight
+(pre-round decode trace): every emitted token re-reads the full weight
 set and the KV prefix once. Speculative decoding amortizes that stream
 over multiple tokens per step — a cheap DRAFT proposes ``k`` tokens,
 the target model scores all ``k+1`` positions in ONE forward (the

@@ -35,46 +35,10 @@ def _wrap_tree(tree):
         lambda v: Tensor(v) if isinstance(v, jax.Array) else v, tree)
 
 
-def effects_token_guard(target_devices) -> None:
-    """Barrier stale ordered-effects tokens before dispatching onto a
-    DIFFERENT device set.
-
-    jax keeps one token per ordered effect (io_callback in a
-    HostEmbedding backward, ordered debug prints...), sharded over the
-    devices of the last program that used it. Dispatching a program on
-    another device set makes get_token_input reshard that token with a
-    device_put — which on jax<0.5 dies in a native CHECK (token arrays
-    cannot take the slow copy path), aborting the process. Running
-    ``jax.effects_barrier()`` first is always safe: it waits for the
-    outstanding effects (preserving ordering) and drops the tokens, so
-    the next program mints a fresh one on its own devices."""
-    try:
-        from jax._src import dispatch as _jd
-        tokens = _jd.runtime_tokens.current_tokens
-    except (ImportError, AttributeError):
-        return
-    if not tokens:
-        return
-    target = set(target_devices)
-    for tok in list(tokens.values()):
-        buf = getattr(tok, "_buf", None)
-        devs = getattr(getattr(buf, "sharding", None), "device_set", None)
-        if devs is not None and set(devs) != target:
-            jax.effects_barrier()
-            return
-
-
-def _devices_of(leaf) -> tuple:
-    devs = getattr(getattr(leaf, "sharding", None), "device_set", None)
-    if devs:
-        return tuple(devs)
-    return (jax.devices()[0],)
-
-
 def cached_lr_device(obj, optimizer):
     """Device f32 scalar for the current lr, re-uploaded only when the
     value changes — a fresh jnp.asarray per step is a host->device
-    transfer (milliseconds of round-trip on tunneled runtimes)."""
+    transfer."""
     lr = float(optimizer.get_lr())
     cache = getattr(obj, "_lr_cache", None)
     if cache is None or lr != cache[0]:
@@ -129,8 +93,7 @@ class TrainStep:
 
         # The PRNG key evolves INSIDE the jitted step: one device dispatch
         # per step total. A separate host-side jax.random.split is a whole
-        # extra launch, which on remote/tunneled TPU runtimes costs
-        # milliseconds of round-trip per step.
+        # extra launch.
         kwargs = {"donate_argnums": (0, 1, 2, 3)} if donate else {}
         step = jax.jit(one_step, **kwargs)
 
@@ -152,8 +115,6 @@ class TrainStep:
 
     def __call__(self, batch) -> jax.Array:
         batch_raw = _unwrap_tree(batch)
-        leaf = next(iter(self.params.values()), None)
-        effects_token_guard(_devices_of(leaf))
         self.params, self.buffers, self.opt_state, self._key, loss = \
             self._step(self.params, self.buffers, self.opt_state,
                        self._key, self._lr_device(), batch_raw)

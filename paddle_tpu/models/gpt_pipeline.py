@@ -22,10 +22,9 @@ import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
-from ..compat import axis_size as _compat_axis_size
 import jax.numpy as jnp
 import numpy as np
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..autograd.engine import no_grad
@@ -339,7 +338,7 @@ class GPTPipelineTrainStep:
                 hidden = outs.reshape(b, *x.shape[1:])
                 loss = head_loss(shared_l, hidden, labels_l)
                 # only the last stage's loss is real; psum broadcasts it
-                n_stages = _compat_axis_size("pp")
+                n_stages = jax.lax.axis_size("pp")
                 stage = jax.lax.axis_index("pp")
                 loss = jnp.where(stage == n_stages - 1, loss, 0.0)
                 loss = jax.lax.psum(loss, "pp")
@@ -466,7 +465,7 @@ class GPTPipelineTrainStep:
         lr = jax.ShapeDtypeStruct(
             (), jnp.float32, sharding=NamedSharding(self.mesh, P()))
         params = {"stacked": self.stacked, "shared": self.shared}
-        with self._remat_scope(), self.mesh:
+        with self._remat_scope():
             return self._step.lower(params, self.opt_state, lr, ids, ids)
 
     def _remat_scope(self):
@@ -495,10 +494,7 @@ class GPTPipelineTrainStep:
             bspec = NamedSharding(self.mesh, self._batch_pspec())
             ids = jax.device_put(ids, bspec)
             labels = jax.device_put(labels, bspec)
-        # the mesh context lets bare-PartitionSpec sharding constraints
-        # inside the partial-manual program resolve on older jax (newer
-        # jax resolves them against the abstract mesh without it)
-        with self._remat_scope(), self.mesh:
+        with self._remat_scope():
             params, self.opt_state, loss = self._step(
                 params, self.opt_state, lr, ids, labels)
         self.stacked = params["stacked"]

@@ -2,55 +2,108 @@
 
 Builds the shared library on first use with g++ (pybind11 is not in this
 image; the C ABI + ctypes replaces the reference's pybind layer for these
-components). All entry points degrade gracefully to Python fallbacks when
-the toolchain is unavailable.
+components). `get_lib` returns None when the toolchain is unavailable and
+the callers below then use their Python fallbacks; `require_lib` raises
+the build error instead, for paths that must not degrade silently.
+
+A library is loaded only if it was built from the present sources, with
+the present flags, on the present machine: its file name carries a hash
+of all three (``native/build/libptnative-<key>.so``). The build uses
+``-march=native``, so a library copied in from another machine — a copy
+keeps neither mtimes nor CPU — simply has another name and is never
+picked up.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
-from typing import Optional
+from typing import List, Optional
 
 import numpy as np
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_REPO_ROOT, "native", "ptnative.cc")
 _SRC_PS = os.path.join(_REPO_ROOT, "native", "pt_ps.cc")
-_LIB_PATH = os.path.join(_REPO_ROOT, "native", "libptnative.so")
+_BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
 
 _lib = None
 _lib_lock = threading.Lock()
-_build_failed = False
+_build_error: Optional[str] = None
 
 
-def _build() -> Optional[str]:
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           _SRC, _SRC_PS, "-o", _LIB_PATH, "-lpthread", "-lrt"]
+def _cpu_identity() -> str:
+    """What ``-march=native`` depends on: architecture + ISA flags."""
+    flags = ""
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return _LIB_PATH
-    except (subprocess.CalledProcessError, FileNotFoundError,
-            subprocess.TimeoutExpired):
-        return None
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = line.split(":", 1)[1].strip()
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {flags}"
+
+
+def _keyed_path(stem: str, sources: List[str], flags: List[str]) -> str:
+    """``native/build/<stem>-<key>.so`` with key = hash(sources' bytes,
+    flags, this machine's CPU)."""
+    h = hashlib.sha256()
+    for src in sources:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    h.update("\0".join(flags).encode())
+    h.update(_cpu_identity().encode())
+    return os.path.join(_BUILD_DIR, f"{stem}-{h.hexdigest()[:16]}.so")
+
+
+def _compile(cmd: List[str], out_path: str, timeout: float) -> None:
+    """Run ``cmd -o <tmp>`` and move the result to ``out_path``
+    atomically (several processes may build the same key at once).
+    Raises RuntimeError with the compiler's message on failure."""
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{out_path}.tmp{os.getpid()}"
+    try:
+        subprocess.run(cmd + ["-o", tmp], check=True, capture_output=True,
+                       timeout=timeout)
+        os.replace(tmp, out_path)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"native build failed ({' '.join(cmd)}): "
+            f"{e.stderr.decode('utf-8', 'replace')[-2000:]}") from e
+    except (FileNotFoundError, subprocess.TimeoutExpired) as e:
+        raise RuntimeError(f"native build failed ({cmd[0]}): {e}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+
+
+_FLAGS = ["-O3", "-march=native", "-shared", "-fPIC", "-std=c++17"]
+
+
+def lib_path() -> str:
+    """Where this machine's build of the present sources lives."""
+    return _keyed_path("libptnative", [_SRC, _SRC_PS], _FLAGS)
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+    global _lib, _build_error
     with _lib_lock:
-        if _lib is not None or _build_failed:
+        if _lib is not None or _build_error is not None:
             return _lib
-        path = _LIB_PATH
-        stale = not os.path.exists(path) or any(
-            os.path.exists(s) and os.path.getmtime(s) > os.path.getmtime(path)
-            for s in (_SRC, _SRC_PS))
-        if stale:
-            path = _build()
-        if path is None or not os.path.exists(path):
-            _build_failed = True
-            return None
+        path = lib_path()
+        if not os.path.exists(path):
+            try:
+                _compile(["g++", *_FLAGS, _SRC, _SRC_PS, "-lpthread",
+                          "-lrt"], path, timeout=120)
+            except RuntimeError as e:
+                _build_error = str(e)
+                return None
         lib = ctypes.CDLL(path)
         lib.ptq_create.restype = ctypes.c_void_p
         lib.ptq_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
@@ -137,47 +190,44 @@ def available() -> bool:
     return get_lib() is not None
 
 
-_CAPI_SRC = os.path.join(_REPO_ROOT, "native", "pt_capi.cc")
-_CAPI_LIB = os.path.join(_REPO_ROOT, "native", "libpt_infer.so")
+def require_lib() -> ctypes.CDLL:
+    """`get_lib`, but a failed build raises with the compiler's message
+    instead of leaving callers on their Python fallbacks."""
+    lib = get_lib()
+    if lib is None:
+        raise RuntimeError(_build_error)
+    return lib
 
+
+_CAPI_SRC = os.path.join(_REPO_ROOT, "native", "pt_capi.cc")
 
 _capi_lock = threading.Lock()
 
 
-def _capi_loadable() -> bool:
-    try:
-        ctypes.CDLL(_CAPI_LIB)
-        return True
-    except OSError:
-        return False
-
-
 def build_capi() -> Optional[str]:
-    """Build the C inference API (native/pt_capi.cc -> libpt_infer.so),
+    """Build the C inference API (native/pt_capi.cc -> libpt_infer-<key>.so),
     the capi_exp-equivalent deployment library. Returns the .so path or
-    None if the toolchain is unavailable."""
+    None if the toolchain is unavailable. The key covers the libpython
+    it links against, so a library built for another interpreter is
+    rebuilt, not returned."""
     import sysconfig
+    inc = sysconfig.get_paths()["include"]
+    libdir = sysconfig.get_config_var("LIBDIR")
+    pyver = f"python{sysconfig.get_config_var('py_version_short')}"
+    flags = ["-O2", "-shared", "-fPIC", "-std=c++17", f"-I{inc}",
+             f"-L{libdir}", f"-l{pyver}", f"-Wl,-rpath,{libdir}"]
     with _capi_lock:
-        fresh = (os.path.exists(_CAPI_LIB) and os.path.exists(_CAPI_SRC)
-                 and os.path.getmtime(_CAPI_SRC) <=
-                 os.path.getmtime(_CAPI_LIB))
-        # a stale-or-foreign cached lib (e.g. linked against another
-        # libpython) must be rebuilt, not returned
-        if fresh and _capi_loadable():
-            return _CAPI_LIB
-        inc = sysconfig.get_paths()["include"]
-        libdir = sysconfig.get_config_var("LIBDIR")
-        pyver = f"python{sysconfig.get_config_var('py_version_short')}"
-        cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _CAPI_SRC,
-               f"-I{inc}", f"-L{libdir}", f"-l{pyver}",
-               f"-Wl,-rpath,{libdir}", "-o", _CAPI_LIB]
+        path = _keyed_path("libpt_infer", [_CAPI_SRC], flags)
+        if not os.path.exists(path):
+            try:
+                _compile(["g++", _CAPI_SRC, *flags], path, timeout=180)
+            except RuntimeError:
+                return None
         try:
-            subprocess.run(cmd, check=True, capture_output=True,
-                           timeout=180)
-            return _CAPI_LIB if _capi_loadable() else None
-        except (subprocess.CalledProcessError, FileNotFoundError,
-                subprocess.TimeoutExpired):
+            ctypes.CDLL(path)
+        except OSError:
             return None
+        return path
 
 
 class ShmQueue:

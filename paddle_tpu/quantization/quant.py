@@ -335,7 +335,7 @@ def quantize_kv(x, eps: float = 1e-8):
     ``(int8 values [..., H, D], float32 scales [..., H])``. Used by the
     paged KV cache (models/gpt.py PagedKVCache int8 mode), where
     halving KV bytes directly halves the dominant decode-step HBM
-    category (PROFILE_DECODE.json: 5.5 GB/step of KV at b128)."""
+    category (pre-round decode trace: 5.5 GB/step of KV at b128)."""
     raw = x.value if isinstance(x, Tensor) else jnp.asarray(x)
     s = jnp.maximum(jnp.max(jnp.abs(raw.astype(jnp.float32)), axis=-1),
                     eps)
@@ -426,7 +426,7 @@ def dequantize_kv_int4_np(packed: np.ndarray, scale: np.ndarray,
 
 class WeightOnlyInt8Linear(Layer):
     """Weight-ONLY int8 linear for decode/serving, where weight
-    STREAMING is the bottleneck (PROFILE_DECODE.json roofline: at small
+    STREAMING is the bottleneck (pre-round decode roofline: at small
     per-step batch the matmuls are bandwidth-bound on the weights, so
     halving weight bytes approaches 2x tokens/s; activations carry
     negligible traffic and stay bf16/f32 — the reference analog is

@@ -544,7 +544,7 @@ class FleetMetrics:
         """The telemetry half of the ``fleet_stats`` payload: merged
         counters/histograms/SLO, pressure verdict, outlier flags, and
         per-replica telemetry state (staleness, signals, counters).
-        The supervision half — probe-failure taxonomy, restarts,
+        The supervision half — probe-failure classification, restarts,
         backoff gates — is joined in by ``Supervisor.fleet_stats``,
         which owns that state."""
         now = time.monotonic()

@@ -94,7 +94,7 @@ slot that stops emitting answers ``{"error": "RequestStalled"}``
 Engine resurrection: when ``max_engine_errors`` consecutive step
 failures mark the engine dead, the server does NOT fail its clients —
 it tears the engine down (pages returned and audited), rebuilds it
-(a PADDLE_TPU_COMPILE_CACHE dir makes the re-compiles cache reads),
+(the persistent compile cache makes the re-compiles cache reads),
 and replays every in-flight request from its prompt + already-emitted
 tokens as one chained greedy prefill. Greedy continuations are
 bit-identical to the uninterrupted run, so clients just see a pause.
@@ -463,7 +463,7 @@ class ServingServer:
         """(Re)build the decode engine from the captured construction
         recipe. The prefix cache is rebuilt too: its books reference
         pages in the engine's allocator, so a cache may never outlive
-        its engine. A PADDLE_TPU_COMPILE_CACHE dir (core/compile_cache,
+        its engine. The persistent compile cache (core/compile_cache,
         enabled inside the engine constructor) turns the rebuilt
         engine's prefill/decode/verify compiles into cache reads — the
         warm-resurrection lane."""
@@ -1949,7 +1949,7 @@ class ServingServer:
             # the per-program flops/bytes from cost_analysis exported
             # above). The chip-MEASURED value still needs an on-chip
             # profiler session (xprof collective stats) — chip-pending,
-            # same convention as the BENCH_STAGED cpu_smoke markers.
+            # same convention as bench_all's cpu_smoke markers.
             g["mesh_model_parallel"] = mi["model_parallel"]
             g["mesh_devices"] = mi["devices"]
             est = getattr(eng, "mesh_collective_bytes_estimate",

@@ -88,7 +88,7 @@ def _rpc(host: str, port: int, payload: Dict, timeout_s: float) -> Dict:
 
 
 def classify_probe_failure(exc: Optional[BaseException]) -> str:
-    """Probe-failure taxonomy (r17): map a probe exception (None = the
+    """Probe-failure classification (r17): map a probe exception (None = the
     reply arrived but was malformed) onto a stable kind. The monitor
     loop keeps per-replica counts per kind — a replica that TIMES OUT
     (wedged/overloaded) and one REFUSING connections (dead port) and
@@ -122,7 +122,7 @@ class Replica:
         self.restarts = 0           # respawns after a death
         self.consec_deaths = 0      # resets on a healthy probe
         self.probe_failures = 0
-        # probe-failure taxonomy (r17): a bare "ok = False" collapsed
+        # probe-failure classification (r17): a bare "ok = False" collapsed
         # timeout/refused/malformed into one signal — these keep the
         # per-kind lifetime counts + the most recent classified error,
         # exported through fleet_stats (a replica that times out under
@@ -848,6 +848,9 @@ class Supervisor:
     # -- internals ---------------------------------------------------------
 
     def _spawn(self, rep: Replica) -> None:
+        from ..core.place import refuse_chip_contention
+        refuse_chip_contention({**os.environ, **self.replica_env},
+                               f"replica {rep.idx}")
         rep.port = _free_port(self.host)
         rep.ready = False
         rep.probe_failures = 0
@@ -949,7 +952,7 @@ class Supervisor:
                         pass
                 else:
                     rep.probe_failures += 1
-                    # taxonomy (r17): timeout / refused / malformed /
+                    # classification (r17): timeout / refused / malformed /
                     # torn are different incidents; count them apart
                     kind = classify_probe_failure(probe_exc)
                     rep.probe_failures_by_kind[kind] = \
@@ -1064,7 +1067,7 @@ class Supervisor:
         telemetry (bucket-exact fleet histograms, merged SLO window,
         pressure verdict, outlier flags) JOINED with the supervision
         state only this process knows — per-replica probe-failure
-        taxonomy, restart counts, and live backoff gates (previously
+        classification, restart counts, and live backoff gates (previously
         computed and exported nowhere)."""
         now = time.monotonic()
         supervision = {}
@@ -1433,7 +1436,7 @@ class FailoverRouter:
             return
         if op == "fleet_stats":
             # fleet telemetry plane (r17): the collector's merged view
-            # + supervision taxonomy, answered BY THE ROUTER (the one
+            # + supervision classification, answered BY THE ROUTER (the one
             # port an operator watches). Duck-typed: a stub supervisor
             # without the plane gets a typed reply, not a crash.
             fs = getattr(self.sup, "fleet_stats", None)

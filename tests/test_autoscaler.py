@@ -719,7 +719,7 @@ class TestJournalEnvMarkers:
 
 def _replica_env(cache_dir):
     env = {"JAX_PLATFORMS": "cpu", "TPU_SKIP_MDS_QUERY": "true",
-           "PADDLE_TPU_COMPILE_CACHE": cache_dir}
+           "JAX_COMPILATION_CACHE_DIR": cache_dir}
     return env
 
 

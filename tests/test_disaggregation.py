@@ -802,7 +802,7 @@ class TestDrainHandoff:
         prompt bit-identically from the spliced pages."""
         from paddle_tpu.serving.supervisor import Supervisor, _rpc
         env = {"JAX_PLATFORMS": "cpu", "TPU_SKIP_MDS_QUERY": "true",
-               "PADDLE_TPU_COMPILE_CACHE": str(tmp_path / "cc")}
+               "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")}
         sup = Supervisor(
             model="gpt_tiny", replicas=2,
             server_args=["--page-size", "8", "--max-seq-len", "96",

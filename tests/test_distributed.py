@@ -132,7 +132,7 @@ def test_sharded_matches_single_device():
 
 
 def test_collectives_in_shard_map():
-    from paddle_tpu.compat import shard_map
+    from jax import shard_map
     from paddle_tpu.distributed import collective as C
 
     hcg = get_hybrid_communicate_group()
@@ -155,7 +155,7 @@ def test_collectives_in_shard_map():
 
 
 def test_ring_attention_matches_full():
-    from paddle_tpu.compat import shard_map
+    from jax import shard_map
     from paddle_tpu.distributed.sp import ring_attention
     from paddle_tpu.ops.nn_functional import scaled_dot_product_attention
 
@@ -181,7 +181,7 @@ def test_ring_attention_matches_full():
 
 
 def test_ulysses_attention_matches_full():
-    from paddle_tpu.compat import shard_map
+    from jax import shard_map
     from paddle_tpu.distributed.sp import ulysses_attention
     from paddle_tpu.ops.nn_functional import scaled_dot_product_attention
 
@@ -206,7 +206,7 @@ def test_ulysses_attention_matches_full():
 
 
 def test_spmd_pipeline_matches_sequential():
-    from paddle_tpu.compat import shard_map
+    from jax import shard_map
     from paddle_tpu.distributed.pp import (pipeline_last_stage_value,
                                            spmd_pipeline)
 
@@ -304,7 +304,7 @@ def test_zigzag_permutation_roundtrip():
 
 
 def test_zigzag_ring_matches_full():
-    from paddle_tpu.compat import shard_map
+    from jax import shard_map
     from paddle_tpu.distributed.sp import ring_attention, zigzag_permutation
     from paddle_tpu.ops.nn_functional import scaled_dot_product_attention
 

@@ -195,7 +195,7 @@ def test_flash_lse_block_merge_matches_dense():
 def test_ring_flash_matches_dense(causal):
     """Ring attention with the flash hop (use_flash=True) over a 4-way
     sequence shard matches dense attention, fwd and grads."""
-    from paddle_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from paddle_tpu.distributed.sp import ring_attention
@@ -235,7 +235,7 @@ def test_ring_flash_matches_dense(causal):
 def test_zigzag_ring_flash_matches_dense():
     """Balanced zigzag causal ring on the flash hop: fwd + grads match
     dense attention after the layout permutation."""
-    from paddle_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from paddle_tpu.distributed.sp import ring_attention, zigzag_permutation

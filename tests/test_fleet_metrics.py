@@ -1,5 +1,5 @@
 """Fleet telemetry plane (r17): collector merge exactness, live SLO
-monitor, outlier detection, probe-failure taxonomy, crash flight
+monitor, outlier detection, probe-failure classification, crash flight
 recorder, and the router's fleet surface.
 
 The contracts this file pins (ISSUE r17 acceptance):
@@ -617,10 +617,10 @@ class TestFleetExposition:
 
 
 # ---------------------------------------------------------------------------
-# Probe-failure taxonomy (satellite)
+# Probe-failure classification (satellite)
 # ---------------------------------------------------------------------------
 
-class TestProbeTaxonomy:
+class TestProbeClassification:
     def test_classification_table(self):
         assert classify_probe_failure(None) == "malformed"
         assert classify_probe_failure(socket.timeout()) == "timeout"
@@ -637,7 +637,7 @@ class TestProbeTaxonomy:
 
     def test_monitor_loop_counts_refused_probes(self):
         """A live process on a dead port: every probe is REFUSED and
-        the taxonomy counter says so (the old code collapsed this
+        the classification counter says so (the old code collapsed this
         into a bare ok=False)."""
         sup = Supervisor(model="gpt_tiny", replicas=1,
                          probe_interval_s=0.05, probe_timeout_s=0.2,
@@ -1003,7 +1003,7 @@ class TestFleetE2E:
         and killing the replica drops it from the rollup (marked
         stale) instead of poisoning fleet totals."""
         env = {"JAX_PLATFORMS": "cpu", "TPU_SKIP_MDS_QUERY": "true",
-               "PADDLE_TPU_COMPILE_CACHE": str(tmp_path / "cc")}
+               "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")}
         sup = Supervisor(
             model="gpt_tiny", replicas=1,
             server_args=["--page-size", "8", "--max-seq-len", "96",

@@ -548,7 +548,7 @@ class TestServingSurface:
         from paddle_tpu.serving.supervisor import (FailoverRouter,
                                                    Supervisor)
         env = {"JAX_PLATFORMS": "cpu", "TPU_SKIP_MDS_QUERY": "true",
-               "PADDLE_TPU_COMPILE_CACHE": str(tmp_path / "cc")}
+               "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")}
         sup = Supervisor(
             model="gpt_tiny", replicas=1,
             server_args=["--page-size", "8", "--max-seq-len", "96",

@@ -426,27 +426,61 @@ class TestServerSpeculative:
 
 
 # ---------------------------------------------------------------------------
-# Persistent compile cache (env-gated)
+# Persistent compile cache (placed from outside)
 # ---------------------------------------------------------------------------
 
 class TestCompileCache:
-    def test_disabled_without_env(self, monkeypatch):
+    @pytest.fixture
+    def dir_updates(self, monkeypatch):
+        """Every ``jax_compilation_cache_dir`` update made in code,
+        recorded instead of applied."""
+        import jax
+        from paddle_tpu.core import compile_cache as cc
+        calls = []
+        real = jax.config.update
+
+        def update(name, val):
+            if name == "jax_compilation_cache_dir":
+                calls.append(val)
+            else:
+                real(name, val)
+        monkeypatch.setattr(jax.config, "update", update)
+        monkeypatch.setattr(cc, "_reset_jax_cache", lambda: None)
+        return calls
+
+    def test_variable_set_means_no_directory_set_in_code(
+            self, monkeypatch, tmp_path, dir_updates):
+        from paddle_tpu.core import compile_cache as cc
+        monkeypatch.setenv(cc.ENV_VAR, str(tmp_path / "outside"))
+        assert cc.enable_compile_cache() == str(tmp_path / "outside")
+        assert dir_updates == []
+
+    def test_variable_unset_means_the_fixed_checkout_directory(
+            self, monkeypatch, dir_updates):
         from paddle_tpu.core import compile_cache as cc
         monkeypatch.delenv(cc.ENV_VAR, raising=False)
-        monkeypatch.setattr(cc, "_enabled_dir", None)
-        assert cc.enable_compile_cache() is None
-        assert cc.compile_cache_dir() is None
+        monkeypatch.setattr(cc, "compile_cache_dir", lambda: None)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.enable_compile_cache() == os.path.join(repo,
+                                                         ".jax_cache")
+        # one fixed path: no pid, time or temporary name in it
+        assert dir_updates == [os.path.join(repo, ".jax_cache")]
 
-    def test_enable_writes_cache_files(self, tmp_path, monkeypatch):
+    def test_enable_writes_cache_files(self, tmp_path):
         import jax
         import jax.numpy as jnp
         from paddle_tpu.core import compile_cache as cc
-        monkeypatch.setattr(cc, "_enabled_dir", None)
         d = str(tmp_path / "cc")
-        assert cc.enable_compile_cache(d) == os.path.abspath(d)
-        # idempotent (and env no longer consulted once enabled)
-        assert cc.enable_compile_cache(d) == os.path.abspath(d)
-        jax.jit(lambda x: (x * 3 + 1).sum())(
-            jnp.ones((64, 64))).block_until_ready()
+        before = cc.compile_cache_dir()
+        try:
+            assert cc.enable_compile_cache(d) == os.path.abspath(d)
+            # idempotent
+            assert cc.enable_compile_cache(d) == os.path.abspath(d)
+            jax.jit(lambda x: (x * 3 + 1).sum())(
+                jnp.ones((64, 64))).block_until_ready()
+        finally:
+            cc.disable_compile_cache()
+            if before:
+                cc.enable_compile_cache(before)
         files = [f for _, _, fs in os.walk(d) for f in fs]
         assert files, "no executable persisted to the cache dir"

@@ -212,15 +212,11 @@ def run_chaos(replicas: int = 2, requests: int = 12, seed: int = 0,
                                   page_size, max_seq_len)
 
     log_dir = log_dir or tempfile.mkdtemp(prefix="pt-chaos-")
-    compile_cache = os.path.join(log_dir, "compile_cache")
     replica_env = {
         # CPU fast lane: the chaos contract is about control flow, not
         # the accelerator; replicas must not fight over a TPU
         "JAX_PLATFORMS": platform,
         "TPU_SKIP_MDS_QUERY": "true",
-        # warm resurrections/restarts: rebuilt engines re-read their
-        # prefill/decode programs instead of recompiling
-        "PADDLE_TPU_COMPILE_CACHE": compile_cache,
         "PT_FAULT_SEED": str(seed),
     }
     if replica_faults:
@@ -491,8 +487,6 @@ def run_disagg_chaos(requests: int = 8, seed: int = 0,
     replica_env = {
         "JAX_PLATFORMS": platform,
         "TPU_SKIP_MDS_QUERY": "true",
-        "PADDLE_TPU_COMPILE_CACHE": os.path.join(log_dir,
-                                                 "compile_cache"),
     }
     server_args = ["--page-size", str(page_size),
                    "--max-seq-len", str(max_seq_len),
@@ -690,8 +684,6 @@ def run_fleet_cache_chaos(requests: int = 8, seed: int = 0,
     replica_env = {
         "JAX_PLATFORMS": platform,
         "TPU_SKIP_MDS_QUERY": "true",
-        "PADDLE_TPU_COMPILE_CACHE": os.path.join(log_dir,
-                                                 "compile_cache"),
     }
     # --spill-mb: both sides of the lane need tiers (the peer exports
     # blobs from them, the fetcher lands blobs into them);
@@ -920,6 +912,7 @@ def run_autoscale_chaos(requests: int = 8, seed: int = 0,
     import numpy as np
 
     import flight_inspect
+    from paddle_tpu.core.place import refuse_chip_contention
     from paddle_tpu.serving.autoscaler import scan_marked_replicas
     from paddle_tpu.serving.server import client_request
     from paddle_tpu.serving.supervisor import _free_port, _rpc
@@ -946,10 +939,6 @@ def run_autoscale_chaos(requests: int = 8, seed: int = 0,
     env.update({
         "JAX_PLATFORMS": platform,
         "TPU_SKIP_MDS_QUERY": "true",
-        # shared across replicas AND supervisor generations: spawns
-        # after the first replica reuse its compiled programs
-        "PADDLE_TPU_COMPILE_CACHE": os.path.join(log_dir,
-                                                 "compile_cache"),
         "PT_AUTOSCALE_HOLD_S": str(hold_s),
     })
     cmd = [sys.executable, "-m", "paddle_tpu.serving.supervisor",
@@ -972,6 +961,9 @@ def run_autoscale_chaos(requests: int = 8, seed: int = 0,
     outcomes: List[Optional[Dict]] = [None] * requests
 
     def launch() -> subprocess.Popen:
+        # this process ran the reference outputs through JAX: the
+        # fleet's replicas must not need the chip it may hold
+        refuse_chip_contention(env, "the supervised fleet")
         return subprocess.Popen(cmd, stdout=sup_log,
                                 stderr=subprocess.STDOUT, env=env)
 
@@ -1282,6 +1274,7 @@ def run_roll_chaos(requests: int = 8, seed: int = 0,
     import numpy as np
 
     import flight_inspect
+    from paddle_tpu.core.place import refuse_chip_contention
     from paddle_tpu.distributed.resilience import \
         ResilientCheckpointManager
     from paddle_tpu.inference import create_decode_engine
@@ -1349,8 +1342,6 @@ def run_roll_chaos(requests: int = 8, seed: int = 0,
     env.update({
         "JAX_PLATFORMS": platform,
         "TPU_SKIP_MDS_QUERY": "true",
-        "PADDLE_TPU_COMPILE_CACHE": os.path.join(log_dir,
-                                                 "compile_cache"),
         "PT_AUTOSCALE_HOLD_S": str(hold_s),
     })
     # cooldown parked high AND min == the boot size: a pressure-driven
@@ -1378,6 +1369,9 @@ def run_roll_chaos(requests: int = 8, seed: int = 0,
     outcomes: List[Optional[Dict]] = [None] * requests
 
     def launch() -> subprocess.Popen:
+        # this process ran the reference outputs through JAX: the
+        # fleet's replicas must not need the chip it may hold
+        refuse_chip_contention(env, "the supervised fleet")
         return subprocess.Popen(cmd, stdout=sup_log,
                                 stderr=subprocess.STDOUT, env=env)
 

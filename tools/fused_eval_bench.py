@@ -60,7 +60,7 @@ def eval_fwd_ms(batch=128, steps=16, windows=3, fused=True):
         run = lambda: float(jitted(state["params"], state["buffers"],
                                    xs)[-1])
         run()
-        dt, _ = _timed_windows(run, n_windows=windows, on_tpu=True)
+        dt, _ = _timed_windows(run, n_windows=windows)
         return dt / steps * 1e3
     finally:
         fc.enable_fused_conv_eval(False)

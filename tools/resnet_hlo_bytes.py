@@ -5,7 +5,7 @@ target (AOT compile — nothing executes) and ranks every top-level
 instruction by the bytes it moves (sum of operand + result buffer
 sizes). This grounds the fused-backward kernel design in which
 round-trips actually carry the r4-measured ~27 GB of backward traffic
-(PROFILE_RESNET.json: the device trace shows conv fusions at 92% of
+(pre-round record: the device trace shows conv fusions at 92% of
 HBM peak — byte COUNT, not per-kernel efficiency, is the whole game).
 
 Usage: python tools/resnet_hlo_bytes.py [--top 40] [--out F.json]
