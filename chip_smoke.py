@@ -539,13 +539,13 @@ def _drive_server(tag, proc, port, prompts, new_tokens, mesh, rehearsal,
             f"reference")
     else:
         # on a TPU a shape the gates admit must have run the kernels
-        walk = [k for k in kernels if k.startswith("_decode")]
-        if not walk or (not mesh and "_argmax_kernel" not in kernels):
+        walk = [k for k in kernels if k.startswith("paged_decode")]
+        if not walk or (not mesh and "fused_argmax" not in kernels):
             raise RuntimeError(f"{tag}: the decode program lacks its "
                                f"Pallas kernels: {kernels}")
         say(f"{tag}: decode attention ran {walk[0]} "
             + ("(o-projection inside the kernel)"
-               if walk[0] == "_decode_fused_kernel" else
+               if walk[0] == "paged_decode_fused" else
                "(fused-epilogue gate closed: the o-projection weight "
                "is over its VMEM budget, XLA runs the matmul)"))
     if mesh:

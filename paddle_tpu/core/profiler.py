@@ -6,6 +6,19 @@ TPU-native equivalent of the reference's profiler
 events are collected in-process; device-side tracing delegates to
 ``jax.profiler`` (XLA/TPU trace → TensorBoard), and every RecordEvent also
 opens a ``jax.named_scope`` so markers show up inside XLA traces.
+
+Host phases (``host_phase`` / ``HostPhases``) say what a host thread
+does between two device programs. A phase is a
+``jax.profiler.TraceAnnotation("pt.host.<name>")``: whenever a profiler
+session runs (``jax.profiler.start_trace``, the server's ``profile``
+op, the benchmark's ``--trace 1``) it is an event on the profiler's
+host plane, in the same ``.xplane.pb`` and on the same clock as the
+device's ``XLA Ops`` / ``XLA Modules`` lines; with no session it costs
+a flag check. Given a ``HostPhases`` accumulator, the same enter and
+exit also read ``time.monotonic`` once each and add the phase's own
+time to it — the engine's step timeline is fed from there
+(``host_us``), always on. ``RecordEvent`` holds the same annotation, so
+the marker API and the engine reach the profiler by one path.
 """
 
 from __future__ import annotations
@@ -40,24 +53,119 @@ class _ProfilerState:
 
 _STATE = _ProfilerState()
 
+HOST_PREFIX = "pt.host."
+_TraceAnnotation = jax.profiler.TraceAnnotation
+_monotonic = time.monotonic
+
+
+class HostPhases:
+    """One thread's per-step accumulator of host-phase time.
+
+    ``us[name]`` holds the seconds spent in phase ``name`` since the
+    owner last emptied it (``take()``); ``t`` is the newest stamp any
+    phase read, so a caller that needs "now" at a phase boundary reads
+    no clock of its own. Phases never overlap: entering one inside
+    another pauses the outer (its annotation closes, its time stops)
+    and resumes it on exit, so the sum over names is wall time spent
+    inside phases, each second counted once. Not thread-safe: one
+    accumulator belongs to one thread at a time (the engine's)."""
+
+    __slots__ = ("us", "t", "_open")
+
+    def __init__(self):
+        self.us: dict = {}
+        self.t = 0.0
+        self._open: Optional["_Phase"] = None
+
+    def phase(self, name: str) -> "_Phase":
+        """``with acc.phase(name):`` — ``host_phase(name)`` whose own
+        time is also added to ``acc.us[name]``."""
+        return _Phase(self, name)
+
+    def take(self) -> dict:
+        """The accumulated ``{phase: seconds}``, and start afresh."""
+        out, self.us = self.us, {}
+        return out
+
+
+class _Phase:
+    """One ``with acc.phase(name):`` block. ``t0`` / ``t1`` are its
+    entry and exit on ``time.monotonic`` (for callers that feed an
+    older counter or a span from the same stamps). The annotation's
+    own enter and exit fall inside the stamps, so what a phase costs
+    is counted as that phase's."""
+
+    __slots__ = ("name", "t0", "t1", "_acc", "_outer", "_since", "_ann")
+
+    def __init__(self, acc: HostPhases, name: str):
+        self._acc = acc
+        self.name = name
+
+    def _run(self, now: float) -> None:
+        """Start, or resume after an inner phase: a new annotation."""
+        self._since = now
+        self._ann = ann = _TraceAnnotation(HOST_PREFIX + self.name)
+        ann.__enter__()
+
+    def _halt(self) -> float:
+        """Stop, or pause for an inner phase; returns the stamp, which
+        the phase that runs next starts from (one clock read for both)."""
+        self._ann.__exit__(None, None, None)
+        acc = self._acc
+        acc.t = now = _monotonic()
+        acc.us[self.name] = acc.us.get(self.name, 0.0) + (now - self._since)
+        return now
+
+    def __enter__(self) -> "_Phase":
+        acc = self._acc
+        outer = self._outer = acc._open
+        if outer is not None:
+            now = outer._halt()
+        else:
+            acc.t = now = _monotonic()
+        self.t0 = now
+        acc._open = self
+        self._run(now)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = now = self._halt()
+        outer = self._acc._open = self._outer
+        if outer is not None:
+            outer._run(now)
+        return False
+
+
+def host_phase(name: str):
+    """The profiler annotation ``pt.host.<name>`` alone, for a phase
+    whose thread keeps no timeline (``HostPhases.phase`` is the one
+    that also counts)."""
+    return _TraceAnnotation(HOST_PREFIX + name)
+
 
 class RecordEvent:
-    """RAII host-event marker; nests a jax.named_scope for device traces."""
+    """RAII host-event marker; nests a jax.named_scope for device
+    traces and a profiler annotation of the same name for the host
+    plane (see ``host_phase``)."""
 
     def __init__(self, name: str, annotation: Optional[str] = None):
         self.name = name
         self.annotation = annotation
         self._scope = None
+        self._ann = None
         self._start = 0.0
 
     def __enter__(self):
         self._start = time.perf_counter() * 1e6
+        self._ann = _TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._scope = jax.named_scope(self.name)
         self._scope.__enter__()
         return self
 
     def __exit__(self, *exc):
         self._scope.__exit__(*exc)
+        self._ann.__exit__(*exc)
         if _STATE.enabled or get_flag("profiler_enabled"):
             evt = _Event(self.name, self._start, time.perf_counter() * 1e6,
                          threading.get_ident(), self.annotation)

@@ -81,15 +81,25 @@ import collections
 import contextlib
 import dataclasses
 import re
+import threading
 import time
 from typing import (Any, Callable, Dict, Hashable, List, Optional,
                     Sequence, Tuple)
 
 import numpy as np
 
+from ..core.profiler import HostPhases
+
 __all__ = ["PageAllocator", "DecodeRequest", "RequestStats",
            "ContinuousBatchingEngine", "create_decode_engine",
-           "SwapFailed"]
+           "SwapFailed", "HOST_PHASES"]
+
+# The host phases inside an engine step, in the order the single-step
+# path runs them (ContinuousBatchingEngine._tl_commit documents each).
+# With `commit` (the timeline's own cost) and `loop` (the caller
+# between two steps, `gap_us`) they partition the stepping thread's
+# time from one step's start to the next.
+HOST_PHASES = ("admit", "upload", "launch", "wait", "emit")
 
 
 class SwapFailed(RuntimeError):
@@ -791,11 +801,24 @@ class ContinuousBatchingEngine:
         # submit, so there is NO per-token cost for unsampled work)
         self._tracer = tracer
         # step timeline (r16): a fixed-size ring of per-step records —
-        # programs launched by kind, decode/verify/chunk/splice wall
-        # ms, slot occupancy and page pressure. Always on: one small
-        # dict per ENGINE STEP (never per token) next to a jit launch.
+        # programs launched by kind, the engine thread's host phases
+        # (`host_us`, see _tl_commit), slot occupancy and page
+        # pressure. Always on: one small dict per ENGINE STEP (never
+        # per token) next to a jit launch.
         self.timeline: "collections.deque" = collections.deque(
             maxlen=max(1, int(timeline_steps)))
+        # host phases of the thread that steps the engine
+        # (core/profiler.py): every `with self._phase(name)` adds its
+        # own time to `_host.us` and is a `pt.host.<name>` event of a
+        # running profiler session. The names are HOST_PHASES and
+        # `commit`; a phase entered inside another pauses the outer
+        # one. step() empties the accumulator, _tl_commit stores it.
+        self._host = HostPhases()
+        self._phase = self._host.phase
+        # where the previous record's commit ended (monotonic) and the
+        # stepping thread's CPU clock there: `gap_us` and `cpu_us`
+        self._tl_end: Optional[float] = None
+        self._tl_cpu: Tuple[int, int] = (0, 0)
         # drained-macro attribution for the NEXT _tl_commit (r19)
         self._tl_macro: Optional[Dict[str, Any]] = None
         # cumulative program launches by kind (every jit call — 1 per
@@ -1293,18 +1316,64 @@ class ContinuousBatchingEngine:
     # -- step timeline + program cost capture (r16) -------------------------
 
     def _tl_commit(self, t_step: float) -> None:
-        """Append one fixed-size step-timeline record (bounded ring)."""
-        now = time.monotonic()
+        """Append one fixed-size step-timeline record (bounded ring).
+
+        Besides occupancy and page pressure a record says where the
+        stepping thread's time went, all on ``time.monotonic``:
+
+        - ``ms``: the whole step, from its start (``t_us``) to the
+          start of this commit.
+        - ``host_us``: ``{phase: µs}`` of the phases inside ``ms``
+          (HOST_PHASES: ``admit``, ``upload``, ``launch``, ``wait``,
+          ``emit``) and ``other``, what they leave of ``ms``. Phases
+          never overlap. ``launch`` is every jit CALL (it returns
+          futures), ``wait`` every blocking read of a device result.
+        - ``commit_us``: this method itself (page accounting for every
+          slot, ``occupancy()``, the record) — what the always-on
+          timeline costs. It runs after ``ms`` is taken.
+        - ``gap_us``: end of the previous record's commit to this
+          step's start — the caller's loop between two steps (the
+          server's inbox drain and swap check; also its sleep when
+          the previous step left nothing to do).
+        - ``cpu_us``: the stepping thread's CPU time
+          (``time.thread_time_ns``) over gap + step + commit; left out
+          when the previous step ran on another thread.
+
+        The older ``_tl_ms`` keys are fed from the same stamps:
+        ``decode_ms`` is the decode program's ``launch`` phase on the
+        single-step path — the DISPATCH, not the decode (on a device
+        the call returns before the program has run; the device's
+        time is in ``wait``) — and dispatch-to-drain of the launch
+        under ``multi_step``; ``prefill_ms`` is upload + launch + the
+        blocking read of the first token; ``chunk_ms`` / ``verify_ms``
+        are upload + launch of those programs, ``splice_ms`` the
+        launch; ``overlap_idle_ms`` is the ``wait`` of the macro
+        drain."""
+        with self._phase("commit") as commit:
+            entry = self._tl_record(t_step, commit.t0)
+        end = commit.t1
+        entry["commit_us"] = round((end - commit.t0) * 1e6, 1)
+        ident, cpu = threading.get_ident(), time.thread_time_ns()
+        if self._tl_cpu[0] == ident:
+            entry["cpu_us"] = round((cpu - self._tl_cpu[1]) * 1e-3, 1)
+        self._tl_end, self._tl_cpu = end, (ident, cpu)
+
+    def _tl_record(self, t_step: float, now: float) -> Dict[str, Any]:
         # per-request page attribution (r18): one pass over the slots
         # per STEP (never per token) keeps peak-pages/page-seconds
         # current for long-running requests
         for r in self._slots:
             if r is not None:
                 self._account_req_pages(r, now)
+        host = {k: round(v * 1e6, 1) for k, v in self._host.take().items()}
+        host["other"] = round((now - t_step) * 1e6 - sum(host.values()), 1)
         entry: Dict[str, Any] = {
             "step": self.steps,
             "t_us": t_step * 1e6,
             "ms": round((now - t_step) * 1e3, 4),
+            "host_us": host,
+            "gap_us": 0.0 if self._tl_end is None
+            else round((t_step - self._tl_end) * 1e6, 1),
             "programs": self._tl_programs,
             "slots_active": self.num_active,
             "slots_decoding": sum(
@@ -1331,6 +1400,7 @@ class ContinuousBatchingEngine:
             entry["macro"] = self._tl_macro
             self._tl_macro = None
         self.timeline.append(entry)
+        return entry
 
     def step_timeline(self) -> List[Dict[str, Any]]:
         """Snapshot of the per-step ring (oldest first) — the server's
@@ -1373,6 +1443,13 @@ class ContinuousBatchingEngine:
 
     def _tl_add_ms(self, key: str, seconds: float) -> None:
         self._tl_ms[key] = self._tl_ms.get(key, 0.0) + seconds * 1e3
+
+    def _phase_seconds(self, *names: str) -> float:
+        """Seconds this step has spent in the named phases so far: a
+        difference of two readings is an interval on the phases' own
+        stamps, with no clock read of its own."""
+        us = self._host.us
+        return sum(us.get(n, 0.0) for n in names)
 
     def _capture_cost(self, kind: str, jitfn, args: Tuple) -> None:
         """Capture flops / bytes-accessed estimates for ``kind`` from
@@ -1519,11 +1596,13 @@ class ContinuousBatchingEngine:
             # spill-side device IO: the page's KV is leaving the
             # device for a spill tier (the cache decides which)
             self.ledger.record("spill", None, pages=[int(page)])
-        k, v, ks, vs = self._gather_jit(
-            self._pools, jnp.asarray(page, jnp.int32))
-        k, v = np.asarray(k), np.asarray(v)
-        ks = None if ks is None else np.asarray(ks)
-        vs = None if vs is None else np.asarray(vs)
+        with self._phase("launch"):
+            k, v, ks, vs = self._gather_jit(
+                self._pools, jnp.asarray(page, jnp.int32))
+        with self._phase("wait"):
+            k, v = np.asarray(k), np.asarray(v)
+            ks = None if ks is None else np.asarray(ks)
+            vs = None if vs is None else np.asarray(vs)
         return [(k[i], v[i],
                  None if ks is None else ks[i],
                  None if vs is None else vs[i])
@@ -1582,11 +1661,12 @@ class ContinuousBatchingEngine:
             # whole contiguous run (padding targets scratch, excluded)
             self.ledger.record("splice", None,
                                pages=[int(p) for p in pages])
-        args = (self._pools, jnp.asarray(page_idx), k, v, ks, vs)
-        t0 = time.monotonic()
-        with count_op_calls() as c:
-            self._pools = self._splice_jit(*args)
-        self._tl_add_ms("splice_ms", time.monotonic() - t0)
+        with self._phase("upload"):
+            args = (self._pools, jnp.asarray(page_idx), k, v, ks, vs)
+        with self._phase("launch") as launch:
+            with count_op_calls() as c:
+                self._pools = self._splice_jit(*args)
+        self._tl_add_ms("splice_ms", launch.t1 - launch.t0)
         self._record_programs("restore", c.count)
         if c.count:
             self._capture_cost("restore", self._splice_jit, args)
@@ -2555,19 +2635,21 @@ class ContinuousBatchingEngine:
             self._check_pools_live("prefill")
             fault_point("serving.prefill")
             kind = "prefill_chained" if chained else "prefill"
-            args = (self._fresh_state(refresh=True), self._pools,
-                    jnp.asarray(row[None]),
-                    jnp.asarray([cached_len], jnp.int32),
-                    jnp.asarray([len(suffix)], jnp.int32),
-                    jnp.asarray(ids))
-            with count_op_calls() as c:
-                out = jit(*args)
+            with self._phase("upload"):
+                args = (self._fresh_state(refresh=True), self._pools,
+                        jnp.asarray(row[None]),
+                        jnp.asarray([cached_len], jnp.int32),
+                        jnp.asarray([len(suffix)], jnp.int32),
+                        jnp.asarray(ids))
+            with self._phase("launch"):
+                with count_op_calls() as c:
+                    out = jit(*args)
             self._record_programs(kind, c.count)
             if c.count:
                 self._capture_cost(kind, jit, args)
             return out
 
-        t0 = time.monotonic()
+        spent = self._phase_seconds("upload", "launch", "wait")
         try:
             if self._prefill_retry is not None:
                 nxt, pools = self._prefill_retry.call(
@@ -2588,57 +2670,65 @@ class ContinuousBatchingEngine:
             self._unwind_prefill_failure(slot, req)
             raise
         self._pools = pools
-        now = time.monotonic()
-        req.stats.prefill_ms = (now - t0) * 1e3
-        self._tl_add_ms("prefill_ms", now - t0)
+        with self._phase("wait"):
+            tok = int(nxt)  # blocks until the prefill program has run
+        # the first token exists from here: `now` is the end of that
+        # wait, and prefill_ms the argument build, the dispatch and
+        # the wait together, from the phases' own stamps
+        now = self._host.t
+        dt = self._phase_seconds("upload", "launch", "wait") - spent
+        req.stats.prefill_ms = dt * 1e3
+        self._tl_add_ms("prefill_ms", dt)
         if tr is not None:
             tr.end(sp_pref, ms=round(req.stats.prefill_ms, 3))
         req.stats.prefill_attempts += 1
         req.stats.prefill_chunks = 1  # whole prefill = one launch
-        if req.deadline_t is not None and now >= req.deadline_t:
-            # deadline expired MID-PREFILL: the forward pass is paid
-            # for, but delivering a token past the deadline breaks the
-            # contract — unwind the admission typed instead (pools were
-            # adopted above, so device state stays coherent)
-            self._account_req_pages(req, now)
-            if self.ledger is not None:
-                # same forensics contract as _evict_slot's deadline
-                # path: snapshot the page history BEFORE free rewrites
-                # it so the typed reply can carry it
-                req.page_forensics = self.ledger.history_for_owner(
-                    req.req_id)
-            with self._led("deadline", req.req_id):
-                self.allocator.free(req.req_id)
-                if cache is not None:
-                    cache.release(keys)
-                    req.cache_keys = ()
-            self._table[slot] = self._scratch
-            req.state = "deadline"
-            req.done = True
-            req.stats.finish_t = now
-            self._notify_complete(req)
-            return None
-        req.stats.first_token_t = now
-        self._lens[slot] = len(req.prompt)
-        self._cur[slot] = int(nxt)
-        req.slot = slot
-        req.state = "decoding"
-        req.generated.append(int(nxt))
-        req.stats.tokens_out = 1
-        if cache is not None:
-            # the slot's full prompt pages now hold valid KV — hand
-            # them to the cache (ownership transfer, refcount held by
-            # this request until it finishes)
-            req.cache_keys = cache.insert(
-                req.prompt, row, self.allocator, req.req_id,
-                self.page_size, keys,
-                device_hits=getattr(req, "_pfx_device_hits", None))
-        self._slots[slot] = req
-        if tr is not None:
-            tr.event("first_token", parent=tr.anchor, token=int(nxt))
-            req.span = tr.begin("decode", parent=tr.anchor)
-        self._emit_token(req, int(nxt))
-        self._maybe_finish(slot)
+        with self._phase("emit"):
+            if req.deadline_t is not None and now >= req.deadline_t:
+                # deadline expired MID-PREFILL: the forward pass is
+                # paid for, but delivering a token past the deadline
+                # breaks the contract — unwind the admission typed
+                # instead (pools were adopted above, so device state
+                # stays coherent)
+                self._account_req_pages(req, now)
+                if self.ledger is not None:
+                    # same forensics contract as _evict_slot's
+                    # deadline path: snapshot the page history BEFORE
+                    # free rewrites it so the typed reply can carry it
+                    req.page_forensics = self.ledger.history_for_owner(
+                        req.req_id)
+                with self._led("deadline", req.req_id):
+                    self.allocator.free(req.req_id)
+                    if cache is not None:
+                        cache.release(keys)
+                        req.cache_keys = ()
+                self._table[slot] = self._scratch
+                req.state = "deadline"
+                req.done = True
+                req.stats.finish_t = now
+                self._notify_complete(req)
+                return None
+            req.stats.first_token_t = now
+            self._lens[slot] = len(req.prompt)
+            self._cur[slot] = tok
+            req.slot = slot
+            req.state = "decoding"
+            req.generated.append(tok)
+            req.stats.tokens_out = 1
+            if cache is not None:
+                # the slot's full prompt pages now hold valid KV — hand
+                # them to the cache (ownership transfer, refcount held
+                # by this request until it finishes)
+                req.cache_keys = cache.insert(
+                    req.prompt, row, self.allocator, req.req_id,
+                    self.page_size, keys,
+                    device_hits=getattr(req, "_pfx_device_hits", None))
+            self._slots[slot] = req
+            if tr is not None:
+                tr.event("first_token", parent=tr.anchor, token=tok)
+                req.span = tr.begin("decode", parent=tr.anchor)
+            self._emit_token(req, tok)
+            self._maybe_finish(slot)
         return True
 
     # -- chunked prefill (r11) ---------------------------------------------
@@ -2707,13 +2797,15 @@ class ContinuousBatchingEngine:
             self._check_pools_live("prefill")
             fault_point("serving.prefill")
             kind = "prefill_chained" if chained else "prefill"
-            args = (self._fresh_state(refresh=True), self._pools,
-                    jnp.asarray(row[None]),
-                    jnp.asarray([done], jnp.int32),
-                    jnp.asarray([len(suffix)], jnp.int32),
-                    jnp.asarray(ids))
-            with count_op_calls() as c:
-                out = jit(*args)
+            with self._phase("upload"):
+                args = (self._fresh_state(refresh=True), self._pools,
+                        jnp.asarray(row[None]),
+                        jnp.asarray([done], jnp.int32),
+                        jnp.asarray([len(suffix)], jnp.int32),
+                        jnp.asarray(ids))
+            with self._phase("launch"):
+                with count_op_calls() as c:
+                    out = jit(*args)
             self._record_programs(kind, c.count)
             if c.count:
                 self._capture_cost(kind, jit, args)
@@ -2724,7 +2816,7 @@ class ContinuousBatchingEngine:
                              idx=req.stats.prefill_chunks,
                              done_tokens=done)
                     if tr is not None else None)
-        t0 = time.monotonic()
+        spent = self._phase_seconds("upload", "launch")
         try:
             if self._prefill_retry is not None:
                 nxt, pools = self._prefill_retry.call(
@@ -2739,14 +2831,16 @@ class ContinuousBatchingEngine:
             self._unwind_prefill_failure(slot, req)
             raise
         self._pools = pools
-        now = time.monotonic()
-        self._tl_add_ms("chunk_ms", now - t0)
+        # the chunk's argument build and dispatch (no blocking read: a
+        # chunk's result is read only after the last one, below)
+        now = self._host.t
+        dt = self._phase_seconds("upload", "launch") - spent
+        self._tl_add_ms("chunk_ms", dt)
         if tr is not None:
             tr.end(sp_chunk, tokens=len(suffix))
-        req.stats.prefill_ms += (now - t0) * 1e3
+        req.stats.prefill_ms += dt * 1e3
         req.stats.prefill_chunks += 1
         if self._chunk_warm[chained]:
-            dt = now - t0
             self.prefill_chunk_ema_s = dt \
                 if self.prefill_chunk_ema_s is None \
                 else 0.8 * self.prefill_chunk_ema_s + 0.2 * dt
@@ -2774,28 +2868,33 @@ class ContinuousBatchingEngine:
             return True
         # last chunk: its logits ARE the whole prefill's logits — emit
         # the first token and promote the slot to the decode batch
-        req.stats.prefill_attempts += 1
-        req.stats.first_token_t = now
-        self._cur[slot] = int(nxt)
-        req.state = "decoding"
-        req.generated.append(int(nxt))
-        req.stats.tokens_out = 1
-        if tr is not None:
-            # close the chunked "prefill" stage, mark the first token,
-            # and open the decode stage — same shape as whole prefill
-            self._tr_end(req, chunks=req.stats.prefill_chunks)
-            tr.event("first_token", parent=tr.anchor, token=int(nxt))
-            req.span = tr.begin("decode", parent=tr.anchor)
-        if cache is not None:
-            # the slot's full prompt pages now hold valid KV — hand
-            # them to the cache (ownership transfer; the matched keys
-            # from admission are the already-acquired chain head)
-            req.cache_keys = cache.insert(
-                req.prompt, row, self.allocator, req.req_id,
-                self.page_size, req.cache_keys,
-                device_hits=getattr(req, "_pfx_device_hits", None))
-        self._emit_token(req, int(nxt))
-        self._maybe_finish(slot)
+        with self._phase("wait"):
+            tok = int(nxt)
+        with self._phase("emit"):
+            req.stats.prefill_attempts += 1
+            req.stats.first_token_t = now
+            self._cur[slot] = tok
+            req.state = "decoding"
+            req.generated.append(tok)
+            req.stats.tokens_out = 1
+            if tr is not None:
+                # close the chunked "prefill" stage, mark the first
+                # token, and open the decode stage — same shape as
+                # whole prefill
+                self._tr_end(req, chunks=req.stats.prefill_chunks)
+                tr.event("first_token", parent=tr.anchor, token=tok)
+                req.span = tr.begin("decode", parent=tr.anchor)
+            if cache is not None:
+                # the slot's full prompt pages now hold valid KV — hand
+                # them to the cache (ownership transfer; the matched
+                # keys from admission are the already-acquired chain
+                # head)
+                req.cache_keys = cache.insert(
+                    req.prompt, row, self.allocator, req.req_id,
+                    self.page_size, req.cache_keys,
+                    device_hits=getattr(req, "_pfx_device_hits", None))
+            self._emit_token(req, tok)
+            self._maybe_finish(slot)
         return True
 
     def _plan_inprogram_chunks(self) -> Optional[Dict[str, Any]]:
@@ -2992,24 +3091,26 @@ class ContinuousBatchingEngine:
             jit = self._build_multi_decode(has_chunk)
             self._multi_jits[has_chunk] = jit
         from ..dispatch import count_op_calls
-        args = [self._fresh_state(), self._pools,
-                jnp.asarray(self._table), jnp.asarray(self._lens),
-                jnp.asarray(self._cur), jnp.asarray(active),
-                jnp.asarray(rem), jnp.asarray(eos)]
-        if spec_on:
-            hist, hlen = self._macro_hist(chunk_plan)
-            args += [jnp.asarray(hist), jnp.asarray(hlen)]
-        if has_chunk:
-            args += [jnp.asarray(chunk_plan["ids"]),
-                     jnp.asarray(chunk_plan["valid"]),
-                     jnp.asarray(chunk_plan["start"]),
-                     jnp.asarray(chunk_plan["final"]),
-                     jnp.asarray(np.int32(chunk_plan["count"])),
-                     jnp.asarray(np.int32(chunk_plan["slot"]))]
-        args = tuple(args)
-        t0 = time.monotonic()
-        with count_op_calls() as c:
-            ring, nsteps, cur, lens, act, pools = jit(*args)
+        with self._phase("upload"):
+            args = [self._fresh_state(), self._pools,
+                    jnp.asarray(self._table), jnp.asarray(self._lens),
+                    jnp.asarray(self._cur), jnp.asarray(active),
+                    jnp.asarray(rem), jnp.asarray(eos)]
+            if spec_on:
+                hist, hlen = self._macro_hist(chunk_plan)
+                args += [jnp.asarray(hist), jnp.asarray(hlen)]
+            if has_chunk:
+                args += [jnp.asarray(chunk_plan["ids"]),
+                         jnp.asarray(chunk_plan["valid"]),
+                         jnp.asarray(chunk_plan["start"]),
+                         jnp.asarray(chunk_plan["final"]),
+                         jnp.asarray(np.int32(chunk_plan["count"])),
+                         jnp.asarray(np.int32(chunk_plan["slot"]))]
+            args = tuple(args)
+        with self._phase("launch") as launch:
+            with count_op_calls() as c:
+                ring, nsteps, cur, lens, act, pools = jit(*args)
+        t0 = launch.t0
         self._record_programs("decode_multi", c.count)
         if c.count:
             self._capture_cost("decode_multi", jit, args)
@@ -3019,7 +3120,7 @@ class ContinuousBatchingEngine:
             "ring": ring, "nsteps": nsteps, "cur": cur, "lens": lens,
             "reqs": reqs, "t_dispatch": t0,
             "launch": self.macro_launches,
-            "dispatch_ms": (time.monotonic() - t0) * 1e3,
+            "dispatch_ms": (launch.t1 - t0) * 1e3,
             "rem": rem, "chunk": chunk_plan,
         }
         return True
@@ -3041,13 +3142,21 @@ class ContinuousBatchingEngine:
         # cleared BEFORE the blocking read: a failed async computation
         # raises here, and retrying dead handles would only re-raise
         self._pending_macro = None
-        t_wait = time.monotonic()
-        ring = np.asarray(pend["ring"])  # blocks until the launch ends
-        idle_s = time.monotonic() - t_wait
-        nsteps = int(pend["nsteps"])
-        lens_f = np.asarray(pend["lens"])
-        cur_f = np.asarray(pend["cur"])
-        now = time.monotonic()
+        with self._phase("wait") as wait:
+            # the first read blocks until the launch ends
+            for k in ("ring", "lens", "cur"):
+                pend[k] = np.asarray(pend[k])
+            pend["nsteps"] = int(pend["nsteps"])
+        with self._phase("emit"):
+            return self._fold_macro(pend, wait.t1 - wait.t0, wait.t1)
+
+    def _fold_macro(self, pend: Dict[str, Any], idle_s: float,
+                    now: float) -> List[Tuple]:
+        """The host half of ``_drain_macro``: fold a drained launch's
+        ring (host arrays by now; ``idle_s`` is how long the drain
+        blocked, ``now`` its end) into the slots and the timeline."""
+        ring, nsteps = pend["ring"], pend["nsteps"]
+        lens_f, cur_f = pend["lens"], pend["cur"]
         self._last_macro_t = now
         dt = now - pend["t_dispatch"]
         # per-MACRO-LAUNCH decode EMA (the r19 satellite):
@@ -3257,15 +3366,19 @@ class ContinuousBatchingEngine:
         for requests that finished inside the launch. Runs AFTER the
         next launch is dispatched, so callback/tracing/metrics work
         overlaps device compute."""
-        while self._pending_emit:
-            req, tok, done = self._pending_emit.pop(0)
-            req.last_emit_t = time.monotonic()
-            if req.on_token is not None:
-                req.on_token(req.req_id, tok, done)
-            if done and req.done:
-                # the request's terminal bookkeeping ran at drain
-                # (notify deferred to exactly here, after its tokens)
-                self._notify_complete(req)
+        if not self._pending_emit:
+            return
+        with self._phase("emit"):
+            while self._pending_emit:
+                req, tok, done = self._pending_emit.pop(0)
+                req.last_emit_t = time.monotonic()
+                if req.on_token is not None:
+                    req.on_token(req.req_id, tok, done)
+                if done and req.done:
+                    # the request's terminal bookkeeping ran at drain
+                    # (notify deferred to exactly here, after its
+                    # tokens)
+                    self._notify_complete(req)
 
     def _macro_multi_step(self) -> int:
         """One multi-step boundary: drain launch K−1, run the host
@@ -3277,9 +3390,10 @@ class ContinuousBatchingEngine:
             self._pending_emit.extend(emissions)
         # the INNER sweeps: the public wrappers would flush-and-
         # deliver the emissions just drained, forfeiting the overlap
-        self._expire_deadlines_inner()
-        self._evict_stalled_inner()
-        self._admit()
+        with self._phase("admit"):
+            self._expire_deadlines_inner()
+            self._evict_stalled_inner()
+            self._admit()
         if self.num_active == 0:
             self._deliver_pending()
             return 0
@@ -3407,75 +3521,83 @@ class ContinuousBatchingEngine:
             self._ensure_pages(i, req, int(old_lens[i]) + int(valid[i]))
         if self._verify_jit is None:
             self._verify_jit = self._build_verify()
-        if cfg.temperature and self._spec_key is None:
-            self._spec_key = jax.random.PRNGKey(cfg.seed)
-        if cfg.temperature:
-            self._spec_key, key = jax.random.split(self._spec_key)
-        else:
-            key = jax.random.PRNGKey(0)  # unused on the greedy path
+        with self._phase("upload"):  # the key is a device argument too
+            if cfg.temperature and self._spec_key is None:
+                self._spec_key = jax.random.PRNGKey(cfg.seed)
+            if cfg.temperature:
+                self._spec_key, key = jax.random.split(self._spec_key)
+            else:
+                key = jax.random.PRNGKey(0)  # unused on the greedy path
+
+        stamps: List[float] = []  # the start of each attempt's upload
 
         def run_verify():
             from ..dispatch import count_op_calls
             from ..distributed.fault_inject import fault_point
             self._check_pools_live("verify")
             fault_point("serving.verify")
-            args = (self._fresh_state(), self._pools,
-                    jnp.asarray(self._table), jnp.asarray(self._lens),
-                    jnp.asarray(tokens), jnp.asarray(valid), key)
-            with count_op_calls() as c:
-                out = self._verify_jit(*args)
+            with self._phase("upload") as upload:
+                args = (self._fresh_state(), self._pools,
+                        jnp.asarray(self._table), jnp.asarray(self._lens),
+                        jnp.asarray(tokens), jnp.asarray(valid), key)
+            stamps.append(upload.t0)
+            with self._phase("launch"):
+                with count_op_calls() as c:
+                    out = self._verify_jit(*args)
             self._record_programs("verify", c.count)
             if c.count:
                 self._capture_cost("verify", self._verify_jit, args)
             return out
 
-        t0v = time.monotonic()
         if self._verify_retry is not None:
             accept, resid, full, pools = self._verify_retry.call(
                 run_verify, site="serving.verify")
         else:
             accept, resid, full, pools = run_verify()
-        t1v = time.monotonic()
+        # first argument build to the end of the last dispatch
+        t0v, t1v = stamps[0], self._host.t
         self._tl_add_ms("verify_ms", t1v - t0v)
         self._pools = pools
-        accept = np.asarray(accept)
-        resid = np.asarray(resid)
-        full = np.asarray(full)
-        self.steps += 1
-        for i in active:
-            req = self._slots[i]
-            k_eff = int(valid[i]) - 1
-            n = 0
-            while n < k_eff and accept[i, n]:
-                n += 1
-            req.stats.spec_steps += 1
-            req.stats.spec_drafted += k_eff
-            req.stats.spec_accepted += n
-            if req.trace is not None:
-                req.trace.add("verify_step", t0v * 1e6, t1v * 1e6,
-                              parent=req.span, step=self.steps,
-                              drafted=k_eff, accepted=n)
-            nxt = int(resid[i, n]) if n < k_eff else int(full[i, k_eff])
-            emitted = [int(t) for t in tokens[i, 1:1 + n]] + [nxt]
-            finished = False
-            for tok in emitted:
-                req.generated.append(tok)
-                req.stats.tokens_out = len(req.generated)
-                self._cur[i] = tok
-                self._emit_token(req, tok)
-                if self._finish_due(req):
-                    finished = True
-                    break  # EOS inside the accepted run: stop emitting
-            if finished:
-                # _maybe_finish frees the slot wholesale (pages AND
-                # remaining reservation) — no rollback bookkeeping
-                self._maybe_finish(i)
-                continue
-            # KV now validly covers cur + the n accepted drafts; the
-            # last emitted token's KV is written by the NEXT step
-            new_len = int(old_lens[i]) + n + 1
-            self._lens[i] = new_len
-            self._rollback_pages(i, req, new_len)
+        with self._phase("wait"):
+            accept = np.asarray(accept)
+            resid = np.asarray(resid)
+            full = np.asarray(full)
+        with self._phase("emit"):
+            self.steps += 1
+            for i in active:
+                req = self._slots[i]
+                k_eff = int(valid[i]) - 1
+                n = 0
+                while n < k_eff and accept[i, n]:
+                    n += 1
+                req.stats.spec_steps += 1
+                req.stats.spec_drafted += k_eff
+                req.stats.spec_accepted += n
+                if req.trace is not None:
+                    req.trace.add("verify_step", t0v * 1e6, t1v * 1e6,
+                                  parent=req.span, step=self.steps,
+                                  drafted=k_eff, accepted=n)
+                nxt = int(resid[i, n]) if n < k_eff else int(full[i, k_eff])
+                emitted = [int(t) for t in tokens[i, 1:1 + n]] + [nxt]
+                finished = False
+                for tok in emitted:
+                    req.generated.append(tok)
+                    req.stats.tokens_out = len(req.generated)
+                    self._cur[i] = tok
+                    self._emit_token(req, tok)
+                    if self._finish_due(req):
+                        finished = True
+                        break  # EOS inside the accepted run: stop emitting
+                if finished:
+                    # _maybe_finish frees the slot wholesale (pages AND
+                    # remaining reservation) — no rollback bookkeeping
+                    self._maybe_finish(i)
+                    continue
+                # KV now validly covers cur + the n accepted drafts; the
+                # last emitted token's KV is written by the NEXT step
+                new_len = int(old_lens[i]) + n + 1
+                self._lens[i] = new_len
+                self._rollback_pages(i, req, new_len)
         return self.num_active
 
     def step(self) -> int:
@@ -3495,6 +3617,7 @@ class ContinuousBatchingEngine:
         # token — next to at least one jit launch)
         self._tl_programs = {}
         self._tl_ms = {}
+        self._host.take()  # phases outside a step belong to no record
         if self.ledger is not None:
             self.ledger.step = self.steps
         t_step = time.monotonic()
@@ -3515,20 +3638,24 @@ class ContinuousBatchingEngine:
             # verify, host draft sources) keep their per-step verify
             # cadence — spec composes AT the boundary for them.
             return self._macro_multi_step()
-        self.expire_deadlines()
-        self.evict_stalled()
-        self._admit()
-        if self.num_active == 0:
-            return 0
+        with self._phase("admit"):
+            self.expire_deadlines()
+            self.evict_stalled()
+            self._admit()
+            if self.num_active == 0:
+                return 0
         if self.prefill_chunk_tokens is not None:
             self._advance_prefill_chunk()
-        if not any(r is not None and r.state == "decoding"
-                   for r in self._slots):
-            # everything active is still mid-prefill (chunked mode):
-            # no decode step to run; the next step() advances the next
-            # chunk. num_active keeps run() looping.
-            return self.num_active
-        t0 = time.monotonic()
+            if not any(r is not None and r.state == "decoding"
+                       for r in self._slots):
+                # everything active is still mid-prefill (only chunked
+                # admission leaves a slot so): no decode step to run;
+                # the next step() advances the next chunk. num_active
+                # keeps run() looping.
+                return self.num_active
+        # the decode EMA is fed from the phases' stamps: the last one
+        # before the decode/verify call, the last one inside it
+        t0 = self._host.t
         try:
             if self._spec_cfg is not None:
                 return self._spec_step()
@@ -3541,65 +3668,81 @@ class ContinuousBatchingEngine:
             # have their own EMA (_advance_prefill_chunk), so a
             # prefill-heavy step can't poison the per-token estimate.
             if self.steps > 1:
-                dt = time.monotonic() - t0
+                dt = self._host.t - t0
                 self.decode_ema_s = dt if self.decode_ema_s is None \
                     else 0.8 * self.decode_ema_s + 0.2 * dt
 
     def _decode_step(self) -> int:
-        jnp = self._jnp
-        if self._decode_jit is None:
-            self._decode_jit = self._build_decode()
-        decoding = np.array([r is not None and r.state == "decoding"
-                             for r in self._slots])
-        table, lens = self._table, self._lens
-        if any(r is not None and r.state == "prefill_partial"
-               for r in self._slots):
-            # half-prefilled slots ride the fixed-shape step MASKED to
-            # the scratch page at length 0: their pages hold a partial
-            # prompt whose next position the NEXT chunk owns — the
-            # decode append must not touch it (writes land on scratch,
-            # attention over an empty slot is defined zeros). Host
-            # lens/table keep the real values; only the device call
-            # sees the mask.
-            table = np.where(decoding[:, None], table,
-                             self._scratch).astype(np.int32)
-            lens = np.where(decoding, lens, 0).astype(np.int32)
         from ..dispatch import count_op_calls
-        args = (self._fresh_state(), self._pools,
-                jnp.asarray(table), jnp.asarray(lens),
-                jnp.asarray(self._cur))
-        t0d = time.monotonic()
-        with count_op_calls() as c:
-            nxt, pools, lens_new = self._decode_jit(*args)
-        t1d = time.monotonic()
+        jnp = self._jnp
+        with self._phase("upload"):
+            if self._decode_jit is None:
+                self._decode_jit = self._build_decode()
+            decoding = np.array([r is not None and r.state == "decoding"
+                                 for r in self._slots])
+            table, lens = self._table, self._lens
+            if any(r is not None and r.state == "prefill_partial"
+                   for r in self._slots):
+                # half-prefilled slots ride the fixed-shape step MASKED
+                # to the scratch page at length 0: their pages hold a
+                # partial prompt whose next position the NEXT chunk
+                # owns — the decode append must not touch it (writes
+                # land on scratch, attention over an empty slot is
+                # defined zeros). Host lens/table keep the real values;
+                # only the device call sees the mask.
+                table = np.where(decoding[:, None], table,
+                                 self._scratch).astype(np.int32)
+                lens = np.where(decoding, lens, 0).astype(np.int32)
+            args = (self._fresh_state(), self._pools,
+                    jnp.asarray(table), jnp.asarray(lens),
+                    jnp.asarray(self._cur))
+        with self._phase("launch") as launch:
+            with count_op_calls() as c:
+                nxt, pools, lens_new = self._decode_jit(*args)
+        # the DISPATCH of the decode program, not the decode: on a
+        # device the call returns futures, and the program's own time
+        # passes inside the `wait` phase below
+        t0d, t1d = launch.t0, launch.t1
         self._tl_add_ms("decode_ms", t1d - t0d)
         self._record_programs("decode", c.count)
         if c.count:
             self._capture_cost("decode", self._decode_jit, args)
         self._pools = pools
-        nxt = np.asarray(nxt)
-        # non-decoding slots wrote to the scratch page; keep their host
-        # length (0 for empty slots, prefill_done_len for half-
-        # prefilled ones)
-        self._lens = np.where(decoding, np.asarray(lens_new),
-                              self._lens).astype(np.int32)
-        self.steps += 1
-        for slot, req in enumerate(self._slots):
-            if req is None or req.state != "decoding":
-                continue
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            req.stats.tokens_out = len(req.generated)
-            self._cur[slot] = tok
-            if req.trace is not None:
-                # pre-timed closed span: one list append per traced
-                # in-flight request, no extra clock reads per slot
-                req.trace.add("decode_step", t0d * 1e6, t1d * 1e6,
-                              parent=req.span, step=self.steps,
-                              token=tok)
-            self._emit_token(req, tok)
-            self._maybe_finish(slot)
-        return self.num_active
+        with self._phase("wait"):
+            # the uploaded arguments and the donated pools' handles are
+            # let go while the program runs, not after the emit loop
+            # (some hundred objects at 24 layers; otherwise they go at
+            # this function's return, in no phase)
+            del args, pools
+            nxt = np.asarray(nxt)
+            lens_new = np.asarray(lens_new)
+        with self._phase("emit"):
+            # non-decoding slots wrote to the scratch page; keep their
+            # host length (0 for empty slots, prefill_done_len for
+            # half-prefilled ones)
+            self._lens = np.where(decoding, lens_new,
+                                  self._lens).astype(np.int32)
+            self.steps += 1
+            for slot, req in enumerate(self._slots):
+                if req is None or req.state != "decoding":
+                    continue
+                tok = int(nxt[slot])
+                req.generated.append(tok)
+                req.stats.tokens_out = len(req.generated)
+                self._cur[slot] = tok
+                if req.trace is not None:
+                    # pre-timed closed span: one list append per traced
+                    # in-flight request, no extra clock reads per slot
+                    req.trace.add("decode_step", t0d * 1e6, t1d * 1e6,
+                                  parent=req.span, step=self.steps,
+                                  token=tok)
+                self._emit_token(req, tok)
+                self._maybe_finish(slot)
+            # the fetched results go here, in the phase that read them:
+            # letting a device result's buffer go is not free, and at
+            # this function's return it would fall in no phase
+            del nxt, lens_new
+            return self.num_active
 
     def run(self, max_steps: int = 100000) -> Dict[int, np.ndarray]:
         """Drive until queue and slots drain; returns {req_id: tokens}

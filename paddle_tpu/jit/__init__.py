@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from ..autograd.engine import no_grad
 from ..core import rng as rng_mod
+from ..core.profiler import host_phase
 from ..nn.layer import Layer, bind_state, functional_state
 from ..tensor import Tensor
 
@@ -115,9 +116,12 @@ class TrainStep:
 
     def __call__(self, batch) -> jax.Array:
         batch_raw = _unwrap_tree(batch)
-        self.params, self.buffers, self.opt_state, self._key, loss = \
-            self._step(self.params, self.buffers, self.opt_state,
-                       self._key, self._lr_device(), batch_raw)
+        # named for a profiler session only: the trainer keeps no
+        # step timeline (core/profiler.py host_phase)
+        with host_phase("train_launch"):
+            self.params, self.buffers, self.opt_state, self._key, loss = \
+                self._step(self.params, self.buffers, self.opt_state,
+                           self._key, self._lr_device(), batch_raw)
         return loss
 
     def multi_step(self, batches) -> jax.Array:
@@ -128,9 +132,10 @@ class TrainStep:
         framework/trainer.h) — the hot loop never returns to Python.
         Returns the per-step losses [n_steps]."""
         batches_raw = _unwrap_tree(batches)
-        self.params, self.buffers, self.opt_state, self._key, losses = \
-            self._multi(self.params, self.buffers, self.opt_state,
-                        self._key, self._lr_device(), batches_raw)
+        with host_phase("train_launch"):
+            self.params, self.buffers, self.opt_state, self._key, losses = \
+                self._multi(self.params, self.buffers, self.opt_state,
+                            self._key, self._lr_device(), batches_raw)
         return losses
 
     def sync_to_model(self) -> None:

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from .naming import named_pallas_call
 
 # 512-blocks measured fastest on TPU v5e (grad 4.2 ms vs 8.0 ms at 128
 # for B8 H12 S1024 D64); auto-clamped to the sequence length.
@@ -380,7 +381,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     if nk == 1:
         # one K block: plain softmax kernel, no streaming axis — every
         # grid dim is parallel and the online-softmax scratch vanishes
-        out, lse = pl.pallas_call(
+        out, lse = named_pallas_call(
+            "flash_fwd_single",
             functools.partial(_fwd_single_block_kernel, scale=scale,
                               causal=causal, block_q=block_q,
                               block_k=block_k),
@@ -408,8 +410,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, nk=nk)
     kvc = _kv_clamp(causal, block_q, block_k)
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = named_pallas_call(
+        "flash_fwd", kernel,
         grid=grid,
         in_specs=[_spec_outer(block_q, d), _spec_inner(block_k, d, kvc),
                   _spec_inner(block_k, d, kvc)],
@@ -456,7 +458,8 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
         # -14.5 ms (-6.7%) on the full BERT-base body step, where the
         # halved launch count composes with XLA's surrounding schedule.
         kv_lim = ((block_q - 1) // block_k) if causal else None
-        dq, dk, dv = pl.pallas_call(
+        dq, dk, dv = named_pallas_call(
+            "flash_bwd_fused",
             functools.partial(_bwd_fused_kernel, scale=scale,
                               causal=causal, block_q=block_q,
                               block_k=block_k, nk=nk),
@@ -490,7 +493,8 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
 
     # dQ: Q blocks outer (parallel), K/V blocks stream on the last axis
     kvc = _kv_clamp(causal, block_q, block_k)
-    dq = pl.pallas_call(
+    dq = named_pallas_call(
+        "flash_bwd_dq",
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, nk=nk),
         grid=(b, h, nq, nk),
@@ -509,7 +513,8 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
 
     # dK/dV: K blocks outer (parallel), Q/dO/lse/delta stream
     qc = _q_clamp(causal, block_q, block_k)
-    dk, dv = pl.pallas_call(
+    dk, dv = named_pallas_call(
+        "flash_bwd_dkv",
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, nq=nq),
         grid=(b, h, nk, nq),

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from .naming import named_pallas_call
 
 # whole-S score blocks: [S, S] f32 intermediates in VMEM. 1024 keeps
 # the backward's live set (~4 x 4 MB) inside the scoped-vmem budget.
@@ -135,7 +136,8 @@ def _folded_fwd(q, k, v, head_dim, scale, causal):
     b, s, e = q.shape
     grp = _heads_per_group(head_dim)
     h = e // head_dim
-    return pl.pallas_call(
+    return named_pallas_call(
+        "folded_fwd",
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           d=head_dim, grp=grp),
         grid=(b, e // 128),
@@ -170,7 +172,8 @@ def _folded_vjp_bwd(head_dim, scale, causal, res, g):
     b, s, e = q.shape
     grp = _heads_per_group(head_dim)
     h = e // head_dim
-    dq, dk, dv = pl.pallas_call(
+    dq, dk, dv = named_pallas_call(
+        "folded_bwd",
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           d=head_dim, grp=grp),
         grid=(b, e // 128),

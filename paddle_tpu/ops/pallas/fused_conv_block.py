@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from .naming import named_pallas_call
 
 
 def _shift_rows(v, s, hw):
@@ -137,7 +138,8 @@ def fused_bottleneck_eval(x, w1, b1, w2, b2, w3, b3):
 
     plane = pl.BlockSpec((1, g * hw, c), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "fused_conv_block",
         functools.partial(_block_kernel, h=h, w=w, m=m, c=c, g=g),
         grid=(n // g,),
         in_specs=[plane, pinned(w1.shape), pinned(w2.shape),

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from .naming import named_pallas_call
 
 _NEG_INF = -1e30
 
@@ -252,7 +253,8 @@ def _fused_argmax_pallas(hidden, weight, vdim, bias, tile: int):
     else:
         w_spec = pl.BlockSpec((d, tile), lambda i: (0, i),
                               memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "fused_argmax",
         functools.partial(_argmax_kernel, tile=tile, vocab=vocab,
                           n_tiles=n_tiles, has_bias=has_bias,
                           vdim=vdim),

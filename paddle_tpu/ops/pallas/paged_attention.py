@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from .naming import named_pallas_call
 
 _NEG_INF = -1e30
 
@@ -210,12 +211,13 @@ def _gather_scales(scales, page_table):
     return jnp.swapaxes(scales[page_table], 2, 3)
 
 
-def _decode_call(kernel, q, k_pages, v_pages, page_table, seq_lens,
+def _decode_call(name, kernel, q, k_pages, v_pages, page_table, seq_lens,
                  k_scale, v_scale, *, out_shape, out_spec, extra=(),
                  extra_flops=0, extra_bytes=0):
-    """The pallas_call both decode kernels share: grid (B,), page table
-    and lengths scalar-prefetched, KV pools left in HBM. ``extra``:
-    (array, BlockSpec) pairs appended to the kernel's inputs."""
+    """The pallas_call both decode kernels share, under the kernel's
+    ``name``: grid (B,), page table and lengths scalar-prefetched, KV
+    pools left in HBM. ``extra``: (array, BlockSpec) pairs appended to
+    the kernel's inputs."""
     b, h, d = q.shape
     n_pool, page = k_pages.shape[:2]
     mp = page_table.shape[1]
@@ -247,8 +249,8 @@ def _decode_call(kernel, q, k_pages, v_pages, page_table, seq_lens,
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    return pl.pallas_call(
-        kernel,
+    return named_pallas_call(
+        name, kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         cost_estimate=pl.CostEstimate(
@@ -267,6 +269,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, seq_lens,
                          k_scale, v_scale, scale):
     b, h, d = q.shape
     return _decode_call(
+        "paged_decode",
         functools.partial(_decode_kernel, page=k_pages.shape[1],
                           scale=scale, quantized=k_scale is not None),
         q, k_pages, v_pages, page_table, seq_lens, k_scale, v_scale,
@@ -287,6 +290,7 @@ def _paged_decode_fused_pallas(q, k_pages, v_pages, page_table, seq_lens,
     brow = (bias.reshape(1, e_out) if has_bias
             else jnp.zeros((1, e_out), jnp.float32))
     out = _decode_call(
+        "paged_decode_fused",
         functools.partial(_decode_fused_kernel, page=k_pages.shape[1],
                           scale=scale, quantized=k_scale is not None,
                           has_bias=has_bias),

@@ -153,6 +153,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from ..core.profiler import host_phase
 from .fleet_metrics import FlightRecorder
 from .metrics import ServingMetrics, SLOAttainment
 from .prefix_cache import PrefixCache
@@ -594,9 +595,16 @@ class ServingServer:
             # re-read self.engine every iteration: resurrection swaps
             # the instance mid-loop
             eng = self.engine
-            self._drain_inbox()
-            self._maybe_apply_swap(eng)
-            has_work = eng.num_queued or eng.num_active
+            # the `loop` host phase (core/profiler.py): what this
+            # thread does between two engine steps. Its time is the
+            # next step record's `gap_us`, taken by the engine from the
+            # end of one commit to the start of the next step; here it
+            # is only named for a profiler session.
+            with host_phase("loop"):
+                with host_phase("inbox"):
+                    self._drain_inbox()
+                self._maybe_apply_swap(eng)
+                has_work = eng.num_queued or eng.num_active
             if has_work:
                 try:
                     before = eng.num_queued + eng.num_active

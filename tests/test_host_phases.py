@@ -1,0 +1,347 @@
+"""Host phases (core/profiler.py HostPhases / host_phase): the engine
+thread's time in the step timeline, and the same phases on the
+profiler's clock.
+
+The partition contract: the phases of a step do not overlap, what they
+leave of ``ms`` is ``other``, the names are the documented set, and on
+the path the benchmark's cells run (whole-prompt prefill, single-step
+decode) ``other`` is small. With ``jax.profiler`` running, every phase
+is a ``pt.host.*`` event on the host plane: every program launch lies
+inside a ``pt.host.launch``, every blocking read inside a
+``pt.host.wait``.
+"""
+
+import glob
+import os
+import statistics
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.core.monitor import StatRegistry
+from paddle_tpu.core.profiler import HostPhases, RecordEvent, host_phase
+from paddle_tpu.inference import SpeculativeConfig, create_decode_engine
+from paddle_tpu.inference.continuous_batching import HOST_PHASES
+from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
+from paddle_tpu.serving import ServingMetrics, SpanTracer
+from paddle_tpu.serving import tracing as span_tracing
+from paddle_tpu.serving.server import ServingServer, client_request
+
+ENGINE_KW = dict(num_slots=2, page_size=8, max_seq_len=96, num_pages=24,
+                 timeline_steps=4096)
+NAMES = set(HOST_PHASES) | {"other"}
+
+# engine variants: the default path the cells run, and the three paths
+# no cell runs (every jit call inside a launch, every blocking read
+# inside a wait; what else they do may be `other`)
+PATHS = {
+    "default": {},
+    "chunked": {"prefill_chunk_tokens": 8},
+    "multi_step": {"multi_step": 4},
+    "speculative": {"speculative": SpeculativeConfig(k=2, draft="ngram")},
+}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache(module_compile_cache):
+    yield
+
+
+@pytest.fixture(scope="module")
+def model():
+    pt.seed(0)
+    m = GPTForCausalLM(gpt_tiny())
+    m.eval()
+    return m
+
+
+def _engine(m, **kw):
+    return create_decode_engine(m, **{**ENGINE_KW, **kw})
+
+
+def _drive(eng, rounds=2, new_tokens=10):
+    """Admits, prefills, decodes and evicts: more requests than slots,
+    so finished slots are refilled mid-flight."""
+    for _ in range(rounds):
+        for i in range(4):
+            eng.submit(np.arange(1, 8 + 3 * i, dtype=np.int32),
+                       new_tokens + i)
+        eng.run()
+
+
+# ---------------------------------------------------------------------------
+# The mechanism
+# ---------------------------------------------------------------------------
+
+class TestHostPhases:
+    def test_nested_phase_pauses_the_outer(self):
+        acc = HostPhases()
+        with acc.phase("admit") as outer:
+            time.sleep(0.002)
+            with acc.phase("launch") as inner:
+                time.sleep(0.004)
+            time.sleep(0.002)
+        us = acc.take()
+        assert set(us) == {"admit", "launch"}
+        # each second counted once: the sum is the outer's wall time
+        assert us["admit"] + us["launch"] == pytest.approx(
+            outer.t1 - outer.t0, abs=1e-9)
+        assert us["launch"] == pytest.approx(inner.t1 - inner.t0, abs=1e-9)
+        assert 0.004 <= us["launch"] < us["admit"] + us["launch"]
+        assert acc.t == outer.t1 and acc.take() == {}
+
+    def test_exception_closes_the_phase(self):
+        acc = HostPhases()
+        with pytest.raises(ValueError):
+            with acc.phase("admit"):
+                with acc.phase("wait"):
+                    raise ValueError("boom")
+        assert acc._open is None and set(acc.us) == {"admit", "wait"}
+
+    def test_cost_without_a_session_is_microseconds(self):
+        acc = HostPhases()
+        n = 2000
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with acc.phase("x"):
+                pass
+        per = (time.perf_counter() - t0) / n
+        assert per < 50e-6, per  # measured 1.4 us; the bound is slack
+
+
+# ---------------------------------------------------------------------------
+# The step timeline's records
+# ---------------------------------------------------------------------------
+
+class TestTimelineRecords:
+    @pytest.mark.parametrize("path", sorted(PATHS))
+    def test_partition_contract(self, model, path):
+        eng = _engine(model, **PATHS[path])
+        _drive(eng)
+        eng.close()
+        tl = eng.step_timeline()
+        assert len(tl) > 10
+        seen = set()
+        for e in tl:
+            host = e["host_us"]
+            assert set(host) <= NAMES and "other" in host, host
+            seen |= set(host)
+            # the phases and `other` add up to the step, and no phase
+            # ran twice over the same time: nothing is negative
+            assert sum(host.values()) == pytest.approx(e["ms"] * 1e3,
+                                                       abs=1.0)
+            assert all(v >= 0 for k, v in host.items() if k != "other")
+            assert host["other"] >= -1.0, host
+            assert e["commit_us"] > 0 and e["gap_us"] >= 0
+        assert all("cpu_us" in e for e in tl[1:])
+        assert all(0 <= e["cpu_us"] <= e["gap_us"] + e["ms"] * 1e3
+                   + e["commit_us"] + 1e3 for e in tl[1:])
+        assert seen == NAMES, seen
+
+    def test_other_is_small_on_the_default_path(self, model):
+        eng = _engine(model)
+        _drive(eng, rounds=3)
+        eng.close()
+        tl = [e for e in eng.step_timeline()[2:] if e["programs"]]
+        # 2 % of the step or 50 us: gpt_tiny's step on the CPU is about
+        # a millisecond, of which the Python between two phases is
+        # some 20 us (a device step is 10 ms, and reads 0.3 % there)
+        over = [e["host_us"]["other"] - 0.02 * e["ms"] * 1e3 for e in tl]
+        assert statistics.median(over) <= 50.0, sorted(over)[-5:]
+
+    def test_older_keys_come_from_the_same_stamps(self, model):
+        eng = _engine(model)
+        _drive(eng, rounds=1)
+        eng.close()
+        for e in eng.step_timeline():
+            host = e["host_us"]
+            if e["programs"] == {"decode": 1}:
+                # decode_ms is the dispatch of the decode program: the
+                # launch phase, to the rounding of the two
+                assert e["decode_ms"] * 1e3 == pytest.approx(
+                    host["launch"], abs=0.2)
+            if "prefill_ms" in e:
+                assert e["prefill_ms"] * 1e3 <= (
+                    host["upload"] + host["launch"] + host["wait"] + 0.5)
+
+    def test_record_size_does_not_depend_on_tokens(self, model):
+        def keys(new_tokens):
+            eng = _engine(model)
+            _drive(eng, rounds=1, new_tokens=new_tokens)
+            eng.close()
+            return {(k, len(v) if isinstance(v, dict) else 1)
+                    for e in eng.step_timeline()
+                    if e["programs"] == {"decode": 1}
+                    for k, v in e.items() if k != "occupancy"}
+        assert keys(4) == keys(40)
+
+    def test_phases_outside_a_step_belong_to_no_record(self, model):
+        eng = _engine(model)
+        eng.submit(np.arange(1, 7, dtype=np.int32), 3)
+        with eng._phase("emit"):
+            time.sleep(0.01)
+        eng.step()
+        assert eng.step_timeline()[-1]["host_us"].get("emit", 0) < 5e3
+        eng.run()
+        eng.close()
+
+    def test_no_span_object_without_sampling(self, model, monkeypatch):
+        """The existing contract, kept: with no profiler session and
+        `trace_sample` 0 a step creates no span object."""
+        made = []
+        real = span_tracing.Span.__init__
+
+        def counting(self, *a, **kw):
+            made.append(1)
+            real(self, *a, **kw)
+
+        monkeypatch.setattr(span_tracing.Span, "__init__", counting)
+        eng = _engine(model, tracer=SpanTracer(sample_rate=0.0))
+        _drive(eng, rounds=1)
+        eng.close()
+        assert made == [] and len(eng.step_timeline()) > 5
+
+
+# ---------------------------------------------------------------------------
+# The same phases on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """One list of ``(name, start_ns, end_ns)`` for every line (a
+    thread) of the host planes that holds a `pt.host.*` or RecordEvent
+    event. Lines are kept apart: every Python thread's line is called
+    "python", and a worker that ran other files first may still have
+    an idle server's loop on a line of its own."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            if any(n.startswith("pt.") or n == "my_region"
+                   for n, _, _ in evs):
+                out.append(evs)
+    return out
+
+
+def _stepping_line(lines):
+    """The line of the thread that stepped an engine."""
+    mine = [evs for evs in lines
+            if any(e[0] == "pt.host.launch" for e in evs)]
+    assert len(mine) == 1, len(mine)  # one thread stepped
+    return mine[0]
+
+
+def _trace(work, tmp_path):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # the annotations, not every call
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(str(tmp_path))
+
+
+def _inside(ev, spans):
+    return any(a <= ev[1] and ev[2] <= b for _, a, b in spans)
+
+
+def _check_line(evs, launches):
+    """One thread's line: phases flat, one launch per program, every
+    jit call inside a launch and every blocking read inside a wait."""
+    phases = sorted((e for e in evs if e[0].startswith("pt.host.")
+                     and e[0] != "pt.host.inbox"),
+                    key=lambda e: e[1])
+    assert {n[len("pt.host."):] for n, _, _ in phases} <= (
+        set(HOST_PHASES) | {"commit", "loop"})
+    for a, b in zip(phases, phases[1:]):
+        assert a[2] <= b[1], (a, b)  # none overlaps another
+    launch = [e for e in phases if e[0] == "pt.host.launch"]
+    wait = [e for e in phases if e[0] == "pt.host.wait"]
+    assert len(launch) == launches
+    # JAX's own events on the same line: a jitted call, a host read.
+    # Building a device argument may run a small program of JAX's own
+    # (convert_element_type for a list, a PRNG key): inside `upload`.
+    upload = [e for e in phases if e[0] == "pt.host.upload"]
+    programs = [e for e in evs if e[0].startswith("PjitFunction(")]
+    reads = [e for e in evs if e[0] == "np.asarray(jax.Array)"
+             or e[0].endswith("Buffer::Await")]
+    assert programs and reads
+    for e in programs:
+        assert _inside(e, launch) or _inside(e, upload), e
+    for span in launch:  # and no launch phase without its program
+        assert any(_inside(e, [span]) for e in programs), span
+    for e in reads:
+        assert _inside(e, wait), e
+
+
+class TestProfilerPlane:
+    @pytest.mark.parametrize("path", sorted(PATHS))
+    def test_engine_phases_on_the_host_plane(self, model, tmp_path, path):
+        eng = _engine(model, **PATHS[path])
+        _drive(eng, rounds=1, new_tokens=4)  # compiled before the trace
+        n0 = len(eng.step_timeline())
+        lines = _trace(lambda: _drive(eng, rounds=1, new_tokens=6),
+                       tmp_path)
+        eng.close()
+        launched = sum(sum(e["programs"].values())
+                       for e in eng.step_timeline()[n0:])
+        _check_line(_stepping_line(lines), launched)
+
+    def test_server_loop_and_inbox(self, model, tmp_path):
+        srv = ServingServer(model, port=0, metrics=ServingMetrics(
+            registry=StatRegistry()), **ENGINE_KW)
+        port = srv.start()
+        try:
+            client_request("127.0.0.1", port, {
+                "op": "generate", "prompt": [1, 2, 3, 4, 5],
+                "max_new_tokens": 3})  # compiled before the trace
+            n0 = len(srv.engine.step_timeline())
+            lines = _trace(lambda: client_request("127.0.0.1", port, {
+                "op": "generate", "prompt": [1, 2, 3, 4, 5, 6],
+                "max_new_tokens": 5}), tmp_path)
+            tl = srv.engine.step_timeline()[n0:]
+        finally:
+            srv.stop()
+        # the engine thread's line holds the loop AND the step's phases
+        line = _stepping_line(lines)
+        loops = [e for e in line if e[0] == "pt.host.loop"]
+        inbox = [e for e in line if e[0] == "pt.host.inbox"]
+        assert loops and len(inbox) == len(loops)
+        assert all(_inside(e, loops) for e in inbox)
+        _check_line(line, sum(sum(e["programs"].values()) for e in tl))
+        # gap_us is that loop: a working gap is about one loop event
+        assert all(e["gap_us"] >= 0 for e in tl)
+
+    def test_record_event_and_trainer_launch(self, tmp_path):
+        def work():
+            with RecordEvent("my_region"):
+                with host_phase("train_launch"):
+                    jax.block_until_ready(jax.numpy.ones((8,)) + 1)
+        names = {e[0] for v in _trace(work, tmp_path) for e in v}
+        assert {"my_region", "pt.host.train_launch"} <= names
+
+    def test_train_step_launch_is_annotated(self, tmp_path):
+        from paddle_tpu import nn, optimizer
+        from paddle_tpu.jit import TrainStep
+        pt.seed(0)
+        net = nn.Linear(4, 2)
+        opt = optimizer.SGD(learning_rate=0.1,
+                            parameters=net.parameters())
+        step = TrainStep(net, opt, lambda m, b: (m(b) ** 2).mean())
+        x = pt.to_tensor(np.ones((3, 4), np.float32))
+        step(x)
+        lines = _trace(lambda: jax.block_until_ready(step(x)), tmp_path)
+        evs = [e for v in lines for e in v]
+        launch = [e for e in evs if e[0] == "pt.host.train_launch"]
+        assert len(launch) == 1
+        assert any(e[0].startswith("PjitFunction(") and _inside(e, launch)
+                   for e in evs)

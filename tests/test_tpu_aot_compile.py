@@ -17,6 +17,7 @@ compile in the test's own process.
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -92,6 +93,16 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _named(compiled, *names):
+    """Every kernel is findable in the compiled program by its stable
+    name (ops/pallas/naming.py): the custom call's instruction is named
+    after it, and its op_name carries the `pt.kernel.<name>` scope."""
+    txt = compiled.as_text()
+    for n in names:
+        assert re.search(rf"%{n}[.\d]* = [^\n]*tpu_custom_call", txt), n
+        assert f"pt.kernel.{n}" in txt, n
+
+
 def _fits_one_v5e(compiled):
     mem = compiled.memory_analysis()
     live = (int(mem.argument_size_in_bytes) + int(mem.temp_size_in_bytes)
@@ -130,7 +141,9 @@ def test_paged_decode_compiles(one_chip, pool):
 
     assert pa.paged_attention_supported(specs[0].shape, specs[1].shape,
                                         backend="tpu")
-    assert _kernel_calls(_compile(decode, *specs)) == 1
+    compiled = _compile(decode, *specs)
+    assert _kernel_calls(compiled) == 1
+    _named(compiled, "paged_decode")
 
 
 @pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
@@ -154,6 +167,7 @@ def test_paged_decode_fused_epilogue_compiles(one_chip, pool):
     assert in_kernel == (pool != "f32")
     compiled = _compile(decode, *specs[:5], w, b, *specs[5:])
     assert _kernel_calls(compiled) == 1
+    _named(compiled, "paged_decode_fused" if in_kernel else "paged_decode")
 
 
 @pytest.mark.parametrize("pool", ["f32", "int8"])
@@ -195,6 +209,7 @@ def test_fused_sample_compiles(one_chip, dtype, transpose_y):
         lambda h, w_: fs.fused_sample(h, w_, transpose_y=transpose_y),
         hidden, w)
     assert _kernel_calls(compiled) == 1
+    _named(compiled, "fused_argmax")
 
 
 # -- attention for training ------------------------------------------------
@@ -212,6 +227,7 @@ def test_flash_fwd_bwd_compiles_train_shape(one_chip):
                              sharding=one_chip)
     compiled = _compile(_grad_of_attention(fa.flash_attention), q)
     assert _kernel_calls(compiled) >= 2  # forward + backward kernels
+    _named(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
     _fits_one_v5e(compiled)
 
 
@@ -272,6 +288,7 @@ def test_folded_fwd_bwd_compiles_bert_shape(one_chip):
 
     compiled = _compile(jax.grad(loss), q)
     assert _kernel_calls(compiled) >= 2
+    _named(compiled, "folded_fwd", "folded_bwd")
     _fits_one_v5e(compiled)
 
 
