@@ -13,12 +13,21 @@ program.
 Design (TPU-native fixed shapes; paper basis: *Ragged Paged Attention*,
 PAPERS.md — the same pool/page-table layout its kernel consumes):
 
-- DEVICE state is fully static-shaped: per-layer page pools, one
-  ``page_table [num_slots, max_pages]``, ``seq_lens [num_slots]``, and
-  the per-slot current token. ONE compiled decode step serves the
-  engine's whole lifetime; prefill compiles once per prompt bucket.
+- DEVICE state is fully static-shaped: per-layer page pools and the
+  decode step's inputs, ONE packed int32 array ``[num_slots,
+  max_pages + 2]`` (page table | ``seq_lens`` | current token). The
+  decode program returns the next step's packed inputs and the engine
+  feeds them back: a decode step that follows no host write uploads
+  nothing and fetches the sampled tokens alone. ONE compiled decode
+  step serves the engine's whole lifetime; prefill compiles once per
+  prompt bucket.
 - HOST state is the scheduler: a free-list `PageAllocator`, the wait
-  queue, and per-slot request bookkeeping. Admission allocates
+  queue, per-slot request bookkeeping, and the mirrors of the packed
+  inputs (``_table``, ``_lens``, ``_cur``: views of one array). The
+  mirrors are the truth: every host write goes through
+  ``_write_slot``, which marks the device's copy stale, and the next
+  decode step sends the mirrors in one transfer (``decode_h2d`` in
+  the step timeline says which kind a step was). Admission allocates
   ceil(capacity/page) pages and runs a bucket-padded prefill whose
   right padding is redirected to the pool's reserved scratch page
   (models/gpt.py paged_kv_append valid_len), so padded prompts never
@@ -28,9 +37,9 @@ PAPERS.md — the same pool/page-table layout its kernel consumes):
   paged_attention_reference), so a freed page can be handed to the
   next request without any cross-slot read hazard.
 - Inactive slots still ride through the fixed-shape decode step (their
-  writes land on the scratch page and their lengths are reset on the
-  host); that is the fixed-slot contract that keeps the hot loop at
-  one compiled program.
+  writes land on the scratch page and the program returns their
+  length as it came in, 0); that is the fixed-slot contract that
+  keeps the hot loop at one compiled program.
 
 Serving hooks (the `paddle_tpu/serving/` subsystem rides on these;
 each defaults OFF so the bare engine behaves exactly as before):
@@ -531,6 +540,9 @@ class ContinuousBatchingEngine:
         self.mesh = mesh
         self._mesh_axis = None
         self._kv_sharding = None
+        # where the decode step's packed inputs live on a mesh: on
+        # every device, whole (None: the default device)
+        self._replicated = None
         self._state_shardings = None
         # identity cache for sharded weights: (kind, name) -> (source
         # array, its device_put result). An unchanged leaf transfers to
@@ -567,6 +579,7 @@ class ContinuousBatchingEngine:
             # ([P+1, page, H]): dim 2 is the head dim in both
             self._kv_sharding = NamedSharding(
                 mesh, PartitionSpec(None, None, axis))
+            self._replicated = NamedSharding(mesh, PartitionSpec())
         self.page_size = int(page_size)
         self.num_slots = int(num_slots)
         self.max_seq_len = int(max_seq_len or cfg.max_seq_len)
@@ -634,11 +647,25 @@ class ContinuousBatchingEngine:
             "ks": [p.k_scale for p in protos],
             "vs": [p.v_scale for p in protos],
         }
-        # host-owned scheduler state
-        self._table = np.full((self.num_slots, self.max_pages),
-                              self._scratch, np.int32)
-        self._lens = np.zeros((self.num_slots,), np.int32)
-        self._cur = np.zeros((self.num_slots,), np.int32)
+        # host-owned scheduler state. The three mirrors of the decode
+        # step's inputs are views of ONE packed int32 array
+        # ``[num_slots, max_pages + 2]`` (page table | length | current
+        # token), so a stale step sends them in one transfer (see
+        # _write_slot / _decode_step). They are written in place,
+        # never rebound.
+        self._packed = np.zeros((self.num_slots, self.max_pages + 2),
+                                np.int32)
+        self._table = self._packed[:, :self.max_pages]
+        self._lens = self._packed[:, self.max_pages]
+        self._cur = self._packed[:, self.max_pages + 1]
+        self._table[:] = self._scratch
+        # the device's copy of ``_packed`` as the last decode program
+        # returned it, or None where a host write (or a failed step)
+        # has made it stale: the next decode step then uploads
+        self._resident = None
+        self._tl_h2d: Optional[int] = None
+        self.decode_steps_resident = 0
+        self.decode_steps_uploaded = 0
         self._slots: List[Optional[DecodeRequest]] = \
             [None] * self.num_slots
         self._queue: List[DecodeRequest] = []
@@ -996,6 +1023,36 @@ class ContinuousBatchingEngine:
         debt += sum(len(r.prompt) for r in self._queue)
         return debt
 
+    # -- the host mirrors of the decode step's inputs ----------------------
+
+    def _write_slot(self, slot: int, table=None, lens=None,
+                    cur=None) -> None:
+        """The one writer of the host mirrors (``_table``, ``_lens``,
+        ``_cur``): sets what is given in ``slot``'s row and marks the
+        device's copy stale, so the next decode step uploads the
+        mirrors instead of feeding the program its own outputs. Only
+        the decode step itself writes past it: it advances the mirrors
+        by what the device computed, which is what keeps the copy
+        current (see _decode_step)."""
+        if table is not None:
+            self._table[slot] = table
+        if lens is not None:
+            self._lens[slot] = lens
+        if cur is not None:
+            self._cur[slot] = cur
+        self._resident = None
+
+    def _place_resident(self, packed: np.ndarray):
+        """The decode step's one host-to-device transfer: the packed
+        mirrors as a device array, replicated over the serving mesh
+        where there is one (where ``jnp.asarray`` of the three arrays
+        ended up, now said outright so that the program's own output
+        and an upload are one signature to the jit)."""
+        if self._replicated is None:
+            return self._jnp.asarray(packed)
+        import jax
+        return jax.device_put(packed, self._replicated)
+
     # -- jitted device programs -------------------------------------------
 
     def _caches(self, pools, table, lens):
@@ -1339,6 +1396,16 @@ class ContinuousBatchingEngine:
           (``time.thread_time_ns``) over gap + step + commit; left out
           when the previous step ran on another thread.
 
+        - ``decode_h2d``: on a record whose step ran the single-step
+          decode program, the host-to-device transfers of its inputs:
+          0 where the program was fed its own outputs, 1 where a host
+          write (admission, first token, finish, eviction), a failed
+          step or a half-prefilled slot made the step send the
+          mirrors. ``flight_summary()`` keeps the totals
+          (``decode_steps_resident``, ``decode_steps_uploaded``). The
+          macro, speculative and chunk programs build their arguments
+          from the mirrors on every launch and record no such key.
+
         The older ``_tl_ms`` keys are fed from the same stamps:
         ``decode_ms`` is the decode program's ``launch`` phase on the
         single-step path — the DISPATCH, not the decode (on a device
@@ -1393,6 +1460,8 @@ class ContinuousBatchingEngine:
                 entry[f"{t.name}_tier_pages"] = int(t.blob_count)
         for k, v in self._tl_ms.items():
             entry[k] = round(v, 4)
+        if self._tl_h2d is not None:
+            entry["decode_h2d"] = self._tl_h2d
         # multi-step decode (r19): the boundary that drained a macro
         # launch marks its entry with the launch's attribution
         # (per_token_timeline() reconstructs per-step rows from it)
@@ -1433,6 +1502,8 @@ class ContinuousBatchingEngine:
             "fused_step": bool(self.fused_step),
             "multi_step": int(self.multi_step),
             "macro_launches": int(self.macro_launches),
+            "decode_steps_resident": int(self.decode_steps_resident),
+            "decode_steps_uploaded": int(self.decode_steps_uploaded),
             "speculative": self._spec_cfg is not None,
             "mesh": self.mesh_info(),
             "programs_launched": dict(self.programs_launched),
@@ -1758,7 +1829,38 @@ class ContinuousBatchingEngine:
         return step
 
     def _build_decode(self):
+        """The per-token decode program over the packed inputs
+        ``[num_slots, max_pages + 2]`` (page table | length | current
+        token; the layout of ``_packed``). It returns the tokens, the
+        pools and the NEXT step's packed inputs, so a step the host did
+        not touch feeds the program its own output and uploads nothing
+        (_decode_step). The body is ``_decode_body_fn`` untouched; the
+        wrapper only unpacks, repacks and masks the lengths: the body
+        returns ``lens + 1`` for every row, and a row that came in
+        empty (length 0: an empty or masked slot, writing to the
+        scratch page) goes out empty, or its length would creep from
+        step to step. The token such a row yields is an in-range
+        argmax nobody reads; admission overwrites it."""
         import jax
+        import jax.numpy as jnp
+
+        body = self._decode_body_fn()
+        mp = self.max_pages
+        replicated = self._replicated
+
+        def step(state, pools, packed):
+            table, lens = packed[:, :mp], packed[:, mp]
+            nxt, pools, lens_new = body(state, pools, table, lens,
+                                        packed[:, mp + 1])
+            lens_new = jnp.where(lens > 0, lens_new, 0)
+            packed = jnp.concatenate(
+                [table, lens_new[:, None], nxt[:, None]], axis=1)
+            if replicated is not None:
+                # the layout _place_resident uploads in: what goes
+                # back in hits the same compiled program
+                packed = jax.lax.with_sharding_constraint(packed,
+                                                          replicated)
+            return nxt, pools, packed
 
         # donate the pools: the append scatters then update the pool
         # buffers IN PLACE instead of materializing a fresh copy of
@@ -1766,7 +1868,7 @@ class ContinuousBatchingEngine:
         # plus 2x peak KV memory); the engine always adopts the
         # returned pools, so the donated buffers are never reused.
         # (On CPU donation is ignored with a warning — harmless.)
-        return jax.jit(self._decode_body_fn(), donate_argnums=(1,))
+        return jax.jit(step, donate_argnums=(1,))
 
     def _build_multi_decode(self, has_chunk: bool = False):
         """The r19 macro program: up to ``multi_step`` iterations of
@@ -2023,9 +2125,7 @@ class ContinuousBatchingEngine:
                 self._prefix_cache.release(req.cache_keys)
         req.cache_keys = ()
         req.prefill_done_len = 0
-        self._table[slot] = self._scratch
-        self._lens[slot] = 0
-        self._cur[slot] = 0
+        self._write_slot(slot, table=self._scratch, lens=0, cur=0)
         self._slots[slot] = None
         req.slot = None
         req.stats.prefill_attempts += 1
@@ -2253,9 +2353,7 @@ class ContinuousBatchingEngine:
         req.done = True
         req.stats.finish_t = time.monotonic()
         req.stats.tokens_out = len(req.generated)
-        self._table[slot] = self._scratch
-        self._lens[slot] = 0
-        self._cur[slot] = 0
+        self._write_slot(slot, table=self._scratch, lens=0, cur=0)
         self._slots[slot] = None
         self._notify_complete(req)
         return req
@@ -2592,7 +2690,7 @@ class ContinuousBatchingEngine:
         row = np.full((self.max_pages,), self._scratch, np.int32)
         row[:len(shared)] = shared
         row[len(shared):len(shared) + len(pages)] = pages
-        self._table[slot] = row
+        self._write_slot(slot, table=row)
         if self.prefill_chunk_tokens is not None:
             # chunked admission (r11): bind the pages, store NOTHING
             # yet — the suffix is enqueued as page-aligned chunks that
@@ -2604,8 +2702,7 @@ class ContinuousBatchingEngine:
             req.state = "prefill_partial"
             req.prefill_done_len = cached_len
             req.slot = slot
-            self._lens[slot] = cached_len
-            self._cur[slot] = 0
+            self._write_slot(slot, lens=cached_len, cur=0)
             self._slots[slot] = req
             if tr is not None:
                 tr.end(sp_admit, cached_pages=len(shared),
@@ -2702,15 +2799,14 @@ class ContinuousBatchingEngine:
                     if cache is not None:
                         cache.release(keys)
                         req.cache_keys = ()
-                self._table[slot] = self._scratch
+                self._write_slot(slot, table=self._scratch)
                 req.state = "deadline"
                 req.done = True
                 req.stats.finish_t = now
                 self._notify_complete(req)
                 return None
             req.stats.first_token_t = now
-            self._lens[slot] = len(req.prompt)
-            self._cur[slot] = tok
+            self._write_slot(slot, lens=len(req.prompt), cur=tok)
             req.slot = slot
             req.state = "decoding"
             req.generated.append(tok)
@@ -2848,7 +2944,7 @@ class ContinuousBatchingEngine:
             # first launch of this variant: compile-dominated, skip
             self._chunk_warm[chained] = True
         req.prefill_done_len = done + len(suffix)
-        self._lens[slot] = req.prefill_done_len
+        self._write_slot(slot, lens=req.prefill_done_len)
         # chunk progress is liveness for the stall watchdog: a long
         # prompt legitimately emits nothing while its chunks land, but
         # a slot whose chunks stopped landing (step failures) still
@@ -2873,7 +2969,7 @@ class ContinuousBatchingEngine:
         with self._phase("emit"):
             req.stats.prefill_attempts += 1
             req.stats.first_token_t = now
-            self._cur[slot] = tok
+            self._write_slot(slot, cur=tok)
             req.state = "decoding"
             req.generated.append(tok)
             req.stats.tokens_out = 1
@@ -2977,9 +3073,8 @@ class ContinuousBatchingEngine:
             if self._prefix_cache is not None and req.cache_keys:
                 self._prefix_cache.release(req.cache_keys)
                 req.cache_keys = ()
-        self._table[slot] = self._scratch  # park on scratch page
-        self._lens[slot] = 0
-        self._cur[slot] = 0
+        # park on the scratch page
+        self._write_slot(slot, table=self._scratch, lens=0, cur=0)
         self._slots[slot] = None
         if notify:
             self._notify_complete(req)
@@ -3187,7 +3282,7 @@ class ContinuousBatchingEngine:
                     creq.state == "prefill_partial":
                 creq.stats.prefill_chunks += plan["count"]
                 creq.prefill_done_len = plan["end"]
-                self._lens[ci] = plan["end"]
+                self._write_slot(ci, lens=plan["end"])
                 creq.last_emit_t = now
                 self._last_chunk_t = now
                 creq.chunk_deferrals = 0
@@ -3282,8 +3377,7 @@ class ContinuousBatchingEngine:
             if self._slots[i] is not req:
                 continue  # defensive: slot reassigned (cannot happen
                 # under the flush discipline, but never corrupt it)
-            self._lens[i] = int(lens_f[i])
-            self._cur[i] = int(cur_f[i])
+            self._write_slot(i, lens=int(lens_f[i]), cur=int(cur_f[i]))
             if self._finish_due(req):
                 # teardown now (pages/reservations back before the
                 # boundary's admission), notify at delivery — after
@@ -3444,7 +3538,7 @@ class ContinuousBatchingEngine:
         committed at admission). Reserve-growth modes only
         (speculative, and multi-step macro dispatch) — vanilla
         per-token admission binds every page up front."""
-        row = self._table[slot]
+        row = self._table[slot].copy()
         want = -(-need_len // self.page_size)
         missing = [j for j in range(want) if row[j] == self._scratch]
         if not missing:
@@ -3454,8 +3548,8 @@ class ContinuousBatchingEngine:
         with self._led(reason, req.req_id):
             pages = self.allocator.alloc_reserved(req.req_id,
                                                   len(missing))
-        for j, p in zip(missing, pages):
-            row[j] = p
+        row[missing] = pages
+        self._write_slot(slot, table=row)
 
     def _rollback_pages(self, slot: int, req: DecodeRequest,
                         new_len: int) -> int:
@@ -3476,7 +3570,9 @@ class ContinuousBatchingEngine:
             with self._led("spec_rollback", req.req_id):
                 self.allocator.release_pages(req.req_id, victims,
                                              rereserve=True)
+            row = row.copy()
             row[keep:] = self._scratch
+            self._write_slot(slot, table=row)
         return len(victims)
 
     def _spec_step(self) -> int:
@@ -3583,7 +3679,7 @@ class ContinuousBatchingEngine:
                 for tok in emitted:
                     req.generated.append(tok)
                     req.stats.tokens_out = len(req.generated)
-                    self._cur[i] = tok
+                    self._write_slot(i, cur=tok)
                     self._emit_token(req, tok)
                     if self._finish_due(req):
                         finished = True
@@ -3596,7 +3692,7 @@ class ContinuousBatchingEngine:
                 # KV now validly covers cur + the n accepted drafts; the
                 # last emitted token's KV is written by the NEXT step
                 new_len = int(old_lens[i]) + n + 1
-                self._lens[i] = new_len
+                self._write_slot(i, lens=new_len)
                 self._rollback_pages(i, req, new_len)
         return self.num_active
 
@@ -3609,22 +3705,30 @@ class ContinuousBatchingEngine:
         and before the donating jit — so an injected step failure
         leaves host and device state exactly as the previous step left
         them (the precondition for the serving layer's resurrection
-        replay)."""
+        replay). A step that raises, there or later, also drops the
+        device's copy of the decode inputs (``_resident``): the next
+        decode step uploads the host mirrors."""
         from ..distributed.fault_inject import fault_point
-        fault_point("engine.step")
-        # r16 step timeline: reset per-step accumulators, commit one
-        # ring entry per step attempt (a dict per STEP — never per
-        # token — next to at least one jit launch)
-        self._tl_programs = {}
-        self._tl_ms = {}
-        self._host.take()  # phases outside a step belong to no record
-        if self.ledger is not None:
-            self.ledger.step = self.steps
-        t_step = time.monotonic()
         try:
-            return self._step_inner()
-        finally:
-            self._tl_commit(t_step)
+            fault_point("engine.step")
+            # r16 step timeline: reset per-step accumulators, commit
+            # one ring entry per step attempt (a dict per STEP — never
+            # per token — next to at least one jit launch)
+            self._tl_programs = {}
+            self._tl_ms = {}
+            self._tl_h2d = None
+            self._host.take()  # phases outside a step: no record's
+            if self.ledger is not None:
+                self.ledger.step = self.steps
+            t_step = time.monotonic()
+            try:
+                return self._step_inner()
+            finally:
+                self._tl_commit(t_step)
+        except BaseException:
+            # whatever failed, the step after it sends the mirrors
+            self._resident = None
+            raise
 
     def _step_inner(self) -> int:
         if self.multi_step > 1 and (self._spec_cfg is None
@@ -3673,32 +3777,52 @@ class ContinuousBatchingEngine:
                     else 0.8 * self.decode_ema_s + 0.2 * dt
 
     def _decode_step(self) -> int:
+        """One decode step over the device's own copy of its inputs.
+
+        The decode program returns the next step's packed inputs
+        (_build_decode) and ``_resident`` holds them from step to
+        step. The host mirrors stay the truth: every host write goes
+        through ``_write_slot``, which drops ``_resident``, and the
+        decode step after it sends the mirrors in ONE transfer. A step
+        the host did not touch sends nothing and fetches the tokens
+        alone; the length mirror advances by the host's own ``+ 1``.
+        A step that fails leaves the copy stale (``step`` drops it)."""
         from ..dispatch import count_op_calls
-        jnp = self._jnp
+        held = self._resident
         with self._phase("upload"):
             if self._decode_jit is None:
                 self._decode_jit = self._build_decode()
-            decoding = np.array([r is not None and r.state == "decoding"
-                                 for r in self._slots])
-            table, lens = self._table, self._lens
-            if any(r is not None and r.state == "prefill_partial"
-                   for r in self._slots):
+            states = [None if r is None else r.state for r in self._slots]
+            decoding = np.array([s == "decoding" for s in states])
+            masked = "prefill_partial" in states
+            send = None
+            if masked:
                 # half-prefilled slots ride the fixed-shape step MASKED
                 # to the scratch page at length 0: their pages hold a
                 # partial prompt whose next position the NEXT chunk
                 # owns — the decode append must not touch it (writes
                 # land on scratch, attention over an empty slot is
-                # defined zeros). Host lens/table keep the real values;
-                # only the device call sees the mask.
-                table = np.where(decoding[:, None], table,
-                                 self._scratch).astype(np.int32)
-                lens = np.where(decoding, lens, 0).astype(np.int32)
-            args = (self._fresh_state(), self._pools,
-                    jnp.asarray(table), jnp.asarray(lens),
-                    jnp.asarray(self._cur))
+                # defined zeros). The mirrors keep the real values and
+                # only the device call sees the mask, so what the
+                # program returns is not the mirrors' next state:
+                # every such step uploads.
+                send = self._packed.copy()
+                send[~decoding, :self.max_pages] = self._scratch
+                send[~decoding, self.max_pages] = 0
+            elif held is None:
+                send = self._packed
+            if send is not None:
+                held = self._place_resident(send)
+            args = (self._fresh_state(), self._pools, held)
         with self._phase("launch") as launch:
             with count_op_calls() as c:
-                nxt, pools, lens_new = self._decode_jit(*args)
+                nxt, pools, held = self._decode_jit(*args)
+        # counted once the program is launched: 0, fed its own outputs
+        self._tl_h2d = int(send is not None)
+        if send is None:
+            self.decode_steps_resident += 1
+        else:
+            self.decode_steps_uploaded += 1
         # the DISPATCH of the decode program, not the decode: on a
         # device the call returns futures, and the program's own time
         # passes inside the `wait` phase below
@@ -3709,19 +3833,24 @@ class ContinuousBatchingEngine:
             self._capture_cost("decode", self._decode_jit, args)
         self._pools = pools
         with self._phase("wait"):
-            # the uploaded arguments and the donated pools' handles are
-            # let go while the program runs, not after the emit loop
-            # (some hundred objects at 24 layers; otherwise they go at
-            # this function's return, in no phase)
-            del args, pools
-            nxt = np.asarray(nxt)
-            lens_new = np.asarray(lens_new)
+            # the arguments and the donated pools' handles are let go
+            # while the program runs, not after the emit loop (some
+            # hundred objects at 24 layers; otherwise they go at this
+            # function's return, in no phase)
+            del args, pools, send
+            nxt = np.asarray(nxt)  # the step's one fetch
         with self._phase("emit"):
-            # non-decoding slots wrote to the scratch page; keep their
-            # host length (0 for empty slots, prefill_done_len for
-            # half-prefilled ones)
-            self._lens = np.where(decoding, lens_new,
-                                  self._lens).astype(np.int32)
+            # the mirrors follow the device: a decoding slot's length
+            # grew by the token appended, its current token is the one
+            # sampled. Other slots wrote to the scratch page and keep
+            # their host values (0 for an empty slot, prefill_done_len
+            # for a half-prefilled one). Past this point mirrors and
+            # the returned copy agree, unless the step was masked; the
+            # finishes below go through _write_slot and drop it again.
+            self._lens[decoding] += 1
+            self._cur[decoding] = nxt[decoding]
+            self._resident = None if masked else held
+            del held
             self.steps += 1
             for slot, req in enumerate(self._slots):
                 if req is None or req.state != "decoding":
@@ -3729,7 +3858,6 @@ class ContinuousBatchingEngine:
                 tok = int(nxt[slot])
                 req.generated.append(tok)
                 req.stats.tokens_out = len(req.generated)
-                self._cur[slot] = tok
                 if req.trace is not None:
                     # pre-timed closed span: one list append per traced
                     # in-flight request, no extra clock reads per slot
@@ -3738,10 +3866,10 @@ class ContinuousBatchingEngine:
                                   token=tok)
                 self._emit_token(req, tok)
                 self._maybe_finish(slot)
-            # the fetched results go here, in the phase that read them:
+            # the fetched result goes here, in the phase that read it:
             # letting a device result's buffer go is not free, and at
             # this function's return it would fall in no phase
-            del nxt, lens_new
+            del nxt
             return self.num_active
 
     def run(self, max_steps: int = 100000) -> Dict[int, np.ndarray]:

@@ -1,0 +1,381 @@
+"""The decode step's inputs stay on the device (ISSUE 26).
+
+The single-step decode program takes ONE packed int32 array
+``[num_slots, max_pages + 2]`` (page table | length | current token)
+and returns the next step's. The engine holds that output
+(``_resident``) and feeds it back; the host mirrors stay the truth and
+are sent, in one transfer, only on the decode step after a host write
+(``_write_slot`` drops the copy) or a failed step. What is pinned here:
+
+- tokens are bit-identical to an engine whose copy is dropped before
+  every step, through the same writer, on the plain, prefix-cache,
+  chunked-prefill, ``multi_step``, speculative and mesh engines, with
+  one compiled decode program either way;
+- a clean step makes no host-to-device transfer and one device-to-host
+  fetch (the tokens), and records ``decode_h2d`` 0; a stale one records
+  1; a step with a half-prefilled slot is always stale;
+- admission, finish, eviction, a deadline that passes inside a prefill,
+  an ``engine.step`` fault and a failed launch each leave the next
+  decode step stale, and the tokens what they were;
+- the device's lengths equal the host's own ``+ 1`` arithmetic while
+  slots fill and empty, and an empty slot's length stays 0 there;
+- ``flight_summary()``'s totals add up to the steps that decoded.
+"""
+
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.distributed import fault_inject as fi
+from paddle_tpu.distributed.topology import make_serving_mesh
+from paddle_tpu.inference import SpeculativeConfig, create_decode_engine
+from paddle_tpu.inference import continuous_batching as cb
+from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
+from paddle_tpu.serving.prefix_cache import PrefixCache
+
+PAGE = 8
+ENGINE_KW = dict(num_slots=2, page_size=PAGE, max_seq_len=64,
+                 timeline_steps=4096)
+
+# engine variants; the last three never run `_decode_step` with a copy
+# to hold (a masked step, or a path that builds its own arguments) and
+# must come out the same all the more
+VARIANTS = {
+    "plain": lambda: {},
+    "prefix_cache": lambda: {"prefix_cache": PrefixCache(PAGE)},
+    "mesh": lambda: {"mesh": make_serving_mesh(2)},
+    "chunked": lambda: {"prefill_chunk_tokens": PAGE},
+    "multi_step": lambda: {"multi_step": 2},
+    "speculative": lambda: {
+        "speculative": SpeculativeConfig(k=2, draft="ngram")},
+}
+SINGLE_STEP = ("plain", "prefix_cache", "mesh", "chunked")
+
+
+@pytest.fixture(autouse=True)
+def _clean_injector():
+    fi.reset()
+    yield
+    fi.reset()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache(module_compile_cache):
+    yield
+
+
+@pytest.fixture(scope="module")
+def model():
+    pt.seed(0)
+    m = GPTForCausalLM(gpt_tiny())
+    m.eval()
+    return m
+
+
+def _engine(m, **kw):
+    return create_decode_engine(m, **{**ENGINE_KW, **kw})
+
+
+def _prompts():
+    """Six prompts over two slots: slots are refilled mid-flight, and
+    the last two repeat the first two (whole pages for a prefix cache
+    to hit)."""
+    rng = np.random.default_rng(0)
+    first = [rng.integers(0, 1024, n).astype(np.int32)
+             for n in (17, 9, 13, 20)]
+    return first + [first[0].copy(), first[3].copy()]
+
+
+def _serve(eng, always_stale=False, new_tokens=(9, 5, 12, 7, 6, 8)):
+    """Run the prompts to the end; ``always_stale`` drops the device's
+    copy before every step through the engine's own writer."""
+    rids = [eng.submit(p, n) for p, n in zip(_prompts(), new_tokens)]
+    while eng.num_queued or eng.num_active:
+        if always_stale:
+            eng._write_slot(0)
+        eng.step()
+    return [eng.result(r).tolist() for r in rids]
+
+
+def _h2d(eng):
+    """``decode_h2d`` of the records that carry it, oldest first."""
+    return [e["decode_h2d"] for e in eng.timeline if "decode_h2d" in e]
+
+
+def _decode_until_clean(eng, limit=8):
+    """Step until a step decoded without an upload."""
+    for _ in range(limit):
+        eng.step()
+        if _h2d(eng)[-1:] == [0]:
+            return
+    raise AssertionError(f"no clean step in {limit}: {_h2d(eng)}")
+
+
+class _CountingNumpy:
+    """``numpy`` for the engine module, counting its fetches of device
+    arrays (``np.asarray`` is how the engine reads a result)."""
+
+    def __init__(self):
+        self.fetched = []
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, x, *a, **k):
+        if isinstance(x, jax.Array):
+            self.fetched.append(tuple(x.shape))
+        return np.asarray(x, *a, **k)
+
+
+# ---------------------------------------------------------------------------
+# Bit-identity against the always-stale engine
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_tokens_identical_to_an_always_stale_engine(model, variant):
+    resident = _engine(model, **VARIANTS[variant]())
+    got = _serve(resident)
+    stale = _engine(model, **VARIANTS[variant]())
+    want = _serve(stale, always_stale=True)
+    assert got == want
+    if variant in SINGLE_STEP:
+        # one compiled decode program, whichever way its input came
+        assert resident._decode_jit._cache_size() == 1
+        assert stale._decode_jit._cache_size() == 1
+        assert set(_h2d(stale)) == {1}
+        assert 0 in _h2d(resident)
+    else:
+        assert _h2d(resident) == [] and resident._decode_jit is None
+    resident.close()
+    stale.close()
+
+
+# ---------------------------------------------------------------------------
+# Transfers of a clean and of a stale step
+# ---------------------------------------------------------------------------
+
+def test_clean_step_uploads_nothing_and_fetches_once(model, monkeypatch):
+    eng = _engine(model)
+    eng.submit(_prompts()[0], 12)
+    eng.submit(_prompts()[1], 12)
+    eng.step()  # admits both: this step's decode uploads
+    assert _h2d(eng) == [1] and eng._resident is not None
+    counting = _CountingNumpy()
+    monkeypatch.setattr(cb, "np", counting)
+    for _ in range(3):
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            eng.step()
+    assert _h2d(eng) == [1, 0, 0, 0]
+    # one fetch a step: the tokens, and nothing else
+    assert counting.fetched == [(eng.num_slots,)] * 3
+
+
+def test_stale_step_is_one_transfer(model, monkeypatch):
+    eng = _engine(model)
+    eng.submit(_prompts()[0], 12)
+    _decode_until_clean(eng)
+    puts = []
+    real = eng._place_resident
+    monkeypatch.setattr(eng, "_place_resident",
+                        lambda a: puts.append(a.shape) or real(a))
+    eng._write_slot(1)  # a host write that changes nothing
+    assert eng._resident is None
+    # the guard lets the one explicit transfer through and nothing
+    # implicit beside it
+    with jax.transfer_guard_host_to_device("disallow"):
+        eng.step()
+    assert _h2d(eng)[-1] == 1
+    assert puts == [(eng.num_slots, eng.max_pages + 2)]
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        eng.step()
+    assert _h2d(eng)[-1] == 0 and len(puts) == 1
+
+
+def test_a_half_prefilled_slot_keeps_every_step_stale(model):
+    eng = _engine(model, prefill_chunk_tokens=PAGE)
+    eng.submit(_prompts()[1], 30)  # 9 tokens: two chunks
+    while not any(r is not None and r.state == "decoding"
+                  for r in eng._slots):
+        eng.step()
+    _decode_until_clean(eng)
+    eng.submit(_prompts()[3], 4)  # 20 tokens: three chunks
+    seen = []
+    for _ in range(3):
+        eng.step()
+        partial = any(r is not None and r.state == "prefill_partial"
+                      for r in eng._slots)
+        seen.append((partial, eng.timeline[-1]["decode_h2d"],
+                     eng._resident is None))
+    # masked steps upload and leave nothing behind to reuse
+    assert (True, 1, True) in seen
+    assert all(h2d == 1 and gone for partial, h2d, gone in seen if partial)
+
+
+# ---------------------------------------------------------------------------
+# What makes the next step stale
+# ---------------------------------------------------------------------------
+
+def _reference_tokens(model, new_tokens):
+    eng = _engine(model)
+    rid = eng.submit(_prompts()[0], new_tokens)
+    return eng.run()[rid].tolist()
+
+
+def _admission(eng):
+    eng.submit(_prompts()[1], 3)
+    return "queued"  # the next step's admission is the host write
+
+
+def _finish(eng):
+    # the short request of the pair is one token from its end
+    short = eng._slots[1]
+    while len(short.generated) < short.max_new_tokens - 1:
+        eng.step()
+    assert _h2d(eng)[-1] == 0
+    eng.step()
+    assert short.done and _h2d(eng)[-1] == 0  # it finished after its step
+
+
+def _eviction(eng):
+    victim = eng._slots[1]
+    victim.deadline_t = time.monotonic() - 1.0
+    assert eng.expire_deadlines() == [victim]
+    assert victim.state == "deadline"
+
+
+def _deadline_in_prefill(eng):
+    """The deadline passes while the prefill runs: the admission is
+    unwound after the pools were adopted."""
+    real = eng._get_prefill(False)
+
+    def slow(*a):
+        time.sleep(0.3)
+        return real(*a)
+
+    eng._prefill_jits[False] = slow
+    eng.submit(_prompts()[2], 5, deadline_t=time.monotonic() + 0.15)
+    late = eng._queue[-1]
+    eng.step()
+    eng._prefill_jits[False] = real
+    assert late.state == "deadline" and eng.num_active == 1
+    return "this_step"
+
+
+def _step_fault(eng):
+    fi.get_injector().arm("engine.step", at_calls=[1])
+    with pytest.raises(fi.InjectedFault):
+        eng.step()
+    fi.reset()
+
+
+def _launch_fault(eng):
+    real = eng._decode_jit
+
+    def broken(*a):
+        raise RuntimeError("launch failed")
+
+    eng._decode_jit = broken
+    with pytest.raises(RuntimeError, match="launch failed"):
+        eng.step()
+    eng._decode_jit = real
+
+
+EVENTS = {"admission": (_admission, False), "finish": (_finish, True),
+          "eviction": (_eviction, True),
+          "deadline_in_prefill": (_deadline_in_prefill, False),
+          "engine_step_fault": (_step_fault, False),
+          "launch_fault": (_launch_fault, False)}
+
+
+@pytest.mark.parametrize("event", sorted(EVENTS))
+def test_event_leaves_the_next_step_stale_and_correct(model, event):
+    happen, second_slot = EVENTS[event]
+    eng = _engine(model)
+    rid = eng.submit(_prompts()[0], 24)
+    if second_slot:
+        eng.submit(_prompts()[1], 6)
+    _decode_until_clean(eng)
+    when = happen(eng)
+    if when is None:
+        assert eng._resident is None
+    if when != "this_step":
+        eng.step()
+    assert eng.timeline[-1]["decode_h2d"] == 1
+    eng.step()
+    assert eng.timeline[-1]["decode_h2d"] == 0
+    assert eng.run()[rid].tolist() == _reference_tokens(model, 24)
+
+
+def test_a_rebuilt_engine_starts_stale_and_continues_the_stream(model):
+    """Resurrection: what was in flight is replayed (prompt plus the
+    tokens already out) on a new engine, whose first decode step has
+    nothing on the device to reuse."""
+    eng = _engine(model)
+    eng.submit(_prompts()[0], 24)
+    _decode_until_clean(eng)
+    (req,) = eng.dump_inflight()
+    done = list(req.generated)
+    assert 0 < len(done) < 24
+    eng.close()
+    fresh = _engine(model)
+    assert fresh._resident is None
+    rid = fresh.submit(np.concatenate([req.prompt, done]).astype(np.int32),
+                       24 - len(done))
+    fresh.step()
+    assert _h2d(fresh) == [1]
+    # a result is the prompt and everything generated after it
+    assert fresh.run()[rid].tolist() == _reference_tokens(model, 24)
+
+
+# ---------------------------------------------------------------------------
+# The host's arithmetic against the device's
+# ---------------------------------------------------------------------------
+
+def test_device_lengths_follow_the_host_mirror(model):
+    eng = _engine(model, num_slots=3, max_seq_len=96)
+    mp = eng.max_pages
+    rng = np.random.default_rng(1)
+    # slot 2 stays empty for the first stretch, then requests of
+    # different lengths fill and empty all three
+    lengths = [(11, 30), (5, 30)] + [
+        (int(rng.integers(3, 20)), int(rng.integers(2, 9)))
+        for _ in range(8)]
+    pending = [(rng.integers(0, 1024, n).astype(np.int32), k)
+               for n, k in lengths]
+    for p, k in pending[:2]:
+        eng.submit(p, k)
+    pending = pending[2:]
+    compared = empty_seen = 0
+    for step in range(6 * PAGE):
+        if step >= 3 * PAGE // 2 and pending and step % 3 == 0:
+            eng.submit(*pending.pop())
+        eng.step()
+        if eng._resident is None:
+            continue
+        dev = np.asarray(eng._resident)
+        assert dev.shape == eng._packed.shape
+        np.testing.assert_array_equal(dev[:, mp], eng._lens)
+        np.testing.assert_array_equal(dev[:, :mp], eng._table)
+        busy = np.array([r is not None for r in eng._slots])
+        np.testing.assert_array_equal(dev[busy, mp + 1], eng._cur[busy])
+        assert (dev[~busy, mp] == 0).all()
+        compared += 1
+        empty_seen += int((~busy).any())
+    assert compared >= 3 * PAGE and empty_seen >= PAGE
+    assert not pending
+    eng.run()
+
+
+def test_flight_summary_totals_add_up(model):
+    eng = _engine(model)
+    _serve(eng)
+    card = eng.flight_summary()
+    h2d = _h2d(eng)
+    assert card["decode_steps_uploaded"] == sum(h2d) > 0
+    assert card["decode_steps_resident"] == h2d.count(0) > 0
+    assert len(h2d) == eng.programs_launched["decode"]
+    assert all(("decode_h2d" in e) == ("decode" in e["programs"])
+               for e in eng.timeline)
