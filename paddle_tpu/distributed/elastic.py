@@ -57,8 +57,19 @@ class FileMembershipStore(MembershipStore):
 
     def register(self, job_id: str, rank: int, meta: Dict) -> None:
         meta = dict(meta, ts=time.time(), host=socket.gethostname())
-        with open(self._path(job_id, rank), "w") as f:
+        self._write(self._path(job_id, rank), meta)
+
+    @staticmethod
+    def _write(path: str, meta: Dict) -> None:
+        """Whole or not at all: a reader of ``members`` that met the file
+        truncated mid-rewrite saw the rank vanish for one observation
+        (a watcher whose FIRST observation that was never saw the scale-
+        down that followed)."""
+        tmp = os.path.join(os.path.dirname(path),
+                           f".{os.path.basename(path)}.{os.getpid()}.tmp")
+        with open(tmp, "w") as f:
             json.dump(meta, f)
+        os.replace(tmp, path)
 
     def heartbeat(self, job_id: str, rank: int) -> None:
         from .fault_inject import fault_point
@@ -68,8 +79,7 @@ class FileMembershipStore(MembershipStore):
             with open(p) as f:
                 meta = json.load(f)
             meta["ts"] = time.time()
-            with open(p, "w") as f:
-                json.dump(meta, f)
+            self._write(p, meta)
 
     def deregister(self, job_id: str, rank: int) -> None:
         try:

@@ -203,3 +203,101 @@ class MoELayer(Layer):
         if self.last_aux_loss is None:
             return None
         return self.last_aux_loss * self.aux_loss_weight
+
+
+# --------------------------------------------------------------------------
+# Dropless routing for serving: every pick is computed, none overflows
+# --------------------------------------------------------------------------
+
+def route_top_k(h, w_router, top_k: int):
+    """Router of a layer whose gates are a softmax over the picked
+    logits (equal to a softmax over all experts renormalised over the
+    picks). ``h`` [T, H] is read in float32 against ``w_router`` [H, E]
+    at the highest matmul precision: an expert's pick must not turn on
+    how the MXU rounds. Returns ``(idx [T, k] int32, gates [T, k]
+    f32)``."""
+    logits = jnp.matmul(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision="highest")
+    vals, idx = jax.lax.top_k(logits, top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+
+
+def expert_tile_rows(picks: int, experts: int, itemsize: int) -> int:
+    """Rows of one tile of the grouped layout: a power of two near the
+    mean rows an expert gets, from one sublane tile (decode: 96 picks
+    over 64 experts) to 256 (a long prefill, where the MXU wants tall
+    tiles and padding every expert to a tile costs a sixth)."""
+    floor = 8 * max(1, 4 // itemsize)
+    tm = floor
+    while tm < 256 and tm * experts < picks:
+        tm *= 2
+    return tm
+
+
+def dropless_experts(u, idx, gates, w_gate, w_up, w_down, *,
+                     held=None, valid=None):
+    """The part of ``sum_k gates[t, k] * FFN_{idx[t, k]}(u[t])`` that the
+    experts held here give, with ``FFN_e(x) = (relu(x @ w_gate[e]) * (x
+    @ w_up[e])) @ w_down[e]``. No capacity and no drop: every pick of a
+    held expert is computed.
+
+    u: [T, H]; idx, gates: [T, k] over ALL experts; w_gate, w_up:
+    [held, H, F]; w_down: [held, F, H]; ``held`` = (first, count) of the
+    expert ids these weights are (default: all of them from 0);
+    ``valid`` [T] bool drops whole rows (padding, empty slots) before
+    they cost a weight read. Picks of experts not held and rows not
+    valid contribute nothing.
+
+    Rows are laid out by expert in tiles of ``tm`` rows, one expert a
+    tile (ops/pallas/grouped_matmul.py): the rank of a pick among its
+    expert's picks is a cumulative sum over a one-hot, so no sort and no
+    scatter-add runs, and the result does not depend on an order of
+    additions other than over k. Returns ``(y [T, H] in u's dtype,
+    counts [held] int32: picks computed per expert)``."""
+    from ..ops.pallas.grouped_matmul import grouped_ffn_in, grouped_matmul
+
+    t, h = u.shape
+    k = idx.shape[1]
+    n_held = w_gate.shape[0]
+    first = 0 if held is None else int(held[0])
+    tm = expert_tile_rows(t * k, n_held, u.dtype.itemsize)
+    tiles = -(-(t * k) // tm) + n_held
+    mp = tiles * tm
+
+    e = idx.reshape(-1) - first  # [T*k], expert id among those held
+    keep = (e >= 0) & (e < n_held)
+    if valid is not None:
+        keep = keep & jnp.repeat(valid, k)
+    onehot = (e[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None]) \
+        & keep[:, None]
+    oh = onehot.astype(jnp.int32)
+    counts = jnp.sum(oh, axis=0)  # [held]
+    rank = jnp.sum((jnp.cumsum(oh, axis=0) - 1) * oh, axis=1)
+    tiles_of = -(-counts // tm)
+    tile_end = jnp.cumsum(tiles_of)  # tiles used up to and with expert e
+    start_row = (tile_end - tiles_of) * tm
+    dest = jnp.where(keep, start_row[jnp.clip(e, 0, n_held - 1)] + rank, mp)
+    used = tile_end[-1]
+    # each tile's expert; tiles past the last used one repeat its expert
+    # so that their weight block is the one already in VMEM
+    tile_ids = jnp.minimum(jnp.arange(tiles, dtype=jnp.int32),
+                           jnp.maximum(used - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, tile_ids, side="right"),
+        n_held - 1).astype(jnp.int32)
+    token = jnp.arange(t * k, dtype=jnp.int32) // k
+    row_token = jnp.zeros((mp,), jnp.int32).at[dest].set(
+        token, mode="drop", unique_indices=True)
+    xs = u[row_token]  # [mp, H]; pad rows repeat row 0, never read back
+    used1 = used.reshape(1).astype(jnp.int32)
+    mid = grouped_ffn_in(xs, w_gate, w_up, tile_expert, used1, tm)
+    ys = grouped_matmul(mid, w_down, tile_expert, used1, tm)
+    # pick by pick, in k's order: one [T, H] gather at a time, so a long
+    # prefill never holds all T * k gathered rows in float32
+    at = jnp.minimum(dest, mp - 1).reshape(t, k)
+    w = jnp.where(keep.reshape(t, k), gates.astype(jnp.float32), 0.0)
+    y = jnp.zeros((t, h), jnp.float32)
+    for j in range(k):
+        row = jnp.where(w[:, j, None] > 0, ys[at[:, j]], 0)
+        y = y + row.astype(jnp.float32) * w[:, j, None]
+    return y.astype(u.dtype), counts

@@ -507,8 +507,8 @@ class ContinuousBatchingEngine:
         import jax.numpy as jnp
 
         from ..core.compile_cache import enable_compile_cache
-        from ..nn.layer import functional_state
-        from ..models.gpt import paged_cache_create
+        from ..models.cache_layout import (UnsupportedCacheLayout,
+                                           create_pools, ring_pages)
 
         # persistent compile cache (core/compile_cache.py places it):
         # the engine's prefill-per-bucket + decode/verify programs are
@@ -529,6 +529,37 @@ class ContinuousBatchingEngine:
         self.pause_admission = False
         cfg = model.config
         self.cfg = cfg
+        # what the model keeps per layer (models/cache_layout.py): KV
+        # heads, head size, a window or none. Layers that keep every
+        # position share the allocator's pages and the one page table;
+        # a window layer holds a ring of `ring_pages` pages a slot in
+        # a pool of its own. Everything below that differs between
+        # decoders comes from here, never from the model's class.
+        self._layout = list(model.cache_layout())
+        self._rings = [None if lc.window is None
+                       else ring_pages(lc.window, page_size)
+                       for lc in self._layout]
+        self._has_rings = any(r is not None for r in self._rings)
+        if not all(lc.plain for lc in self._layout):
+            # a ring holds a window's worth of ONE sequence's own keys,
+            # and a heads-major page is not the page the spill codecs,
+            # the int8 scales and the verify path read: what would have
+            # to restore, share, rewind or re-enter such a cache is
+            # refused here, typed, until it has a parity test
+            refused = [
+                ("a prefix cache (a hit cannot restore a window "
+                 "layer's ring)", prefix_cache is not None),
+                ("a serving mesh", mesh is not None),
+                ("int8 KV pages", bool(kv_int8)),
+                ("multi_step > 1", int(multi_step) > 1),
+                ("speculative decoding (a rejected draft cannot be "
+                 "rewound out of a ring)", speculative is not None),
+                ("chunked prefill", prefill_chunk_tokens is not None)]
+            for what, asked in refused:
+                if asked:
+                    raise UnsupportedCacheLayout(
+                        f"a cache layout with window layers or grouped "
+                        f"heads does not support {what} yet")
         # tensor-parallel serving (mesh=None = single-device, the
         # byte-for-byte pre-r10 behavior): weights shard per their
         # mp_layers pspecs, KV pools shard over heads, page table and
@@ -565,10 +596,11 @@ class ContinuousBatchingEngine:
                     f"serving mesh axes {extra} must have size 1 "
                     f"(only {axis!r} shards the decode engine)")
             n = int(mesh.shape[axis])
-            if cfg.num_heads % n:
+            kv_heads = sorted({lc.kv_heads for lc in self._layout})
+            if cfg.num_heads % n or any(h % n for h in kv_heads):
                 raise ValueError(
-                    f"num_heads {cfg.num_heads} not divisible by mesh "
-                    f"{axis}={n}")
+                    f"num_heads {cfg.num_heads} (KV heads {kv_heads}) "
+                    f"not divisible by mesh {axis}={n}")
             if cfg.vocab_size % n:
                 raise ValueError(
                     f"vocab_size {cfg.vocab_size} not divisible by "
@@ -631,22 +663,21 @@ class ContinuousBatchingEngine:
         self.forecast_admission = bool(forecast_admission)
         self.forecast_denials = 0
         self._scratch = self.num_pages  # reserved page index
-        dt = functional_state(model)["params"]["gpt.wte.weight"].dtype
-        nh, hd, nl = cfg.num_heads, cfg.head_dim, cfg.num_layers
-        self._nl = nl
+        dt = self._layout[0].dtype
+        self._nl = len(self._layout)
         # one DISTINCT pool per layer (not nl references to one array:
         # the jitted step donates the pool buffers, and donating the
-        # same buffer for two arguments is an error)
-        protos = [paged_cache_create(
-            1, self.num_pages, self.page_size, nh, hd, dt,
-            self.max_pages, quantized=self.kv_int8,
-            kv_sharding=self._kv_sharding) for _ in range(nl)]
-        self._pools = {
-            "k": [p.k_pages for p in protos],
-            "v": [p.v_pages for p in protos],
-            "ks": [p.k_scale for p in protos],
-            "vs": [p.v_scale for p in protos],
-        }
+        # same buffer for two arguments is an error); a window layer's
+        # pool is its rings, `num_slots * ring` pages and the scratch
+        protos = [create_pools(
+            lc, self.num_pages if ring is None else self.num_slots * ring,
+            self.page_size, self.max_pages, quantized=self.kv_int8,
+            kv_sharding=self._kv_sharding)
+            for lc, ring in zip(self._layout, self._rings)]
+        self._pools = {"k": [p[0] for p in protos],
+                       "v": [p[1] for p in protos],
+                       "ks": [p[2] for p in protos],
+                       "vs": [p[3] for p in protos]}
         # host-owned scheduler state. The three mirrors of the decode
         # step's inputs are views of ONE packed int32 array
         # ``[num_slots, max_pages + 2]`` (page table | length | current
@@ -664,6 +695,14 @@ class ContinuousBatchingEngine:
         # has made it stale: the next decode step then uploads
         self._resident = None
         self._tl_h2d: Optional[int] = None
+        # counters the model's own programs report (a routed model: the
+        # experts its rows touched), packed behind the tokens in the
+        # step's one fetch: names by program kind as traced, this
+        # step's values for the timeline record, running totals
+        self._traced_stats = None
+        self._stat_names: Dict[str, List[Tuple[str, str]]] = {}
+        self._tl_stats: Dict[str, Dict[str, Any]] = {}
+        self.model_counters: Dict[str, Dict[str, float]] = {}
         self.decode_steps_resident = 0
         self.decode_steps_uploaded = 0
         self._slots: List[Optional[DecodeRequest]] = \
@@ -1055,11 +1094,61 @@ class ContinuousBatchingEngine:
 
     # -- jitted device programs -------------------------------------------
 
-    def _caches(self, pools, table, lens):
+    def _caches(self, pools, table, lens, rows=None):
+        """One cache a layer over the pools. A window layer's table is
+        its slots' rings (``rows``: the slot of each batch row; the
+        decode step's rows are the slots in order)."""
+        from ..models.cache_layout import ring_table
         from ..models.gpt import PagedKVCache
+        if rows is None and self._has_rings:
+            rows = self._jnp.arange(table.shape[0], dtype=self._jnp.int32)
         return [PagedKVCache(pools["k"][i], pools["v"][i],
                              pools["ks"][i], pools["vs"][i],
-                             table, lens) for i in range(self._nl)]
+                             table if ring is None
+                             else ring_table(rows, ring), lens)
+                for i, ring in enumerate(self._rings)]
+
+    def _take_stats(self):
+        """At trace time, after the model's call: the counters of the
+        forward just traced (``pop_step_stats``: ``{group: {name: int32
+        scalar}}``), or None from a model that reports none."""
+        pop = getattr(self.model, "pop_step_stats", None)
+        self._traced_stats = None if pop is None else pop()
+
+    def _pack_stats(self, kind: str, nxt):
+        """The program's first result with the traced counters behind
+        the tokens, so that they come back in the fetch the step makes
+        anyway; ``nxt`` itself where the model reports none (then the
+        program is the one it always was)."""
+        stats, self._traced_stats = self._traced_stats, None
+        if not stats:
+            return nxt
+        jnp = self._jnp
+        names = [(g, k) for g in sorted(stats) for k in sorted(stats[g])]
+        self._stat_names[kind] = names
+        return jnp.concatenate(
+            [nxt.reshape(-1).astype(jnp.int32),
+             jnp.stack([stats[g][k].astype(jnp.int32)
+                        for g, k in names])])
+
+    def _fold_stats(self, kind: str, values) -> None:
+        """Counters fetched behind a program's tokens: into this step's
+        record (the largest where a step ran the program twice) and
+        the running totals. A name that ends in ``_x1000`` carries
+        thousandths."""
+        for (group, key), v in zip(self._stat_names.get(kind, ()), values):
+            v = float(v)
+            if key.endswith("_x1000"):
+                key, v = key[:-6], v / 1000.0
+            elif v == int(v):
+                v = int(v)
+            rec = self._tl_stats.setdefault(group, {})
+            rec[key] = max(v, rec.get(key, v))
+            tot = self.model_counters.setdefault(
+                f"{group}.{key}", {"n": 0, "sum": 0.0, "max": v})
+            tot["n"] += 1
+            tot["sum"] += v
+            tot["max"] = max(tot["max"], v)
 
     def _fresh_state(self, refresh: bool = False):
         """Model functional state (params AND buffers — converted
@@ -1462,6 +1551,15 @@ class ContinuousBatchingEngine:
             entry[k] = round(v, 4)
         if self._tl_h2d is not None:
             entry["decode_h2d"] = self._tl_h2d
+        if self._has_rings:
+            # pages in use by kind of layer, a layer of each: the
+            # allocator's (every position kept) and the rings' (a
+            # sequence never holds more than its ring)
+            entry["kv_pages"] = {
+                "global": self.num_pages - entry["free_pages"],
+                "window": self.window_pages_in_use()}
+        # the model's own counters of this step's programs
+        entry.update(self._tl_stats)
         # multi-step decode (r19): the boundary that drained a macro
         # launch marks its entry with the launch's attribution
         # (per_token_timeline() reconstructs per-step rows from it)
@@ -1470,6 +1568,13 @@ class ContinuousBatchingEngine:
             self._tl_macro = None
         self.timeline.append(entry)
         return entry
+
+    def window_pages_in_use(self) -> int:
+        """Ring pages that hold a live sequence's keys, in one window
+        layer: ``min(ring, ceil(len / page))`` over the active slots."""
+        ring = max((r for r in self._rings if r is not None), default=0)
+        return sum(min(ring, -(-int(self._lens[i]) // self.page_size))
+                   for i, r in enumerate(self._slots) if r is not None)
 
     def step_timeline(self) -> List[Dict[str, Any]]:
         """Snapshot of the per-step ring (oldest first) — the server's
@@ -1504,6 +1609,10 @@ class ContinuousBatchingEngine:
             "macro_launches": int(self.macro_launches),
             "decode_steps_resident": int(self.decode_steps_resident),
             "decode_steps_uploaded": int(self.decode_steps_uploaded),
+            "model_counters": {k: dict(v) for k, v in
+                               self.model_counters.items()},
+            "window_ring_pages": max(
+                (r for r in self._rings if r is not None), default=None),
             "speculative": self._spec_cfg is not None,
             "mesh": self.mesh_info(),
             "programs_launched": dict(self.programs_launched),
@@ -1815,6 +1924,7 @@ class ContinuousBatchingEngine:
                     # sampler (nn/decode.py) — the same call generate()
                     # and the speculative verify make
                     nxt, _ = sample_token(raw(logits)[:, -1], 0.0)
+                self._take_stats()
             new_pools = {
                 "k": [raw(c.k_pages) for c in nc],
                 "v": [raw(c.v_pages) for c in nc],
@@ -1860,7 +1970,7 @@ class ContinuousBatchingEngine:
                 # back in hits the same compiled program
                 packed = jax.lax.with_sharding_constraint(packed,
                                                           replicated)
-            return nxt, pools, packed
+            return self._pack_stats("decode", nxt), pools, packed
 
         # donate the pools: the append scatters then update the pool
         # buffers IN PLACE instead of materializing a fresh copy of
@@ -1977,8 +2087,8 @@ class ContinuousBatchingEngine:
         def raw(t):
             return t.value if isinstance(t, Tensor) else t
 
-        def prefill(state, pools, trow, slens, plen, ids):
-            caches = self._caches(pools, trow, slens)
+        def prefill(state, pools, trow, slens, plen, ids, rows=None):
+            caches = self._caches(pools, trow, slens, rows)
             with jax.named_scope(
                     "pt.prefill_chained" if chained else "pt.prefill"), \
                     self._head_ctx(), self._fuse_ctx(), \
@@ -2003,7 +2113,9 @@ class ContinuousBatchingEngine:
                         prefill_chained=chained)
                     nxt, _ = sample_token(raw(logits)[:1, plen[0] - 1],
                                           0.0)
-            nxt = nxt[0]
+                self._take_stats()
+            nxt = self._pack_stats(
+                "prefill_chained" if chained else "prefill", nxt[0])
             new_pools = {
                 "k": [raw(c.k_pages) for c in nc],
                 "v": [raw(c.v_pages) for c in nc],
@@ -2738,6 +2850,9 @@ class ContinuousBatchingEngine:
                         jnp.asarray([cached_len], jnp.int32),
                         jnp.asarray([len(suffix)], jnp.int32),
                         jnp.asarray(ids))
+                if self._has_rings:
+                    # whose rings the window layers write
+                    args += (jnp.asarray([slot], jnp.int32),)
             with self._phase("launch"):
                 with count_op_calls() as c:
                     out = jit(*args)
@@ -2768,7 +2883,13 @@ class ContinuousBatchingEngine:
             raise
         self._pools = pools
         with self._phase("wait"):
-            tok = int(nxt)  # blocks until the prefill program has run
+            # blocks until the prefill program has run; the model's
+            # counters, where it reports any, ride behind the token
+            got = np.asarray(nxt).reshape(-1)
+            tok = int(got[0])
+            if got.size > 1:
+                self._fold_stats(
+                    "prefill_chained" if chained else "prefill", got[1:])
         # the first token exists from here: `now` is the end of that
         # wait, and prefill_ms the argument build, the dispatch and
         # the wait together, from the phases' own stamps
@@ -2899,6 +3020,9 @@ class ContinuousBatchingEngine:
                         jnp.asarray([done], jnp.int32),
                         jnp.asarray([len(suffix)], jnp.int32),
                         jnp.asarray(ids))
+                if self._has_rings:
+                    # whose rings the window layers write
+                    args += (jnp.asarray([slot], jnp.int32),)
             with self._phase("launch"):
                 with count_op_calls() as c:
                     out = jit(*args)
@@ -3717,6 +3841,7 @@ class ContinuousBatchingEngine:
             self._tl_programs = {}
             self._tl_ms = {}
             self._tl_h2d = None
+            self._tl_stats = {}
             self._host.take()  # phases outside a step: no record's
             if self.ledger is not None:
                 self.ledger.step = self.steps
@@ -3839,6 +3964,10 @@ class ContinuousBatchingEngine:
             # function's return, in no phase)
             del args, pools, send
             nxt = np.asarray(nxt)  # the step's one fetch
+            if nxt.size > self.num_slots:
+                # the model's counters came back behind the tokens
+                self._fold_stats("decode", nxt[self.num_slots:])
+                nxt = nxt[:self.num_slots]
         with self._phase("emit"):
             # the mirrors follow the device: a decoding slot's length
             # grew by the token appended, its current token is the one

@@ -1085,6 +1085,15 @@ class GPTForCausalLM(Layer):
             return None
         return w, False, getattr(self.lm_head, "bias", None)
 
+    def cache_layout(self):
+        """What the serving engine builds its pools from
+        (models/cache_layout.py): every layer alike, one KV head a
+        query head, every position kept."""
+        from .cache_layout import LayerCache
+        c = self.config
+        return [LayerCache(c.num_heads, c.head_dim, None,
+                           self.gpt.wte.weight.dtype)] * c.num_layers
+
     def decode_hidden(self, input_ids, caches, prefill_lens=None,
                       prefill_chained=False):
         """Cached forward returning FINAL HIDDEN STATES instead of

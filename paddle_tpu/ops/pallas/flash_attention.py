@@ -53,7 +53,7 @@ _GRID_SEMANTICS = pltpu.CompilerParams(
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
-                *, scale, causal, block_q, block_k, nk):
+                *, scale, causal, block_q, block_k, nk, window=None):
     qb = pl.program_id(2)
     kb = pl.program_id(3)
 
@@ -72,6 +72,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
     # regression is steeper; the extra branch breaks Mosaic's pipeline.
     # The single masked body stays.)
     relevant = (kb * block_k <= (qb + 1) * block_q - 1) if causal else True
+    if window is not None:
+        # a query at p sees keys p - window + 1 .. p: K blocks that end
+        # before the first row's bound contribute nothing either
+        relevant = relevant & (
+            (kb + 1) * block_k - 1 >= qb * block_q - (window - 1))
 
     @pl.when(relevant)
     def _step():
@@ -88,7 +93,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            seen = q_pos >= k_pos
+            if window is not None:
+                seen = seen & (k_pos > q_pos - window)
+            s = jnp.where(seen, s, _NEG_INF)
         m_run = m_ref[:, :1]  # [BQ, 1]
         l_run = l_run_ref[:, :1]
         m_blk = jnp.max(s, axis=1, keepdims=True)
@@ -112,7 +120,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
 
 
 def _fwd_single_block_kernel(q_ref, k_ref, v_ref, o_ref, l_ref,
-                             *, scale, causal, block_q, block_k):
+                             *, scale, causal, block_q, block_k,
+                             window=None):
     """Forward for the nk == 1 case (the whole K axis is one block,
     e.g. S=512 at the default 512 block): a plain in-register softmax.
     The streaming kernel's online-softmax machinery — running max,
@@ -130,7 +139,10 @@ def _fwd_single_block_kernel(q_ref, k_ref, v_ref, o_ref, l_ref,
             jnp.int32, (block_q, block_k), 0)
         k_pos = jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen = seen & (k_pos > q_pos - window)
+        s = jnp.where(seen, s, _NEG_INF)
     m = jnp.max(s, axis=1, keepdims=True)
     p = jnp.exp(s - m)
     denom = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
@@ -307,12 +319,19 @@ def _spec_outer(block, d):
                         memory_space=pltpu.VMEM)
 
 
-def _spec_inner(block, d, clamp=None):
+def _spec_inner(block, d, clamp=None, group=1):
     """Block streamed by the INNER grid axis (grid dim 3). ``clamp(i, j)``
     maps the stream index per outer block — causal kernels clamp masked
     steps to the last/first relevant block, so Pallas sees a repeated
     block index and skips the HBM re-fetch for steps pl.when guards off.
+    ``group`` > 1: grouped heads, query head h streams KV head
+    h // group.
     """
+    if group > 1:
+        keep = clamp or (lambda i, j: j)
+        return pl.BlockSpec((1, 1, block, d),
+                            lambda b, h, i, j: (b, h // group, keep(i, j), 0),
+                            memory_space=pltpu.VMEM)
     if clamp is None:
         return pl.BlockSpec((1, 1, block, d),
                             lambda b, h, i, j: (b, h, j, 0),
@@ -351,20 +370,30 @@ def _spec3_indexed(block, d, lim=None):
                         memory_space=pltpu.VMEM)
 
 
-def _spec3_pinned(block, d):
+def _spec3_pinned(block, d, group=1):
     """3-dim-grid spec: the same (b, h) block regardless of the third
-    grid axis (the single outer block of an nq==1/nk==1 kernel)."""
+    grid axis (the single outer block of an nq==1/nk==1 kernel);
+    ``group`` > 1 pins KV head h // group."""
+    if group > 1:
+        return pl.BlockSpec((1, 1, block, d),
+                            lambda b, h, i: (b, h // group, 0, 0),
+                            memory_space=pltpu.VMEM)
     return pl.BlockSpec((1, 1, block, d),
                         lambda b, h, i: (b, h, 0, 0),
                         memory_space=pltpu.VMEM)
 
 
-def _kv_clamp(causal, block_q, block_k):
-    """For Q-outer kernels: the last K block visible to Q block i."""
+def _kv_clamp(causal, block_q, block_k, window=None):
+    """For Q-outer kernels: the last K block visible to Q block i and,
+    under a window, the first."""
     if not causal:
         return None
-    return lambda i, j: jnp.minimum(
-        j, ((i + 1) * block_q - 1) // block_k)
+    if window is None:
+        return lambda i, j: jnp.minimum(
+            j, ((i + 1) * block_q - 1) // block_k)
+    return lambda i, j: jnp.clip(
+        j, jnp.maximum(i * block_q - (window - 1), 0) // block_k,
+        ((i + 1) * block_q - 1) // block_k)
 
 
 def _q_clamp(causal, block_q, block_k):
@@ -374,10 +403,17 @@ def _q_clamp(causal, block_q, block_k):
     return lambda i, j: jnp.maximum(j, (i * block_k) // block_q)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, group=1,
+               window=None):
+    """``group`` > 1: k, v are [B, H // group, S, D] and query head h
+    reads KV head h // group. ``window``: with ``causal``, a query at p
+    sees keys p - window + 1 .. p; blocks behind the window are skipped
+    (neither fetched nor computed), the edge block is masked. Both
+    default to the plain kernel, whose program they leave untouched."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     nk = sk // block_k
+    extra = {} if window is None else {"window": int(window)}
     if nk == 1:
         # one K block: plain softmax kernel, no streaming axis — every
         # grid dim is parallel and the online-softmax scratch vanishes
@@ -385,11 +421,11 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
             "flash_fwd_single",
             functools.partial(_fwd_single_block_kernel, scale=scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k),
+                              block_k=block_k, **extra),
             grid=(b, h, sq // block_q),
             in_specs=[_spec3_indexed(block_q, d),
-                      _spec3_pinned(block_k, d),
-                      _spec3_pinned(block_k, d)],
+                      _spec3_pinned(block_k, d, group),
+                      _spec3_pinned(block_k, d, group)],
             out_specs=[_spec3_indexed(block_q, d),
                        _spec3_indexed(block_q, 1)],
             out_shape=[
@@ -408,13 +444,15 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
         return out, lse
     grid = (b, h, sq // block_q, nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, nk=nk)
-    kvc = _kv_clamp(causal, block_q, block_k)
+                               block_q=block_q, block_k=block_k, nk=nk,
+                               **extra)
+    kvc = _kv_clamp(causal, block_q, block_k, window)
     out, lse = named_pallas_call(
         "flash_fwd", kernel,
         grid=grid,
-        in_specs=[_spec_outer(block_q, d), _spec_inner(block_k, d, kvc),
-                  _spec_inner(block_k, d, kvc)],
+        in_specs=[_spec_outer(block_q, d),
+                  _spec_inner(block_k, d, kvc, group),
+                  _spec_inner(block_k, d, kvc, group)],
         out_specs=[
             _spec_outer(block_q, d),
             _spec_lane1_outer(block_q),
@@ -586,6 +624,25 @@ def flash_attention_lse(q, k, v, causal: bool = False,
     out, lse = _flash_attention_bhsd_lse(qT, kT, vT, float(scale),
                                          bool(causal), block_q, block_k)
     return jnp.swapaxes(out, 1, 2), jnp.swapaxes(lse[..., 0], 1, 2)
+
+
+def flash_attention_grouped(q, k, v, window: Optional[int] = None,
+                            scale: Optional[float] = None,
+                            block_q: int = DEFAULT_BLOCK_Q,
+                            block_k: int = DEFAULT_BLOCK_K):
+    """Causal forward for serving prefill, layout [B, S, H, D] with
+    k, v [B, S, KVH, D], KVH dividing H: query head h reads KV head
+    h // (H // KVH) through the K/V block index maps (K and V are not
+    repeated). ``window``: a query at p sees keys p - window + 1 .. p.
+    Forward only (no vjp): the same kernels as ``flash_attention``."""
+    b, sq, h, d = q.shape
+    group = h // k.shape[2]
+    block_q, block_k = _resolve_blocks(sq, k.shape[1], block_q, block_k)
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    out, _ = _flash_fwd(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                        jnp.swapaxes(v, 1, 2), float(scale), True,
+                        block_q, block_k, group=group, window=window)
+    return jnp.swapaxes(out, 1, 2)
 
 
 def _resolve_blocks(sq, sk, block_q, block_k):

@@ -366,6 +366,201 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, seq_lens,
 
 
 # --------------------------------------------------------------------------
+# Grouped heads and a lower bound on the keys (MXU page walk)
+# --------------------------------------------------------------------------
+#
+# Query heads that share KV heads (KV head = query head // group) and
+# keys bounded below (a window layer: keys before ``kv_start`` are not
+# seen). With a group of G > 1 query rows a KV head, QK^T and PV are
+# [G, D] x [page, D] products and go to the MXU, which wants each KV
+# head's page as a [page, D] tile: these pools are HEADS-MAJOR inside a
+# page, ``[P, KVH, page, D]`` (models/cache_layout.py ``heads_major``),
+# so a page is still one contiguous DMA and KV head ``j`` of it is
+# ``buf[j]``, no relayout. (A ``[P, page, KVH, D]`` pool with KVH = 4
+# is tiled (4, 128): regrouping it costs a copy of the pool a call.)
+
+def _decode_grouped_kernel(pt_ref, len_ref, lo_ref, q_ref, kp_ref, vp_ref,
+                           o_ref, k_buf, v_buf, sems, *,
+                           page: int, scale: float, kvh: int):
+    """One grid step = one sequence. ``q_ref`` is ``[1, KVH, Gp, D]``:
+    the group padded to a sublane tile with zero rows, whose outputs
+    the caller drops. The walk starts at the page that holds
+    ``kv_start`` and masks inside it; pages behind the bound are never
+    fetched."""
+    b = pl.program_id(0)
+    seq_len = len_ref[b]
+    lo = lo_ref[b]
+    first = lo // page
+    n_pages = pl.cdiv(seq_len, page)
+    gp, d = q_ref.shape[2], q_ref.shape[3]
+
+    def copies(i, slot):
+        idx = pt_ref[b, i]
+        return [pltpu.make_async_copy(kp_ref.at[idx], k_buf.at[slot],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(vp_ref.at[idx], v_buf.at[slot],
+                                      sems.at[1, slot])]
+
+    @pl.when(n_pages > first)
+    def _():
+        for c in copies(first, jax.lax.rem(first, 2)):
+            c.start()
+
+    def body(i, carry):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_pages)
+        def _():
+            for c in copies(i + 1, jax.lax.rem(i + 1, 2)):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        kpos = i * page + jax.lax.broadcasted_iota(
+            jnp.int32, (gp, page), 1)
+        seen = (kpos < seq_len) & (kpos >= lo)
+        out = []
+        for j in range(kvh):
+            m, l, acc = carry[j]  # noqa: E741
+            s = jax.lax.dot_general(
+                q_ref[0, j], k_buf[slot, j], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [Gp, page]
+            s = jnp.where(seen, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)  # noqa: E741
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v_buf.dtype), v_buf[slot, j],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [Gp, D]
+            out.append((m_new, l, acc))
+        return tuple(out)
+
+    init = tuple((jnp.full((gp, 1), _NEG_INF, jnp.float32),
+                  jnp.zeros((gp, 1), jnp.float32),
+                  jnp.zeros((gp, d), jnp.float32)) for _ in range(kvh))
+    final = jax.lax.fori_loop(first, n_pages, body, init)
+    for j in range(kvh):
+        _, l, acc = final[j]  # noqa: E741
+        o_ref[0, j] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_decode_grouped_pallas(q, k_pages, v_pages, page_table, seq_lens,
+                                 kv_start, scale):
+    """q: [B, Hq, D]; pools [P, KVH, page, D]; returns [B, Hq, D]."""
+    b, hq, d = q.shape
+    kvh, page = k_pages.shape[1:3]
+    group = hq // kvh
+    tile = 8 * max(1, 4 // k_pages.dtype.itemsize)
+    gp = -(-group // tile) * tile
+    qg = q.reshape(b, kvh, group, d).astype(k_pages.dtype)
+    if gp != group:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+    mp = page_table.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, kvh, gp, d), lambda i, *_: (i, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, kvh, gp, d), lambda i, *_: (i, 0, 0, 0),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, kvh, page, d), k_pages.dtype),
+            pltpu.VMEM((2, kvh, page, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    out = named_pallas_call(
+        "paged_decode_grouped",
+        functools.partial(_decode_grouped_kernel, page=page, scale=scale,
+                          kvh=kvh),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, gp, d), q.dtype),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * int(b) * hq * page * d * mp,
+            bytes_accessed=(2 * int(b) * mp * page * kvh * d
+                            * k_pages.dtype.itemsize),
+            transcendentals=b * hq * page * mp),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )(page_table, seq_lens, kv_start, qg, k_pages, v_pages)
+    return out[:, :, :group].reshape(b, hq, d)
+
+
+def paged_attention_grouped_reference(q, k_pages, v_pages, page_table,
+                                      seq_lens, kv_start=None,
+                                      scale: Optional[float] = None):
+    """Dense-gather reference of :func:`paged_attention_grouped`: one
+    query token a sequence (the LAST position), heads-major pools."""
+    b, sq, h, d = q.shape
+    kvh, page = k_pages.shape[1:3]
+    mp = page_table.shape[1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+
+    def gather(pages):
+        g = pages[page_table].astype(jnp.float32)  # [B, mp, KVH, page, D]
+        return jnp.swapaxes(g, 2, 3).reshape(b, mp * page, kvh, d)
+
+    k, v = gather(k_pages), gather(v_pages)
+    qf = q.astype(jnp.float32).reshape(b, sq, kvh, h // kvh, d)
+    logits = jnp.einsum("bqjgd,bkjd->bjgqk", qf, k) * scale
+    kpos = jnp.arange(mp * page, dtype=jnp.int32)[None]
+    seen = kpos < seq_lens[:, None]
+    if kv_start is not None:
+        seen = seen & (kpos >= kv_start[:, None])
+    logits = jnp.where(seen[:, None, None, None], logits, _NEG_INF)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    p = jnp.where(seen[:, None, None, None], jnp.exp(logits - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)  # noqa: E741
+    out = jnp.einsum("bjgqk,bkjd->bqjgd", p / jnp.maximum(l, 1e-30), v)
+    return out.reshape(b, sq, h, d).astype(q.dtype)
+
+
+def paged_grouped_supported(q_shape, kp_shape,
+                            backend: Optional[str] = None) -> bool:
+    """Gate for the grouped kernel: single-token decode, head size one
+    lane tile, whole sublane tiles a page."""
+    from .flash_attention import _FORCE_DEPTH
+    if backend is None:
+        backend = jax.default_backend()
+    if backend != "tpu" and _FORCE_DEPTH == 0:
+        return False
+    b, sq, h, d = q_shape
+    kvh, page = kp_shape[1:3]
+    return sq == 1 and d == 128 and page % 16 == 0 and h % kvh == 0
+
+
+def paged_attention_grouped(q, k_pages, v_pages, page_table, seq_lens,
+                            kv_start=None, scale: Optional[float] = None):
+    """Single-token paged attention with grouped heads over heads-major
+    pools. q: [B, 1, H, D]; pools ``[P, KVH, page, D]`` with KVH
+    dividing H (query head h reads KV head h // (H // KVH)); seq_lens
+    [B] lengths INCLUDING the appended token; ``kv_start`` [B] hides
+    the keys before it (a window layer passes ``len - window``, floored
+    at 0). Returns [B, 1, H, D]. Not under head sharding."""
+    if get_head_sharding() is not None:
+        raise NotImplementedError("grouped heads under head sharding")
+    b, sq, h, d = q.shape
+    scale = float(1.0 / math.sqrt(d) if scale is None else scale)
+    if paged_grouped_supported(q.shape, k_pages.shape):
+        lo = jnp.zeros_like(seq_lens) if kv_start is None else kv_start
+        out = _paged_decode_grouped_pallas(
+            q.reshape(b, h, d), k_pages, v_pages,
+            page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+            lo.astype(jnp.int32), scale)
+        return out.reshape(b, sq, h, d)
+    return paged_attention_grouped_reference(
+        q, k_pages, v_pages, page_table, seq_lens, kv_start=kv_start,
+        scale=scale)
+
+
+# --------------------------------------------------------------------------
 # Head sharding (tensor-parallel serving over a `model` mesh axis)
 # --------------------------------------------------------------------------
 

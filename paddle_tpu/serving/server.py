@@ -2234,13 +2234,23 @@ def _build_model(name: str):
     import paddle_tpu as pt
     from ..models.gpt import (GPTForCausalLM, gpt_125m, gpt_1p3b,
                               gpt_350m, gpt_tiny)
-    configs = {"gpt_tiny": gpt_tiny, "gpt_125m": gpt_125m,
-               "gpt_350m": gpt_350m, "gpt_1p3b": gpt_1p3b}
+    from ..models.smallthinker import (SmallThinkerForCausalLM,
+                                       smallthinker_21b_a3b,
+                                       smallthinker_tiny)
+    configs = {"gpt_tiny": (GPTForCausalLM, gpt_tiny),
+               "gpt_125m": (GPTForCausalLM, gpt_125m),
+               "gpt_350m": (GPTForCausalLM, gpt_350m),
+               "gpt_1p3b": (GPTForCausalLM, gpt_1p3b),
+               "smallthinker_tiny": (SmallThinkerForCausalLM,
+                                     smallthinker_tiny),
+               "smallthinker_21b_a3b": (SmallThinkerForCausalLM,
+                                        smallthinker_21b_a3b)}
     if name not in configs:
         raise SystemExit(f"unknown --model {name!r}; choose from "
                          f"{sorted(configs)}")
     pt.seed(0)
-    model = GPTForCausalLM(configs[name]())
+    cls, preset = configs[name]
+    model = cls(preset())
     model.eval()
     return model
 

@@ -223,7 +223,10 @@ def test_elastic_membership():
         # a 1-cpu host the main thread otherwise reaches stop() before
         # the watch loop ever runs, the baseline is post-scale-down,
         # and on_change can never fire (observed deterministic there)
-        assert wait_for(lambda: m0._last_members is not None)
+        # ... and that baseline must hold BOTH ranks: a first observation
+        # taken before rank 1 registered is [0], and if rank 1 stops
+        # before the next one the scale-down is never seen as a change
+        assert wait_for(lambda: m0._last_members == [0, 1])
         m1.stop()  # scale-down event
         assert wait_for(lambda: not m0.healthy())
         # the watch-loop callback runs on its own cadence — poll it too

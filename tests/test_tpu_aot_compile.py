@@ -306,6 +306,65 @@ def test_flash_fwd_bwd_compiles_long_seq(one_chip, s, d, heads):
     _fits_one_v5e(compiled)
 
 
+# -- the routed decoder's kernels at its published widths ---------------------
+# (28 query heads over 4 KV heads of 128, window 4096, 64 experts of
+# 2560 x 768 top-6, 16 slots, pages of 64: models/smallthinker.py)
+
+def _bf16(one_chip, *shape, dt=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+
+@pytest.mark.parametrize("pages,width", [(4097, 256), (16 * 66 + 1, 66)],
+                         ids=["global", "ring"])
+def test_paged_decode_grouped_compiles(one_chip, pages, width):
+    """Group of 7 on the MXU over heads-major pools, the allocator's
+    table and a window layer's ring with its lower bound."""
+    specs = (_bf16(one_chip, 16, 1, 28, 128),
+             _bf16(one_chip, pages, 4, 64, 128),
+             _bf16(one_chip, pages, 4, 64, 128),
+             _bf16(one_chip, 16, width, dt=jnp.int32),
+             _bf16(one_chip, 16, dt=jnp.int32),
+             _bf16(one_chip, 16, dt=jnp.int32))
+    assert pa.paged_grouped_supported(specs[0].shape, specs[1].shape,
+                                      backend="tpu")
+    compiled = _compile(
+        lambda q, k, v, t, n, lo: pa.paged_attention_grouped(
+            q, k, v, t, n, kv_start=lo), *specs)
+    assert _kernel_calls(compiled) == 1
+    _named(compiled, "paged_decode_grouped")
+
+
+@pytest.mark.parametrize("s,window", [(512, None), (8192, None),
+                                      (8192, 4096)])
+def test_flash_grouped_window_compiles(one_chip, s, window):
+    q = _bf16(one_chip, 1, s, 28, 128)
+    kv = _bf16(one_chip, 1, s, 4, 128)
+    compiled = _compile(
+        lambda q, k, v: fa.flash_attention_grouped(q, k, v, window=window),
+        q, kv, kv)
+    assert _kernel_calls(compiled) == 1
+    _named(compiled, "flash_fwd_single" if s == 512 else "flash_fwd")
+
+
+@pytest.mark.parametrize("tokens", [16, 8192], ids=["decode", "prefill"])
+def test_dropless_experts_compile(one_chip, tokens):
+    """The expert layer's two regimes of one code path: 16 rows x 6
+    picks in tiles of 16 rows, 8192 x 6 in tiles of 256."""
+    from paddle_tpu.distributed.moe import dropless_experts
+    specs = (_bf16(one_chip, tokens, 2560),
+             _bf16(one_chip, tokens, 6, dt=jnp.int32),
+             _bf16(one_chip, tokens, 6, dt=jnp.float32),
+             _bf16(one_chip, 64, 2560, 768), _bf16(one_chip, 64, 2560, 768),
+             _bf16(one_chip, 64, 768, 2560),
+             _bf16(one_chip, tokens, dt=jnp.bool_))
+    compiled = _compile(
+        lambda u, i, g, wg, wu, wd, ok: dropless_experts(
+            u, i, g, wg, wu, wd, valid=ok), *specs)
+    assert _kernel_calls(compiled) == 2
+    _named(compiled, "moe_ffn_in", "moe_ffn_out")
+    _fits_one_v5e(compiled)
+
+
 # -- scale proofs on a described v4-64 pod ---------------------------------
 
 def test_10b_v4_64_aot_fits(v4_pod):
