@@ -690,11 +690,22 @@ class ContinuousBatchingEngine:
         self._lens = self._packed[:, self.max_pages]
         self._cur = self._packed[:, self.max_pages + 1]
         self._table[:] = self._scratch
-        # the device's copy of ``_packed`` as the last decode program
-        # returned it, or None where a host write (or a failed step)
-        # has made it stale: the next decode step then uploads
+        # the device's copy of ``_packed`` as the NEWEST decode program
+        # launched returns it (what the mirrors will read once every
+        # launched step is settled), or None where a host write (or a
+        # failed step) has made it stale: the next decode step then
+        # uploads
         self._resident = None
-        self._tl_h2d: Optional[int] = None
+        # the decode step launched and not yet fetched (_launch_decode's
+        # record), at most one: the look-ahead of depth one. Its tokens
+        # are handed out by the next step(), under the step launched
+        # from its outputs, or by whoever has to write a slot first
+        # (_settle_inflight)
+        self._inflight: Optional[Dict[str, Any]] = None
+        self._settled_t = 0.0  # end of the newest settle (decode EMA)
+        # this step's decode launch for the record: (decode_h2d,
+        # decode_ahead)
+        self._tl_decode: Optional[Tuple[int, int]] = None
         # counters the model's own programs report (a routed model: the
         # experts its rows touched), packed behind the tokens in the
         # step's one fetch: names by program kind as traced, this
@@ -705,6 +716,10 @@ class ContinuousBatchingEngine:
         self.model_counters: Dict[str, Dict[str, float]] = {}
         self.decode_steps_resident = 0
         self.decode_steps_uploaded = 0
+        self.decode_steps_ahead = 0
+        # rows a look-ahead step computed for a request that finished
+        # in the step before it: dropped at the settle, never handed out
+        self.decode_rows_dropped = 0
         self._slots: List[Optional[DecodeRequest]] = \
             [None] * self.num_slots
         self._queue: List[DecodeRequest] = []
@@ -1070,9 +1085,13 @@ class ContinuousBatchingEngine:
         ``_cur``): sets what is given in ``slot``'s row and marks the
         device's copy stale, so the next decode step uploads the
         mirrors instead of feeding the program its own outputs. Only
-        the decode step itself writes past it: it advances the mirrors
-        by what the device computed, which is what keeps the copy
-        current (see _decode_step)."""
+        the settle of a decode step writes past it: it advances the
+        mirrors by what the device computed, which is what keeps the
+        copy current (see _decode_step). A caller that computes what
+        it writes from the mirrors settles the step in flight first
+        (_settle_inflight); a finish inside a settle is the one write
+        that may land under a step in flight, and that step's row for
+        the slot is dropped when it is settled."""
         if table is not None:
             self._table[slot] = table
         if lens is not None:
@@ -1249,8 +1268,10 @@ class ContinuousBatchingEngine:
                 f"move to a new weight generation")
         # macro boundary (r19): a dispatched-but-undrained launch still
         # reads the OLD weights — drain it so the swap lands between
-        # launches, never under one
+        # launches, never under one; the same for a decode step in
+        # flight
         self._flush_macro()
+        self._settle_inflight()
         if self.num_active:
             raise SwapFailed(
                 f"engine busy: {self.num_active} active slot(s) — "
@@ -1485,15 +1506,38 @@ class ContinuousBatchingEngine:
           (``time.thread_time_ns``) over gap + step + commit; left out
           when the previous step ran on another thread.
 
-        - ``decode_h2d``: on a record whose step ran the single-step
-          decode program, the host-to-device transfers of its inputs:
-          0 where the program was fed its own outputs, 1 where a host
-          write (admission, first token, finish, eviction), a failed
-          step or a half-prefilled slot made the step send the
-          mirrors. ``flight_summary()`` keeps the totals
+        - ``decode_h2d``: on a record whose step LAUNCHED the
+          single-step decode program, the host-to-device transfers of
+          its inputs: 0 where the program was fed its own outputs, 1
+          where a host write (admission, first token, finish,
+          eviction), a failed step or a half-prefilled slot made the
+          step send the mirrors. ``flight_summary()`` keeps the totals
           (``decode_steps_resident``, ``decode_steps_uploaded``). The
           macro, speculative and chunk programs build their arguments
           from the mirrors on every launch and record no such key.
+        - ``decode_ahead``: beside ``decode_h2d``, 1 where that program
+          was launched while the previous one's tokens were still
+          unfetched (the look-ahead: the device goes from one program
+          to the next with no gap), else 0; the total is
+          ``decode_steps_ahead``.
+
+        **A step launched in one ``step()`` call and settled in the
+        next** (_decode_step): a record describes one CALL. Its
+        ``programs``, ``decode_h2d``, ``decode_ahead`` and
+        ``decode_ms`` belong to the program the call launched; its
+        ``wait`` and ``emit``, the tokens it handed out, ``step`` and
+        the model's counters belong to the program it settled, which
+        the call before it launched (the same program only on a masked
+        step, which is settled where it is launched). ``ms`` is the
+        call, whatever it held: in the steady state one launch and one
+        settle, so its median is still the time from one handed-out
+        token to the next less commit and loop. A traced request's
+        ``decode_step`` span carries the launch stamps of the program
+        that computed its token. ``decode_ema_s`` averages, per
+        settled step, the time from its launch (or from the end of the
+        settle before it, where that is later: a step launched ahead)
+        to the end of its own settle: the cadence at which a slot
+        receives tokens, which is what the deadline gate multiplies.
 
         The older ``_tl_ms`` keys are fed from the same stamps:
         ``decode_ms`` is the decode program's ``launch`` phase on the
@@ -1549,8 +1593,8 @@ class ContinuousBatchingEngine:
                 entry[f"{t.name}_tier_pages"] = int(t.blob_count)
         for k, v in self._tl_ms.items():
             entry[k] = round(v, 4)
-        if self._tl_h2d is not None:
-            entry["decode_h2d"] = self._tl_h2d
+        if self._tl_decode is not None:
+            entry["decode_h2d"], entry["decode_ahead"] = self._tl_decode
         if self._has_rings:
             # pages in use by kind of layer, a layer of each: the
             # allocator's (every position kept) and the rings' (a
@@ -1609,6 +1653,8 @@ class ContinuousBatchingEngine:
             "macro_launches": int(self.macro_launches),
             "decode_steps_resident": int(self.decode_steps_resident),
             "decode_steps_uploaded": int(self.decode_steps_uploaded),
+            "decode_steps_ahead": int(self.decode_steps_ahead),
+            "decode_rows_dropped": int(self.decode_rows_dropped),
             "model_counters": {k: dict(v) for k, v in
                                self.model_counters.items()},
             "window_ring_pages": max(
@@ -2562,7 +2608,11 @@ class ContinuousBatchingEngine:
         for slot, req in enumerate(self._slots):
             if req is not None and req.deadline_t is not None \
                     and now >= req.deadline_t:
-                expired.append(self._evict_slot(slot, "deadline"))
+                # an eviction writes the slot: the step in flight is
+                # folded first, and the request may have finished in it
+                self._settle_inflight()
+                if self._slots[slot] is req:
+                    expired.append(self._evict_slot(slot, "deadline"))
         return expired
 
     def evict_stalled(self, now: Optional[float] = None
@@ -2582,7 +2632,7 @@ class ContinuousBatchingEngine:
         if self.stall_timeout_s is None:
             return []
         now = time.monotonic() if now is None else now
-        out: List[DecodeRequest] = []
+        stalled: List[Tuple[int, DecodeRequest]] = []
         for slot, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -2605,8 +2655,16 @@ class ContinuousBatchingEngine:
                 # and the waiting slot still stalls out typed.
                 last = max(last, self._last_chunk_t)
             if now - last > self.stall_timeout_s:
-                out.append(self._evict_slot(slot, "stalled"))
-        return out
+                stalled.append((slot, req))
+        if not stalled:
+            return []
+        # judged on what was DELIVERED by ``now``: a token still in a
+        # step in flight was not. An eviction writes the slot, so that
+        # step is folded first (its token goes out before the typed
+        # completion), and a request that finished in it is done
+        self._settle_inflight()
+        return [self._evict_slot(slot, "stalled")
+                for slot, req in stalled if self._slots[slot] is req]
 
     def dump_inflight(self) -> List[DecodeRequest]:
         """Snapshot every request the engine still owes an answer for
@@ -2622,6 +2680,7 @@ class ContinuousBatchingEngine:
         # the pre-launch state is equally gapless to replay from
         try:
             self._flush_macro()
+            self._settle_inflight()
         except Exception:
             # the in-flight computation died with the engine; its
             # tokens were never generated as far as any client knows
@@ -2638,6 +2697,12 @@ class ContinuousBatchingEngine:
             # pending weight swap of its num_active == 0 window
             return
         self._shed_overloaded()
+        if self._queue and any(r is None for r in self._slots):
+            # an admission writes a slot, and what is admissible
+            # depends on the pages a finish gives back: the step in
+            # flight is folded before the queue is looked at (whether
+            # the head then fits or not: the conservative side)
+            self._settle_inflight()
         for slot in range(self.num_slots):
             if self._slots[slot] is not None:
                 continue
@@ -3822,16 +3887,37 @@ class ContinuousBatchingEngine:
 
     def step(self) -> int:
         """Admit what fits, spend the chunked-prefill budget (at most
-        one slot's next chunk), run ONE fixed-shape decode step (or one
-        draft-and-verify speculative step) for every slot past prefill,
-        evict what finished. Returns the number of still-active slots.
+        one slot's next chunk), LAUNCH one fixed-shape decode step for
+        every slot past prefill and hand out the tokens of the decode
+        step launched by the call before (or run one draft-and-verify
+        speculative step, or one macro boundary), evict what finished.
+        Returns the number of still-active slots.
+
+        **One decode step stays in flight ahead of the host**
+        (_decode_step). A call that finds a step in flight, its
+        outputs usable and no slot write due launches the next step
+        from those outputs FIRST and only then blocks on the tokens of
+        the one in flight, so the device goes from program to program
+        while the host fetches, emits, finishes and commits. Whatever
+        writes a slot settles the step in flight before it (admission,
+        eviction, swap; every outside reader: _settle_inflight), and
+        the call then runs the synchronous sequence: the case "nothing
+        in flight", not a second path. A call that launches where
+        nothing was in flight hands out no decode token; its step is
+        settled by the next call. A call that leaves no slot active
+        drops what is still in flight: every row of it belongs to a
+        request that is gone.
+
         The ``engine.step`` fault site fires FIRST — before admission
-        and before the donating jit — so an injected step failure
-        leaves host and device state exactly as the previous step left
-        them (the precondition for the serving layer's resurrection
-        replay). A step that raises, there or later, also drops the
-        device's copy of the decode inputs (``_resident``): the next
-        decode step uploads the host mirrors."""
+        and before the donating jit. A step that raises, there or
+        later (in a launch, at the fetch of the step in flight), drops
+        the result in flight together with the device's copy of the
+        decode inputs (``_resident``): those tokens were never handed
+        out, the mirrors are the truth, and the next decode step
+        uploads them and computes the same position again. So after
+        an injected step failure host state is exactly as the last
+        HANDED-OUT step left it (the precondition for the serving
+        layer's resurrection replay)."""
         from ..distributed.fault_inject import fault_point
         try:
             fault_point("engine.step")
@@ -3840,19 +3926,22 @@ class ContinuousBatchingEngine:
             # per token — next to at least one jit launch)
             self._tl_programs = {}
             self._tl_ms = {}
-            self._tl_h2d = None
+            self._tl_decode = None
             self._tl_stats = {}
             self._host.take()  # phases outside a step: no record's
             if self.ledger is not None:
                 self.ledger.step = self.steps
             t_step = time.monotonic()
             try:
-                return self._step_inner()
+                active = self._step_inner()
+                if not active:
+                    self._inflight = None
+                return active
             finally:
                 self._tl_commit(t_step)
         except BaseException:
             # whatever failed, the step after it sends the mirrors
-            self._resident = None
+            self._resident = self._inflight = None
             raise
 
     def _step_inner(self) -> int:
@@ -3867,7 +3956,14 @@ class ContinuousBatchingEngine:
             # verify, host draft sources) keep their per-step verify
             # cadence — spec composes AT the boundary for them.
             return self._macro_multi_step()
+        if self._resident is None:
+            # a finish at the last settle wrote its slot under the step
+            # in flight: that step's outputs cannot feed another, and
+            # the slot it freed is about to be looked at
+            self._settle_inflight()
         with self._phase("admit"):
+            # each of the three settles the step in flight before it
+            # writes a slot, and leaves it alone where it writes none
             self.expire_deadlines()
             self.evict_stalled()
             self._admit()
@@ -3882,45 +3978,98 @@ class ContinuousBatchingEngine:
                 # the next step() advances the next chunk. num_active
                 # keeps run() looping.
                 return self.num_active
-        # the decode EMA is fed from the phases' stamps: the last one
-        # before the decode/verify call, the last one inside it
+        if self._spec_cfg is None:
+            return self._decode_step()
+        # the verify EMA is fed from the phases' stamps: the last one
+        # before the call, the last one inside it
         t0 = self._host.t
         try:
-            if self._spec_cfg is not None:
-                return self._spec_step()
-            return self._decode_step()
+            return self._spec_step()
         finally:
-            # skip the first step: its wall time is dominated by the
-            # one-off decode/prefill compiles and would poison the
-            # deadline gate's estimate for the engine's whole warmup.
-            # Only the decode/verify call is timed — chunk prefills
-            # have their own EMA (_advance_prefill_chunk), so a
-            # prefill-heavy step can't poison the per-token estimate.
-            if self.steps > 1:
-                dt = self._host.t - t0
-                self.decode_ema_s = dt if self.decode_ema_s is None \
-                    else 0.8 * self.decode_ema_s + 0.2 * dt
+            self._feed_decode_ema(self._host.t - t0)
+
+    def _feed_decode_ema(self, dt: float) -> None:
+        """One more decode (or verify) step of ``dt`` seconds into the
+        deadline gate's estimate. The first step is skipped: its wall
+        time is dominated by the one-off decode/prefill compiles and
+        would poison the estimate for the engine's whole warmup. Chunk
+        prefills have their own EMA (_advance_prefill_chunk), so a
+        prefill-heavy step can't poison the per-token estimate."""
+        if self.steps > 1:
+            self.decode_ema_s = dt if self.decode_ema_s is None \
+                else 0.8 * self.decode_ema_s + 0.2 * dt
 
     def _decode_step(self) -> int:
-        """One decode step over the device's own copy of its inputs.
+        """Launch one decode step over the device's own copy of its
+        inputs, and settle the one launched before it.
 
         The decode program returns the next step's packed inputs
-        (_build_decode) and ``_resident`` holds them from step to
-        step. The host mirrors stay the truth: every host write goes
+        (_build_decode) and ``_resident`` holds those of the newest
+        launch. The host mirrors stay the truth: every host write goes
         through ``_write_slot``, which drops ``_resident``, and the
         decode step after it sends the mirrors in ONE transfer. A step
         the host did not touch sends nothing and fetches the tokens
         alone; the length mirror advances by the host's own ``+ 1``.
-        A step that fails leaves the copy stale (``step`` drops it)."""
+
+        1. Ahead. With a step k in flight (whoever had to write a slot
+           has settled it by now, _step_inner), k+1 is launched from
+           k's device outputs (``pools``, ``packed``) first; then k is
+           settled: its tokens fetched, the mirrors advanced, tokens
+           emitted, slots finished. Fetch latency, ``emit``, ``commit``
+           and the caller's loop pass under k+1's device time.
+        2. Settle before any host write. A writer that finds a step in
+           flight folds it first and the call runs upload (once, the
+           mirrors as of that step), launch: the pipeline's bubble.
+        3. A slot that finishes in k while k+1 is in flight, by
+           ``eos_token`` or by count alike (a count-known finish RIDES
+           the look-ahead: the other rows' step is not held back for
+           it): the row k+1 computed for it is dropped at k+1's settle
+           and reaches no ``generated``, ``on_token``, trace or
+           counter. Its KV append landed in a page the slot still
+           owned when k+1 was launched, or on the scratch page; the
+           pages are freed, cached or re-bound by host code that runs
+           after that launch, so any program that writes them again is
+           ordered behind it. The finish drops ``_resident``, so the
+           next call settles k+1 and uploads.
+        4. Outside readers (expire_deadlines, evict_stalled,
+           dump_inflight, swap_weights, close) settle the step in
+           flight before they change a slot.
+        5. A step that fails leaves copy and step in flight dropped
+           (``step``). A masked step (a half-prefilled slot) is
+           settled in the call that launches it: its outputs are not
+           the mirrors' next state."""
+        # nothing counts as in flight while a settle runs: a callback
+        # that comes back in through a public method finds settled
+        # state, and a settle that raises drops both steps (``step``)
+        pend, self._inflight = self._inflight, None
+        new = self._launch_decode(ahead=pend is not None)
+        if pend is not None:
+            self._settle_decode(pend)
+        if new["masked"]:
+            self._settle_decode(new)
+        else:
+            self._inflight = new
+        return self.num_active
+
+    def _launch_decode(self, ahead: bool) -> Dict[str, Any]:
+        """Launch the decode program over ``_resident`` (or, where that
+        is stale, over one upload of the mirrors) and return the record
+        of the step now in flight; does not block. ``ahead``: the step
+        before it is still unfetched."""
         from ..dispatch import count_op_calls
         held = self._resident
-        with self._phase("upload"):
+        with self._phase("upload") as upload:
             if self._decode_jit is None:
                 self._decode_jit = self._build_decode()
             states = [None if r is None else r.state for r in self._slots]
-            decoding = np.array([s == "decoding" for s in states])
+            rows = [(slot, self._slots[slot])
+                    for slot, s in enumerate(states) if s == "decoding"]
             masked = "prefill_partial" in states
             send = None
+            if masked or held is None:
+                # a copy: the mirrors are written in place, and a
+                # transfer may alias host memory until it completes
+                send = self._packed.copy()
             if masked:
                 # half-prefilled slots ride the fixed-shape step MASKED
                 # to the scratch page at length 0: their pages hold a
@@ -3931,66 +4080,91 @@ class ContinuousBatchingEngine:
                 # only the device call sees the mask, so what the
                 # program returns is not the mirrors' next state:
                 # every such step uploads.
-                send = self._packed.copy()
-                send[~decoding, :self.max_pages] = self._scratch
-                send[~decoding, self.max_pages] = 0
-            elif held is None:
-                send = self._packed
+                idle = np.array([s != "decoding" for s in states])
+                send[idle, :self.max_pages] = self._scratch
+                send[idle, self.max_pages] = 0
             if send is not None:
                 held = self._place_resident(send)
             args = (self._fresh_state(), self._pools, held)
         with self._phase("launch") as launch:
             with count_op_calls() as c:
-                nxt, pools, held = self._decode_jit(*args)
-        # counted once the program is launched: 0, fed its own outputs
-        self._tl_h2d = int(send is not None)
-        if send is None:
-            self.decode_steps_resident += 1
-        else:
-            self.decode_steps_uploaded += 1
+                nxt, self._pools, held = self._decode_jit(*args)
+            # the newest launch's outputs; a masked step leaves nothing
+            # to reuse
+            self._resident = None if masked else held
+            if c.count:
+                self._capture_cost("decode", self._decode_jit, args)
+            # the arguments and the donated pools' handles are let go
+            # here, in a phase (some hundred objects at 24 layers)
+            del args, held
+            # counted once the program is launched: 0, fed its own
+            # outputs
+            self._tl_decode = (int(send is not None), int(ahead))
+            if send is None:
+                self.decode_steps_resident += 1
+            else:
+                self.decode_steps_uploaded += 1
+            self.decode_steps_ahead += int(ahead)
+            self._record_programs("decode", c.count)
         # the DISPATCH of the decode program, not the decode: on a
         # device the call returns futures, and the program's own time
-        # passes inside the `wait` phase below
-        t0d, t1d = launch.t0, launch.t1
-        self._tl_add_ms("decode_ms", t1d - t0d)
-        self._record_programs("decode", c.count)
-        if c.count:
-            self._capture_cost("decode", self._decode_jit, args)
-        self._pools = pools
+        # passes until its settle's `wait` ends
+        self._tl_add_ms("decode_ms", launch.t1 - launch.t0)
+        return {"nxt": nxt, "rows": rows, "masked": masked,
+                "t0": upload.t0, "t0d": launch.t0, "t1d": launch.t1}
+
+    def _settle_inflight(self) -> None:
+        """Fold the decode step in flight, if there is one, into host
+        state: what everything that writes a slot, and every outside
+        reader, does first. The step is forgotten BEFORE the blocking
+        read: a failed computation raises there, and its handles would
+        only raise again."""
+        pend, self._inflight = self._inflight, None
+        if pend is None:
+            return
+        try:
+            self._settle_decode(pend)
+        except BaseException:
+            self._resident = None  # its outputs, which never came
+            raise
+
+    def _settle_decode(self, pend: Dict[str, Any]) -> None:
+        """The host half of a launched decode step: fetch its tokens
+        (the step's one fetch), advance the mirrors, emit, finish.
+        Rows of requests that left their slot since the launch (a
+        finish in the step before, under this one) are dropped."""
         with self._phase("wait"):
-            # the arguments and the donated pools' handles are let go
-            # while the program runs, not after the emit loop (some
-            # hundred objects at 24 layers; otherwise they go at this
-            # function's return, in no phase)
-            del args, pools, send
-            nxt = np.asarray(nxt)  # the step's one fetch
+            nxt = np.asarray(pend.pop("nxt"))
             if nxt.size > self.num_slots:
                 # the model's counters came back behind the tokens
                 self._fold_stats("decode", nxt[self.num_slots:])
                 nxt = nxt[:self.num_slots]
         with self._phase("emit"):
-            # the mirrors follow the device: a decoding slot's length
-            # grew by the token appended, its current token is the one
-            # sampled. Other slots wrote to the scratch page and keep
-            # their host values (0 for an empty slot, prefill_done_len
-            # for a half-prefilled one). Past this point mirrors and
-            # the returned copy agree, unless the step was masked; the
-            # finishes below go through _write_slot and drop it again.
-            self._lens[decoding] += 1
-            self._cur[decoding] = nxt[decoding]
-            self._resident = None if masked else held
-            del held
-            self.steps += 1
-            for slot, req in enumerate(self._slots):
-                if req is None or req.state != "decoding":
-                    continue
+            rows = [(slot, req) for slot, req in pend["rows"]
+                    if self._slots[slot] is req]
+            self.decode_rows_dropped += len(pend["rows"]) - len(rows)
+            if rows:
+                # the mirrors follow the device: a decoding slot's
+                # length grew by the token appended, its current token
+                # is the one sampled. Other slots wrote to the scratch
+                # page and keep their host values (0 for an empty slot,
+                # prefill_done_len for a half-prefilled one). With
+                # every launched step settled, mirrors and
+                # ``_resident`` agree; the finishes below go through
+                # _write_slot and drop it.
+                idx = [slot for slot, _ in rows]
+                self._lens[idx] += 1
+                self._cur[idx] = nxt[idx]
+                self.steps += 1
+            t0d, t1d = pend["t0d"] * 1e6, pend["t1d"] * 1e6
+            for slot, req in rows:
                 tok = int(nxt[slot])
                 req.generated.append(tok)
                 req.stats.tokens_out = len(req.generated)
                 if req.trace is not None:
                     # pre-timed closed span: one list append per traced
                     # in-flight request, no extra clock reads per slot
-                    req.trace.add("decode_step", t0d * 1e6, t1d * 1e6,
+                    req.trace.add("decode_step", t0d, t1d,
                                   parent=req.span, step=self.steps,
                                   token=tok)
                 self._emit_token(req, tok)
@@ -3999,7 +4173,10 @@ class ContinuousBatchingEngine:
             # letting a device result's buffer go is not free, and at
             # this function's return it would fall in no phase
             del nxt
-            return self.num_active
+        end = self._host.t
+        if rows:
+            self._feed_decode_ema(end - max(pend["t0"], self._settled_t))
+        self._settled_t = end
 
     def run(self, max_steps: int = 100000) -> Dict[int, np.ndarray]:
         """Drive until queue and slots drain; returns {req_id: tokens}
@@ -4038,6 +4215,7 @@ class ContinuousBatchingEngine:
         # (anything drained EARLIER still delivers).
         try:
             self._flush_macro()
+            self._settle_inflight()
         except Exception:
             self._pending_macro = None
             self._deliver_pending()
