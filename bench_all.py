@@ -649,264 +649,6 @@ def bench_fused_decode(on_tpu: bool) -> Dict:
                     "tokens/s measures host overhead, not the fusion"}
 
 
-def bench_multi_step_decode(on_tpu: bool) -> Dict:
-    """Device-resident multi-step decode A/B (r19, ROADMAP item 2):
-    the ragged_serving request stream through the SAME engine at
-    ``multi_step`` N ∈ {1, 4, 8, 16} — N fused decode steps per
-    on-device program launch (one early-exit while_loop + a [B, N]
-    token ring read back once per launch) vs the per-token engine.
-    Reports tokens/s, host program launches per emitted token (the
-    number the macro launch exists to shrink), steps-per-launch, the
-    host-overlap idle fraction, and the bit_identical flag over the
-    full greedy token streams."""
-    import paddle_tpu as pt
-    from paddle_tpu.inference import create_decode_engine
-    from paddle_tpu.models import GPTForCausalLM, gpt_tiny
-
-    if on_tpu:
-        cfg = _decode_1p3b_cfg()
-        slots, page, max_seq = 32, 64, 1024
-        lens = [64, 96, 128, 192, 256, 384, 512, 640]
-        n_req, new_toks = 64, 64
-    else:
-        cfg = gpt_tiny()
-        slots, page, max_seq = 2, 8, 64
-        lens = [5, 9, 13]
-        n_req, new_toks = 4, 16
-
-    pt.seed(0)
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        _to_bf16_except_norms(model)
-    model.eval()
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, cfg.vocab_size,
-                            (lens[i % len(lens)],)).astype(np.int32)
-               for i in range(n_req)]
-
-    def run_mode(n: int) -> Dict:
-        eng = create_decode_engine(model, num_slots=slots,
-                                   page_size=page, max_seq_len=max_seq,
-                                   multi_step=n)
-        # warm THE MEASURED ENGINE's compiles (per-instance closures;
-        # see bench_ragged_serving) — one request per distinct bucket
-        for p in prompts[:len(lens)]:
-            eng.submit(p, max_new_tokens=2)
-        eng.run()
-        launches0 = dict(eng.programs_launched)
-        t0 = time.perf_counter()
-        rids = [eng.submit(p, max_new_tokens=new_toks) for p in prompts]
-        try:
-            results = eng.run()
-        finally:
-            tl = eng.step_timeline()
-            eng.close()
-        wall = time.perf_counter() - t0
-        gen = sum(len(results[rid]) - len(p)
-                  for rid, p in zip(rids, prompts))
-        launches = sum(v - launches0.get(k, 0)
-                       for k, v in eng.programs_launched.items())
-        macro = [e["macro"] for e in tl if "macro" in e]
-        idle = [m["overlap_idle_ms"] for m in macro]
-        ms = [m["ms"] for m in macro]
-        return {"tokens_per_s": round(gen / max(1e-9, wall), 1),
-                "launches": launches,
-                "launches_per_token": round(launches / max(1, gen), 4),
-                "steps_per_launch": (round(sum(m["steps"]
-                                               for m in macro)
-                                           / len(macro), 2)
-                                     if macro else 1.0),
-                "host_overlap_idle_frac": (
-                    round(sum(idle) / max(1e-9, sum(ms)), 3)
-                    if macro else None),
-                "tokens": {rid: results[rid].tolist() for rid in rids}}
-
-    by_n = {str(n): run_mode(n) for n in (1, 4, 8, 16)}
-    base = by_n["1"].pop("tokens")
-    bit_identical = all(v.pop("tokens") == base
-                        for k, v in by_n.items() if k != "1")
-    l1 = by_n["1"]["launches_per_token"]
-    l16 = by_n["16"]["launches_per_token"]
-    return {"metric": "gpt1p3b_multi_step_decode_ab_chip" if on_tpu
-            else "gpt_tiny_multi_step_decode_ab_cpu_smoke",
-            "unit": "tokens/s + launches/token (A/B over N)",
-            "by_multi_step": by_n,
-            "bit_identical": bool(bit_identical),
-            "launches_per_token_1": l1,
-            "launches_per_token_16": l16,
-            "launch_reduction": round(1.0 - l16 / l1, 3) if l1 else None,
-            "requests": n_req, "prompt_lens": lens,
-            "new_tokens_per_req": new_toks, "num_slots": slots,
-            "page_size": page,
-            "note": "launches counts every jitted program call "
-                    "(prefill + decode/decode_multi) over the timed "
-                    "stream. Even the cpu lane speeds up (per-launch "
-                    "python dispatch + readback is real overhead at "
-                    "tiny scale); the MAGNITUDE claim needs real "
-                    "chips, where what a host launch/sync round "
-                    "trip costs is not measured yet. "
-                    "host_overlap_idle_frac ~0 = the host "
-                    "never blocked at a drain (the dispatch-then-"
-                    "drain overlap fully hid device time)"}
-
-
-def bench_inprogram_inner_loop(on_tpu: bool) -> Dict:
-    """In-program inner loop A/B (r22, ROADMAP item 3a/3b): the SAME
-    multi_step=4 + speculative(k=4, ngram) + chunked-prefill engine
-    config run with ``inprogram=True`` (draft/verify/rewind and up to
-    N chained prefill chunks inside the macro program) vs
-    ``inprogram=False`` (the PR 14 boundary-interleaved mode: one
-    fused ``verify`` launch per step, chunks stalling the boundary).
-    Short INTERACTIVE streams decode while a long prompt arrives
-    mid-flight, so the chunk path runs against live decode — reports
-    launches per emitted token (the number the in-program move exists
-    to shrink), short-stream TPOT p99, tokens/s, and the
-    bit_identical flag over the full greedy streams."""
-    import paddle_tpu as pt
-    from paddle_tpu.inference import (SpeculativeConfig,
-                                      create_decode_engine)
-    from paddle_tpu.models import GPTForCausalLM, gpt_tiny
-
-    if on_tpu:
-        cfg = _decode_1p3b_cfg()
-        slots, page, max_seq = 16, 64, 1024
-        short_len, short_new, n_short, conc = 64, 64, 16, 8
-        long_len, long_new, chunk = 512, 32, 256
-    else:
-        cfg = gpt_tiny()
-        slots, page, max_seq = 2, 8, 96
-        short_len, short_new, n_short, conc = 6, 12, 6, 2
-        long_len, long_new, chunk = 41, 8, 8
-
-    pt.seed(0)
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        _to_bf16_except_norms(model)
-    model.eval()
-    rng = np.random.default_rng(0)
-    shorts = [rng.integers(0, cfg.vocab_size,
-                           (short_len,)).astype(np.int32)
-              for _ in range(n_short)]
-    longp = rng.integers(0, cfg.vocab_size,
-                         (long_len,)).astype(np.int32)
-
-    def run_mode(inprogram: bool):
-        eng = create_decode_engine(
-            model, num_slots=slots, page_size=page,
-            max_seq_len=max_seq, multi_step=4,
-            speculative=SpeculativeConfig(k=4, draft="ngram"),
-            prefill_chunk_tokens=chunk, inprogram=inprogram)
-        # warm the measured engine's compiles (per-instance closures)
-        w = eng.submit(shorts[0], max_new_tokens=2)
-        wl = eng.submit(longp, max_new_tokens=2)
-        eng.run()
-        eng.result(w, pop=True)
-        eng.result(wl, pop=True)
-        launches0 = dict(eng.programs_launched)
-        tok_t: Dict[int, list] = {}
-
-        def on_token(rid, tok, done):
-            tok_t.setdefault(rid, []).append(time.perf_counter())
-
-        short_rids: list = []
-
-        def submit_short(i):
-            short_rids.append(eng.submit(
-                shorts[i], max_new_tokens=short_new,
-                on_token=on_token))
-
-        t0 = time.perf_counter()
-        for i in range(conc):
-            submit_short(i)
-        next_short, long_rid = conc, None
-        outputs: Dict[int, list] = {}
-        done_shorts = 0
-        steps = 0
-        want = n_short + 1
-        while len(outputs) < want:
-            eng.step()
-            steps += 1
-            if steps > 100000:
-                raise RuntimeError("stream did not drain")
-            for rid in list(short_rids) + (
-                    [long_rid] if long_rid is not None else []):
-                if rid in outputs:
-                    continue
-                res = eng.result(rid, pop=True)
-                if res is None:
-                    continue
-                outputs[rid] = [int(t) for t in res]
-                if rid in short_rids:
-                    done_shorts += 1
-                    if next_short < n_short:
-                        submit_short(next_short)
-                        next_short += 1
-                    # the long prompt lands once decode is flowing,
-                    # keyed to completion count so both modes see the
-                    # same trace
-                    if long_rid is None and done_shorts >= 1:
-                        long_rid = eng.submit(longp,
-                                              max_new_tokens=long_new,
-                                              on_token=on_token)
-        wall = time.perf_counter() - t0
-        launches = sum(v - launches0.get(k, 0)
-                       for k, v in eng.programs_launched.items())
-        by_kind = {k: v - launches0.get(k, 0)
-                   for k, v in eng.programs_launched.items()
-                   if v - launches0.get(k, 0)}
-        eng.close()
-        gen = sum(len(outputs[r]) for r in short_rids) \
-            - n_short * short_len + len(outputs[long_rid]) - long_len
-        gaps = []
-        for rid in short_rids:
-            ts = tok_t.get(rid, [])
-            gaps.extend(b - a for a, b in zip(ts, ts[1:]))
-        ordered = [outputs[r] for r in short_rids + [long_rid]]
-        return {"tokens_per_s": round(gen / max(1e-9, wall), 1),
-                "launches": launches,
-                "launches_by_kind": by_kind,
-                "launches_per_token": round(launches / max(1, gen), 4),
-                "short_tpot_p50_ms": round(
-                    float(np.percentile(gaps, 50)) * 1e3, 3),
-                "short_tpot_p99_ms": round(
-                    float(np.percentile(gaps, 99)) * 1e3, 3),
-                "wall_s": round(wall, 3)}, ordered
-
-    boundary, out_b = run_mode(False)
-    inprog, out_i = run_mode(True)
-    bit_identical = out_b == out_i
-    return {"metric": "gpt1p3b_inprogram_inner_loop_ab_chip" if on_tpu
-            else "gpt_tiny_inprogram_inner_loop_ab_cpu_smoke",
-            "unit": "launches/token + tokens/s + TPOT ms (A/B)",
-            "boundary": boundary, "inprogram": inprog,
-            "bit_identical": bool(bit_identical),
-            "launch_reduction": round(
-                1.0 - inprog["launches_per_token"]
-                / boundary["launches_per_token"], 3)
-            if boundary["launches_per_token"] else None,
-            "tpot_p99_improved": (inprog["short_tpot_p99_ms"]
-                                  < boundary["short_tpot_p99_ms"]),
-            "multi_step": 4, "speculate_k": 4,
-            "prefill_chunk_tokens": chunk, "num_slots": slots,
-            "page_size": page,
-            "note": "one engine config, two cadences: boundary mode "
-                    "launches the fused verify every step and stalls "
-                    "a boundary per prefill chunk; in-program mode "
-                    "rides both inside the macro while_loop (one "
-                    "launch covers up to N*(k+1) verified positions "
-                    "+ up to N chained chunks). The launch-count win "
-                    "is structural; the LATENCY magnitude claim "
-                    "needs real chips, where what a launch/sync "
-                    "round trip costs is not measured yet "
-                    "(cpu_smoke = chip-pending). "
-                    "In-program TPOT is bimodal by construction: a "
-                    "launch's tokens drain together (~0 ms gaps "
-                    "in-launch, the launch wall between launches), "
-                    "so p50 collapses while p99 tracks launch time — "
-                    "on chips the launch covers N*(k+1) positions "
-                    "for ONE round trip, which is the win"}
-
-
 # ONE set of workload constants, interpolated into both the subprocess
 # payload and the result-dict metadata below — the result entry
 # must describe the workload that was actually measured
@@ -3134,9 +2876,6 @@ def run_staged(on_tpu: bool) -> Dict:
                      ("paged_decode", bench_paged_decode),
                      ("ragged_serving", bench_ragged_serving),
                      ("fused_decode", bench_fused_decode),
-                     ("multi_step_decode", bench_multi_step_decode),
-                     ("inprogram_inner_loop",
-                      bench_inprogram_inner_loop),
                      ("chunked_prefill", bench_chunked_prefill),
                      ("mesh_decode", bench_mesh_decode),
                      ("serving_prefix", bench_serving_prefix),

@@ -68,17 +68,6 @@ in-flight decode streams keep ticking while a long prompt trickles in
 (the TTFT-vs-TPOT head-of-line fix; greedy outputs stay bit-identical
 to whole prefill).
 
-Multi-step engines (``multi_step=N``, r19) replace the per-token
-launch/readback cadence with one on-device N-step program per
-boundary (models/gpt.py ``multi_step_decode``): admission and chunked
-prefill run AT the boundary (they mutate the launch's inputs and
-donate the pools, so they cannot run under an in-flight launch),
-while token delivery/tracing/metrics and the serving loop's inbox
-work OVERLAP the launch (dispatch-then-drain: ring K−1 streams after
-launch K is dispatched). Greedy outputs stay bit-identical to
-``multi_step=1`` (the default, which is byte-for-byte the per-token
-engine).
-
 Reference analog: the inference engine's multi-stream serving loop
 (`inference/api/analysis_predictor.cc` + TensorRT's enqueue batching),
 rebuilt as a scheduler over one jitted step instead of a stream pool.
@@ -109,6 +98,12 @@ __all__ = ["PageAllocator", "DecodeRequest", "RequestStats",
 # between two steps, `gap_us`) they partition the stepping thread's
 # time from one step's start to the next.
 HOST_PHASES = ("admit", "upload", "launch", "wait", "emit")
+
+
+def _raw(t):
+    """The array under a Tensor (or the array itself)."""
+    from ..tensor import Tensor
+    return t.value if isinstance(t, Tensor) else t
 
 
 class SwapFailed(RuntimeError):
@@ -496,8 +491,6 @@ class ContinuousBatchingEngine:
                  mesh=None,
                  prefill_chunk_tokens: Optional[int] = None,
                  fused_step: bool = True,
-                 multi_step: int = 1,
-                 inprogram: bool = True,
                  tracer=None, timeline_steps: int = 256,
                  capture_costs: bool = False,
                  page_ledger: bool = True,
@@ -551,7 +544,6 @@ class ContinuousBatchingEngine:
                  "layer's ring)", prefix_cache is not None),
                 ("a serving mesh", mesh is not None),
                 ("int8 KV pages", bool(kv_int8)),
-                ("multi_step > 1", int(multi_step) > 1),
                 ("speculative decoding (a rejected draft cannot be "
                  "rewound out of a ring)", speculative is not None),
                 ("chunked prefill", prefill_chunk_tokens is not None)]
@@ -813,63 +805,11 @@ class ContinuousBatchingEngine:
         # False is byte-for-byte the pre-r13 trace — the same
         # escape-hatch pattern as mesh=None / prefill_chunk_tokens=None.
         self.fused_step = bool(fused_step)
-        # device-resident multi-step decode (r19, ROADMAP item 2):
-        # multi_step=N wraps N fused decode steps in ONE on-device
-        # lax.while_loop program (models/gpt.py multi_step_decode) —
-        # early exit on EOS via masked carry, KV appends against
-        # PRE-BOUND page budgets (admission reserves the growth pages;
-        # _dispatch_macro converts reservation -> physical pages before
-        # every launch, which cannot fail by the PR 4 contract), and a
-        # device-side token ring [B, N] read back once per launch.
-        # Launches are dispatch-then-drain: step K's results are
-        # drained at boundary K+1, so token delivery/tracing/metrics
-        # and the serving loop's inbox work overlap the device compute
-        # (JAX async dispatch; no new threads). Admission and chunked
-        # prefill run at the boundary, in the drain->dispatch gap —
-        # they rewrite the launch's table/lens/cur inputs and donate
-        # the pools, so they cannot run under an in-flight launch;
-        # that gap is the N-vs-TTFT trade. multi_step=1 (the default)
-        # is byte-for-byte the per-token engine. r22 (in-program inner
-        # loop) moves speculative verify and chained prefill chunks
-        # INSIDE the macro program when eligible (see _spec_inprogram /
-        # _chunk_inprogram below); `inprogram=False` pins the r19
-        # boundary-interleaved behavior as the bisection rung.
-        self.multi_step = int(multi_step)
-        if self.multi_step < 1:
-            raise ValueError(
-                f"multi_step must be >= 1 (1 = per-token decode); got "
-                f"{multi_step}")
-        self.inprogram = bool(inprogram)
-        # macro program variants keyed by has_chunk (a launch with a
-        # scheduled in-program chunk is a different traced program than
-        # a decode/verify-only one; both are built at most once)
-        self._multi_jits: Dict[bool, Any] = {}
-        # in-flight macro launch: device handles + the slot->request
-        # snapshot the drain folds back (None = nothing dispatched)
-        self._pending_macro: Optional[Dict[str, Any]] = None
-        # drained-but-undelivered (req, token, done) emissions, in the
-        # exact (in-macro step, slot) order the per-token engine would
-        # have streamed them; delivered AFTER the next launch is
-        # dispatched (host/device overlap), and flushed per-request by
-        # _notify_complete so streamed tokens always precede the
-        # completion notification on every terminal path
-        self._pending_emit: List[Tuple] = []
-        self.macro_launches = 0
-        # macro-EMA warmup: the first launch is compile-dominated
-        # (the skip-first-step rule, applied per program kind)
-        self._macro_warm = False
-        # engine-wide last-macro-drain timestamp: the stall watchdog's
-        # liveness signal for decoding slots between boundaries (a
-        # healthy macro delivers every decoding slot's tokens at each
-        # drain; a broken one lets this go stale and the stall fires)
-        self._last_macro_t = 0.0
-        # page-growth discipline: multi-step shares the speculative
-        # reserve-then-grow contract — admission binds only the
-        # prefill-covering pages and RESERVES the rest, macro dispatch
-        # grows each slot's page set to cover its next min(N, rem)
-        # positions out of that reservation (guaranteed to succeed)
-        self._reserve_growth = (speculative is not None or
-                                self.multi_step > 1)
+        # page-growth discipline of a speculative engine: admission
+        # binds only the prefill-covering pages and RESERVES the rest,
+        # and a verify step grows each slot's page set out of that
+        # reservation (guaranteed to succeed)
+        self._reserve_growth = speculative is not None
         # traced-program op counts per jitted step kind (the launch
         # counter: dispatch.count_op_calls around each jit call counts
         # the ops traced into the program on a (re)trace, zero on the
@@ -900,8 +840,6 @@ class ContinuousBatchingEngine:
         # stepping thread's CPU clock there: `gap_us` and `cpu_us`
         self._tl_end: Optional[float] = None
         self._tl_cpu: Tuple[int, int] = (0, 0)
-        # drained-macro attribution for the NEXT _tl_commit (r19)
-        self._tl_macro: Optional[Dict[str, Any]] = None
         # cumulative program launches by kind (every jit call — 1 per
         # launch, unlike step_programs which records traced-op counts)
         self.programs_launched: Dict[str, int] = {}
@@ -936,30 +874,6 @@ class ContinuousBatchingEngine:
             self._verify_retry = verify_retry
         else:
             self._verify_retry = None
-        # r22 in-program eligibility. Speculative verify moves inside
-        # the macro while_loop only when every piece has a device twin:
-        # multi_step > 1 (there IS a macro program), greedy verify
-        # (temperature 0 — the bit-identical serving mode; residual
-        # resampling stays at the boundary), and a draft source
-        # expressible as pure array math over the stored history
-        # (ngram/self — ModelDraft and CallableDraft run host code).
-        # Chunked prefill moves inside only when speculation either is
-        # off or also moved inside (a half-in half-out split would put
-        # the boundary back).
-        self._spec_inprogram = False
-        self._spec_device_draft = None
-        if (self.inprogram and self.multi_step > 1
-                and self._spec_cfg is not None
-                and float(self._spec_cfg.temperature) == 0.0):
-            from .speculative import device_draft_params
-            p = device_draft_params(self._spec_draft)
-            if p is not None:
-                self._spec_inprogram = True
-                self._spec_device_draft = p
-        self._chunk_inprogram = (
-            self.inprogram and self.multi_step > 1
-            and self.prefill_chunk_tokens is not None
-            and (self._spec_cfg is None or self._spec_inprogram))
 
     # -- request lifecycle -------------------------------------------------
 
@@ -1251,9 +1165,9 @@ class ContinuousBatchingEngine:
         ``set_state_dict`` raises mid-apply on a shape mismatch and
         silently coerces dtypes, so the only safe swap is one that
         cannot hit either path. Any validation failure, and any
-        in-flight work (active slots or an undrained macro launch), is
-        a typed :class:`SwapFailed` with the old weights still serving
-        and the old generation pinned. Queued-but-unadmitted requests
+        in-flight work (active slots), is a typed :class:`SwapFailed`
+        with the old weights still serving and the old generation
+        pinned. Queued-but-unadmitted requests
         survive the swap: their memoized chain keys are invalidated so
         their prefills insert under the NEW generation's keys.
 
@@ -1266,11 +1180,8 @@ class ContinuousBatchingEngine:
             raise SwapFailed(
                 f"generation {gen} is already serving; a swap must "
                 f"move to a new weight generation")
-        # macro boundary (r19): a dispatched-but-undrained launch still
-        # reads the OLD weights — drain it so the swap lands between
-        # launches, never under one; the same for a decode step in
-        # flight
-        self._flush_macro()
+        # a decode step in flight still reads the OLD weights: settle it
+        # so the swap lands between launches, never under one
         self._settle_inflight()
         if self.num_active:
             raise SwapFailed(
@@ -1513,8 +1424,8 @@ class ContinuousBatchingEngine:
           eviction), a failed step or a half-prefilled slot made the
           step send the mirrors. ``flight_summary()`` keeps the totals
           (``decode_steps_resident``, ``decode_steps_uploaded``). The
-          macro, speculative and chunk programs build their arguments
-          from the mirrors on every launch and record no such key.
+          speculative and chunk programs build their arguments from
+          the mirrors on every launch and record no such key.
         - ``decode_ahead``: beside ``decode_h2d``, 1 where that program
           was launched while the previous one's tokens were still
           unfetched (the look-ahead: the device goes from one program
@@ -1543,12 +1454,10 @@ class ContinuousBatchingEngine:
         ``decode_ms`` is the decode program's ``launch`` phase on the
         single-step path — the DISPATCH, not the decode (on a device
         the call returns before the program has run; the device's
-        time is in ``wait``) — and dispatch-to-drain of the launch
-        under ``multi_step``; ``prefill_ms`` is upload + launch + the
+        time is in ``wait``); ``prefill_ms`` is upload + launch + the
         blocking read of the first token; ``chunk_ms`` / ``verify_ms``
         are upload + launch of those programs, ``splice_ms`` the
-        launch; ``overlap_idle_ms`` is the ``wait`` of the macro
-        drain."""
+        launch."""
         with self._phase("commit") as commit:
             entry = self._tl_record(t_step, commit.t0)
         end = commit.t1
@@ -1604,12 +1513,6 @@ class ContinuousBatchingEngine:
                 "window": self.window_pages_in_use()}
         # the model's own counters of this step's programs
         entry.update(self._tl_stats)
-        # multi-step decode (r19): the boundary that drained a macro
-        # launch marks its entry with the launch's attribution
-        # (per_token_timeline() reconstructs per-step rows from it)
-        if self._tl_macro is not None:
-            entry["macro"] = self._tl_macro
-            self._tl_macro = None
         self.timeline.append(entry)
         return entry
 
@@ -1649,8 +1552,6 @@ class ContinuousBatchingEngine:
             "prefill_debt_tokens": int(self.prefill_debt_tokens),
             "prefill_chunk_tokens": self.prefill_chunk_tokens,
             "fused_step": bool(self.fused_step),
-            "multi_step": int(self.multi_step),
-            "macro_launches": int(self.macro_launches),
             "decode_steps_resident": int(self.decode_steps_resident),
             "decode_steps_uploaded": int(self.decode_steps_uploaded),
             "decode_steps_ahead": int(self.decode_steps_ahead),
@@ -1926,24 +1827,41 @@ class ContinuousBatchingEngine:
                     for v, _ in placed)}
         return info
 
-    def _decode_body_fn(self):
-        """The ONE single-token decode step body: shared verbatim by
-        the per-token decode jit (``multi_step=1`` — byte-for-byte the
-        pre-r19 trace) and by every iteration of the r19 multi-step
-        macro program (models/gpt.py ``multi_step_decode``), so the
-        two modes' per-step math is identical by construction — the
-        bit-identity contract tests/test_multi_step_decode.py pins."""
+    def _new_pools(self, nc):
+        """The pools a traced program hands back, from the caches the
+        model returned."""
+        return self._constrain_pools({
+            "k": [_raw(c.k_pages) for c in nc],
+            "v": [_raw(c.v_pages) for c in nc],
+            "ks": [_raw(c.k_scale) if self.kv_int8 else None for c in nc],
+            "vs": [_raw(c.v_scale) if self.kv_int8 else None for c in nc]})
+
+    def _build_decode(self):
+        """The per-token decode program over the packed inputs
+        ``[num_slots, max_pages + 2]`` (page table | length | current
+        token; the layout of ``_packed``). It returns the tokens, the
+        pools and the NEXT step's packed inputs, so a step the host did
+        not touch feeds the program its own output and uploads nothing
+        (_launch_decode). The model returns ``lens + 1`` for every row,
+        and a row that came in empty (length 0: an empty or masked
+        slot, writing to the scratch page) goes out empty, or its
+        length would creep from step to step. The token such a row
+        yields is an in-range argmax nobody reads; admission
+        overwrites it."""
         import jax
+        import jax.numpy as jnp
 
         from ..autograd.engine import no_grad
         from ..nn.decode import sample_token
         from ..nn.layer import bind_state
         from ..tensor import Tensor
 
-        def raw(t):
-            return t.value if isinstance(t, Tensor) else t
+        mp = self.max_pages
+        replicated = self._replicated
 
-        def step(state, pools, table, lens, tokens):
+        def step(state, pools, packed):
+            table, lens = packed[:, :mp], packed[:, mp]
+            tokens = packed[:, mp + 1]
             caches = self._caches(pools, table, lens)
             # named_scope: metadata-only, UNCONDITIONAL (never keyed on
             # tracing state, so programs are identical tracing on/off)
@@ -1960,55 +1878,19 @@ class ContinuousBatchingEngine:
                         Tensor(tokens[:, None]), caches)
                     w, ty, bias = hp
                     nxt, _ = fused_sample_token(
-                        raw(hidden)[:, -1], raw(w), 0.0,
+                        _raw(hidden)[:, -1], _raw(w), 0.0,
                         transpose_y=ty,
-                        bias=None if bias is None else raw(bias))
+                        bias=None if bias is None else _raw(bias))
                 else:
                     logits, nc = self.model.forward(
                         Tensor(tokens[:, None]), caches=caches)
                     # greedy serving mode through the ONE shared
                     # sampler (nn/decode.py) — the same call generate()
                     # and the speculative verify make
-                    nxt, _ = sample_token(raw(logits)[:, -1], 0.0)
+                    nxt, _ = sample_token(_raw(logits)[:, -1], 0.0)
                 self._take_stats()
-            new_pools = {
-                "k": [raw(c.k_pages) for c in nc],
-                "v": [raw(c.v_pages) for c in nc],
-                "ks": [raw(c.k_scale) if self.kv_int8 else None
-                       for c in nc],
-                "vs": [raw(c.v_scale) if self.kv_int8 else None
-                       for c in nc],
-            }
-            return nxt, self._constrain_pools(new_pools), \
-                raw(nc[0].seq_lens)
-
-        return step
-
-    def _build_decode(self):
-        """The per-token decode program over the packed inputs
-        ``[num_slots, max_pages + 2]`` (page table | length | current
-        token; the layout of ``_packed``). It returns the tokens, the
-        pools and the NEXT step's packed inputs, so a step the host did
-        not touch feeds the program its own output and uploads nothing
-        (_decode_step). The body is ``_decode_body_fn`` untouched; the
-        wrapper only unpacks, repacks and masks the lengths: the body
-        returns ``lens + 1`` for every row, and a row that came in
-        empty (length 0: an empty or masked slot, writing to the
-        scratch page) goes out empty, or its length would creep from
-        step to step. The token such a row yields is an in-range
-        argmax nobody reads; admission overwrites it."""
-        import jax
-        import jax.numpy as jnp
-
-        body = self._decode_body_fn()
-        mp = self.max_pages
-        replicated = self._replicated
-
-        def step(state, pools, packed):
-            table, lens = packed[:, :mp], packed[:, mp]
-            nxt, pools, lens_new = body(state, pools, table, lens,
-                                        packed[:, mp + 1])
-            lens_new = jnp.where(lens > 0, lens_new, 0)
+            pools = self._new_pools(nc)
+            lens_new = jnp.where(lens > 0, _raw(nc[0].seq_lens), 0)
             packed = jnp.concatenate(
                 [table, lens_new[:, None], nxt[:, None]], axis=1)
             if replicated is not None:
@@ -2026,112 +1908,20 @@ class ContinuousBatchingEngine:
         # (On CPU donation is ignored with a warning — harmless.)
         return jax.jit(step, donate_argnums=(1,))
 
-    def _build_multi_decode(self, has_chunk: bool = False):
-        """The r19 macro program: up to ``multi_step`` iterations of
-        the EXACT single-token decode body wrapped in one on-device
-        early-exit loop (models/gpt.py ``multi_step_decode``), with
-        the per-slot stop/mask bookkeeping the host used to run
-        between launches carried in-program. ONE compile serves the
-        engine lifetime (N is static; rem/eos/active are data).
-
-        r22 (in-program inner loop): when ``_spec_inprogram`` the
-        iteration body is the fused VERIFY step instead of the decode
-        step — draft (device ngram/self twin), verify k+1 positions,
-        and rewind via ``masked_run_advance`` carries, widening the
-        token ring to [B, N, k+1]. When ``has_chunk`` the program also
-        advances one half-prefilled slot's scheduled chained-prefill
-        chunks, one per iteration, under a ``lax.cond``. Spec/chunk
-        both off traces the byte-for-byte r19 program."""
-        import jax
-        import jax.numpy as jnp
-
-        from ..models.gpt import multi_step_decode
-
-        body = self._decode_body_fn()
-        n = self.multi_step
-        scratch = self._scratch
-        spec_on = self._spec_inprogram
-        if not spec_on and not has_chunk:
-            def macro(state, pools, table, lens, tokens, active, rem,
-                      eos):
-                def step_fn(pl, tbl, ln, cur):
-                    return body(state, pl, tbl, ln, cur)
-
-                with jax.named_scope("pt.multi_step"):
-                    return multi_step_decode(step_fn, pools, table,
-                                             lens, tokens, active,
-                                             rem, eos, n, scratch)
-
-            return jax.jit(macro, donate_argnums=(1,))
-
-        verify_body = self._verify_body_fn() if spec_on else None
-        prefill_body = self._prefill_body_fn(True) if has_chunk else None
-        dcfg = self._spec_device_draft
-        k = int(self._spec_cfg.k) if spec_on else 0
-        vocab = int(self.cfg.vocab_size)
-
-        def macro(state, pools, table, lens, tokens, active, rem, eos,
-                  *extra):
-            from ..nn.decode import ngram_draft_tokens
-            idx = 0
-            spec = chunk = None
-            if spec_on:
-                hist, hist_len = extra[0], extra[1]
-                idx = 2
-
-                def draft_fn(h, hl, cur):
-                    if dcfg["kind"] == "self":
-                        return jnp.broadcast_to(
-                            cur[:, None], (cur.shape[0], k))
-                    return ngram_draft_tokens(
-                        h, hl, k, dcfg["max_ngram"], dcfg["min_ngram"])
-
-                def verify_fn(pl, tbl, ln, toks, valid):
-                    key = jax.random.PRNGKey(0)  # greedy: unused
-                    return verify_body(state, pl, tbl, ln, toks,
-                                       valid, key)
-
-                spec = {"k": k, "vocab": vocab, "draft_fn": draft_fn,
-                        "verify_fn": verify_fn, "hist": hist,
-                        "hist_len": hist_len}
-            if has_chunk:
-                (c_ids, c_valid, c_start, c_final, c_count,
-                 c_slot) = extra[idx:idx + 6]
-
-                def prefill_fn(pl, trow, slens, plen, ids):
-                    return prefill_body(state, pl, trow, slens, plen,
-                                        ids)
-
-                chunk = {"prefill_fn": prefill_fn, "ids": c_ids,
-                         "valid": c_valid, "start": c_start,
-                         "final": c_final, "count": c_count,
-                         "slot": c_slot}
-
-            def step_fn(pl, tbl, ln, cur):
-                return body(state, pl, tbl, ln, cur)
-
-            with jax.named_scope("pt.multi_step_inner"):
-                return multi_step_decode(step_fn, pools, table, lens,
-                                         tokens, active, rem, eos, n,
-                                         scratch, spec=spec,
-                                         chunk=chunk)
-
-        return jax.jit(macro, donate_argnums=(1,))
-
-    def _prefill_body_fn(self, chained: bool):
-        """The unjitted prefill body — ``_build_prefill`` wraps it in
-        its own jit for boundary launches; the r22 macro builder
-        embeds it in the while_loop body so a chained chunk advances
-        INSIDE the macro program."""
+    def _build_prefill(self, chained: bool):
+        """One jitted prefill; jax.jit's shape-keyed cache compiles it
+        once per prompt bucket (the bucket IS the ids shape). The
+        ``chained`` variant starts from a non-empty slot (seq_lens =
+        the prefix-cache hit length) and attends the stored prefix
+        through the paged-attention reference (models/gpt.py
+        prefill_chained); the fresh variant keeps the exact dense
+        chunk-attention program the bit-identical tests pin."""
         import jax
 
         from ..autograd.engine import no_grad
         from ..nn.decode import sample_token
         from ..nn.layer import bind_state
         from ..tensor import Tensor
-
-        def raw(t):
-            return t.value if isinstance(t, Tensor) else t
 
         def prefill(state, pools, trow, slens, plen, ids, rows=None):
             caches = self._caches(pools, trow, slens, rows)
@@ -2150,53 +1940,38 @@ class ContinuousBatchingEngine:
                         prefill_chained=chained)
                     w, ty, bias = hp
                     nxt, _ = fused_sample_token(
-                        raw(hidden)[:1, plen[0] - 1], raw(w), 0.0,
+                        _raw(hidden)[:1, plen[0] - 1], _raw(w), 0.0,
                         transpose_y=ty,
-                        bias=None if bias is None else raw(bias))
+                        bias=None if bias is None else _raw(bias))
                 else:
                     logits, nc = self.model.forward(
                         Tensor(ids), caches=caches, prefill_lens=plen,
                         prefill_chained=chained)
-                    nxt, _ = sample_token(raw(logits)[:1, plen[0] - 1],
+                    nxt, _ = sample_token(_raw(logits)[:1, plen[0] - 1],
                                           0.0)
                 self._take_stats()
             nxt = self._pack_stats(
                 "prefill_chained" if chained else "prefill", nxt[0])
-            new_pools = {
-                "k": [raw(c.k_pages) for c in nc],
-                "v": [raw(c.v_pages) for c in nc],
-                "ks": [raw(c.k_scale) if self.kv_int8 else None
-                       for c in nc],
-                "vs": [raw(c.v_scale) if self.kv_int8 else None
-                       for c in nc],
-            }
-            return nxt, self._constrain_pools(new_pools)
+            return nxt, self._new_pools(nc)
 
-        return prefill
-
-    def _build_prefill(self, chained: bool):
-        """One jitted prefill; jax.jit's shape-keyed cache compiles it
-        once per prompt bucket (the bucket IS the ids shape). The
-        ``chained`` variant starts from a non-empty slot (seq_lens =
-        the prefix-cache hit length) and attends the stored prefix
-        through the paged-attention reference (models/gpt.py
-        prefill_chained); the fresh variant keeps the exact dense
-        chunk-attention program the bit-identical tests pin."""
-        import jax
-
-        return jax.jit(self._prefill_body_fn(chained),
-                       donate_argnums=(1,))
+        return jax.jit(prefill, donate_argnums=(1,))
 
     def _get_prefill(self, chained: bool):
         if self._prefill_jits.get(chained) is None:
             self._prefill_jits[chained] = self._build_prefill(chained)
         return self._prefill_jits[chained]
 
-    def _verify_body_fn(self):
-        """The unjitted speculative-verify body — ``_build_verify``
-        wraps it for boundary launches; the r22 macro builder embeds
-        it as the while_loop iteration body when speculation runs
-        in-program."""
+    def _build_verify(self):
+        """ONE jitted speculative verify step for the engine's whole
+        lifetime (fixed [num_slots, k+1] shape): append the pending
+        token + k drafts through the page tables (ragged per-slot
+        valid counts park the tail on the scratch page), score all
+        k+1 positions via models/gpt.py ``verify_step`` (the chained-
+        prefill q_offsets paged-attention path), and compute the
+        accept/resample decisions with nn/decode.py's shared sampler
+        math. Lengths stay host-owned: the host rolls back past the
+        longest accepted prefix, so rejected positions are simply
+        never attended again."""
         import jax
 
         from ..autograd.engine import no_grad
@@ -2206,9 +1981,6 @@ class ContinuousBatchingEngine:
 
         temp = float(self._spec_cfg.temperature)
         tk = self._spec_cfg.top_k
-
-        def raw(t):
-            return t.value if isinstance(t, Tensor) else t
 
         def verify(state, pools, table, lens, tokens, valid, key):
             caches = self._caches(pools, table, lens)
@@ -2230,40 +2002,17 @@ class ContinuousBatchingEngine:
                         prefill_chained=True)
                     w, ty, bias = hp
                     accept, resid, full, _ = fused_verify_tokens(
-                        raw(hidden), tokens[:, 1:], raw(w), temp, tk,
+                        _raw(hidden), tokens[:, 1:], _raw(w), temp, tk,
                         key, transpose_y=ty,
-                        bias=None if bias is None else raw(bias))
+                        bias=None if bias is None else _raw(bias))
                 else:
                     logits, nc = self.model.verify_step(Tensor(tokens),
                                                         caches, valid)
                     accept, resid, full, _ = speculative_verify_tokens(
-                        raw(logits), tokens[:, 1:], temp, tk, key)
-            new_pools = {
-                "k": [raw(c.k_pages) for c in nc],
-                "v": [raw(c.v_pages) for c in nc],
-                "ks": [raw(c.k_scale) if self.kv_int8 else None
-                       for c in nc],
-                "vs": [raw(c.v_scale) if self.kv_int8 else None
-                       for c in nc],
-            }
-            return accept, resid, full, self._constrain_pools(new_pools)
+                        _raw(logits), tokens[:, 1:], temp, tk, key)
+            return accept, resid, full, self._new_pools(nc)
 
-        return verify
-
-    def _build_verify(self):
-        """ONE jitted speculative verify step for the engine's whole
-        lifetime (fixed [num_slots, k+1] shape): append the pending
-        token + k drafts through the page tables (ragged per-slot
-        valid counts park the tail on the scratch page), score all
-        k+1 positions via models/gpt.py ``verify_step`` (the chained-
-        prefill q_offsets paged-attention path), and compute the
-        accept/resample decisions with nn/decode.py's shared sampler
-        math. Lengths stay host-owned: the host rolls back past the
-        longest accepted prefix, so rejected positions are simply
-        never attended again."""
-        import jax
-
-        return jax.jit(self._verify_body_fn(), donate_argnums=(1,))
+        return jax.jit(verify, donate_argnums=(1,))
 
     def _unwind_prefill_failure(self, slot: int, req: DecodeRequest
                                 ) -> None:
@@ -2437,11 +2186,6 @@ class ContinuousBatchingEngine:
         self._on_complete = fn
 
     def _notify_complete(self, req: DecodeRequest) -> None:
-        # multi-step decode (r19): a request terminating at a macro
-        # boundary may still hold undelivered ring tokens — stream
-        # them FIRST so tokens always precede the completion, on
-        # every terminal path (no-op outside multi-step mode)
-        self._flush_req_emissions(req)
         tr = req.trace
         if tr is not None:
             # EVERY terminal path funnels through here, so this is the
@@ -2462,21 +2206,6 @@ class ContinuousBatchingEngine:
         # the completion notification; callbacks run on the engine
         # thread and must not raise — the server's callback catches
         # its own socket errors
-        if self.multi_step > 1 and (self._spec_cfg is None
-                                    or self._spec_inprogram):
-            # multi-step mode (r19): EVERY emission rides the pending
-            # queue — boundary-time prefill first-tokens included —
-            # so the stream keeps (step, slot) order: the drained
-            # ring's tokens (earlier steps) always precede this
-            # boundary's admissions, and per-request streams match
-            # multi_step=1 exactly (cross-request interleave matches
-            # too whenever admission lands at the same points; the
-            # boundary-coarsened admission CADENCE is the one thing N
-            # changes). _deliver_pending streams the queue after the
-            # next launch is dispatched; terminal paths flush a
-            # request's share first (_notify_complete).
-            self._pending_emit.append((req, tok, self._finish_due(req)))
-            return
         req.last_emit_t = time.monotonic()
         if req.on_token is not None:
             req.on_token(req.req_id, tok, self._finish_due(req))
@@ -2548,20 +2277,9 @@ class ContinuousBatchingEngine:
         if self.decode_ema_s is not None:
             need = 1 if req.eos_token is not None else req.max_new_tokens
             # decode_ema_s is per LAUNCH: one token for the per-token
-            # engine, up to k+1 for a speculative verify, up to
-            # multi_step for a macro launch (r19 — the EMA is tracked
-            # per macro at drain, so the per-token estimate is ema/N
-            # and charging ema per token would shed feasible work)
-            if self._spec_cfg is not None:
-                per_step = self._spec_cfg.k + 1
-                if self._spec_inprogram:
-                    # r22: one macro launch carries up to N verify
-                    # iterations, each emitting up to k+1 tokens
-                    per_step *= self.multi_step
-            else:
-                per_step = self.multi_step
-            steps = -(-need // per_step)
-            est = steps * self.decode_ema_s
+            # engine, up to k+1 for a speculative verify
+            per_step = 1 if self._spec_cfg is None else self._spec_cfg.k + 1
+            est = -(-need // per_step) * self.decode_ema_s
             if self.prefill_chunk_tokens is not None:
                 cached = 0
                 if self._prefix_cache is not None:
@@ -2570,15 +2288,7 @@ class ContinuousBatchingEngine:
                     cached = len(shared) * self.page_size
                 chunks = -(-(len(req.prompt) - cached)
                            // self.prefill_chunk_tokens)
-                if self._chunk_inprogram:
-                    # r22 in-program units: chained chunks ride macro
-                    # launches (up to N per launch), so a queued
-                    # prompt's best case is ceil(chunks/N) whole
-                    # launches at the per-LAUNCH decode EMA — not
-                    # per-chunk boundary wall time
-                    est += (-(-chunks // self.multi_step)
-                            * self.decode_ema_s)
-                elif self.prefill_chunk_ema_s is not None:
+                if self.prefill_chunk_ema_s is not None:
                     est += chunks * self.prefill_chunk_ema_s
             return now + est > req.deadline_t
         return False
@@ -2590,15 +2300,7 @@ class ContinuousBatchingEngine:
         active slots are evicted mid-flight with their pages (and any
         speculative reservation) returned. Runs at the top of every
         step and is safe to call from the serving loop even when the
-        step itself is failing (host state only). Multi-step engines
-        flush the in-flight launch first — never sweep stale slot
-        state, and deliver its tokens/completions so a failing step
-        loop can't strand answered work (r19)."""
-        self._flush_macro()
-        return self._expire_deadlines_inner(now)
-
-    def _expire_deadlines_inner(self, now: Optional[float] = None
-                                ) -> List[DecodeRequest]:
+        step itself is failing (host state only)."""
         now = time.monotonic() if now is None else now
         expired: List[DecodeRequest] = []
         for req in [r for r in self._queue
@@ -2624,28 +2326,12 @@ class ContinuousBatchingEngine:
         the serving loop calls it even mid engine failure."""
         if self.stall_timeout_s is None:
             return []
-        self._flush_macro()
-        return self._evict_stalled_inner(now)
-
-    def _evict_stalled_inner(self, now: Optional[float] = None
-                             ) -> List[DecodeRequest]:
-        if self.stall_timeout_s is None:
-            return []
         now = time.monotonic() if now is None else now
         stalled: List[Tuple[int, DecodeRequest]] = []
         for slot, req in enumerate(self._slots):
             if req is None:
                 continue
             last = max(req.last_emit_t, req.stats.admit_t)
-            if self.multi_step > 1 and req.state == "decoding":
-                # multi-step mode delivers tokens once per macro
-                # boundary, not per step — engine-wide drain progress
-                # is the liveness signal (every decoding slot gets
-                # tokens each healthy launch; a broken engine stops
-                # draining anywhere and the timestamp goes stale, so
-                # a genuine stall still fires typed). Same shape as
-                # the chunked-prefill _last_chunk_t rule below.
-                last = max(last, self._last_macro_t)
             if req.state == "prefill_partial":
                 # a half-prefilled slot may be healthily WAITING its
                 # turn for the single per-step chunk budget while
@@ -2674,19 +2360,13 @@ class ContinuousBatchingEngine:
         engine via a chained greedy prefill (bit-identical continuation
         is the paged design's recovery dividend). Does NOT release
         anything; callers tear down via close()."""
-        # multi-step (r19): fold any in-flight launch's tokens into
-        # the snapshot first — those tokens were NEVER delivered (the
-        # ring streams at the NEXT boundary), so on a failed drain
-        # the pre-launch state is equally gapless to replay from
-        try:
-            self._flush_macro()
+        # fold the step in flight into the snapshot first: its tokens
+        # were never delivered, so on a failed settle the state before
+        # the launch is equally gapless to replay from
+        # (a computation that died with the engine: its tokens were
+        # never generated as far as any client knows)
+        with contextlib.suppress(Exception):
             self._settle_inflight()
-        except Exception:
-            # the in-flight computation died with the engine; its
-            # tokens were never generated as far as any client knows
-            # (earlier drains' emissions still deliver)
-            self._pending_macro = None
-            self._deliver_pending()
         live = [r for r in self._slots if r is not None]
         return sorted(live + list(self._queue), key=lambda r: r.req_id)
 
@@ -2810,8 +2490,7 @@ class ContinuousBatchingEngine:
             # speculative AND multi-step modes bind only the
             # prefill-covering pages and RESERVE the rest of the
             # capacity: decode grows the page set on demand
-            # (_ensure_pages — per spec step, or per macro launch to
-            # cover the next min(N, rem) positions) and speculative
+            # (_ensure_pages, per spec step) and speculative
             # rollback returns wholly-unused pages (_rollback_pages)
             # without ever risking a mid-decode allocation failure
             prefill_need = (-(-len(req.prompt) // self.page_size)
@@ -3032,7 +2711,7 @@ class ContinuousBatchingEngine:
             return sel(partial, decoding, time.monotonic())
         return min(partial, key=lambda sr: sr[1].req_id)[0]
 
-    def _advance_prefill_chunk(self, slot: Optional[int] = None) -> bool:
+    def _advance_prefill_chunk(self) -> bool:
         """Spend this step's prefill budget: advance AT MOST ONE
         half-prefilled slot by one page-aligned chunk of
         ``prefill_chunk_tokens`` tokens through the chained-prefill jit
@@ -3043,18 +2722,12 @@ class ContinuousBatchingEngine:
         fixed chunk bucket, so the engine pays one prefill compile per
         chained-ness, not one per suffix length. The final chunk's
         logits produce the first generated token, exactly like a whole
-        prefill. Returns True when a chunk ran.
-
-        ``slot``: pre-selected target (the r22 in-program planner
-        already ran the scheduler's pick and routes the dense FRESH
-        first chunk back here) — skips re-selection so the
-        chunk-budget policy is consulted exactly once per boundary."""
+        prefill. Returns True when a chunk ran."""
         partial = [(i, r) for i, r in enumerate(self._slots)
                    if r is not None and r.state == "prefill_partial"]
         if not partial:
             return False
-        if slot is None:
-            slot = self._select_chunk_slot(partial)
+        slot = self._select_chunk_slot(partial)
         if slot is None:
             return False  # scheduler deferred: decode preempts
         jnp = self._jnp
@@ -3071,7 +2744,7 @@ class ContinuousBatchingEngine:
         # chunk is byte-for-byte the whole-prefill admission
         chained = done > 0
         jit = self._get_prefill(chained)
-        row = self._table[slot]
+        row = self._table[slot]  # the live row: insert() retargets it
 
         def run_chunk():
             from ..dispatch import count_op_calls
@@ -3080,8 +2753,12 @@ class ContinuousBatchingEngine:
             fault_point("serving.prefill")
             kind = "prefill_chained" if chained else "prefill"
             with self._phase("upload"):
+                # a copy of the row: the upload may alias what it is
+                # given (a CPU device does), and the mirrors are written
+                # in place while the chunk is still in flight
+                # (_launch_decode sends a copy for the same reason)
                 args = (self._fresh_state(refresh=True), self._pools,
-                        jnp.asarray(row[None]),
+                        jnp.asarray(row[None].copy()),
                         jnp.asarray([done], jnp.int32),
                         jnp.asarray([len(suffix)], jnp.int32),
                         jnp.asarray(ids))
@@ -3182,74 +2859,21 @@ class ContinuousBatchingEngine:
             self._maybe_finish(slot)
         return True
 
-    def _plan_inprogram_chunks(self) -> Optional[Dict[str, Any]]:
-        """r22: schedule up to ``multi_step`` CHAINED prefill chunks of
-        one half-prefilled slot as per-iteration work INSIDE the next
-        macro launch. Consults the same chunk-budget policy as the
-        boundary path (one scheduler pick per boundary), then builds
-        the chunk arrays the macro program indexes per iteration:
-        iteration j runs chunk j while the other slots decode/verify —
-        the launch never stalls for the prefill, which is the r22
-        answer to the N-vs-TTFT trade.
-
-        The dense FRESH first chunk of an uncached prompt stays at the
-        boundary (routed back through ``_advance_prefill_chunk``): the
-        bit-identical pins fix chunk 1 to the exact dense prefill
-        program, and it is also each prompt's only non-chained chunk.
-        Returns the plan dict (``None``: nothing to do this launch)."""
-        partial = [(i, r) for i, r in enumerate(self._slots)
-                   if r is not None and r.state == "prefill_partial"]
-        if not partial:
-            return None
-        slot = self._select_chunk_slot(partial)
-        if slot is None:
-            return None  # scheduler deferred: decode preempts
-        req = self._slots[slot]
-        if req.prefill_done_len == 0:
-            self._advance_prefill_chunk(slot=slot)
-            return None
-        n = self.multi_step
-        chunk = self.prefill_chunk_tokens
-        done = req.prefill_done_len
-        total = len(req.prompt)
-        count = min(n, -(-(total - done) // chunk))
-        ids = np.zeros((n, chunk), np.int32)
-        valid = np.zeros((n,), np.int32)
-        start = np.zeros((n,), np.int32)
-        final = np.zeros((n,), bool)
-        pos = done
-        for j in range(count):
-            suffix = req.prompt[pos:pos + chunk]
-            ids[j, :len(suffix)] = suffix
-            valid[j] = len(suffix)
-            start[j] = pos
-            pos += len(suffix)
-            final[j] = pos == total
-        return {"slot": slot, "req": req, "count": count,
-                "done0": done, "end": pos, "tokens": pos - done,
-                "has_final": bool(final[:count].any()),
-                "final_idx": int(np.argmax(final)) if final.any() else -1,
-                "ids": ids, "valid": valid, "start": start,
-                "final": final}
-
     def _finish_due(self, req: DecodeRequest) -> bool:
         hit_eos = (req.eos_token is not None and req.generated and
                    req.generated[-1] == req.eos_token)
         return len(req.generated) >= req.max_new_tokens or hit_eos
 
-    def _maybe_finish(self, slot: int, notify: bool = True) -> None:
+    def _maybe_finish(self, slot: int) -> None:
         req = self._slots[slot]
         if req is None:
             return
         if self._finish_due(req):
-            self._finish_slot(slot, notify=notify)
+            self._finish_slot(slot)
 
-    def _finish_slot(self, slot: int, notify: bool = True) -> None:
+    def _finish_slot(self, slot: int) -> None:
         """Terminal "done" teardown for one slot: free pages, release
-        cache pins, park on scratch. ``notify=False`` (the macro-drain
-        path, r19) defers _notify_complete to the delivery phase so
-        the request's ring tokens stream before its completion —
-        delivery calls _notify_complete after the last token."""
+        cache pins, park on scratch, notify."""
         req = self._slots[slot]
         req.done = True
         req.state = "done"
@@ -3265,458 +2889,7 @@ class ContinuousBatchingEngine:
         # park on the scratch page
         self._write_slot(slot, table=self._scratch, lens=0, cur=0)
         self._slots[slot] = None
-        if notify:
-            self._notify_complete(req)
-
-    # -- device-resident multi-step decode (r19) ----------------------------
-    #
-    # multi_step=N turns the per-token launch cadence into one macro
-    # launch per N tokens: _dispatch_macro pre-binds each decoding
-    # slot's growth pages out of its admission reservation and fires
-    # the on-device while_loop program (models/gpt.py
-    # multi_step_decode); JAX async dispatch returns immediately, so
-    # the boundary that DRAINS launch K runs at the top of step K+1 —
-    # the host spends launch K's device time delivering ring K−1's
-    # tokens (on_token/tracing/metrics) and on the serving loop's
-    # inbox/socket work. Admission and chunked prefill run at the
-    # boundary itself, in the drain->dispatch gap: they rewrite the
-    # launch's table/lens/cur inputs and donate the pools, so they
-    # cannot run under an in-flight launch (the device idles for that
-    # window — the N-vs-TTFT trade the README tuning rule names). Every
-    # external entry point that reads or mutates slot state
-    # (expire_deadlines, evict_stalled, dump_inflight, close) flushes
-    # the in-flight launch first, so host state is never stale where
-    # it matters, and _notify_complete streams a request's undelivered
-    # ring tokens before its completion on every terminal path.
-
-    def _macro_hist(self, chunk_plan: Optional[Dict[str, Any]] = None):
-        """Token histories for the in-program draft source (r22): each
-        decoding slot's prompt+generated ids right-padded to
-        ``[num_slots, max_seq_len]`` (submit() guarantees prompt +
-        max_new fits, so the boundary draft and its device twin see
-        the SAME history — bit-identical drafts). The chunk-plan slot
-        uploads its full prompt so the history is ready the moment the
-        program activates it at the final chunk."""
-        hcap = int(self.max_seq_len)
-        hist = np.zeros((self.num_slots, hcap), np.int32)
-        hlen = np.zeros((self.num_slots,), np.int32)
-        for i, r in enumerate(self._slots):
-            if r is None:
-                continue
-            if r.state == "decoding":
-                t = np.asarray(r.tokens, np.int32)
-            elif chunk_plan is not None and i == chunk_plan["slot"]:
-                t = np.asarray(r.prompt, np.int32)
-            else:
-                continue
-            t = t[:hcap]
-            hist[i, :len(t)] = t
-            hlen[i] = len(t)
-        return hist, hlen
-
-    def _dispatch_macro(self,
-                        chunk_plan: Optional[Dict[str, Any]] = None
-                        ) -> bool:
-        """Launch ONE macro program covering up to ``multi_step``
-        decode steps for every decoding slot. Returns True when a
-        launch happened (False: nothing is decoding and no chunk is
-        scheduled). Does NOT block: the device handles land in
-        ``_pending_macro`` for the next boundary's drain.
-
-        r22: with in-program speculation each iteration is a verify
-        step emitting up to k+1 tokens, so the page pre-bind covers
-        ``lens + min(N·(k+1), rem)`` and the token histories ship with
-        the launch; with a ``chunk_plan`` the launch also carries one
-        half-prefilled slot's chained-chunk schedule (the slot enters
-        INACTIVE and the program activates it when its final chunk
-        lands, so its rem/eos stop bookkeeping rides the launch
-        too)."""
-        jnp = self._jnp
-        n = self.multi_step
-        spec_on = self._spec_inprogram
-        per_iter = (int(self._spec_cfg.k) + 1) if spec_on else 1
-        reqs: Dict[int, DecodeRequest] = {}
-        active = np.zeros((self.num_slots,), bool)
-        rem = np.zeros((self.num_slots,), np.int32)
-        eos = np.full((self.num_slots,), -1, np.int32)
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != "decoding":
-                continue
-            r_rem = r.max_new_tokens - len(r.generated)
-            active[i] = True
-            rem[i] = r_rem
-            if r.eos_token is not None:
-                eos[i] = int(r.eos_token)
-            # pre-bind the launch's growth pages out of the admission
-            # reservation (PR 4 contract: cannot fail) — the page
-            # table is then a CONSTANT of the program and in-program
-            # appends are pure index writes through it. The budget
-            # clip inside the program (k_eff) bounds every append
-            # below lens + min(N·per_iter, rem), so this covers the
-            # speculative worst case exactly.
-            self._ensure_pages(
-                i, r, int(self._lens[i]) + min(n * per_iter, r_rem))
-            reqs[i] = r
-        if chunk_plan is not None:
-            ci, cr = chunk_plan["slot"], chunk_plan["req"]
-            rem[ci] = cr.max_new_tokens
-            if cr.eos_token is not None:
-                eos[ci] = int(cr.eos_token)
-            if chunk_plan["has_final"]:
-                # the slot may activate and decode inside THIS launch
-                self._ensure_pages(
-                    ci, cr, len(cr.prompt)
-                    + min(n * per_iter, cr.max_new_tokens))
-        if not reqs and chunk_plan is None:
-            return False
-        has_chunk = chunk_plan is not None
-        jit = self._multi_jits.get(has_chunk)
-        if jit is None:
-            jit = self._build_multi_decode(has_chunk)
-            self._multi_jits[has_chunk] = jit
-        from ..dispatch import count_op_calls
-        with self._phase("upload"):
-            args = [self._fresh_state(), self._pools,
-                    jnp.asarray(self._table), jnp.asarray(self._lens),
-                    jnp.asarray(self._cur), jnp.asarray(active),
-                    jnp.asarray(rem), jnp.asarray(eos)]
-            if spec_on:
-                hist, hlen = self._macro_hist(chunk_plan)
-                args += [jnp.asarray(hist), jnp.asarray(hlen)]
-            if has_chunk:
-                args += [jnp.asarray(chunk_plan["ids"]),
-                         jnp.asarray(chunk_plan["valid"]),
-                         jnp.asarray(chunk_plan["start"]),
-                         jnp.asarray(chunk_plan["final"]),
-                         jnp.asarray(np.int32(chunk_plan["count"])),
-                         jnp.asarray(np.int32(chunk_plan["slot"]))]
-            args = tuple(args)
-        with self._phase("launch") as launch:
-            with count_op_calls() as c:
-                ring, nsteps, cur, lens, act, pools = jit(*args)
-        t0 = launch.t0
-        self._record_programs("decode_multi", c.count)
-        if c.count:
-            self._capture_cost("decode_multi", jit, args)
-        self._pools = pools
-        self.macro_launches += 1
-        self._pending_macro = {
-            "ring": ring, "nsteps": nsteps, "cur": cur, "lens": lens,
-            "reqs": reqs, "t_dispatch": t0,
-            "launch": self.macro_launches,
-            "dispatch_ms": (launch.t1 - t0) * 1e3,
-            "rem": rem, "chunk": chunk_plan,
-        }
-        return True
-
-    def _drain_macro(self) -> List[Tuple]:
-        """Block on the in-flight macro launch (if any) and fold its
-        ring into host state: generated token lists, per-slot
-        lens/cur, finished-slot teardown (pages freed, reservations
-        returned — notify deferred), the per-launch decode EMA and
-        the step-timeline macro record. Returns the emission schedule
-        ``[(req, token, done)]`` in exact (in-macro step, slot) order
-        — the same order ``multi_step=1`` streams — WITHOUT delivering
-        it: the boundary delivers after the next launch is dispatched
-        (host/device overlap), and _notify_complete flushes a
-        terminating request's share first."""
-        pend = self._pending_macro
-        if pend is None:
-            return []
-        # cleared BEFORE the blocking read: a failed async computation
-        # raises here, and retrying dead handles would only re-raise
-        self._pending_macro = None
-        with self._phase("wait") as wait:
-            # the first read blocks until the launch ends
-            for k in ("ring", "lens", "cur"):
-                pend[k] = np.asarray(pend[k])
-            pend["nsteps"] = int(pend["nsteps"])
-        with self._phase("emit"):
-            return self._fold_macro(pend, wait.t1 - wait.t0, wait.t1)
-
-    def _fold_macro(self, pend: Dict[str, Any], idle_s: float,
-                    now: float) -> List[Tuple]:
-        """The host half of ``_drain_macro``: fold a drained launch's
-        ring (host arrays by now; ``idle_s`` is how long the drain
-        blocked, ``now`` its end) into the slots and the timeline."""
-        ring, nsteps = pend["ring"], pend["nsteps"]
-        lens_f, cur_f = pend["lens"], pend["cur"]
-        self._last_macro_t = now
-        dt = now - pend["t_dispatch"]
-        # per-MACRO-LAUNCH decode EMA (the r19 satellite):
-        # decode_ema_s now tracks one dispatch->drain launch window;
-        # per-token estimates derive as ema/multi_step and the
-        # deadline gate charges ceil(need/multi_step) launches
-        # (_deadline_hopeless). First launch is compile-dominated —
-        # skip it, the same warmup rule as the per-token EMA.
-        if self._macro_warm:
-            self.decode_ema_s = dt if self.decode_ema_s is None \
-                else 0.8 * self.decode_ema_s + 0.2 * dt
-        else:
-            self._macro_warm = True
-        reqs = dict(pend["reqs"])
-        plan = pend.get("chunk")
-        spec_mode = ring.ndim == 3
-        k = int(self._spec_cfg.k) if spec_mode else 0
-        # --- fold the in-program chunk plan (r22) -----------------------
-        # All of the plan's chunks ran (the program's cond keeps the
-        # loop alive through iteration count-1 even when every decode
-        # slot stopped), so the host bookkeeping is unconditional; the
-        # final chunk's first token, if any, sits in the ring at
-        # final_idx and the slot joins the generic fold below.
-        if plan is not None:
-            ci = plan["slot"]
-            creq = plan["req"]
-            if self._slots[ci] is creq and \
-                    creq.state == "prefill_partial":
-                creq.stats.prefill_chunks += plan["count"]
-                creq.prefill_done_len = plan["end"]
-                self._write_slot(ci, lens=plan["end"])
-                creq.last_emit_t = now
-                self._last_chunk_t = now
-                creq.chunk_deferrals = 0
-                if creq.trace is not None:
-                    creq.trace.add(
-                        "prefill_chunk_inprogram",
-                        pend["t_dispatch"] * 1e6, now * 1e6,
-                        parent=creq.span, chunks=plan["count"],
-                        tokens=plan["tokens"], launch=pend["launch"])
-                if creq.deadline_t is not None and \
-                        now >= creq.deadline_t:
-                    # expired mid-prefill: chunks are paid for, but a
-                    # token past the deadline breaks the contract —
-                    # same typed eviction as the boundary path
-                    self._evict_slot(ci, "deadline")
-                elif plan["has_final"]:
-                    # promote: the final chunk's logits produced the
-                    # first token inside the program — same shape as
-                    # the boundary promotion in _advance_prefill_chunk
-                    fj = plan["final_idx"]
-                    nxt0 = int(ring[ci, fj, 0] if spec_mode
-                               else ring[ci, fj])
-                    creq.stats.prefill_attempts += 1
-                    creq.stats.first_token_t = now
-                    creq.state = "decoding"
-                    if creq.trace is not None:
-                        self._tr_end(creq,
-                                     chunks=creq.stats.prefill_chunks)
-                        creq.trace.event("first_token",
-                                         parent=creq.trace.anchor,
-                                         token=nxt0)
-                        creq.span = creq.trace.begin(
-                            "decode", parent=creq.trace.anchor)
-                    if self._prefix_cache is not None:
-                        creq.cache_keys = self._prefix_cache.insert(
-                            creq.prompt, self._table[ci],
-                            self.allocator, creq.req_id,
-                            self.page_size, creq.cache_keys,
-                            device_hits=getattr(
-                                creq, "_pfx_device_hits", None))
-                    # join the generic ring/lens/finish fold: its
-                    # first token (and any decode tokens the program
-                    # ran after activation) stream in ring order
-                    reqs[ci] = creq
-        emissions: List[Tuple] = []
-        per_step_tokens: List[int] = []
-        emitted_ct = {i: 0 for i in reqs}
-        runs_tot = drafted_tot = accepted_tot = 0
-        rem0 = pend.get("rem")
-        for j in range(nsteps):
-            count = 0
-            for i in sorted(reqs):
-                req = reqs[i]
-                if spec_mode:
-                    toks = []
-                    for t in ring[i, j]:
-                        t = int(t)
-                        if t < 0:
-                            break  # run entries are front-packed
-                        toks.append(t)
-                else:
-                    t = int(ring[i, j])
-                    toks = [t] if t >= 0 else []
-                if not toks:
-                    continue
-                if spec_mode and not (plan is not None
-                                      and i == plan["slot"]
-                                      and j == plan["final_idx"]):
-                    # reconstruct the per-verify-step stats the
-                    # boundary path records on the host: drafted =
-                    # the budget-clipped k_eff the program used,
-                    # accepted = run length minus the correction/
-                    # bonus token (an EOS inside an accepted run
-                    # truncates the recorded run — terminal, rare)
-                    k_eff = max(
-                        min(k, int(rem0[i]) - emitted_ct[i] - 1), 0)
-                    req.stats.spec_steps += 1
-                    req.stats.spec_drafted += k_eff
-                    req.stats.spec_accepted += max(len(toks) - 1, 0)
-                    runs_tot += 1
-                    drafted_tot += k_eff
-                    accepted_tot += max(len(toks) - 1, 0)
-                emitted_ct[i] += len(toks)
-                for tok in toks:
-                    req.generated.append(tok)
-                    req.stats.tokens_out = len(req.generated)
-                    emissions.append((req, tok, self._finish_due(req)))
-                count += len(toks)
-            per_step_tokens.append(count)
-        for i in sorted(reqs):
-            req = reqs[i]
-            if self._slots[i] is not req:
-                continue  # defensive: slot reassigned (cannot happen
-                # under the flush discipline, but never corrupt it)
-            self._write_slot(i, lens=int(lens_f[i]), cur=int(cur_f[i]))
-            if self._finish_due(req):
-                # teardown now (pages/reservations back before the
-                # boundary's admission), notify at delivery — after
-                # the request's ring tokens have streamed
-                self._finish_slot(i, notify=False)
-            elif spec_mode:
-                # in-program rejection rollback (r22): the program
-                # rewound seq_lens past the rejected drafts; return
-                # the pages whose every position sits at or beyond
-                # the accepted length (rereserve — later growth still
-                # cannot fail). Finished slots freed everything above.
-                self._rollback_pages(i, req, int(lens_f[i]))
-            if req.trace is not None:
-                req.trace.add("macro_step", pend["t_dispatch"] * 1e6,
-                              now * 1e6, parent=req.span,
-                              step=self.steps + nsteps,
-                              launch=pend["launch"],
-                              steps_run=nsteps,
-                              tokens=emitted_ct.get(i, 0))
-        self.steps += nsteps
-        # step-timeline macro record (r16 ring, r19 fields): the entry
-        # committed for THIS boundary carries the drained launch's
-        # attribution; per_token_timeline() reconstructs per-step rows
-        self._tl_add_ms("decode_ms", dt)
-        self._tl_add_ms("overlap_idle_ms", idle_s)
-        self._tl_macro = {
-            "launch": pend["launch"], "steps": nsteps,
-            "tokens": int(sum(per_step_tokens)),
-            "per_step_tokens": per_step_tokens,
-            "ms": round(dt * 1e3, 4),
-            "overlap_idle_ms": round(idle_s * 1e3, 4),
-            "dispatch_ms": round(pend["dispatch_ms"], 4),
-        }
-        if spec_mode:
-            # r22 additive keys: verify iterations broken out so the
-            # timeline can attribute macro time to speculation
-            self._tl_macro["spec"] = {
-                "runs": runs_tot, "drafted": drafted_tot,
-                "accepted": accepted_tot}
-        if plan is not None:
-            self._tl_macro["chunks"] = int(plan["count"])
-        return emissions
-
-    def _flush_macro(self) -> None:
-        """EXTERNAL-entry drain: block on any in-flight macro launch
-        AND deliver everything pending immediately — callbacks,
-        completion notifications included. Called by every public
-        entry point that reads or mutates slot state
-        (expire_deadlines, evict_stalled, dump_inflight, close), so
-        outside a boundary there is never a request whose tokens were
-        folded but whose completion is still owed (the resurrection
-        path depends on this: a request finishing inside a flushed
-        launch must answer its client BEFORE the completion hook is
-        detached, or the client hangs). The boundary itself
-        (_macro_multi_step) drains WITHOUT this helper and defers
-        delivery past the next dispatch — that is the overlap."""
-        if self._pending_macro is not None:
-            self._pending_emit.extend(self._drain_macro())
-        self._deliver_pending()
-
-    def _flush_req_emissions(self, req: DecodeRequest) -> None:
-        """Stream ONE request's undelivered ring tokens (terminal-path
-        ordering: tokens before completion). No-op for requests with
-        nothing pending."""
-        if not self._pending_emit:
-            return
-        mine = [e for e in self._pending_emit if e[0] is req]
-        if not mine:
-            return
-        self._pending_emit = [e for e in self._pending_emit
-                              if e[0] is not req]
-        for _req, tok, done in mine:
-            req.last_emit_t = time.monotonic()
-            if req.on_token is not None:
-                req.on_token(req.req_id, tok, done)
-
-    def _deliver_pending(self) -> None:
-        """Deliver the drained emission schedule in order — on_token
-        callbacks, stall-watchdog liveness, completion notifications
-        for requests that finished inside the launch. Runs AFTER the
-        next launch is dispatched, so callback/tracing/metrics work
-        overlaps device compute."""
-        if not self._pending_emit:
-            return
-        with self._phase("emit"):
-            while self._pending_emit:
-                req, tok, done = self._pending_emit.pop(0)
-                req.last_emit_t = time.monotonic()
-                if req.on_token is not None:
-                    req.on_token(req.req_id, tok, done)
-                if done and req.done:
-                    # the request's terminal bookkeeping ran at drain
-                    # (notify deferred to exactly here, after its
-                    # tokens)
-                    self._notify_complete(req)
-
-    def _macro_multi_step(self) -> int:
-        """One multi-step boundary: drain launch K−1, run the host
-        boundary work (deadline/stall sweeps, admission, one chunked-
-        prefill advance), dispatch launch K, then deliver ring K−1's
-        tokens while the device runs K."""
-        emissions = self._drain_macro()
-        if emissions:
-            self._pending_emit.extend(emissions)
-        # the INNER sweeps: the public wrappers would flush-and-
-        # deliver the emissions just drained, forfeiting the overlap
-        with self._phase("admit"):
-            self._expire_deadlines_inner()
-            self._evict_stalled_inner()
-            self._admit()
-        if self.num_active == 0:
-            self._deliver_pending()
-            return 0
-        chunk_plan = None
-        if self.prefill_chunk_tokens is not None:
-            if self._chunk_inprogram:
-                # r22: chained chunks ride INSIDE the launch (up to N
-                # of one slot's chunks, one per iteration); only the
-                # dense fresh first chunk still runs here at the
-                # boundary (inside _plan_inprogram_chunks)
-                chunk_plan = self._plan_inprogram_chunks()
-            else:
-                self._advance_prefill_chunk()
-        self._dispatch_macro(chunk_plan)
-        self._deliver_pending()
-        return self.num_active
-
-    def per_token_timeline(self) -> List[Dict[str, Any]]:
-        """Step-timeline view with macro-launch entries expanded back
-        into per-token-step rows (the r19 observability contract: the
-        ring marks macro launches; this reconstructs the per-step
-        attribution a per-token engine's ring would have carried).
-        Non-macro entries pass through unchanged."""
-        out: List[Dict[str, Any]] = []
-        for entry in self.timeline:
-            macro = entry.get("macro")
-            if not macro or not macro.get("steps"):
-                out.append(dict(entry))
-                continue
-            nsteps = macro["steps"]
-            base = entry["step"] - nsteps
-            for j, toks in enumerate(macro["per_step_tokens"]):
-                out.append({
-                    "step": base + j + 1,
-                    "ms": round(macro["ms"] / nsteps, 4),
-                    "tokens": toks,
-                    "macro_launch": macro["launch"],
-                    "macro_offset": j,
-                })
-        return out
+        self._notify_complete(req)
 
     # -- speculative decoding ----------------------------------------------
 
@@ -3724,17 +2897,14 @@ class ContinuousBatchingEngine:
                       need_len: int) -> None:
         """Grow the slot's page set to cover positions [0, need_len)
         out of the request's reservation (guaranteed: capacity was
-        committed at admission). Reserve-growth modes only
-        (speculative, and multi-step macro dispatch) — vanilla
+        committed at admission). Speculative engines only — vanilla
         per-token admission binds every page up front."""
         row = self._table[slot].copy()
         want = -(-need_len // self.page_size)
         missing = [j for j in range(want) if row[j] == self._scratch]
         if not missing:
             return
-        reason = ("spec_grow" if self._spec_cfg is not None
-                  else "macro_grow")
-        with self._led(reason, req.req_id):
+        with self._led("spec_grow", req.req_id):
             pages = self.allocator.alloc_reserved(req.req_id,
                                                   len(missing))
         row[missing] = pages
@@ -3890,7 +3060,7 @@ class ContinuousBatchingEngine:
         one slot's next chunk), LAUNCH one fixed-shape decode step for
         every slot past prefill and hand out the tokens of the decode
         step launched by the call before (or run one draft-and-verify
-        speculative step, or one macro boundary), evict what finished.
+        speculative step), evict what finished.
         Returns the number of still-active slots.
 
         **One decode step stays in flight ahead of the host**
@@ -3945,17 +3115,6 @@ class ContinuousBatchingEngine:
             raise
 
     def _step_inner(self) -> int:
-        if self.multi_step > 1 and (self._spec_cfg is None
-                                    or self._spec_inprogram):
-            # device-resident multi-step decode (r19): one boundary =
-            # drain launch K−1, boundary scheduling, dispatch launch
-            # K, deliver K−1's ring. r22: a greedy speculative engine
-            # with a device-implementable draft rides the SAME macro
-            # boundary — draft/verify/rewind run inside the launch
-            # (_spec_inprogram). Other speculative engines (sampled
-            # verify, host draft sources) keep their per-step verify
-            # cadence — spec composes AT the boundary for them.
-            return self._macro_multi_step()
         if self._resident is None:
             # a finish at the last settle wrote its slot under the step
             # in flight: that step's outputs cannot feed another, and
@@ -4208,17 +3367,12 @@ class ContinuousBatchingEngine:
         — the graceful-drain endpoint bench/tests call on every exit
         path (a drained `run()` followed by close() is the clean
         shutdown; close() mid-flight is the hard stop)."""
-        # multi-step (r19): drain + deliver any in-flight launch so
-        # teardown evictions see current state and streamed tokens
-        # precede every eviction notification. A failed drain means
-        # the launch's tokens never existed for any client — drop it
-        # (anything drained EARLIER still delivers).
-        try:
-            self._flush_macro()
+        # settle the step in flight so teardown evictions see current
+        # state and streamed tokens precede every eviction
+        # notification. A failed settle means its tokens never existed
+        # for any client: drop it.
+        with contextlib.suppress(Exception):
             self._settle_inflight()
-        except Exception:
-            self._pending_macro = None
-            self._deliver_pending()
         for slot, req in enumerate(self._slots):
             if req is not None:
                 self._evict_slot(slot, "evicted")

@@ -6,8 +6,7 @@ Ragged Paged Attention layout the allocator books. Every page event
 the `PageAllocator` (and the engine's spill/restore device IO) performs
 is appended to a BOUNDED ring with its owner, the engine step it
 happened on, and the reason the engine was touching pages at the time
-(admit / done / deadline / stalled / spec_rollback / macro_grow —
-the r19 multi-step launch's reservation→page growth — / dedup_hit —
+(admit / done / deadline / stalled / spec_rollback / dedup_hit —
 the r23 cross-request fold that releases a content-duplicate page
 and moves the shared one to a ("dedup", key) owner — / close / ...).
 

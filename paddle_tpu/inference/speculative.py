@@ -39,8 +39,7 @@ from typing import Any, Callable, List, Optional, Sequence
 import numpy as np
 
 __all__ = ["SpeculativeConfig", "NGramDraft", "ModelDraft",
-           "CallableDraft", "SelfDraft", "as_spec_config",
-           "device_draft_params"]
+           "CallableDraft", "SelfDraft", "as_spec_config"]
 
 
 class NGramDraft:
@@ -187,11 +186,10 @@ class ModelDraft:
 class SelfDraft:
     """Repeat the last emitted token ``k`` times. The degenerate
     prompt-lookup draft (NGramDraft's no-match fallback, promoted to
-    the whole policy): free to compute, device-implementable as a
-    broadcast, and surprisingly effective on runs of repeated tokens
-    (whitespace, padding, looping greedy tails). Exists mostly as the
-    simplest in-program draft source (r22) and as a bisection rung
-    between "spec off" and "ngram"."""
+    the whole policy): free to compute and surprisingly effective on
+    runs of repeated tokens (whitespace, padding, looping greedy
+    tails). Exists mostly as a bisection rung between "spec off" and
+    "ngram"."""
 
     def propose(self, histories: Sequence[Optional[np.ndarray]],
                 k: int) -> np.ndarray:
@@ -265,25 +263,6 @@ class SpeculativeConfig:
         if callable(getattr(d, "forward", None)):
             return ModelDraft(d, window=self.draft_window)
         raise ValueError(f"cannot build a draft source from {d!r}")
-
-
-def device_draft_params(draft) -> Optional[dict]:
-    """Describe a draft source as a device-implementable program, or
-    ``None`` if it has no device twin.
-
-    The in-program inner loop (r22) moves drafting inside the macro
-    ``while_loop``, so the draft must be expressible as pure array math
-    over the slot's stored token history. NGramDraft has an exact
-    gather-based twin (nn/decode.py ``ngram_draft_tokens``); SelfDraft
-    is a broadcast. ModelDraft / CallableDraft run arbitrary host code
-    and stay at the launch boundary — the engine falls back to the
-    boundary-interleaved path for them."""
-    if isinstance(draft, NGramDraft):
-        return {"kind": "ngram", "max_ngram": draft.max_ngram,
-                "min_ngram": draft.min_ngram}
-    if isinstance(draft, SelfDraft):
-        return {"kind": "self"}
-    return None
 
 
 def as_spec_config(spec) -> "SpeculativeConfig":

@@ -362,301 +362,6 @@ def paged_page_splice(pools, page, k_blocks, v_blocks,
     }
 
 
-def multi_step_decode(step_fn, pools, table, lens, tokens, active,
-                      rem, eos, num_steps: int, scratch: int,
-                      spec=None, chunk=None):
-    """Device-resident multi-step decode (r19, ROADMAP item 2): run up
-    to ``num_steps`` fused decode steps in ONE on-device
-    ``lax.while_loop`` program, so the host pays one launch + one
-    readback per N tokens instead of per token — the launch/sync
-    boundary was the remaining overhead after PR 8 fused the step to
-    ~one program (the Neptune / FusionStitching locality argument one
-    level up).
-
-    r22 (ROADMAP item 3a/3b) moves the remaining BOUNDARY work into
-    the program too, both optional and Python-static so ``spec=None,
-    chunk=None`` traces byte-for-byte the r19 program:
-
-    - ``spec`` (in-program speculative verify): a dict with static
-      ``k``/``vocab`` and three closures + carries — ``draft_fn(hist,
-      hist_len, cur) -> [B, k]`` proposals (nn/decode.py
-      ``ngram_draft_tokens`` or self-draft, both pure gathers),
-      ``verify_fn(pools, table, lens, toks [B, k+1], valid [B]) ->
-      (accept, resid, full, pools)`` (the engine's fused
-      ``verify_step`` math), and ``hist``/``hist_len`` [B, H]/[B]
-      history buffers the accepted runs append to. Each iteration
-      drafts, verifies all k+1 positions in one ragged chained-prefill
-      pass, folds the accepted run through nn/decode.py
-      ``masked_run_advance`` (EOS/budget truncation as masked
-      carries), and REWINDS ``seq_lens`` past rejections inside the
-      program — a k-token accepted run costs zero extra launches. The
-      token ring widens to ``[B, num_steps, k+1]`` (−1 beyond each
-      iteration's emitted share). Greedy only: acceptance is
-      exact-match against the target's own argmax, so emission is
-      bit-identical to per-token decode regardless of draft quality.
-
-    - ``chunk`` (in-program chunked prefill): a dict with
-      ``prefill_fn(pools, trow, slens, plen, ids) -> (nxt, pools)``
-      (the engine's chained-prefill body — the ``q_offsets`` ragged
-      paged-attention path), per-iteration ``ids [num_steps, bucket]``
-      / ``valid`` / ``start`` / ``final`` schedules, and traced
-      ``count``/``slot`` scalars. Iteration ``j < count`` advances the
-      one half-prefilled slot's next page-aligned chunk inside the
-      same program (``lax.cond`` skips the work on decode-only
-      iterations); the FINAL chunk samples the slot's first token,
-      writes it into the ring, and activates the slot for the next
-      iteration's decode — a long prompt streams in without ever
-      stalling a launch.
-
-    ``step_fn(pools, table, lens, cur) -> (nxt, new_pools,
-    new_lens)`` is the engine's SINGLE-TOKEN decode body — exactly the
-    trace a ``multi_step=1`` launch runs — so every in-program
-    iteration is bit-identical to one host-driven step by
-    construction. The loop only adds the host bookkeeping the engine
-    used to do between launches, in carry form:
-
-    - masking: iteration inputs are re-derived per step — an inactive
-      slot (finished mid-launch, half-prefilled, or empty) sees the
-      scratch-page table at length 0, exactly how ``_decode_step``
-      masks non-decoding slots, so its KV writes land on scratch and
-      its pages are never touched;
-    - early exit: the while_loop stops as soon as EVERY slot has
-      stopped (EOS or budget — nn/decode.py ``masked_carry_advance``,
-      the carry-form twin of the host's ``_finish_due``), so a batch
-      that finishes at iteration j pays j steps, not N;
-    - the token ring: each iteration writes its sampled tokens into a
-      ``[B, num_steps]`` ring (−1 for masked slots), read back ONCE
-      per launch — the host drains it through on_token/tracing at the
-      next boundary while the device runs the following launch.
-
-    Page growth stays host-owned and PRE-BOUND: the engine converts
-    each slot's admission reservation into physical pages covering
-    ``lens + min(num_steps, rem)`` positions BEFORE the launch (the
-    PR 4 reservation machinery guarantees this cannot fail), so the
-    page table is a constant of the program and in-program appends
-    are pure index writes through it.
-
-    Returns ``(ring, steps_done, cur, lens, active, pools)`` — final
-    carry values the host folds back into its slot state at drain.
-    ``ring`` is ``[B, num_steps]`` int32 (``spec=None`` — one token
-    per iteration) or ``[B, num_steps, k+1]`` (in-program speculative:
-    one accepted RUN per iteration)."""
-    import jax
-
-    from ..nn.decode import masked_carry_advance
-
-    if spec is None and chunk is None:
-        # r19 path, byte-for-byte (the escape-hatch contract: a plain
-        # multi_step engine's trace is unchanged by r22)
-        b = tokens.shape[0]
-        ring0 = jnp.full((b, num_steps), -1, jnp.int32)
-        emitted0 = jnp.zeros((b,), jnp.int32)
-        rem = rem.astype(jnp.int32)
-        eos = eos.astype(jnp.int32)
-
-        def cond(carry):
-            j, _cur, _lens, act, _emitted, _ring, _pl = carry
-            return jnp.logical_and(j < num_steps, jnp.any(act))
-
-        def body(carry):
-            j, cur, lens_c, act, emitted, ring, pl = carry
-            # per-iteration masking (the _decode_step contract):
-            # inactive slots ride the fixed-shape step parked on the
-            # scratch page at length 0 — defined zeros out, writes
-            # land on scratch
-            table_eff = jnp.where(act[:, None], table,
-                                  scratch).astype(jnp.int32)
-            lens_eff = jnp.where(act, lens_c, 0).astype(jnp.int32)
-            nxt, pl, _ = step_fn(pl, table_eff, lens_eff, cur)
-            col = jnp.where(act, nxt, -1).astype(jnp.int32)
-            ring = jax.lax.dynamic_update_slice(ring, col[:, None],
-                                                (0, j))
-            # this iteration appended cur's KV for every active slot —
-            # advance their lengths with the PRE-update mask
-            lens_c = jnp.where(act, lens_c + 1, lens_c)
-            cur, act, emitted = masked_carry_advance(nxt, cur, act,
-                                                     emitted, rem, eos)
-            return (j + 1, cur, lens_c, act, emitted, ring, pl)
-
-        j, cur, lens_c, act, _emitted, ring, pl = jax.lax.while_loop(
-            cond, body,
-            (jnp.asarray(0, jnp.int32), tokens.astype(jnp.int32),
-             lens.astype(jnp.int32), active, emitted0, ring0, pools))
-        return ring, j, cur, lens_c, act, pl
-
-    # -- r22 extended path: in-program speculative verify and/or
-    # in-program chunked prefill ------------------------------------
-    from ..nn.decode import masked_run_advance
-
-    b = tokens.shape[0]
-    k = int(spec["k"]) if spec is not None else 0
-    width = k + 1
-    if spec is not None:
-        ring0 = jnp.full((b, num_steps, width), -1, jnp.int32)
-        hist0 = spec["hist"].astype(jnp.int32)
-        hlen0 = spec["hist_len"].astype(jnp.int32)
-        hcap = hist0.shape[1]
-    else:
-        ring0 = jnp.full((b, num_steps), -1, jnp.int32)
-    emitted0 = jnp.zeros((b,), jnp.int32)
-    rem = rem.astype(jnp.int32)
-    eos = eos.astype(jnp.int32)
-    if chunk is not None:
-        chunk_count = chunk["count"].astype(jnp.int32)
-        chunk_slot = chunk["slot"].astype(jnp.int32)
-
-    def cond(carry):
-        j, _cur, _lens, act = carry[0], carry[1], carry[2], carry[3]
-        alive = jnp.any(act)
-        if chunk is not None:
-            # chunk-only launches are legal (nothing decoding yet):
-            # the loop runs until every scheduled chunk has landed
-            alive = jnp.logical_or(alive, j < chunk_count)
-        return jnp.logical_and(j < num_steps, alive)
-
-    def body(carry):
-        if spec is not None:
-            (j, cur, lens_c, act, emitted, ring, pl, hist,
-             hist_len) = carry
-        else:
-            j, cur, lens_c, act, emitted, ring, pl = carry
-            hist = hist_len = None
-        table_eff = jnp.where(act[:, None], table,
-                              scratch).astype(jnp.int32)
-        lens_eff = jnp.where(act, lens_c, 0).astype(jnp.int32)
-        if spec is None:
-            nxt, pl, _ = step_fn(pl, table_eff, lens_eff, cur)
-            col = jnp.where(act, nxt, -1).astype(jnp.int32)
-            ring = jax.lax.dynamic_update_slice(ring, col[:, None],
-                                                (0, j))
-            lens_c = jnp.where(act, lens_c + 1, lens_c)
-            cur, act, emitted = masked_carry_advance(nxt, cur, act,
-                                                     emitted, rem, eos)
-        else:
-            # draft clip: emit at most the remaining budget, exactly
-            # the host _spec_step's k_eff = min(k, rem - 1) rule with
-            # rem counted from the in-carry emitted total
-            k_eff = jnp.clip(rem - emitted - 1, 0, k)
-            valid = jnp.where(act, 1 + k_eff, 0).astype(jnp.int32)
-            drafts = spec["draft_fn"](hist, hist_len, cur)
-            drafts = jnp.clip(drafts.astype(jnp.int32), 0,
-                              spec["vocab"] - 1)
-            toks = jnp.concatenate([cur[:, None], drafts], axis=1)
-            accept, _resid, full, pl = spec["verify_fn"](
-                pl, table_eff, lens_eff, toks, valid)
-            acc = jnp.logical_and(
-                accept, jnp.arange(k)[None, :] < k_eff[:, None])
-            nacc = jnp.sum(jnp.cumprod(acc.astype(jnp.int32), axis=1),
-                           axis=1)
-            # greedy verify: resid == full[:, :-1], so the
-            # correction/bonus token is full[:, n] in both the
-            # n < k_eff and n == k_eff cases — the target's own next
-            # token given the accepted prefix
-            nxt = jnp.take_along_axis(full.astype(jnp.int32),
-                                      nacc[:, None], axis=1)[:, 0]
-            run = jnp.where(jnp.arange(width)[None, :] < nacc[:, None],
-                            jnp.pad(drafts, ((0, 0), (0, 1))),
-                            nxt[:, None])
-            act_pre = act
-            run_masked, emit_len, cur, act, emitted = \
-                masked_run_advance(run, nacc + 1, cur, act, emitted,
-                                   rem, eos)
-            ring = jax.lax.dynamic_update_slice(
-                ring, run_masked[:, None, :], (0, j, 0))
-            # the in-program rewind: seq_lens advance past cur + the
-            # accepted drafts ONLY — rejected positions fall back off
-            # the valid range and the next iteration's verify appends
-            # straight over their stale KV
-            lens_c = jnp.where(act_pre, lens_c + nacc + 1, lens_c)
-            # append the emitted run to the draft history
-            for r in range(width):
-                idx = jnp.minimum(hist_len + r, hcap - 1)
-                put = jnp.logical_and(act_pre, r < emit_len)
-                old = jnp.take_along_axis(hist, idx[:, None],
-                                          axis=1)[:, 0]
-                hist = hist.at[jnp.arange(b), idx].set(
-                    jnp.where(put, run[:, r], old))
-            hist_len = jnp.where(
-                act_pre, jnp.minimum(hist_len + emit_len, hcap),
-                hist_len)
-        if chunk is not None:
-            def run_chunk(op):
-                if spec is not None:
-                    cur, lens_c, act, emitted, ring, pl, hist, \
-                        hist_len = op
-                else:
-                    cur, lens_c, act, emitted, ring, pl = op
-                    hist = hist_len = None
-                ids_j = jax.lax.dynamic_slice_in_dim(chunk["ids"], j,
-                                                     1, 0)
-                valid_j = jax.lax.dynamic_index_in_dim(
-                    chunk["valid"], j, 0, keepdims=False)
-                start_j = jax.lax.dynamic_index_in_dim(
-                    chunk["start"], j, 0, keepdims=False)
-                final_j = jax.lax.dynamic_index_in_dim(
-                    chunk["final"], j, 0, keepdims=False)
-                trow = jnp.take(table, chunk_slot[None],
-                                axis=0).astype(jnp.int32)
-                nxt_c, pl = chunk["prefill_fn"](
-                    pl, trow, start_j[None], valid_j[None], ids_j)
-                nxt_c = nxt_c.astype(jnp.int32)
-                plen = start_j + valid_j
-                onehot = jnp.arange(b) == chunk_slot
-                upd = jnp.logical_and(final_j, onehot)
-                # first-token stop rule (the host's _maybe_finish
-                # after a final chunk's emission)
-                slot_rem = jnp.take(rem, chunk_slot)
-                slot_eos = jnp.take(eos, chunk_slot)
-                stop = jnp.logical_or(nxt_c == slot_eos,
-                                      slot_rem <= 1)
-                cur = jnp.where(upd, nxt_c, cur)
-                lens_c = jnp.where(upd, plen, lens_c)
-                emitted = jnp.where(upd, 1, emitted)
-                # activation: the promoted slot joins the decode from
-                # the NEXT iteration (this iteration's decode already
-                # ran on the pre-chunk mask)
-                act = jnp.where(upd, jnp.logical_not(stop), act)
-                if spec is not None:
-                    ring = ring.at[chunk_slot, j, 0].set(
-                        jnp.where(final_j, nxt_c,
-                                  ring[chunk_slot, j, 0]))
-                    hidx = jnp.minimum(plen, hcap - 1)
-                    hist = hist.at[chunk_slot, hidx].set(
-                        jnp.where(final_j, nxt_c,
-                                  hist[chunk_slot, hidx]))
-                    hist_len = jnp.where(upd, plen + 1, hist_len)
-                    return (cur, lens_c, act, emitted, ring, pl,
-                            hist, hist_len)
-                ring = ring.at[chunk_slot, j].set(
-                    jnp.where(final_j, nxt_c, ring[chunk_slot, j]))
-                return (cur, lens_c, act, emitted, ring, pl)
-
-            if spec is not None:
-                ops = (cur, lens_c, act, emitted, ring, pl, hist,
-                       hist_len)
-            else:
-                ops = (cur, lens_c, act, emitted, ring, pl)
-            ops = jax.lax.cond(j < chunk_count, run_chunk,
-                               lambda op: op, ops)
-            if spec is not None:
-                (cur, lens_c, act, emitted, ring, pl, hist,
-                 hist_len) = ops
-            else:
-                cur, lens_c, act, emitted, ring, pl = ops
-        if spec is not None:
-            return (j + 1, cur, lens_c, act, emitted, ring, pl, hist,
-                    hist_len)
-        return (j + 1, cur, lens_c, act, emitted, ring, pl)
-
-    init = [jnp.asarray(0, jnp.int32), tokens.astype(jnp.int32),
-            lens.astype(jnp.int32), active, emitted0, ring0, pools]
-    if spec is not None:
-        init += [hist0, hlen0]
-    out = jax.lax.while_loop(cond, body, tuple(init))
-    j, cur, lens_c, act, _emitted, ring, pl = out[:7]
-    return ring, j, cur, lens_c, act, pl
-
-
 def _remat_block(block, x):
     """Run ``block`` under jax.checkpoint as ONE taped op: the pure kernel
     takes (hidden, *param_values) so the eager tape differentiates through

@@ -28,8 +28,7 @@ F = dispatch.wrapped_ops
 
 __all__ = ["BeamSearchDecoder", "dynamic_decode", "sample_token",
            "fused_sample_token", "fused_verify_tokens",
-           "speculative_verify_tokens", "masked_carry_advance",
-           "masked_run_advance", "ngram_draft_tokens"]
+           "speculative_verify_tokens"]
 
 
 # ---------------------------------------------------------------------------
@@ -59,145 +58,6 @@ def sample_token(last, temperature: float = 0.0, top_k=None, key=None):
     key, sub = jax.random.split(key)
     return jax.random.categorical(sub, scaled, axis=-1).astype(
         jnp.int32), key
-
-
-def masked_carry_advance(nxt, cur, active, emitted, rem, eos):
-    """Carry-form sampler update for the device-resident multi-step
-    decode loop (r19, models/gpt.py ``multi_step_decode``): fold one
-    freshly sampled token batch into the ``(cur, active, emitted)``
-    loop carry under the per-slot active mask.
-
-    ``nxt``: [B] int32 tokens this iteration's :func:`sample_token` /
-    :func:`fused_sample_token` produced; ``cur``: [B] the previous
-    carry tokens; ``active``: [B] bool, which slots are still
-    generating; ``emitted``: [B] int32, tokens emitted so far THIS
-    macro launch; ``rem``: [B] int32, each slot's remaining emission
-    budget (``max_new_tokens - len(generated)`` at the boundary);
-    ``eos``: [B] int32 EOS ids (−1 = none — token ids are >= 0, so −1
-    never matches).
-
-    Returns ``(cur', active', emitted')``. The stop rule mirrors the
-    host engine's ``_finish_due`` exactly — a slot stops after
-    emitting EOS or its budget's last token — so an N-step launch's
-    per-slot token streams are bit-identical to N host-driven steps.
-    A stopped slot keeps its last token in ``cur`` and rides the rest
-    of the launch masked (the harness redirects its KV writes to the
-    scratch page), exactly like a parked slot in the per-token
-    engine."""
-    emitted = emitted + active.astype(jnp.int32)
-    stop = (nxt == eos) | (emitted >= rem)
-    new_active = jnp.logical_and(active, jnp.logical_not(stop))
-    return jnp.where(active, nxt, cur), new_active, emitted
-
-
-def masked_run_advance(run, run_len, cur, active, emitted, rem, eos):
-    """Carry-form accept/rewind twin of :func:`masked_carry_advance`
-    for per-iteration ACCEPTED RUNS (r22 in-program speculative
-    verify, models/gpt.py ``multi_step_decode``): fold a ``[B, W]``
-    token run — each slot's accepted draft prefix plus its
-    correction/bonus token, ``W = k+1`` — into the ``(cur, active,
-    emitted)`` loop carry, truncating each slot's run exactly as the
-    host engine would have by emitting it token by token through
-    ``_finish_due``:
-
-    - an EOS inside the run ends the emission AT that token (later
-      accepted drafts are rewound — they were never emitted);
-    - the emission budget ``rem`` caps the total: a run whose last
-      token lands exactly on the budget stops the slot there (the
-      draft clip ``k_eff = min(k, budget-1)`` guarantees a run never
-      OVERSHOOTS the budget, so the cap only ever bites at the run's
-      final token — the same invariant the host ``_spec_step`` holds).
-
-    ``run``: [B, W] int32 candidate tokens (positions past
-    ``run_len`` are ignored); ``run_len``: [B] int32 in [1, W];
-    ``cur``/``active``/``emitted``/``rem``/``eos``: the
-    :func:`masked_carry_advance` carries. Returns ``(run_masked
-    [B, W] int32 with −1 beyond each slot's emitted share, emit_len
-    [B] int32, cur', active', emitted')`` — ``run_masked`` is exactly
-    the widened token-ring row the macro program commits for this
-    iteration, so the host's drain replays the per-token stream by
-    reading it left to right."""
-    b, w = run.shape
-    run = run.astype(jnp.int32)
-    jpos = jnp.arange(w)[None, :]
-    in_run = jpos < run_len[:, None]
-    budget = jnp.maximum(rem - emitted, 0)
-    # first EOS position within the run (w when none): emitting stops
-    # AFTER that token, exactly like the host's append-then-check loop
-    is_eos = (run == eos[:, None]) & in_run
-    eos_idx = jnp.argmax(
-        jnp.concatenate([is_eos, jnp.ones((b, 1), bool)], axis=1),
-        axis=1)
-    emit_len = jnp.minimum(run_len, jnp.minimum(eos_idx + 1, budget))
-    emit_len = jnp.where(active, jnp.maximum(emit_len, 0), 0)
-    last = jnp.take_along_axis(
-        run, jnp.maximum(emit_len - 1, 0)[:, None], axis=1)[:, 0]
-    hit_eos = (eos_idx + 1) <= emit_len
-    new_emitted = emitted + emit_len
-    stop = hit_eos | (new_emitted >= rem)
-    new_active = jnp.logical_and(active, jnp.logical_not(stop))
-    run_masked = jnp.where(
-        (jpos < emit_len[:, None]) & active[:, None], run, -1)
-    new_cur = jnp.where(active & (emit_len > 0), last, cur)
-    return run_masked, emit_len, new_cur, new_active, new_emitted
-
-
-def ngram_draft_tokens(hist, hist_len, k: int, max_ngram: int = 3,
-                       min_ngram: int = 1):
-    """Device twin of inference/speculative.py ``NGramDraft._lookup``
-    (r22 in-program drafting): prompt-lookup drafting as pure gathers
-    over the slot's stored token history, so the draft runs INSIDE
-    the macro decode program with zero host round trips.
-
-    ``hist``: [B, H] int32 token history buffer (prompt + generated,
-    right-padded — contents past ``hist_len`` are ignored);
-    ``hist_len``: [B] int32 valid lengths. Returns ``[B, k]`` int32
-    proposals with EXACTLY the host source's semantics: the longest
-    ``max_ngram..min_ngram`` suffix that re-occurs earlier in the
-    history (most recent occurrence wins) proposes the k tokens that
-    followed it there, clipped continuations pad with their last
-    token, and no match at any order repeats the last history token.
-    Draft QUALITY is all this affects — greedy verify emission is
-    independent of the proposals — so the twin exists to keep
-    in-program acceptance rates identical to the host source's, not
-    for correctness."""
-    b, hcap = hist.shape
-    n = hist_len.astype(jnp.int32)
-    pos = jnp.arange(hcap)
-    last = jnp.take_along_axis(
-        hist, jnp.maximum(n - 1, 0)[:, None], axis=1)
-    out = jnp.broadcast_to(last, (b, k)).astype(jnp.int32)
-    found = jnp.zeros((b,), bool)
-    for g in range(max_ngram, min_ngram - 1, -1):
-        # host rule: orders above n-1 are skipped (the suffix must
-        # leave at least one earlier token to match against)
-        g_ok = g <= (n - 1)
-        pat_idx = jnp.maximum(n[:, None] - g + jnp.arange(g)[None, :],
-                              0)
-        pat = jnp.take_along_axis(hist, pat_idx, axis=1)     # [B, g]
-        win_idx = jnp.minimum(pos[:, None] + jnp.arange(g)[None, :],
-                              hcap - 1)                      # [H, g]
-        win = hist[:, win_idx]                               # [B,H,g]
-        match = (win == pat[:, None, :]).all(-1)             # [B, H]
-        # windows end at e = s+g <= n-1: the suffix itself (ending at
-        # n) is excluded, exactly the host's h[:n-1] window view
-        valid_s = (pos[None, :] + g) <= (n[:, None] - 1)
-        hit = match & valid_s & g_ok[:, None]
-        any_hit = hit.any(-1)
-        # most recent earlier occurrence wins: the largest start
-        s_best = jnp.argmax(jnp.where(hit, pos[None, :], -1), axis=-1)
-        e = s_best + g
-        cont_idx = jnp.minimum(e[:, None] + jnp.arange(k)[None, :],
-                               hcap - 1)
-        cont = jnp.take_along_axis(hist, cont_idx, axis=1)   # [B, k]
-        clen = jnp.clip(n[:, None] - e[:, None], 1, k)
-        cont_last = jnp.take_along_axis(cont, clen - 1, axis=1)
-        cont = jnp.where(jnp.arange(k)[None, :] < clen, cont,
-                         cont_last)
-        take = any_hit & jnp.logical_not(found)
-        out = jnp.where(take[:, None], cont, out).astype(jnp.int32)
-        found = found | any_hit
-    return out
 
 
 def _head_logits(hidden, weight, bias, transpose_y: bool):

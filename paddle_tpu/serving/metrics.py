@@ -53,11 +53,6 @@ CHUNK_COUNT_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0,
 PAGE_COUNT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0,
                       256.0, 512.0)
 
-# multi-step decode (r19): decode steps executed per macro launch —
-# lives in [1, multi_step]; below-N buckets show early EOS exits
-STEPS_PER_LAUNCH_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0,
-                            24.0, 32.0, 48.0, 64.0)
-
 
 class Histogram:
     """Fixed-bucket latency histogram with quantiles over a bounded
@@ -371,11 +366,6 @@ class ServingMetrics:
                 # counter contract holds)
                 "traces_sampled_total", "traces_finished_total",
                 "trace_spans_dropped_total",
-                # multi-step decode (r19): macro launches — synced
-                # from the engine's lifetime macro_launches counter at
-                # scrape time (monotonic across resurrections is NOT
-                # guaranteed engine-side, so the server accumulates)
-                "macro_steps_total",
                 # disaggregated serving (r20): cross-replica KV
                 # handoff accounting — pages spliced from wire-fetched
                 # blobs, bytes pulled over fetch_pages, and fetch
@@ -444,17 +434,6 @@ class ServingMetrics:
         # footprint was still capacity spent)
         self.request_peak_pages = Histogram(
             f"{prefix}.request_peak_pages", buckets=PAGE_COUNT_BUCKETS)
-        # multi-step decode (r19): decode steps per macro launch
-        # (early-EOS exits land under N) and host time spent BLOCKED
-        # on a macro drain (0-ish = the overlap worked: the device
-        # finished while the host ran the serving loop) — both fed
-        # from step-timeline macro records at scrape time, like
-        # step_ms
-        self.steps_per_launch = Histogram(
-            f"{prefix}.steps_per_launch",
-            buckets=STEPS_PER_LAUNCH_BUCKETS)
-        self.host_overlap_idle_ms = Histogram(
-            f"{prefix}.host_overlap_idle_ms")
         # disaggregated serving (r20): wall time of the fetch_pages
         # RPC a decode replica's connection thread spent pulling a
         # request's chain from a peer (the number that must sit well
@@ -492,11 +471,6 @@ class ServingMetrics:
         self.request_peak_pages = Histogram(
             f"{self.prefix}.request_peak_pages",
             buckets=PAGE_COUNT_BUCKETS)
-        self.steps_per_launch = Histogram(
-            f"{self.prefix}.steps_per_launch",
-            buckets=STEPS_PER_LAUNCH_BUCKETS)
-        self.host_overlap_idle_ms = Histogram(
-            f"{self.prefix}.host_overlap_idle_ms")
         self.handoff_ms = Histogram(f"{self.prefix}.handoff_ms")
         self.swap_ms = Histogram(f"{self.prefix}.swap_ms")
 
@@ -663,8 +637,6 @@ class ServingMetrics:
                 "restore_ms": self.restore_ms,
                 "step_ms": self.step_ms,
                 "request_peak_pages": self.request_peak_pages,
-                "steps_per_launch": self.steps_per_launch,
-                "host_overlap_idle_ms": self.host_overlap_idle_ms,
                 "handoff_ms": self.handoff_ms,
                 "swap_ms": self.swap_ms}
 

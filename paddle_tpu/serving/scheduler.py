@@ -77,19 +77,11 @@ class SLOConfig:
     # the mandatory next admission
     max_bypass: int = 4
     retry_after_ms: int = 1000
-    # chunked prefill (r11): consecutive ENGINE BOUNDARIES a
+    # chunked prefill (r11): consecutive engine step() calls a
     # lower-class prefill chunk may be deferred by higher-class decode
     # before it runs anyway (the starvation bound of
-    # decode-preempts-prefill). Units are engine step() calls — with
-    # multi-step decode (r19, multi_step=N) each boundary covers up
-    # to N generated tokens, so a deferral budget of 4 means up to
-    # 4*N decode tokens of delay, not 4; TTFT-sensitive deployments
-    # running large N should shrink this accordingly. With the r22
-    # in-program inner loop a GRANT costs decode nothing (the chunks
-    # ride inside the macro launch, one per iteration, instead of
-    # stalling the boundary) and each grant advances up to N chunks,
-    # so deferring is only worth it when the launch itself must stay
-    # small — the default budget is then an upper bound, not a tune.
+    # decode-preempts-prefill): a budget of 4 is up to 4 decode tokens
+    # of delay.
     max_chunk_deferrals: int = 4
     # per-class cap on in-flight half-prefilled debt (tokens) at
     # admission; None = unbounded. A class with zero in-flight debt is
@@ -204,24 +196,7 @@ class SLOScheduler:
         prompt still finishes (the bypass-bound idea applied to the
         prefill budget). With nothing decoding there is nothing to
         protect: the top-ranked chunk always runs (the engine relies
-        on this for drain progress).
-
-        Multi-step decode (r19): this hook runs once per BOUNDARY, so
-        under ``multi_step=N`` each deferral costs up to N decode
-        tokens of prefill delay and each granted chunk displaces
-        nothing (the chunk runs at the boundary, outside the macro
-        launch) — the deferral bound is a boundary count, exactly as
-        the deadline gate's estimates are per-launch
-        (``decode_ema_s`` tracks one macro launch there).
-
-        In-program inner loop (r22): a grant now schedules up to N of
-        the slot's CHAINED chunks inside the macro launch itself — the
-        decode batch keeps decoding through the same iterations, so
-        preempting the chunk no longer protects interactive TPOT from
-        a launch stall; it only bounds the launch's extra chunk work.
-        The deadline gate mirrors this by charging ceil(chunks/N)
-        whole launches at ``decode_ema_s`` (in-program units) instead
-        of per-chunk boundary wall time."""
+        on this for drain progress)."""
         if not partial:
             return None
         ranked = sorted(partial, key=lambda sr: (

@@ -124,12 +124,6 @@ page-aligned 256-token chunk of one admitted prompt before the decode
 step (the TTFT-vs-TPOT head-of-line fix; greedy outputs stay
 bit-identical to whole prefill, and the ``serving_prefill_debt_tokens``
 gauge tracks the outstanding work).
-Multi-step decode: ``--multi-step 8`` runs 8 decode steps per device
-program launch (r19: one on-device early-exit loop + a token ring
-read back once per launch; the host schedules and streams while the
-device computes). Greedy outputs stay bit-identical to the per-token
-engine; admission and chunked-prefill boundaries coarsen to every N
-steps, so keep N small for TTFT-sensitive traffic.
 Speculative decoding: ``--speculate 4`` (n-gram/prompt-lookup draft,
 no second model) or ``--speculate 4 --draft-model gpt_tiny`` (a small
 model drafts; its greedy guesses are verified in one multi-token
@@ -451,11 +445,6 @@ class ServingServer:
         # step-histogram scrape marker: (engine identity, last step
         # observed) — resurrection swaps the engine and resets it
         self._tl_seen: tuple = (None, -1)
-        # macro-launch scrape marker (r19): (restart epoch, engine
-        # launches already counted) — the serving_macro_steps_total
-        # counter accumulates deltas so a resurrection's reset engine
-        # counter never winds it backwards
-        self._macro_seen: tuple = (None, 0)
         # one jax.profiler capture at a time (r18 profile op)
         self._profile_lock = threading.Lock()
         self.port: Optional[int] = None
@@ -1307,9 +1296,6 @@ class ServingServer:
                       eng, "step_timeline", lambda: [])()[-16:],
                   "programs_launched": dict(
                       getattr(eng, "programs_launched", {}) or {}),
-                  # multi-step decode (r19)
-                  "multi_step": getattr(eng, "multi_step", 1),
-                  "macro_launches": getattr(eng, "macro_launches", 0),
                   # weight hot-swap (r24)
                   "weight_generation": self._weight_generation,
                   "weight_swaps": getattr(eng, "weight_swaps", 0)})
@@ -1382,12 +1368,6 @@ class ServingServer:
                   "events": self.tracer.events(),
                   "step_timeline": getattr(
                       eng, "step_timeline", lambda: [])(),
-                  # multi-step decode (r19): macro entries expanded
-                  # back into per-token-step rows, and the configured
-                  # steps-per-launch (1 = per-token, no macro entries)
-                  "multi_step": getattr(eng, "multi_step", 1),
-                  "per_token_timeline": getattr(
-                      eng, "per_token_timeline", lambda: [])(),
                   "program_costs": getattr(
                       eng, "program_costs", lambda: {})(),
                   "sample_rate": self.tracer.sample_rate})
@@ -1838,10 +1818,6 @@ class ServingServer:
                 # launch counts ({"decode": N, ...} — populated as
                 # each program kind first traces)
                 "fused_step": getattr(eng, "fused_step", None),
-                # multi-step decode (r19): decode steps per launch (1 =
-                # per-token) and lifetime macro launches this engine ran
-                "multi_step": getattr(eng, "multi_step", 1),
-                "macro_launches": getattr(eng, "macro_launches", 0),
                 "step_programs": dict(
                     getattr(eng, "step_programs", {}) or {}),
                 # end-to-end tracing (r16): the sampling rate and how
@@ -1929,7 +1905,7 @@ class ServingServer:
         if tl:
             g["step_last_ms"] = tl[-1].get("ms", 0.0)
             g["step_last_decode_ms"] = tl[-1].get("decode_ms", 0.0)
-            self._feed_step_histogram(eng, tl)
+            self._feed_step_histogram(tl)
         # program-cost gauges (r16 satellite): flops / bytes-accessed
         # per program kind from jit cost_analysis at build time
         for kind, cost in getattr(eng, "program_costs",
@@ -1965,7 +1941,7 @@ class ServingServer:
             g["mesh_collective_bytes"] = est if est is not None else 0.0
         return g
 
-    def _feed_step_histogram(self, eng, tl) -> None:
+    def _feed_step_histogram(self, tl) -> None:
         """Observe ring entries newer than the last scrape into the
         serving_step_ms histogram. The marker keys on the RESTART
         COUNT (monotonic) — id(eng) could be reused by a later engine
@@ -1978,27 +1954,8 @@ class ServingServer:
             s = entry.get("step", 0)
             if s > seen:
                 self.metrics.step_ms.observe(entry.get("ms", 0.0))
-                # multi-step decode (r19): a boundary entry carrying a
-                # drained macro launch feeds the steps-per-launch and
-                # host-overlap-idle distributions
-                macro = entry.get("macro")
-                if macro:
-                    self.metrics.steps_per_launch.observe(
-                        float(macro.get("steps", 0)))
-                    self.metrics.host_overlap_idle_ms.observe(
-                        float(macro.get("overlap_idle_ms", 0.0)))
                 seen = s
         self._tl_seen = (key, seen)
-        # macro-launch counter: accumulate engine deltas per restart
-        # epoch (a rebuilt engine starts its counter at 0)
-        ml = int(getattr(eng, "macro_launches", 0) or 0)
-        mkey, mseen = self._macro_seen
-        if mkey != self._restarts:
-            mkey, mseen = self._restarts, 0
-        if ml > mseen:
-            self.metrics.counter("macro_steps_total").add(ml - mseen)
-            mseen = ml
-        self._macro_seen = (mkey, mseen)
 
     # max chain pages served per fetch_pages reply: bounds one reply's
     # size (a page blob is small — page*H*D*2*itemsize per layer — but
@@ -2326,18 +2283,6 @@ def main(argv=None) -> None:
              "interactive TPOT, larger chunks finish batch prefills "
              "sooner")
     parser.add_argument(
-        "--multi-step", type=int, default=1, metavar="N",
-        help="device-resident multi-step decode (r19): run N decode "
-             "steps per device program launch (one on-device "
-             "early-exit loop with a [slots, N] token ring read back "
-             "once per launch), overlapping host scheduling with "
-             "device compute. 1 (the default) is the per-token "
-             "engine, byte-for-byte. Greedy outputs are bit-identical "
-             "for any N; larger N cuts host launch overhead per token "
-             "but coarsens admission/chunked-prefill boundaries (new "
-             "requests wait up to N steps), so keep N small when "
-             "TTFT matters")
-    parser.add_argument(
         "--no-fused-step", action="store_true",
         help="disable the fused decode hot path (r13: attention + "
              "out-projection folded into one kernel, sampling streamed "
@@ -2471,11 +2416,6 @@ def main(argv=None) -> None:
         # rides in engine_kwargs, so a resurrected engine honors the
         # escape hatch too (fused is the engine default)
         engine_kwargs["fused_step"] = False
-    if args.multi_step != 1:
-        # rides in engine_kwargs -> the resurrection recipe, so a
-        # rebuilt engine keeps the macro-launch cadence (and replays
-        # bit-identically onto it)
-        engine_kwargs["multi_step"] = args.multi_step
     if args.no_page_ledger:
         engine_kwargs["page_ledger"] = False
     if args.forecast_admission:

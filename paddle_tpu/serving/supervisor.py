@@ -2079,13 +2079,6 @@ def main(argv=None) -> None:
              "--no-fused-step; fused is the default, greedy outputs "
              "are bit-identical either way)")
     parser.add_argument(
-        "--multi-step", type=int, default=1, metavar="N",
-        help="device-resident multi-step decode per replica (r19), "
-             "threaded to every replica's server as its --multi-step: "
-             "N decode steps per device program launch (1 = the "
-             "per-token default; greedy outputs are bit-identical "
-             "for any N)")
-    parser.add_argument(
         "--spill-mb", type=int, default=None, metavar="MB",
         help="hierarchical prefix cache per replica (r15): host-RAM "
              "spill tier of this many MB, threaded to every replica's "
@@ -2245,8 +2238,6 @@ def main(argv=None) -> None:
         server_args += ["--prefill-chunk", str(args.prefill_chunk)]
     if args.no_fused_step:
         server_args += ["--no-fused-step"]
-    if args.multi_step != 1:
-        server_args += ["--multi-step", str(args.multi_step)]
     if args.spill_mb is not None:
         server_args += ["--spill-mb", str(args.spill_mb)]
     if args.spill_dir is not None:

@@ -938,30 +938,6 @@ class TestChaosHarness:
         assert report.replicas_checked == 2
 
     @pytest.mark.slow
-    def test_chaos_inprogram_inner_loop(self):
-        """r22 chaos lane: one replica armed with ``--multi-step 4
-        --speculate 4 --prefill-chunk 8`` (fault sites UNCHANGED) —
-        the engine.step burst forces a resurrection that rebuilds the
-        in-program spec/chunk engine and replays onto it. Typed
-        termination everywhere, zero leaks, clean ledger reconcile,
-        bit-identical successes vs the vanilla in-process oracle."""
-        chaos = _load_chaos()
-        report = chaos.run_chaos(
-            replicas=1, requests=8, seed=0, kill_replica=False,
-            extra_server_args=["--multi-step", "4",
-                               "--speculate", "4",
-                               "--prefill-chunk", "8"])
-        assert report.ok, report.to_dict()
-        assert report.hangs == 0
-        assert report.mismatches == 0
-        assert report.leak_failures == 0
-        assert report.ledger_failures == 0
-        assert report.completed + report.typed_errors == 8
-        # the burst really resurrected the in-program engine
-        assert report.engine_restarts >= 1, report.to_dict()
-        assert report.replicas_checked == 1
-
-    @pytest.mark.slow
     def test_chaos_soak(self):
         """Soak variant: more requests, hotter fault schedule, a second
         seed — the invariants must hold wherever the schedule lands."""

@@ -9,7 +9,8 @@ are sent, in one transfer, only on the decode step after a host write
 
 - tokens are bit-identical to an engine whose copy is dropped before
   every step, through the same writer, on the plain, prefix-cache,
-  chunked-prefill, ``multi_step``, speculative and mesh engines, with
+  chunked-prefill, int8, speculative and mesh engines, chunked prefill
+  on a mesh and a decoder with window rings and grouped heads, with
   one compiled decode program either way;
 - a clean step makes no host-to-device transfer and one device-to-host
   fetch (the tokens), and records ``decode_h2d`` 0; a stale one records
@@ -53,6 +54,7 @@ from paddle_tpu.distributed import fault_inject as fi
 from paddle_tpu.distributed.topology import make_serving_mesh
 from paddle_tpu.inference import SpeculativeConfig, create_decode_engine
 from paddle_tpu.inference import continuous_batching as cb
+from paddle_tpu.models import SmallThinkerForCausalLM, smallthinker_tiny
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 from paddle_tpu.serving.prefix_cache import PrefixCache
 
@@ -60,19 +62,24 @@ PAGE = 8
 ENGINE_KW = dict(num_slots=2, page_size=PAGE, max_seq_len=64,
                  timeline_steps=4096)
 
-# engine variants; the last three never run `_decode_step` with a copy
-# to hold (a masked step, or a path that builds its own arguments) and
-# must come out the same all the more
+# engine variants; a chunked engine's masked steps have no copy to hold,
+# and the speculative one builds its own arguments and never runs the
+# single-step program: they must come out the same all the more.
+# `rings` is the second decoder (window rings, grouped heads), at the
+# tiny size
 VARIANTS = {
     "plain": lambda: {},
     "prefix_cache": lambda: {"prefix_cache": PrefixCache(PAGE)},
     "mesh": lambda: {"mesh": make_serving_mesh(2)},
     "chunked": lambda: {"prefill_chunk_tokens": PAGE},
-    "multi_step": lambda: {"multi_step": 2},
+    "chunked_mesh": lambda: {"prefill_chunk_tokens": PAGE,
+                             "mesh": make_serving_mesh(2)},
+    "int8": lambda: {"kv_int8": True},
+    "rings": lambda: {},
     "speculative": lambda: {
         "speculative": SpeculativeConfig(k=2, draft="ngram")},
 }
-SINGLE_STEP = ("plain", "prefix_cache", "mesh", "chunked")
+SINGLE_STEP = tuple(v for v in VARIANTS if v != "speculative")
 
 
 @pytest.fixture(autouse=True)
@@ -93,6 +100,20 @@ def model():
     m = GPTForCausalLM(gpt_tiny())
     m.eval()
     return m
+
+
+@pytest.fixture(scope="module")
+def rings_model():
+    return SmallThinkerForCausalLM(smallthinker_tiny(), seed=3)
+
+
+@pytest.fixture
+def build(model, rings_model):
+    """An engine of a variant, on the model the variant serves."""
+    def make(variant, **kw):
+        m = rings_model if variant == "rings" else model
+        return _engine(m, **VARIANTS[variant](), **kw)
+    return make
 
 
 def _engine(m, **kw):
@@ -118,7 +139,7 @@ def _serve(eng, always_stale=False, never_ahead=False, calls=None,
     ``on_token`` call in order."""
     on_token = None if calls is None else \
         (lambda r, t, d: calls.append((r, t, d)))
-    rids = [eng.submit(p, n, on_token=on_token)
+    rids = [eng.submit(p % eng.cfg.vocab_size, n, on_token=on_token)
             for p, n in zip(_prompts(), new_tokens)]
     while eng.num_queued or eng.num_active:
         if always_stale:
@@ -134,7 +155,7 @@ def _h2d(eng):
     return [e["decode_h2d"] for e in eng.timeline if "decode_h2d" in e]
 
 
-def _decode_until_clean(eng, limit=8):
+def _decode_until_clean(eng, limit=16):
     """Step until a step decoded without an upload."""
     for _ in range(limit):
         eng.step()
@@ -164,10 +185,10 @@ class _CountingNumpy:
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
-def test_tokens_identical_to_an_always_stale_engine(model, variant):
-    resident = _engine(model, **VARIANTS[variant]())
+def test_tokens_identical_to_an_always_stale_engine(build, variant):
+    resident = build(variant)
     got = _serve(resident)
-    stale = _engine(model, **VARIANTS[variant]())
+    stale = build(variant)
     want = _serve(stale, always_stale=True)
     assert got == want
     if variant in SINGLE_STEP:
@@ -223,16 +244,17 @@ def test_stale_step_is_one_transfer(model, monkeypatch):
     assert _h2d(eng)[-1] == 0 and len(puts) == 1
 
 
-@pytest.mark.parametrize("variant", ["plain", "mesh"])
+@pytest.mark.parametrize("variant", ["plain", "mesh", "chunked_mesh"])
 def test_an_upload_never_hands_the_device_the_mirrors_themselves(
-        model, variant, monkeypatch):
+        build, variant, monkeypatch):
     """The mirrors are written in place, a transfer may alias host
     memory or copy it late, and on a mesh the step's one fetch waits for
     the first device alone: an upload of ``_packed`` itself let the
     second device read lengths the host had already advanced (the
     ``[mesh]`` flake of the bit-identity test under load, ROADMAP D8).
-    What is uploaded is a copy nobody writes again."""
-    eng = _engine(model, **VARIANTS[variant]())
+    What is uploaded is a copy nobody writes again: the packed inputs
+    of a decode step, and the table row of a prefill chunk."""
+    eng = build(variant)
     sent = []
     real = eng._place_resident
 
@@ -242,9 +264,30 @@ def test_an_upload_never_hands_the_device_the_mirrors_themselves(
         return real(a)
 
     monkeypatch.setattr(eng, "_place_resident", place)
+    rows = []
+    real_asarray = eng._jnp.asarray
+
+    class _Jnp:
+        """``jnp`` for this engine, keeping what a chunk uploads."""
+
+        def __getattr__(self, name):
+            return getattr(jax.numpy, name)
+
+        def asarray(self, a, *args, **kw):
+            if isinstance(a, np.ndarray) and a.shape == (1, eng.max_pages):
+                assert not np.shares_memory(a, eng._packed)
+                rows.append((a, a.copy()))
+            return real_asarray(a, *args, **kw)
+
+    eng._jnp = _Jnp()
     _serve(eng)
     assert len(sent) == eng.decode_steps_uploaded > 0
     assert all((a == was).all() for a, was in sent)
+    # a row a prefill, whole or a chunk (a chunk's ids have that shape
+    # here too: they are the chunk's own array)
+    assert len(rows) >= sum(eng.programs_launched.get(k, 0) for k in
+                            ("prefill", "prefill_chained")) > 0
+    assert all((a == was).all() for a, was in rows)
     eng.close()
 
 
@@ -457,10 +500,10 @@ def _serve_streams(eng, never_ahead=False):
 
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
-def test_tokens_identical_to_an_engine_that_never_runs_ahead(model, variant):
-    ahead = _engine(model, **VARIANTS[variant]())
+def test_tokens_identical_to_an_engine_that_never_runs_ahead(build, variant):
+    ahead = build(variant)
     got, got_calls = _serve_streams(ahead)
-    sync = _engine(model, **VARIANTS[variant]())
+    sync = build(variant)
     want, want_calls = _serve_streams(sync, never_ahead=True)
     assert got == want
     # a request's stream is the same calls in the same order; only the
@@ -553,10 +596,11 @@ IN_FLIGHT = dict(EVENTS, stall_eviction=(_stall_eviction, True),
                  dump_inflight=(_dump, True), swap_weights=(_swap, True))
 
 
+@pytest.mark.parametrize("variant", ["plain", "chunked"])
 @pytest.mark.parametrize("event", sorted(IN_FLIGHT))
-def test_event_arrives_with_a_step_in_flight(model, event):
+def test_event_arrives_with_a_step_in_flight(build, model, event, variant):
     happen, second_slot = IN_FLIGHT[event]
-    eng = _engine(model)
+    eng = build(variant)
     calls = []
     rid = eng.submit(_prompts()[0], 24,
                      on_token=lambda r, t, d: calls.append(t))

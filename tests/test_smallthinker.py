@@ -166,7 +166,7 @@ def test_gpt_answers_a_uniform_layout_and_packs_nothing():
 # -- what is not supported yet refuses typed, at construction -----------------
 
 @pytest.mark.parametrize("option", [
-    "prefix_cache", "mesh", "kv_int8", "multi_step", "speculative",
+    "prefix_cache", "mesh", "kv_int8", "speculative",
     "prefill_chunk_tokens"])
 def test_unsupported_options_refuse_typed_at_construction(tiny, option):
     _, model, _, _ = tiny
@@ -177,7 +177,7 @@ def test_unsupported_options_refuse_typed_at_construction(tiny, option):
         from jax.sharding import Mesh
         kw = {"mesh": Mesh(np.array(jax.devices()[:1]), ("model",))}
     else:
-        kw = {"kv_int8": {"kv_int8": True}, "multi_step": {"multi_step": 4},
+        kw = {"kv_int8": {"kv_int8": True},
               "speculative": {"speculative": 2},
               "prefill_chunk_tokens": {"prefill_chunk_tokens": 8}}[option]
     with pytest.raises(UnsupportedCacheLayout):

@@ -2,9 +2,8 @@
 
 Every serving feature keeps an escape hatch whose OFF position is
 byte-for-byte the previous engine (mesh=None, --no-fused-step,
-speculative off, --prefill-chunk unset, --multi-step 1, and r22's
-inprogram=False), and greedy outputs are pinned bit-identical across
-all of them. When a deployment's outputs look wrong, the rule is:
+speculative off, --prefill-chunk unset), and greedy outputs are
+pinned bit-identical across all of them. When a deployment's outputs look wrong, the rule is:
 walk the hatches one at a time against a pinned stream and file the
 bug against the FIRST rung that diverges — not against "the engine".
 
@@ -14,7 +13,6 @@ per-token reference (everything off), then re-runs the stream up the
 feature ladder, enabling one feature per rung:
 
     mesh -> chunked prefill -> speculative -> fused step
-         -> multi_step=N (boundary) -> in-program inner loop (r22)
 
 and reports the first rung whose greedy stream differs from the
 reference. Exit code 0: every rung bit-identical (the pinned
@@ -23,7 +21,7 @@ per-request first-divergence offsets).
 
 Usage:
     JAX_PLATFORMS=cpu python tools/bisect_decode.py \
-        [--model gpt_tiny] [--multi-step 4] [--speculate 3] \
+        [--model gpt_tiny] [--speculate 3] \
         [--prefill-chunk 8] [--mesh N] [--max-new 8] [--seed 0]
 
 On CPU with gpt_tiny this takes ~a minute; on a chip point it at the
@@ -97,15 +95,9 @@ def _ladder(args, mesh):
                       f"{args.draft})", "speculative", dict(acc)))
     # fused is ON by default at every rung above; the fused-off lane
     # is its own rung so a fusion regression bisects apart from the
-    # macro-loop features stacked on top of it
+    # features under it
     rungs.append(("fused step OFF (--no-fused-step lane)", "no-fused",
                   dict(acc, fused_step=False)))
-    acc = dict(acc, multi_step=args.multi_step, inprogram=False)
-    rungs.append((f"multi_step={args.multi_step} (boundary, "
-                  f"inprogram=False)", "multi_step", dict(acc)))
-    acc = dict(acc, inprogram=True)
-    rungs.append(("in-program inner loop (r22)", "inprogram",
-                  dict(acc)))
     return rungs
 
 
@@ -123,7 +115,6 @@ def main(argv=None) -> int:
         description="bisect a greedy-output divergence down the "
                     "serving feature ladder")
     p.add_argument("--model", default="gpt_tiny")
-    p.add_argument("--multi-step", type=int, default=4)
     p.add_argument("--speculate", type=int, default=3,
                    help="draft k (0 = skip the speculative rung)")
     p.add_argument("--draft", default="ngram",

@@ -187,11 +187,10 @@ def run_chaos(replicas: int = 2, requests: int = 12, seed: int = 0,
     invariant 1 still covers them.
 
     ``extra_server_args`` appends raw server CLI flags to every
-    replica — the r22 chaos lane passes ``["--multi-step", "4",
-    "--speculate", "4", "--prefill-chunk", "8"]`` so the UNCHANGED
-    fault sites fire against the in-program inner loop: resurrections
-    rebuild the macro spec/chunk engine, replay rides it, and the
-    leak/ledger audits cover its exit paths."""
+    replica (``["--speculate", "4", "--prefill-chunk", "8"]``) so the
+    UNCHANGED fault sites fire against that engine: resurrections
+    rebuild it, replay rides it, and the leak/ledger audits cover its
+    exit paths."""
     import numpy as np
 
     from paddle_tpu.distributed import fault_inject as fi
@@ -238,8 +237,8 @@ def run_chaos(replicas: int = 2, requests: int = 12, seed: int = 0,
                    os.path.join(flight_root, "replica{replica}"),
                    "--flight-budget-mb", str(flight_budget_mb)]
     if extra_server_args:
-        # r22 lane: the in-program knobs never change a greedy output,
-        # so the in-process oracle above stays the reference verbatim
+        # these knobs never change a greedy output, so the in-process
+        # oracle above stays the reference verbatim
         server_args += list(extra_server_args)
     sup = Supervisor(model=model, replicas=replicas,
                      server_args=server_args, replica_env=replica_env,
@@ -1697,19 +1696,12 @@ def main(argv=None) -> int:
     parser.add_argument("--platform", default="cpu")
     parser.add_argument("--log-dir", default=None)
     parser.add_argument(
-        "--multi-step", type=int, default=1, metavar="N",
-        help="arm every replica's engine with N-step macro decode "
-             "(r19/r22); 1 = the per-token engine")
-    parser.add_argument(
         "--speculate", type=int, default=0, metavar="K",
         help="arm every replica with ngram speculative decoding at "
-             "draft k=K (with --multi-step > 1 the verify rides "
-             "inside the macro program, r22); 0 = off")
+             "draft k=K; 0 = off")
     parser.add_argument(
         "--prefill-chunk", type=int, default=0, metavar="TOKENS",
-        help="arm every replica with chunked prefill (with "
-             "--multi-step > 1 the chunks ride inside the macro "
-             "program, r22); 0 = off")
+        help="arm every replica with chunked prefill; 0 = off")
     parser.add_argument(
         "--disagg", action="store_true",
         help="run INVARIANT 6 instead (r20): 1 prefill + 1 decode "
@@ -1775,8 +1767,6 @@ def main(argv=None) -> int:
         return 0 if report.ok else 1
 
     extra = []
-    if args.multi_step > 1:
-        extra += ["--multi-step", str(args.multi_step)]
     if args.speculate > 0:
         extra += ["--speculate", str(args.speculate)]
     if args.prefill_chunk > 0:

@@ -163,58 +163,6 @@ def promoted_cases():
 
     prefix_restore.op_name = "paged_page_splice"
 
-    def multi_step_decode():
-        # r19 device-resident multi-step decode: the macro loop's
-        # per-iteration hot op — fused decode attention at MID-MACRO
-        # lengths. In-program steps decode at seq_lens that are not
-        # page-aligned (lens grow by one inside the launch between
-        # page boundaries), so this shape class pins the page-walk +
-        # epilogue at the offsets the while_loop body actually runs,
-        # where the fused_decode_step case above pins the boundary-
-        # aligned shape. The whole-loop program is model-shaped (it
-        # contains the transformer), so the op-level case benches its
-        # dominant inner op; bench_all multi_step_decode carries the
-        # end-to-end launches/token A/B.
-        h, d = 8, 64
-        e = h * d
-        n_pages, page = 65, 16
-        kp = _f32(n_pages, page, h, d)
-        vp = _f32(n_pages, page, h, d)
-        table = np.arange(8 * 8, dtype=np.int32).reshape(8, 8)
-        # the _paged_case lens shifted +3 into their pages: iteration
-        # j=3 of a macro launch that started page-aligned
-        lens = np.asarray([128, 115, 99, 83, 67, 51, 35, 19], np.int32)
-        return (_f32(8, 1, h, d), kp, vp, table, lens,
-                _f32(e, e), _f32(e))
-
-    multi_step_decode.op_name = "paged_attention_fused"
-
-    def inprogram_verify():
-        # r22 in-program speculative verify: the macro while_loop's
-        # per-iteration hot op when speculation runs inside the launch
-        # — a k+1 = 5-position verify window per SLOT, batched over
-        # the whole slot set, appended at MID-MACRO lengths. Unlike
-        # fused_verify above (one slot, page-aligned done=128), the
-        # in-program iterations verify at whatever non-page-aligned
-        # lengths the accepted runs left behind (lens grow by 1..k+1
-        # per iteration), so this pins the ragged q_offsets page-walk
-        # + fused epilogue at exactly those offsets. The whole-loop
-        # program is model-shaped; this is its dominant inner op.
-        h, d = 8, 64
-        e = h * d
-        n_pages, page, s = 161, 16, 5
-        kp = _f32(n_pages, page, h, d)
-        vp = _f32(n_pages, page, h, d)
-        table = np.arange(8 * 9, dtype=np.int32).reshape(8, 9)
-        # the multi_step_decode mid-macro offsets, shifted by the
-        # ragged run lengths a speculative launch accumulates
-        done = np.asarray([131, 115, 99, 83, 67, 51, 35, 19], np.int32)
-        lens = done + s
-        return (_f32(8, s, h, d), kp, vp, table, lens,
-                _f32(e, e), _f32(e), None, None, None, done)
-
-    inprogram_verify.op_name = "paged_attention_fused"
-
     def page_fetch_splice():
         # r20 disaggregated serving: the decode-side splice of a
         # FETCHED chain run — a 4-page contiguous prefix pulled over
@@ -256,9 +204,7 @@ def promoted_cases():
             "fused_decode_step": fused_decode_step,
             "fused_verify": fused_verify,
             "fused_sample": fused_sample,
-            "prefix_restore": prefix_restore,
-            "multi_step_decode": multi_step_decode,
-            "inprogram_verify": inprogram_verify}
+            "prefix_restore": prefix_restore}
 
 
 def bench_op(name: str, make_args, repeat: int) -> dict:
