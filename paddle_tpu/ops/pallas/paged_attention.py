@@ -34,6 +34,15 @@ Design (house style: lane-native layout, online softmax, ragged skip):
   applied to the scores (they leave the D sum). The scale rows are not
   DMA'd per page — see `_gather_scales`.
 
+- Fused epilogue (`paged_attention_fused`, the GPT decode step's op):
+  the same page walk a slot, then ONE output projection a call — each
+  grid step leaves its context row in a VMEM scratch laid out by head,
+  the last grid step pushes all slots' rows through the o-projection
+  weight in one pass, and the weight reaches VMEM by one async copy
+  that runs under the walks (`_decode_fused_kernel`). What a call costs,
+  fixed and per page, is `tools/paged_decode_report.py`'s reading
+  (PERF.md §6).
+
 A pure-JAX reference (`paged_attention_reference`) implements identical
 semantics by gathering pages densely — the CPU fast lane and the
 numeric tests run it, and the public entry `paged_attention` routes to
@@ -67,6 +76,13 @@ DEFAULT_PAGE_SIZE = 64
 def _col(x):
     """[1, H] (heads on lanes) -> [H, 1] (heads on sublanes)."""
     return x[..., None][0]
+
+
+def _pad_to_sublane_tile(rows: int, dtype) -> int:
+    """``rows`` rounded up to whole sublane tiles of ``dtype`` (8 rows
+    of 32 bits, 16 of bf16, 32 of int8)."""
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return -(-rows // tile) * tile
 
 
 # --------------------------------------------------------------------------
@@ -163,21 +179,37 @@ def _decode_kernel(pt_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref,
 
 
 def _decode_fused_kernel(pt_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref,
-                         vs_ref, w_ref, b_ref, o_ref, k_buf, v_buf,
-                         sems, *, page: int, scale: float,
+                         vs_ref, w_hbm, b_ref, o_ref, k_buf, v_buf, sems,
+                         ctx_buf, w_buf, w_sem, *, page: int, scale: float,
                          quantized: bool, has_bias: bool):
-    """Fused attention epilogue (r13): the softmax-normalized per-head
-    context never leaves VMEM — it is pushed straight through the
-    output projection (``w_ref`` [E, E_out] resident in VMEM across the
-    whole grid, ``b_ref`` [1, E_out]), so the kernel emits the
-    attention BLOCK's output row instead of raw per-head context. One
-    launch where the unfused path runs attention + reshape + matmul +
-    bias-add (the Tensix/Neptune epilogue-fusion recipe: fold the
-    chain into the kernel that already holds the data)."""
+    """Fused attention epilogue: the softmax-normalized per-head context
+    never leaves VMEM — the kernel emits the attention BLOCK's output
+    rows (head-concat, output projection, bias) instead of raw per-head
+    context. One launch where the unfused path runs attention + reshape
+    + matmul + bias-add.
+
+    The projection runs ONCE A CALL, not once a slot. Every grid step
+    walks its slot's pages and leaves the context row in ``ctx_buf``
+    ``[H, Bp, D]`` (head ``hh`` of all slots is one ``[Bp, D]`` tile, so
+    nothing moves heads from sublanes to lanes); the last grid step
+    pushes all slots' rows through the weight in one pass, one
+    ``[Bp, D] @ [D, E_out]`` dot a head, and writes the one
+    ``[B, E_out]`` output block that stays resident over the grid. The
+    weight ``w_hbm`` [E, E_out] stays in HBM as an operand; one async
+    copy into ``w_buf`` starts in the first grid step and is waited for
+    just before the projection, so it passes under the page walks."""
+    i = pl.program_id(0)
+    w_copy = pltpu.make_async_copy(w_hbm, w_buf, w_sem.at[0])
+
+    @pl.when(i == 0)
+    def _():
+        w_copy.start()
+
     ctx = _walk_pages(pt_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref,
                       vs_ref, k_buf, v_buf, sems,
                       page=page, scale=scale, quantized=quantized)
     h, d = ctx.shape
+    bp = ctx_buf.shape[1]
     # mimic the unfused lowering's rounding: the standalone kernel
     # rounds the context to the output dtype (bf16 in bf16 serving)
     # BEFORE the model's out-projection matmul, whose MXU dot then
@@ -185,20 +217,29 @@ def _decode_fused_kernel(pt_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref,
     # on-chip divergence is limited to XLA tiling, not operand
     # precision. (Exact on-chip bit-identity is NOT claimed — see
     # `paged_attention_fused`; the CPU-lane references are bit-equal.)
-    ctx = ctx.astype(o_ref.dtype)
-    # row @ W with row = ctx flattened head-major (the [H*D] order the
-    # model's reshape produces), as one dot per head over that head's
-    # D weight rows: the flatten itself would move heads from sublanes
-    # to lanes
-    out = jnp.zeros((1, o_ref.shape[-1]), jnp.float32)
-    for hh in range(h):
-        out = out + jax.lax.dot_general(
-            ctx[hh:hh + 1], w_ref[hh * d:(hh + 1) * d, :],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [1, E_out]
-    if has_bias:
-        out = out + b_ref[...].astype(jnp.float32)
-    o_ref[0] = out.astype(o_ref.dtype)
+    # The scratch keeps the rounded values as f32 (exact), so a row is
+    # placed with a 32-bit select and no packed sublane is addressed.
+    ctx = ctx.astype(o_ref.dtype).astype(jnp.float32)
+    mine = jax.lax.broadcasted_iota(jnp.int32, (h, bp, d), 1) == i
+    ctx_buf[...] = jnp.where(mine, ctx[:, None, :], ctx_buf[...])
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        w_copy.wait()
+        # rows @ W with a row = ctx flattened head-major (the [H*D]
+        # order the model's reshape produces), as one dot per head over
+        # that head's D weight rows. Rows past B are never written and
+        # never read back: a row of a matmul depends on no other row.
+        rows = ctx_buf[...].astype(o_ref.dtype)
+        out = jnp.zeros((bp, o_ref.shape[-1]), jnp.float32)
+        for hh in range(h):
+            out = out + jax.lax.dot_general(
+                rows[hh], w_buf[hh * d:(hh + 1) * d, :],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [Bp, E_out]
+        if has_bias:
+            out = out + b_ref[...].astype(jnp.float32)
+        o_ref[...] = out[:o_ref.shape[0]].astype(o_ref.dtype)
 
 
 def _gather_scales(scales, page_table):
@@ -213,11 +254,12 @@ def _gather_scales(scales, page_table):
 
 def _decode_call(name, kernel, q, k_pages, v_pages, page_table, seq_lens,
                  k_scale, v_scale, *, out_shape, out_spec, extra=(),
-                 extra_flops=0, extra_bytes=0):
+                 extra_scratch=(), extra_flops=0, extra_bytes=0):
     """The pallas_call both decode kernels share, under the kernel's
     ``name``: grid (B,), page table and lengths scalar-prefetched, KV
     pools left in HBM. ``extra``: (array, BlockSpec) pairs appended to
-    the kernel's inputs."""
+    the kernel's inputs; ``extra_scratch``: scratch shapes appended to
+    the page walk's."""
     b, h, d = q.shape
     n_pool, page = k_pages.shape[:2]
     mp = page_table.shape[1]
@@ -247,6 +289,7 @@ def _decode_call(name, kernel, q, k_pages, v_pages, page_table, seq_lens,
             pltpu.VMEM((2, page, h, d), k_pages.dtype),
             pltpu.VMEM((2, page, h, d), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            *extra_scratch,
         ],
     )
     return named_pallas_call(
@@ -280,35 +323,37 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, seq_lens,
 
 def _paged_decode_fused_pallas(q, k_pages, v_pages, page_table, seq_lens,
                                k_scale, v_scale, scale, w, bias):
-    """Fused-epilogue variant of :func:`_paged_decode_pallas`: same
-    grid/scratch layout plus the projection weight as a VMEM-resident
-    block (constant index map and one buffer — one HBM read for the
-    whole grid) and an output row of E_out lanes per sequence."""
+    """Fused-epilogue variant of :func:`_paged_decode_pallas`: the same
+    grid and page-walk scratch, plus the projection weight as an HBM
+    operand with a VMEM scratch of its size (one copy a call, started
+    in the first grid step: `_decode_fused_kernel`), the contexts'
+    scratch ``[H, Bp, D]`` (B padded to a sublane tile of the output
+    dtype) and one ``[B, E_out]`` output block for the whole grid."""
     b, h, d = q.shape
     e_out = w.shape[1]
     has_bias = bias is not None
     brow = (bias.reshape(1, e_out) if has_bias
             else jnp.zeros((1, e_out), jnp.float32))
-    out = _decode_call(
+    bp = _pad_to_sublane_tile(b, q.dtype)
+    return _decode_call(
         "paged_decode_fused",
         functools.partial(_decode_fused_kernel, page=k_pages.shape[1],
                           scale=scale, quantized=k_scale is not None,
                           has_bias=has_bias),
         q, k_pages, v_pages, page_table, seq_lens, k_scale, v_scale,
         extra=[
-            (w, pl.BlockSpec((h * d, e_out), lambda i, *_: (0, 0),
-                             memory_space=pltpu.VMEM,
-                             pipeline_mode=pl.Buffered(1))),
+            (w, pl.BlockSpec(memory_space=pl.ANY)),
             (brow, pl.BlockSpec((1, e_out), lambda i, *_: (0, 0),
                                 memory_space=pltpu.VMEM))],
-        # [B, 1, E_out]: a one-row block must span its array's
-        # second-minor dim
-        out_shape=jax.ShapeDtypeStruct((b, 1, e_out), q.dtype),
-        out_spec=pl.BlockSpec((1, 1, e_out), lambda i, *_: (i, 0, 0),
+        extra_scratch=[
+            pltpu.VMEM((h, bp, d), jnp.float32),
+            pltpu.VMEM((h * d, e_out), w.dtype),
+            pltpu.SemaphoreType.DMA((1,))],
+        out_shape=jax.ShapeDtypeStruct((b, e_out), q.dtype),
+        out_spec=pl.BlockSpec((b, e_out), lambda i, *_: (0, 0),
                               memory_space=pltpu.VMEM),
         extra_flops=2 * int(b) * h * d * e_out,
         extra_bytes=h * d * e_out * w.dtype.itemsize)
-    return out[:, 0]
 
 
 # --------------------------------------------------------------------------
@@ -452,8 +497,7 @@ def _paged_decode_grouped_pallas(q, k_pages, v_pages, page_table, seq_lens,
     b, hq, d = q.shape
     kvh, page = k_pages.shape[1:3]
     group = hq // kvh
-    tile = 8 * max(1, 4 // k_pages.dtype.itemsize)
-    gp = -(-group // tile) * tile
+    gp = _pad_to_sublane_tile(group, k_pages.dtype)
     qg = q.reshape(b, kvh, group, d).astype(k_pages.dtype)
     if gp != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
@@ -737,9 +781,9 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
 # Fused attention epilogue (r13): attention + out-projection, one launch
 # --------------------------------------------------------------------------
 
-# VMEM budget for the resident o-projection weight block: the fused
-# kernel keeps W [E, E_out] live next to the double-buffered page set,
-# so the gate admits only weights that fit comfortably (v4/v5 cores
+# VMEM budget for the o-projection weight's scratch: the fused kernel
+# copies W [E, E_out] whole into VMEM next to the double-buffered page
+# set, so the gate admits only weights that fit comfortably (v4/v5 cores
 # carry 16 MB VMEM; 8 MB leaves the page buffers + q + output headroom).
 _FUSED_W_VMEM_BYTES = 8 * 1024 * 1024
 

@@ -222,6 +222,71 @@ class TestFusedKernelsInterpret:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-3, atol=2e-3)
 
+    @staticmethod
+    def _ragged_batch(rng, b, lens, dtype=jnp.float32):
+        """``b`` slots over pages of 8, two heads of 64: every slot owns
+        three distinct pages whatever its length (a parked slot's table
+        row is as stale as the engine leaves it)."""
+        page, h, d, mp = 8, 2, 64, 3
+        e = h * d
+        n_pages = b * mp + 1
+
+        def arr(*shape):
+            return jnp.asarray(rng.standard_normal(shape), dtype)
+
+        table = jnp.asarray(
+            rng.permutation(n_pages - 1)[:b * mp].reshape(b, mp), jnp.int32)
+        return (arr(b, 1, h, d), arr(n_pages, page, h, d),
+                arr(n_pages, page, h, d), table,
+                jnp.asarray(lens, jnp.int32), arr(e, e), arr(e))
+
+    @pytest.mark.parametrize("with_bias", [True, False],
+                             ids=["bias", "no_bias"])
+    def test_fused_epilogue_live_parked_partial_interleaved(self, rng,
+                                                            with_bias):
+        """One projection a call over all 16 slots' rows: live slots
+        (whole pages), parked slots (length 0) and slots whose last page
+        is partial, interleaved, each match the reference ROW BY ROW; a
+        parked slot's row is the bias alone (zeros without one)."""
+        lens = [16, 0, 7, 24, 0, 0, 1, 8, 19, 0, 24, 3, 0, 16, 23, 0]
+        q, kp, vp, table, lens, w, bias = self._ragged_batch(rng, 16, lens)
+        bias = bias if with_bias else None
+        with fa.force_flash_for_aot():
+            assert pa.fused_epilogue_supported(q.shape, kp.shape, w.shape)
+            out = pa.paged_attention_fused(q, kp, vp, table, lens, w, bias)
+        ref = pa.paged_attention_fused_reference(q, kp, vp, table, lens,
+                                                 w, bias)
+        assert out.shape == ref.shape == (16, 1, w.shape[1])
+        out, ref = np.asarray(out), np.asarray(ref)
+        for row in range(16):
+            np.testing.assert_allclose(out[row], ref[row], rtol=2e-3,
+                                       atol=2e-3, err_msg=f"slot {row}")
+        parked = np.asarray(lens) == 0
+        want = np.asarray(bias) if with_bias else np.zeros(w.shape[1])
+        assert (out[parked, 0] == want).all()
+
+    @pytest.mark.parametrize("b,dtype", [(5, jnp.float32),
+                                         (12, jnp.bfloat16)],
+                             ids=["5_f32", "12_bf16"])
+    def test_fused_epilogue_batch_not_a_sublane_tile(self, rng, b, dtype):
+        """The gate admits any batch: the contexts' scratch is padded to
+        the output dtype's sublane tile (8 rows of f32, 16 of bf16) and
+        the rows past the batch reach no output row."""
+        lens = ([9, 0, 24, 17, 1] * 3)[:b]
+        q, kp, vp, table, lens, w, bias = self._ragged_batch(
+            rng, b, lens, dtype)
+        with fa.force_flash_for_aot():
+            assert pa.fused_epilogue_supported(
+                q.shape, kp.shape, w.shape, w_itemsize=w.dtype.itemsize)
+            out = pa.paged_attention_fused(q, kp, vp, table, lens, w, bias)
+        ref = pa.paged_attention_fused_reference(q, kp, vp, table, lens,
+                                                 w, bias)
+        assert out.shape == (b, 1, w.shape[1]) and out.dtype == dtype
+        tol = 2e-3 if dtype == jnp.float32 else 0.15
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=tol, atol=tol)
+
     def test_fused_argmax_kernel_matches_reference(self, rng):
         hidden = jnp.asarray(rng.standard_normal((4, 128)), jnp.float32)
         w = jnp.asarray(rng.standard_normal((1000, 128)), jnp.float32)

@@ -127,14 +127,17 @@ def test_promoted_cases_are_real_ops_and_cpu_gated(tmp_path):
 
 
 def test_pending_cases_are_tracked_and_cpu_gated(tmp_path):
-    """Pending-tier ops (benchable, but baselines not yet complete on
-    every platform — today: paged_attention, whose tpu_v5e number needs
-    a chip-attached host) must be (1) real registered dispatch entries,
-    (2) runnable through the harness and gated against a committed
-    cpu_smoke_pending baseline, and (3) accounted for in
+    """Pending-tier ops (benchable, but with no committed baseline on
+    every platform — today: paged_attention) must be (1) real registered
+    dispatch entries, (2) runnable through the harness (exit code 0, one
+    well-formed log a case) and (3) accounted for in
     op_baselines/PENDING.json with the missing platform named — no
-    silently unbaselined op."""
-    from check_op_benchmark_result import compare, load_logs_dir
+    silently unbaselined op. No wall time is compared here: this tier's
+    CPU number is the dense-gather reference timed beside the other
+    xdist workers, which says nothing about the kernel and failed tier-1
+    on load alone (ROADMAP D5); what the kernels cost is read on the
+    chip (tools/paged_decode_report.py, PERF.md)."""
+    from check_op_benchmark_result import load_logs_dir
     from op_benchmark import default_cases, pending_cases
 
     import paddle_tpu.dispatch as dispatch
@@ -152,9 +155,6 @@ def test_pending_cases_are_tracked_and_cpu_gated(tmp_path):
             in dispatch.wrapped_ops, name
         assert meta["missing"] and meta["why_missing"], name
 
-    dev = load_logs_dir(
-        os.path.join(TOOLS, "op_baselines", "cpu_smoke_pending"))
-    assert set(dev) == set(pend)
     r = subprocess.run(
         [sys.executable, os.path.join(TOOLS, "op_benchmark.py"),
          "--platform", "cpu", "--ops", ",".join(sorted(pend)),
@@ -162,20 +162,10 @@ def test_pending_cases_are_tracked_and_cpu_gated(tmp_path):
         capture_output=True, text=True,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-2000:]
-    failures, checked = compare(dev, load_logs_dir(str(tmp_path / "pr")),
-                                threshold=4.0)
-    assert checked == len(pend)
-    if failures:  # transient host-load spike: reproduce before failing
-        r2 = subprocess.run(
-            [sys.executable, os.path.join(TOOLS, "op_benchmark.py"),
-             "--platform", "cpu", "--ops", ",".join(sorted(pend)),
-             "--repeat", "10", "--output", str(tmp_path / "pr2")],
-            capture_output=True, text=True,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        assert r2.returncode == 0, r2.stderr[-2000:]
-        failures, _ = compare(dev, load_logs_dir(str(tmp_path / "pr2")),
-                              threshold=4.0)
-    assert not failures, failures
+    logs = load_logs_dir(str(tmp_path / "pr"))
+    assert set(logs) == set(pend)
+    assert all(rec["avg_us"] > 0 and rec["repeat"] == 10
+               for rec in logs.values()), logs
 
 
 @pytest.mark.parametrize("ops", ["add,matmul,softmax,layer_norm"])

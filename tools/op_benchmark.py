@@ -94,8 +94,9 @@ def pending_cases():
     complete on ANY committed platform dir (tools/op_baselines/
     PENDING.json records which platform is missing and why). Kept OUT
     of default_cases() so test_op_benchmark_gate's completeness check
-    over the committed baseline dirs stays exact; the gate covers
-    these via the *_pending baseline dirs instead.
+    over the committed baseline dirs stays exact; the gate runs these
+    through the harness and holds them to PENDING.json, and compares
+    no wall time for them.
 
     A case whose name is not itself a registered op (a named SHAPE
     CLASS of one) carries the op on its builder's ``op_name``
