@@ -222,6 +222,33 @@ def route_top_k(h, w_router, top_k: int):
     return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
 
 
+def route_sigmoid_top_k(u, w_router, bias, top_k: int,
+                        scaling: float = 1.0):
+    """Router of a layer that scores every expert by a sigmoid and
+    picks by score plus a bias an expert: ``s = sigmoid(u W_r)`` in
+    float32 at the highest matmul precision, the ``k`` largest ``s +
+    bias`` picked (the bias balances load and takes no part in the
+    gate), gates ``s_e / sum of the picked s`` times ``scaling``.
+    Returns ``(idx [T, k] int32, gates [T, k] f32)``."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        u.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision="highest"))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, idx, axis=-1)
+    gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
+    return idx.astype(jnp.int32), gates
+
+
+def gated_ffn(u, w_gate, w_up, w_down, activation: str = "silu"):
+    """``(act(u W_gate) * (u W_up)) W_down``: an expert every token
+    goes through (a shared expert), as plain matmuls."""
+    from ..ops.pallas.grouped_matmul import ACTIVATIONS
+    mid = ACTIVATIONS[activation](
+        jnp.matmul(u, w_gate, preferred_element_type=jnp.float32)) * \
+        jnp.matmul(u, w_up, preferred_element_type=jnp.float32)
+    return jnp.matmul(mid.astype(u.dtype), w_down)
+
+
 def expert_tile_rows(picks: int, experts: int, itemsize: int) -> int:
     """Rows of one tile of the grouped layout: a power of two near the
     mean rows an expert gets, from one sublane tile (decode: 96 picks
@@ -235,11 +262,11 @@ def expert_tile_rows(picks: int, experts: int, itemsize: int) -> int:
 
 
 def dropless_experts(u, idx, gates, w_gate, w_up, w_down, *,
-                     held=None, valid=None):
+                     held=None, valid=None, activation: str = "relu"):
     """The part of ``sum_k gates[t, k] * FFN_{idx[t, k]}(u[t])`` that the
-    experts held here give, with ``FFN_e(x) = (relu(x @ w_gate[e]) * (x
-    @ w_up[e])) @ w_down[e]``. No capacity and no drop: every pick of a
-    held expert is computed.
+    experts held here give, with ``FFN_e(x) = (act(x @ w_gate[e]) * (x
+    @ w_up[e])) @ w_down[e]`` (``activation``: ``relu`` or ``silu``). No
+    capacity and no drop: every pick of a held expert is computed.
 
     u: [T, H]; idx, gates: [T, k] over ALL experts; w_gate, w_up:
     [held, H, F]; w_down: [held, F, H]; ``held`` = (first, count) of the
@@ -290,7 +317,8 @@ def dropless_experts(u, idx, gates, w_gate, w_up, w_down, *,
         token, mode="drop", unique_indices=True)
     xs = u[row_token]  # [mp, H]; pad rows repeat row 0, never read back
     used1 = used.reshape(1).astype(jnp.int32)
-    mid = grouped_ffn_in(xs, w_gate, w_up, tile_expert, used1, tm)
+    mid = grouped_ffn_in(xs, w_gate, w_up, tile_expert, used1, tm,
+                         activation)
     ys = grouped_matmul(mid, w_down, tile_expert, used1, tm)
     # pick by pick, in k's order: one [T, H] gather at a time, so a long
     # prefill never holds all T * k gathered rows in float32

@@ -501,7 +501,8 @@ class ContinuousBatchingEngine:
 
         from ..core.compile_cache import enable_compile_cache
         from ..models.cache_layout import (UnsupportedCacheLayout,
-                                           create_pools, ring_pages)
+                                           create_pools,
+                                           create_state_pools, ring_pages)
 
         # persistent compile cache (core/compile_cache.py places it):
         # the engine's prefill-per-bucket + decode/verify programs are
@@ -523,35 +524,49 @@ class ContinuousBatchingEngine:
         cfg = model.config
         self.cfg = cfg
         # what the model keeps per layer (models/cache_layout.py): KV
-        # heads, head size, a window or none. Layers that keep every
-        # position share the allocator's pages and the one page table;
-        # a window layer holds a ring of `ring_pages` pages a slot in
-        # a pool of its own. Everything below that differs between
+        # heads, head size, a window or none, or a state of fixed size
+        # a sequence. Layers that keep every position share the
+        # allocator's pages and the one page table; a window layer
+        # holds a ring of `ring_pages` pages a slot in a pool of its
+        # own; a state layer a row a slot of its state pool, which no
+        # page table addresses. Everything below that differs between
         # decoders comes from here, never from the model's class.
         self._layout = list(model.cache_layout())
+        self._has_state = any(lc.state is not None for lc in self._layout)
         self._rings = [None if lc.window is None
                        else ring_pages(lc.window, page_size)
                        for lc in self._layout]
         self._has_rings = any(r is not None for r in self._rings)
+        # layers whose memory is a row a SLOT: their programs are told
+        # which slot each batch row is
+        self._slot_rows = self._has_rings or self._has_state
         if not all(lc.plain for lc in self._layout):
             # a ring holds a window's worth of ONE sequence's own keys,
-            # and a heads-major page is not the page the spill codecs,
-            # the int8 scales and the verify path read: what would have
-            # to restore, share, rewind or re-enter such a cache is
-            # refused here, typed, until it has a parity test
+            # a state layer what the whole sequence left (no snapshot
+            # of an earlier position), and a heads-major page is not
+            # the page the spill codecs, the int8 scales and the verify
+            # path read: what would have to restore, share, rewind or
+            # re-enter such a cache is refused here, typed, until it
+            # has a parity test. (Spill and handoff move a prefix
+            # cache's pages: refused with it. Resurrection replays
+            # prompt + tokens through a fresh prefill and needs none.)
             refused = [
                 ("a prefix cache (a hit cannot restore a window "
-                 "layer's ring)", prefix_cache is not None),
+                 "layer's ring, nor a state layer's state at the "
+                 "prefix's end)", prefix_cache is not None),
                 ("a serving mesh", mesh is not None),
                 ("int8 KV pages", bool(kv_int8)),
                 ("speculative decoding (a rejected draft cannot be "
-                 "rewound out of a ring)", speculative is not None),
-                ("chunked prefill", prefill_chunk_tokens is not None)]
+                 "rewound out of a ring or a state)",
+                 speculative is not None),
+                ("chunked prefill (a chunk would have to continue a "
+                 "ring or a state)", prefill_chunk_tokens is not None)]
             for what, asked in refused:
                 if asked:
                     raise UnsupportedCacheLayout(
-                        f"a cache layout with window layers or grouped "
-                        f"heads does not support {what} yet")
+                        f"a cache layout with window layers, grouped "
+                        f"heads or state layers does not support "
+                        f"{what} yet")
         # tensor-parallel serving (mesh=None = single-device, the
         # byte-for-byte pre-r10 behavior): weights shard per their
         # mp_layers pspecs, KV pools shard over heads, page table and
@@ -660,16 +675,23 @@ class ContinuousBatchingEngine:
         # one DISTINCT pool per layer (not nl references to one array:
         # the jitted step donates the pool buffers, and donating the
         # same buffer for two arguments is an error); a window layer's
-        # pool is its rings, `num_slots * ring` pages and the scratch
-        protos = [create_pools(
-            lc, self.num_pages if ring is None else self.num_slots * ring,
-            self.page_size, self.max_pages, quantized=self.kv_int8,
-            kv_sharding=self._kv_sharding)
+        # pool is its rings, `num_slots * ring` pages and the scratch;
+        # a state layer has no pages: a row a slot of `state` and `tail`
+        per_layer = [
+            (None,) * 4 + create_state_pools(lc, self.num_slots)
+            if lc.state is not None else create_pools(
+                lc,
+                self.num_pages if ring is None else self.num_slots * ring,
+                self.page_size, self.max_pages, quantized=self.kv_int8,
+                kv_sharding=self._kv_sharding) + (None, None)
             for lc, ring in zip(self._layout, self._rings)]
-        self._pools = {"k": [p[0] for p in protos],
-                       "v": [p[1] for p in protos],
-                       "ks": [p[2] for p in protos],
-                       "vs": [p[3] for p in protos]}
+        self._pools = {
+            name: [p[j] for p in per_layer] for j, name in
+            enumerate(("k", "v", "ks", "vs", "state", "tail"))}
+        self.state_pool_bytes = sum(
+            int(x.size) * x.dtype.itemsize
+            for kind in ("state", "tail") for x in self._pools[kind]
+            if x is not None)
         # host-owned scheduler state. The three mirrors of the decode
         # step's inputs are views of ONE packed int32 array
         # ``[num_slots, max_pages + 2]`` (page table | length | current
@@ -712,6 +734,13 @@ class ContinuousBatchingEngine:
         # rows a look-ahead step computed for a request that finished
         # in the step before it: dropped at the settle, never handed out
         self.decode_rows_dropped = 0
+        # of those, the rows whose step moved a state layer's row of
+        # the finished slot in place (a KV append for such a row lands
+        # on a page nobody reads; this lands in the slot's own row).
+        # Harmless only because nothing reads a slot's state between
+        # its finish and the next admission, and a prompt writes the
+        # whole row from zero (tests/test_solar_open2.py pins it)
+        self.state_rows_overwritten = 0
         self._slots: List[Optional[DecodeRequest]] = \
             [None] * self.num_slots
         self._queue: List[DecodeRequest] = []
@@ -1031,14 +1060,16 @@ class ContinuousBatchingEngine:
         """One cache a layer over the pools. A window layer's table is
         its slots' rings (``rows``: the slot of each batch row; the
         decode step's rows are the slots in order)."""
-        from ..models.cache_layout import ring_table
+        from ..models.cache_layout import StateCache, ring_table
         from ..models.gpt import PagedKVCache
-        if rows is None and self._has_rings:
+        if rows is None and self._slot_rows:
             rows = self._jnp.arange(table.shape[0], dtype=self._jnp.int32)
-        return [PagedKVCache(pools["k"][i], pools["v"][i],
-                             pools["ks"][i], pools["vs"][i],
-                             table if ring is None
-                             else ring_table(rows, ring), lens)
+        return [StateCache(pools["state"][i], pools["tail"][i], rows, lens)
+                if pools["state"][i] is not None
+                else PagedKVCache(pools["k"][i], pools["v"][i],
+                                  pools["ks"][i], pools["vs"][i],
+                                  table if ring is None
+                                  else ring_table(rows, ring), lens)
                 for i, ring in enumerate(self._rings)]
 
     def _take_stats(self):
@@ -1504,13 +1535,17 @@ class ContinuousBatchingEngine:
             entry[k] = round(v, 4)
         if self._tl_decode is not None:
             entry["decode_h2d"], entry["decode_ahead"] = self._tl_decode
-        if self._has_rings:
+        if self._slot_rows:
             # pages in use by kind of layer, a layer of each: the
             # allocator's (every position kept) and the rings' (a
             # sequence never holds more than its ring)
             entry["kv_pages"] = {
-                "global": self.num_pages - entry["free_pages"],
-                "window": self.window_pages_in_use()}
+                "global": self.num_pages - entry["free_pages"]}
+            if self._has_rings:
+                entry["kv_pages"]["window"] = self.window_pages_in_use()
+        if self._has_state:
+            # rows of the state pool that hold a live sequence
+            entry["state_slots"] = entry["slots_active"]
         # the model's own counters of this step's programs
         entry.update(self._tl_stats)
         self.timeline.append(entry)
@@ -1556,6 +1591,8 @@ class ContinuousBatchingEngine:
             "decode_steps_uploaded": int(self.decode_steps_uploaded),
             "decode_steps_ahead": int(self.decode_steps_ahead),
             "decode_rows_dropped": int(self.decode_rows_dropped),
+            "state_pool_bytes": int(self.state_pool_bytes),
+            "state_rows_overwritten": int(self.state_rows_overwritten),
             "model_counters": {k: dict(v) for k, v in
                                self.model_counters.items()},
             "window_ring_pages": max(
@@ -1691,8 +1728,8 @@ class ContinuousBatchingEngine:
                     else jax.lax.with_sharding_constraint(x, spec)
                     for x in xs]
 
-        return {"k": pin(pools["k"]), "v": pin(pools["v"]),
-                "ks": pin(pools["ks"]), "vs": pin(pools["vs"])}
+        return dict(pools, k=pin(pools["k"]), v=pin(pools["v"]),
+                    ks=pin(pools["ks"]), vs=pin(pools["vs"]))
 
     # -- spill-tier device IO (r15) -----------------------------------------
 
@@ -1778,8 +1815,10 @@ class ContinuousBatchingEngine:
 
             def splice(pools, pg, kb, vb, ksb, vsb):
                 with jax.named_scope("pt.page_splice"):
-                    return self._constrain_pools(
-                        paged_page_splice(pools, pg, kb, vb, ksb, vsb))
+                    # the K/V pools of a plain layout; what else the
+                    # pools hold (no state layer here) passes through
+                    spliced = paged_page_splice(pools, pg, kb, vb, ksb, vsb)
+                    return self._constrain_pools(dict(pools, **spliced))
 
             self._splice_jit = jax.jit(splice, donate_argnums=(0,))
         from ..dispatch import count_op_calls
@@ -1830,11 +1869,14 @@ class ContinuousBatchingEngine:
     def _new_pools(self, nc):
         """The pools a traced program hands back, from the caches the
         model returned."""
-        return self._constrain_pools({
-            "k": [_raw(c.k_pages) for c in nc],
-            "v": [_raw(c.v_pages) for c in nc],
-            "ks": [_raw(c.k_scale) if self.kv_int8 else None for c in nc],
-            "vs": [_raw(c.v_scale) if self.kv_int8 else None for c in nc]})
+        def take(name, only=True):
+            return [_raw(getattr(c, name)) if only and hasattr(c, name)
+                    else None for c in nc]
+        pools = self._constrain_pools({
+            "k": take("k_pages"), "v": take("v_pages"),
+            "ks": take("k_scale", self.kv_int8),
+            "vs": take("v_scale", self.kv_int8)})
+        return dict(pools, state=take("state"), tail=take("tail"))
 
     def _build_decode(self):
         """The per-token decode program over the packed inputs
@@ -2059,7 +2101,8 @@ class ContinuousBatchingEngine:
         AFTER execution began, the donated pools are gone — a retry
         would feed the jit dead buffers. Surface a terminal
         (non-transient) error instead of a confusing backend one."""
-        k0 = self._pools["k"][0]
+        k0 = next(x for kind in ("k", "state") for x in self._pools[kind]
+                  if x is not None)
         if getattr(k0, "is_deleted", None) is not None \
                 and k0.is_deleted():
             raise RuntimeError(
@@ -2594,8 +2637,9 @@ class ContinuousBatchingEngine:
                         jnp.asarray([cached_len], jnp.int32),
                         jnp.asarray([len(suffix)], jnp.int32),
                         jnp.asarray(ids))
-                if self._has_rings:
-                    # whose rings the window layers write
+                if self._slot_rows:
+                    # whose rings the window layers write, whose row
+                    # the state layers
                     args += (jnp.asarray([slot], jnp.int32),)
             with self._phase("launch"):
                 with count_op_calls() as c:
@@ -2762,8 +2806,9 @@ class ContinuousBatchingEngine:
                         jnp.asarray([done], jnp.int32),
                         jnp.asarray([len(suffix)], jnp.int32),
                         jnp.asarray(ids))
-                if self._has_rings:
-                    # whose rings the window layers write
+                if self._slot_rows:
+                    # whose rings the window layers write, whose row
+                    # the state layers
                     args += (jnp.asarray([slot], jnp.int32),)
             with self._phase("launch"):
                 with count_op_calls() as c:
@@ -3111,8 +3156,21 @@ class ContinuousBatchingEngine:
                 self._tl_commit(t_step)
         except BaseException:
             # whatever failed, the step after it sends the mirrors
-            self._resident = self._inflight = None
+            self._drop_inflight()
             raise
+
+    def _drop_inflight(self) -> None:
+        """After a failed step: forget the device's copy of the decode
+        inputs and the step in flight. Its KV appends are computed
+        again into the same places by the step that follows; a state
+        layer's row it has already MOVED, and a second pass would move
+        it twice, so there the step in flight is settled instead (its
+        tokens are real: the program ran) and dropped only if its
+        fetch fails too."""
+        if self._has_state:
+            with contextlib.suppress(Exception):
+                self._settle_inflight()
+        self._resident = self._inflight = None
 
     def _step_inner(self) -> int:
         if self._resident is None:
@@ -3201,7 +3259,11 @@ class ContinuousBatchingEngine:
         # that comes back in through a public method finds settled
         # state, and a settle that raises drops both steps (``step``)
         pend, self._inflight = self._inflight, None
-        new = self._launch_decode(ahead=pend is not None)
+        try:
+            new = self._launch_decode(ahead=pend is not None)
+        except BaseException:
+            self._inflight = pend  # whoever handles the failure decides
+            raise
         if pend is not None:
             self._settle_decode(pend)
         if new["masked"]:
@@ -3301,7 +3363,10 @@ class ContinuousBatchingEngine:
         with self._phase("emit"):
             rows = [(slot, req) for slot, req in pend["rows"]
                     if self._slots[slot] is req]
-            self.decode_rows_dropped += len(pend["rows"]) - len(rows)
+            dropped = len(pend["rows"]) - len(rows)
+            self.decode_rows_dropped += dropped
+            if self._has_state:
+                self.state_rows_overwritten += dropped
             if rows:
                 # the mirrors follow the device: a decoding slot's
                 # length grew by the token appended, its current token

@@ -4,6 +4,9 @@ from .gpt import (GPTAttention, GPTBlock, GPTConfig, GPTForCausalLM, GPTMLP,
                   GPTModel, PagedKVCache, StaticKVCache, ernie_10b,
                   gpt_125m, gpt_1p3b, gpt_350m, gpt_tiny,
                   paged_cache_create, paged_kv_append)
-from .cache_layout import LayerCache, UnsupportedCacheLayout, ring_pages
+from .cache_layout import (LayerCache, StateCache, UnsupportedCacheLayout,
+                           ring_pages)
 from .smallthinker import (SmallThinkerConfig, SmallThinkerForCausalLM,
                            smallthinker_21b_a3b, smallthinker_tiny)
+from .solar_open2 import (SolarOpen2Config, SolarOpen2ForCausalLM,
+                          solar_open2_250b, solar_open2_tiny)

@@ -1,11 +1,14 @@
-"""What the serving engine asks a decoder about its KV cache.
+"""What the serving engine asks a decoder about the memory it keeps a
+sequence.
 
 ``model.cache_layout()`` returns one :class:`LayerCache` a layer. The
 engine builds a pool a layer from it and never reads head counts from a
-config: a layer with ``window=None`` keeps every position, in pages the
-engine's allocator hands out and one page table addresses (as GPT's
-layers all do); a layer with a window keeps the last ``window``
-positions, in a ring of :func:`ring_pages` pages a slot that lives in
+config. A layer names a KIND of memory: keys and values by position
+(``state`` None), or a state of fixed size a sequence (``state`` set: a
+recurrent layer). A key-value layer with ``window=None`` keeps every
+position, in pages the engine's allocator hands out and one page table
+addresses (as GPT's layers all do); a layer with a window keeps the
+last ``window`` positions, in a ring of :func:`ring_pages` pages a slot that lives in
 the layer's own pool and needs no allocator: slot ``r`` owns pool pages
 ``r * R .. r * R + R - 1`` and position ``p`` lies in ring page
 ``(p // page) % R``.
@@ -15,12 +18,23 @@ the layer's own pool and needs no allocator: slot ``r`` owns pool pages
 VPU); ``True`` is ``[P, KVH, page, D]``, which grouped heads want: each
 KV head's page is a ``[page, D]`` tile for the MXU
 (ops/pallas/paged_attention.py ``paged_attention_grouped``).
+
+A state layer keeps ``state`` = (heads, key size, value size) float32 a
+sequence and, where it has a causal convolution, the last ``conv`` =
+(taps - 1, channels) inputs of it in ``dtype``: row ``r`` of both pools
+belongs to slot ``r`` and the row behind the slots is scratch
+(:class:`StateCache`). No page table addresses it, its size does not
+grow with the sequence, and it is UPDATED where keys are appended: a
+step computed twice moves it twice, and what it was at an earlier
+position is gone. What has to restore, share, rewind or re-enter a
+sequence's memory is therefore refused for such a layer
+(``UnsupportedCacheLayout``) until it keeps snapshots.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -32,11 +46,34 @@ class LayerCache:
     window: Optional[int]  # None: every position is kept
     dtype: Any
     heads_major: bool = False
+    state: Optional[Tuple[int, int, int]] = None  # a state layer's
+    conv: Optional[Tuple[int, int]] = None
 
     @property
     def plain(self) -> bool:
         """GPT's layout: what every engine option was written for."""
-        return self.window is None and not self.heads_major
+        return self.window is None and not self.heads_major \
+            and self.state is None
+
+
+class StateCache(NamedTuple):
+    """A state layer's memory as the model's forward sees it: the two
+    pools whole (``state`` [slots + 1, H, d_k, d_v] float32, ``tail``
+    [slots + 1, taps - 1, channels]), the pool row of each batch row
+    (``rows`` [B]) and the lengths stored so far (``seq_lens`` [B]: 0
+    is a parked slot, whose row a single-token step leaves alone)."""
+    state: Any
+    tail: Any
+    rows: Any
+    seq_lens: Any
+
+
+def create_state_pools(lc: LayerCache, slots: int):
+    """``(state, tail)`` of one state layer: a row a slot and the
+    scratch row behind them, zero-filled."""
+    taps, channels = lc.conv or (0, 0)
+    return (jnp.zeros((slots + 1,) + tuple(lc.state), jnp.float32),
+            jnp.zeros((slots + 1, taps, channels), lc.dtype))
 
 
 def create_pools(lc: LayerCache, pages: int, page_size: int,

@@ -313,21 +313,29 @@ class SmallThinkerModel(Layer):
         self.norm = make((cfg.hidden_size,), "norm")
 
 
-class SmallThinkerForCausalLM(Layer):
-    """``abstract=True`` builds the parameters as shapes only
+class ServedDecoderLM(Layer):
+    """What the decoders served from shapes share: the parameters are
+    built as shapes first, then initialised or loaded.
+
+    ``abstract=True`` builds the parameters as shapes only
     (``jax.ShapeDtypeStruct``): nothing is initialised, and
     :meth:`load_weights` then puts the real arrays in. A serving
     process whose weights come from elsewhere (the benchmark, a
-    checkpoint) never holds two copies of an 11 GB model."""
+    checkpoint) never holds two copies of an 11 GB model. A subclass
+    names its ``body`` (the Layer built from ``(config, make)``) and
+    gives ``cache_layout`` and ``decode_hidden``."""
 
-    def __init__(self, config: SmallThinkerConfig, abstract: bool = False,
-                 seed: int = 0):
+    body = None
+
+    def __init__(self, config, abstract: bool = False, seed: int = 0):
         super().__init__()
         self.config = config
         wdt = jnp.dtype(config.dtype)
         specs = []
 
         def make(shape, init, dtype=None):
+            """``init``: a standard deviation, "norm" (ones, float32)
+            or ``f(key, shape) -> float32 array``."""
             dt = jnp.dtype("float32") if init == "norm" else \
                 jnp.dtype(dtype or wdt)
             p = Parameter(jax.ShapeDtypeStruct(tuple(shape), dt),
@@ -335,7 +343,7 @@ class SmallThinkerForCausalLM(Layer):
             specs.append((p, init))
             return p
 
-        self.model = SmallThinkerModel(config, make)
+        self.model = self.body(config, make)
         self.lm_head = make((config.vocab_rows, config.hidden_size),
                             config.initializer_range)
         self._specs = specs
@@ -346,9 +354,8 @@ class SmallThinkerForCausalLM(Layer):
     # -- weights ------------------------------------------------------------
 
     def init_weights(self, seed: int = 0) -> None:
-        """N(0, initializer_range), the two projections into the
-        residual stream scaled by 1/sqrt(2 layers), norms 1: one jitted
-        call for the whole model."""
+        """N(0, std) a matrix, norms 1, what a leaf's own rule gives:
+        one jitted call for the whole model."""
         specs = self._specs
 
         shapes = [(p.shape, p.dtype, init) for p, init in specs]
@@ -356,12 +363,14 @@ class SmallThinkerForCausalLM(Layer):
         def build(key):
             out = []
             for j, (shape, dt, init) in enumerate(shapes):
+                k = jax.random.fold_in(key, j)
                 if init == "norm":
                     out.append(jnp.ones(shape, dt))
+                elif callable(init):
+                    out.append(init(k, shape).astype(dt))
                 else:
-                    out.append((jax.random.normal(
-                        jax.random.fold_in(key, j), shape, jnp.float32)
-                        * init).astype(dt))
+                    out.append((jax.random.normal(k, shape, jnp.float32)
+                                * init).astype(dt))
             return out
 
         vals = jax.jit(build)(jax.random.key(int(seed)))
@@ -383,15 +392,7 @@ class SmallThinkerForCausalLM(Layer):
                     f"{tuple(w.shape)} {w.dtype}")
             p.value = w
 
-    # -- what the engine asks -------------------------------------------------
-
-    def cache_layout(self):
-        c = self.config
-        dt = jnp.dtype(c.dtype)
-        return [LayerCache(c.num_key_value_heads, c.head_dim,
-                           c.sliding_window_size if w else None, dt,
-                           heads_major=True)
-                for w in c.sliding_window_layout]
+    # -- what the engine asks of any of them ----------------------------------
 
     def head_params(self):
         """``(weight [V, D], transpose_y, bias)`` for the streaming
@@ -409,8 +410,50 @@ class SmallThinkerForCausalLM(Layer):
         out, self._stats = self._stats, None
         return out
 
+    def _keep_stats(self, counts, prefill: bool) -> None:
+        """``counts``: picks computed per held expert, a row a layer."""
+        cnt = jnp.stack(counts)  # [layers, held]
+        if not prefill:
+            self._stats = {"moe": {
+                "touched": jnp.sum(cnt > 0).astype(jnp.int32),
+                "max_load": jnp.max(cnt).astype(jnp.int32)}}
+            return
+        mean = jnp.maximum(jnp.sum(cnt, axis=1), 1) / cnt.shape[1]
+        self._stats = {"moe": {"max_over_mean_x1000": jnp.max(
+            jnp.max(cnt, axis=1) / mean * 1000.0).astype(jnp.int32)}}
+
     def logits(self, hidden):
         return jnp.matmul(_raw(hidden), _raw(self.lm_head).T)
+
+    def forward(self, input_ids, caches=None, prefill_lens=None,
+                prefill_chained=False):
+        """Logits. With ``caches``: ``(logits, new_caches)`` through the
+        cached path; without: the whole sequence at once, nothing
+        stored."""
+        if caches is None:
+            b, s = _raw(input_ids).shape
+            hidden, _ = self.decode_hidden(
+                input_ids, None, prefill_lens=jnp.full((b,), s, jnp.int32))
+            self._stats = None
+            return self.logits(hidden)
+        hidden, nc = self.decode_hidden(
+            input_ids, caches, prefill_lens=prefill_lens,
+            prefill_chained=prefill_chained)
+        return self.logits(hidden), nc
+
+
+class SmallThinkerForCausalLM(ServedDecoderLM):
+    body = SmallThinkerModel
+
+    # -- what the engine asks -------------------------------------------------
+
+    def cache_layout(self):
+        c = self.config
+        dt = jnp.dtype(c.dtype)
+        return [LayerCache(c.num_key_value_heads, c.head_dim,
+                           c.sliding_window_size if w else None, dt,
+                           heads_major=True)
+                for w in c.sliding_window_layout]
 
     def decode_hidden(self, input_ids, caches, prefill_lens=None,
                       prefill_chained=False):
@@ -484,7 +527,8 @@ class SmallThinkerForCausalLM(Layer):
                 m, cnt = dropless_experts(
                     u.reshape(b * s, -1), idx, gates, _raw(blk.w_gate),
                     _raw(blk.w_up), _raw(blk.w_down),
-                    held=c.experts_held, valid=live.reshape(-1))
+                    held=c.experts_held, valid=live.reshape(-1),
+                    activation="relu")
             counts.append(cnt)
             x = y + m.reshape(b, s, -1)
             if prefill_lens is not None:
@@ -492,29 +536,5 @@ class SmallThinkerForCausalLM(Layer):
                 # laid out by expert: one layer's at a time
                 x = jax.lax.optimization_barrier(x)
         x = rms_norm32(x, _raw(self.model.norm), c.rms_norm_eps).astype(dt)
-        cnt = jnp.stack(counts)  # [layers, held]
-        if prefill_lens is None:
-            self._stats = {"moe": {
-                "touched": jnp.sum(cnt > 0).astype(jnp.int32),
-                "max_load": jnp.max(cnt).astype(jnp.int32)}}
-        else:
-            mean = jnp.maximum(jnp.sum(cnt, axis=1), 1) / cnt.shape[1]
-            self._stats = {"moe": {"max_over_mean_x1000": jnp.max(
-                jnp.max(cnt, axis=1) / mean * 1000.0).astype(jnp.int32)}}
+        self._keep_stats(counts, prefill_lens is not None)
         return x, new_caches
-
-    def forward(self, input_ids, caches=None, prefill_lens=None,
-                prefill_chained=False):
-        """Logits. With ``caches``: ``(logits, new_caches)`` through the
-        cached path; without: the whole sequence at once, every layer's
-        attention over the chunk's own keys."""
-        if caches is None:
-            b, s = _raw(input_ids).shape
-            hidden, _ = self.decode_hidden(
-                input_ids, None, prefill_lens=jnp.full((b,), s, jnp.int32))
-            self._stats = None
-            return self.logits(hidden)
-        hidden, nc = self.decode_hidden(
-            input_ids, caches, prefill_lens=prefill_lens,
-            prefill_chained=prefill_chained)
-        return self.logits(hidden), nc

@@ -11,8 +11,9 @@ one expert re-use the block already in VMEM, and tiles past ``used``
 index, so nothing is fetched for them and their bodies are skipped;
 their output rows are never read.
 
-``grouped_ffn_in`` computes ``relu(x @ w_gate[e]) * (x @ w_up[e])`` in
-one pass over ``x``; ``grouped_matmul`` is the plain product (the down
+``grouped_ffn_in`` computes ``act(x @ w_gate[e]) * (x @ w_up[e])`` in
+one pass over ``x`` (``act`` the model's: ``relu`` or ``silu``);
+``grouped_matmul`` is the plain product (the down
 projection). Both accumulate in float32 on the MXU and store in the
 activation's dtype. Off the TPU the same layout runs through a gather
 of the tiles' weights (tiny sizes only: the CPU tests).
@@ -44,7 +45,10 @@ def _col_block(n: int, cap: int = 256) -> int:
     return best or n
 
 
-def _kernel(te_ref, used_ref, x_ref, *refs, gated: bool):
+ACTIVATIONS = {"relu": lambda a: jnp.maximum(a, 0.0), "silu": jax.nn.silu}
+
+
+def _kernel(te_ref, used_ref, x_ref, *refs, act):
     w_refs, o_ref = refs[:-1], refs[-1]
 
     @pl.when(pl.program_id(0) < used_ref[0])
@@ -53,11 +57,11 @@ def _kernel(te_ref, used_ref, x_ref, *refs, gated: bool):
         acc = jax.lax.dot_general(
             x, w_refs[0][0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        if gated:
+        if act is not None:
             up = jax.lax.dot_general(
                 x, w_refs[1][0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            acc = jnp.maximum(acc, 0.0) * up
+            acc = act(acc) * up
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -68,22 +72,23 @@ def grouped_supported(backend=None) -> bool:
     return backend == "tpu" or _FORCE_DEPTH > 0
 
 
-def _reference(x, ws, tile_expert, tm: int, gated: bool):
+def _reference(x, ws, tile_expert, tm: int, act):
     nt = tile_expert.shape[0]
     xt = x.reshape(nt, tm, x.shape[1])
     acc = jnp.einsum("tmk,tkn->tmn", xt, ws[0][tile_expert],
                      preferred_element_type=jnp.float32)
-    if gated:
+    if act is not None:
         up = jnp.einsum("tmk,tkn->tmn", xt, ws[1][tile_expert],
                         preferred_element_type=jnp.float32)
-        acc = jnp.maximum(acc, 0.0) * up
+        acc = act(acc) * up
     return acc.astype(x.dtype).reshape(nt * tm, -1)
 
 
-def _call(name, x, ws, tile_expert, used, tm: int):
-    gated = len(ws) == 2
+def _call(name, x, ws, tile_expert, used, tm: int, act=None):
+    """``act``: the gate's activation where ``ws`` is (gate, up), None
+    for the plain product."""
     if not grouped_supported():
-        return _reference(x, ws, tile_expert, tm, gated)
+        return _reference(x, ws, tile_expert, tm, act)
     mp, k = x.shape
     n = ws[0].shape[2]
     tn = _col_block(n)
@@ -103,7 +108,7 @@ def _call(name, x, ws, tile_expert, used, tm: int):
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, te, nu: (i, j)))
     flops = 2 * mp * k * n * len(ws)
     call = named_pallas_call(
-        name, functools.partial(_kernel, gated=gated),
+        name, functools.partial(_kernel, act=act),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
         cost_estimate=pl.CostEstimate(
@@ -116,13 +121,16 @@ def _call(name, x, ws, tile_expert, used, tm: int):
     return call(tile_expert, used, x, *ws)
 
 
-def grouped_ffn_in(x, w_gate, w_up, tile_expert, used, tm: int):
-    """``relu(x @ w_gate[e]) * (x @ w_up[e])`` per row tile.
+def grouped_ffn_in(x, w_gate, w_up, tile_expert, used, tm: int,
+                   activation: str = "relu"):
+    """``act(x @ w_gate[e]) * (x @ w_up[e])`` per row tile, ``act`` one
+    of ``ACTIVATIONS``.
 
     x: [tiles * tm, K]; w_gate, w_up: [E, K, F]; tile_expert: [tiles]
     int32 (each tile's expert; tiles past ``used`` repeat the last
     used tile's); used: [1] int32. Returns [tiles * tm, F]."""
-    return _call("moe_ffn_in", x, (w_gate, w_up), tile_expert, used, tm)
+    return _call("moe_ffn_in", x, (w_gate, w_up), tile_expert, used, tm,
+                 ACTIVATIONS[activation])
 
 
 def grouped_matmul(x, w, tile_expert, used, tm: int):

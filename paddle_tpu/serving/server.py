@@ -2194,6 +2194,8 @@ def _build_model(name: str):
     from ..models.smallthinker import (SmallThinkerForCausalLM,
                                        smallthinker_21b_a3b,
                                        smallthinker_tiny)
+    from ..models.solar_open2 import (SolarOpen2ForCausalLM,
+                                      solar_open2_250b, solar_open2_tiny)
     configs = {"gpt_tiny": (GPTForCausalLM, gpt_tiny),
                "gpt_125m": (GPTForCausalLM, gpt_125m),
                "gpt_350m": (GPTForCausalLM, gpt_350m),
@@ -2201,7 +2203,15 @@ def _build_model(name: str):
                "smallthinker_tiny": (SmallThinkerForCausalLM,
                                      smallthinker_tiny),
                "smallthinker_21b_a3b": (SmallThinkerForCausalLM,
-                                        smallthinker_21b_a3b)}
+                                        smallthinker_21b_a3b),
+               "solar_open2_tiny": (SolarOpen2ForCausalLM,
+                                    solar_open2_tiny),
+               # one period of the published widths, one chip's share
+               # of eight: 40 of 320 experts, an eighth of the head
+               "solar_open2_250b_cut": (
+                   SolarOpen2ForCausalLM, lambda: solar_open2_250b(
+                       4, experts_held=(0, 40), vocab_size=24576,
+                       dtype="bfloat16"))}
     if name not in configs:
         raise SystemExit(f"unknown --model {name!r}; choose from "
                          f"{sorted(configs)}")

@@ -54,7 +54,9 @@ from paddle_tpu.distributed import fault_inject as fi
 from paddle_tpu.distributed.topology import make_serving_mesh
 from paddle_tpu.inference import SpeculativeConfig, create_decode_engine
 from paddle_tpu.inference import continuous_batching as cb
-from paddle_tpu.models import SmallThinkerForCausalLM, smallthinker_tiny
+from paddle_tpu.models import (SmallThinkerForCausalLM,
+                               SolarOpen2ForCausalLM, smallthinker_tiny,
+                               solar_open2_tiny)
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 from paddle_tpu.serving.prefix_cache import PrefixCache
 
@@ -65,7 +67,8 @@ ENGINE_KW = dict(num_slots=2, page_size=PAGE, max_seq_len=64,
 # engine variants; a chunked engine's masked steps have no copy to hold,
 # and the speculative one builds its own arguments and never runs the
 # single-step program: they must come out the same all the more.
-# `rings` is the second decoder (window rings, grouped heads), at the
+# `rings` is the second decoder (window rings, grouped heads), `state`
+# the third (a state a slot updated in place beside the pages), at the
 # tiny size
 VARIANTS = {
     "plain": lambda: {},
@@ -76,6 +79,7 @@ VARIANTS = {
                              "mesh": make_serving_mesh(2)},
     "int8": lambda: {"kv_int8": True},
     "rings": lambda: {},
+    "state": lambda: {},
     "speculative": lambda: {
         "speculative": SpeculativeConfig(k=2, draft="ngram")},
 }
@@ -107,11 +111,16 @@ def rings_model():
     return SmallThinkerForCausalLM(smallthinker_tiny(), seed=3)
 
 
+@pytest.fixture(scope="module")
+def state_model():
+    return SolarOpen2ForCausalLM(solar_open2_tiny(), seed=3)
+
+
 @pytest.fixture
-def build(model, rings_model):
+def build(model, rings_model, state_model):
     """An engine of a variant, on the model the variant serves."""
     def make(variant, **kw):
-        m = rings_model if variant == "rings" else model
+        m = {"rings": rings_model, "state": state_model}.get(variant, model)
         return _engine(m, **VARIANTS[variant](), **kw)
     return make
 

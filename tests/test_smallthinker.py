@@ -348,9 +348,10 @@ def test_flash_prefill_kernel_group_7_and_a_window(interpret, s, window):
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("act", ["relu", "silu"])
 @pytest.mark.parametrize("rows,tm", [(12, 8), (300, 64)])
 def test_grouped_matmul_kernels_match_the_gathered_product(interpret, rows,
-                                                           tm):
+                                                           tm, act):
     rng = np.random.default_rng(rows)
     e, h, f = 5, 128, 256
     wg, wu, wd = _layer_weights(rng, e, h, f)
@@ -359,11 +360,11 @@ def test_grouped_matmul_kernels_match_the_gathered_product(interpret, rows,
     used = jnp.asarray([tiles - 2], jnp.int32)
     x = jnp.asarray(rng.standard_normal((tiles * tm, h)), jnp.float32)
     live = (tiles - 2) * tm
-    mid = gm.grouped_ffn_in(x, wg, wu, tile_expert, used, tm)
-    want = gm._reference(x, (wg, wu), tile_expert, tm, True)
+    mid = gm.grouped_ffn_in(x, wg, wu, tile_expert, used, tm, act)
+    want = gm._reference(x, (wg, wu), tile_expert, tm, gm.ACTIVATIONS[act])
     np.testing.assert_allclose(np.asarray(mid)[:live], np.asarray(want)[:live],
                                rtol=1e-4, atol=1e-4)
     out = gm.grouped_matmul(want, wd, tile_expert, used, tm)
-    want = gm._reference(want, (wd,), tile_expert, tm, False)
+    want = gm._reference(want, (wd,), tile_expert, tm, None)
     np.testing.assert_allclose(np.asarray(out)[:live], np.asarray(want)[:live],
                                rtol=1e-4, atol=1e-4)

@@ -365,6 +365,69 @@ def test_dropless_experts_compile(one_chip, tokens):
     _fits_one_v5e(compiled)
 
 
+# -- the state layers' kernels at Solar Open 2's published widths --------------
+# (64 heads of 128, float32 states, 32 slots: models/solar_open2.py)
+
+@pytest.mark.parametrize("t", [1024, 8192], ids=["bucket_1024", "segment"])
+def test_kda_chunk_fwd_compiles(one_chip, t):
+    """The chunked scan over a prompt's segment: every product in
+    float32, the transposed product into the state, the blocked
+    triangular solve."""
+    from paddle_tpu.ops.pallas import kda
+    specs = (_bf16(one_chip, 1, t, 8192), _bf16(one_chip, 1, t, 8192),
+             _bf16(one_chip, 1, t, 8192),
+             _bf16(one_chip, 1, t, 8192, dt=jnp.float32),
+             _bf16(one_chip, 1, t, 64, dt=jnp.float32),
+             _bf16(one_chip, 1, 64, 128, 128, dt=jnp.float32),
+             _bf16(one_chip, 1, dt=jnp.int32))
+    assert kda.kda_supported(128, 128, backend="tpu")
+    compiled = _compile(
+        lambda q, k, v, g, b, s, n: kda.kda_chunk_fwd(q, k, v, g, b, s, n,
+                                                      heads=64), *specs)
+    assert _kernel_calls(compiled) == 1
+    _named(compiled, "kda_chunk_fwd")
+
+
+def test_kda_decode_compiles_in_place(one_chip):
+    """One token a slot over the state pool: the pool is the call's
+    input AND output (no second 138 MB copy)."""
+    from paddle_tpu.ops.pallas import kda
+    specs = (_bf16(one_chip, 32, 64, 128), _bf16(one_chip, 32, 64, 128),
+             _bf16(one_chip, 32, 64, 128),
+             _bf16(one_chip, 32, 64, 128, dt=jnp.float32),
+             _bf16(one_chip, 32, 64, dt=jnp.float32),
+             _bf16(one_chip, 33, 64, 128, 128, dt=jnp.float32),
+             _bf16(one_chip, 32, dt=jnp.int32),
+             _bf16(one_chip, 32, dt=jnp.bool_))
+    with fa.force_flash_for_aot():
+        compiled = jax.jit(kda.kda_decode, donate_argnums=(5,)).lower(
+            *specs).compile()
+    assert _kernel_calls(compiled) == 1
+    _named(compiled, "kda_decode")
+    pool = 33 * 64 * 128 * 128 * 4
+    mem = compiled.memory_analysis()
+    assert int(mem.alias_size_in_bytes) >= pool
+    assert int(mem.temp_size_in_bytes) < pool // 8
+
+
+def test_dropless_experts_with_silu_and_a_share_compile(one_chip):
+    """The expert layer as the third decoder calls it: silu, 40 of 320
+    experts held, a decode step's 32 rows x 8 picks."""
+    from paddle_tpu.distributed.moe import dropless_experts
+    specs = (_bf16(one_chip, 32, 4096),
+             _bf16(one_chip, 32, 8, dt=jnp.int32),
+             _bf16(one_chip, 32, 8, dt=jnp.float32),
+             _bf16(one_chip, 40, 4096, 1280), _bf16(one_chip, 40, 4096, 1280),
+             _bf16(one_chip, 40, 1280, 4096),
+             _bf16(one_chip, 32, dt=jnp.bool_))
+    compiled = _compile(
+        lambda u, i, g, wg, wu, wd, ok: dropless_experts(
+            u, i, g, wg, wu, wd, held=(0, 40), valid=ok,
+            activation="silu"), *specs)
+    assert _kernel_calls(compiled) == 2
+    _named(compiled, "moe_ffn_in", "moe_ffn_out")
+
+
 # -- scale proofs on a described v4-64 pod ---------------------------------
 
 def test_10b_v4_64_aot_fits(v4_pod):
