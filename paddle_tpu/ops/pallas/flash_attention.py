@@ -34,8 +34,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from .naming import named_pallas_call
 
-# 512-blocks measured fastest on TPU v5e (grad 4.2 ms vs 8.0 ms at 128
-# for B8 H12 S1024 D64); auto-clamped to the sequence length.
+# Blocks of 512 x 512, auto-clamped to the sequence length. What a grid
+# step costs on a v5e (PR 33, tools/flash_report.py: device time of the
+# forward call at seven shapes of d 128 bf16, split by least squares): a
+# step over a block that holds a visible key 0.93 us (its two matmuls
+# need 0.68), a step the causal skip guards off 0.11 us, a row of blocks
+# 0.27 us to start and finish. Smaller blocks pay the per-step part more
+# often for the same keys.
 # PT_FLASH_BLOCK_Q/K override for shape-specific tuning (the analog of
 # the reference's per-kernel-key JIT selection, operators/jit/README).
 import contextlib as _contextlib
@@ -52,6 +57,88 @@ _GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
+_LANES = 128
+
+
+def _over_lanes(x, n):
+    """A lane-wide ``[rows, 128]`` carry laid beside ``n`` columns. Its
+    128 lanes hold one value a row, so a multiple of 128 columns is the
+    same registers again (no cross-lane move) and fewer are a slice."""
+    if n == _LANES:
+        return x
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _lane_sums(p):
+    """``[rows, n]`` -> ``[rows, 128]``: the sum of each row's columns
+    that share a lane (elementwise adds of whole registers); summing the
+    128 lanes gives the row's sum."""
+    out = p[:, :_LANES]
+    for c in range(_LANES, p.shape[1], _LANES):
+        out = out + p[:, c:c + _LANES]
+    return out
+
+
+def _scores(q, k_blk, scale, q0=None, k0=None, window=None):
+    """``q k^T * scale`` in f32 (operands stay in the input dtype: bf16
+    on the MXU at full rate). With ``q0`` / ``k0``, the positions of
+    the block's first query and key, the causal (and window) mask is
+    laid over it; without, the block is taken as wholly visible."""
+    s = jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # [BQ, BK] f32
+    if q0 is None:
+        return s
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen = seen & (k_pos > q_pos - window)
+    return jnp.where(seen, s, _NEG_INF)
+
+
+def _softmax_block(s, m):
+    """One online-softmax step over a score block: the new running max
+    (``[BQ, 128]``, every lane of a row the same value), ``alpha`` that
+    rescales what was accumulated under the old one, and ``p``.
+
+    The carries stay 128 lanes wide on purpose. A ``[BQ, 1]`` slice of
+    the scratch costs a cross-lane permute for every register of scores
+    it is subtracted from. The compiler's schedule of a 512 x 512 step
+    (PR 33, compiled for a v5e) held 2,534 slots of the cross-lane unit
+    in 2,025 bundles that way and holds 570 in 1,083 this way, against
+    1,024 cycles of MXU work; on the chip the call at B2 H16 S2048 d128
+    went from 733 to 351 us (tools/flash_report.py)."""
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - _over_lanes(m_new, s.shape[1]))
+    return m_new, alpha, p
+
+
+def _normalised(acc, l_lanes, m):
+    """(o, lse rows) from the accumulator, the per-lane partial sums
+    and the running max, all still lane-wide."""
+    denom = jnp.maximum(jnp.broadcast_to(
+        jnp.sum(l_lanes, axis=1, keepdims=True), l_lanes.shape), 1e-30)
+    return (acc / _over_lanes(denom, acc.shape[1]),
+            (m + jnp.log(denom))[:, :1])
+
+
+def _relevant(qb, kb, block_q, block_k, window):
+    """Does the K block hold a key that some query of the Q block sees:
+    not wholly above the diagonal, nor wholly behind the window."""
+    relevant = kb * block_k <= (qb + 1) * block_q - 1
+    if window is not None:
+        # a query at p sees keys p - window + 1 .. p
+        relevant = relevant & (
+            (kb + 1) * block_k - 1 >= qb * block_q - (window - 1))
+    return relevant
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
                 *, scale, causal, block_q, block_k, nk, window=None):
     qb = pl.program_id(2)
@@ -63,94 +150,60 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_run_ref[...] = jnp.zeros_like(l_run_ref)
 
-    # causal: K blocks fully above the diagonal contribute nothing.
-    # (r4, measured: splitting the body into masked-diagonal vs
-    # unmasked-fully-visible pl.when branches to skip the iota/where
-    # chain on interior blocks made things WORSE — b8 s1024 d128 causal
-    # fwd+bwd 5.06 -> 8.39 ms scanned wall-clock, and both sides carry
-    # the same ~3 ms amortized dispatch floor so the true device-time
-    # regression is steeper; the extra branch breaks Mosaic's pipeline.
-    # The single masked body stays.)
-    relevant = (kb * block_k <= (qb + 1) * block_q - 1) if causal else True
-    if window is not None:
-        # a query at p sees keys p - window + 1 .. p: K blocks that end
-        # before the first row's bound contribute nothing either
-        relevant = relevant & (
-            (kb + 1) * block_k - 1 >= qb * block_q - (window - 1))
-
-    @pl.when(relevant)
+    # causal: K blocks wholly above the diagonal (or behind the window)
+    # contribute nothing. Every other block takes the ONE masked body,
+    # also those the diagonal does not cut: the iota / compare / select
+    # chain hides under the MXU. Measured (PR 33, device time of the
+    # call): B2 H16 S2048 351 us with this body, 360 with a second,
+    # unmasked body for the wholly visible blocks; [1, 64, 8192] over 8
+    # KV heads 8.99 against 9.35 ms (the compiler schedules the masked
+    # step in 1,083 bundles, the unmasked one in 1,137). Walking the
+    # diagonal block in sub-tiles and skipping those above it did not
+    # pay either: no change with 256-wide sub-tiles, 6 % slower with
+    # 128-wide ones (narrow bands reload the MXU's weights as often as
+    # they stream rows); folding ``scale`` into the exponent: 0.4 %.
+    @pl.when(_relevant(qb, kb, block_q, block_k, window) if causal else True)
     def _step():
-        # operands stay in the input dtype (bf16 on the MXU at full
-        # rate); all accumulation is f32 via preferred_element_type
-        q = q_ref[0, 0]  # [BQ, D]
-        k_blk = k_ref[0, 0]  # [BK, D]
         v_blk = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [BQ, BK] f32
-        if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            seen = q_pos >= k_pos
-            if window is not None:
-                seen = seen & (k_pos > q_pos - window)
-            s = jnp.where(seen, s, _NEG_INF)
-        m_run = m_ref[:, :1]  # [BQ, 1]
-        l_run = l_run_ref[:, :1]
-        m_blk = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_run, m_blk)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_run - m_new)
-        l_new = l_run * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_run_ref[...] = jnp.broadcast_to(l_new, l_run_ref.shape)
+        where = (qb * block_q, kb * block_k, window) if causal else ()
+        s = _scores(q_ref[0, 0], k_ref[0, 0], scale, *where)
+        m_new, alpha, p = _softmax_block(s, m_ref[...])
+        # the running sum is kept a partial sum a lane (no cross-lane
+        # reduce a step): _finish adds the 128 lanes once a row of blocks
+        l_run_ref[...] = l_run_ref[...] * alpha + _lane_sums(p)
+        acc_ref[...] = (
+            acc_ref[...] * _over_lanes(alpha, acc_ref.shape[1])
+            + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        m_ref[...] = m_new
 
     @pl.when(kb == nk - 1)
     def _finish():
-        denom = jnp.maximum(l_run_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o, lse = _normalised(acc_ref[...], l_run_ref[...], m_ref[...])
+        o_ref[0, 0] = o.astype(o_ref.dtype)
         # logsumexp per row, stored [BQ, 1] (lane-1 layout keeps the
         # block spec legal on TPU: last dim equals the array dim)
-        l_ref[0, 0] = m_ref[:, :1] + jnp.log(denom)
+        l_ref[0, 0] = lse
 
 
 def _fwd_single_block_kernel(q_ref, k_ref, v_ref, o_ref, l_ref,
                              *, scale, causal, block_q, block_k,
                              window=None):
     """Forward for the nk == 1 case (the whole K axis is one block,
-    e.g. S=512 at the default 512 block): a plain in-register softmax.
-    The streaming kernel's online-softmax machinery — running max,
-    alpha rescale of the accumulator, (BQ, 128) m/l scratch broadcasts
-    — exists to merge MULTIPLE K blocks and is pure overhead with one."""
-    qb = pl.program_id(2)
-    q = q_ref[0, 0]  # [BQ, D]
-    k_blk = k_ref[0, 0]
+    e.g. S=512 at the default 512 block): the same chain with one step,
+    so no scratch, no rescale and every grid dim parallel."""
     v_blk = v_ref[0, 0]
-    s = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # [BQ, BK] f32
-    if causal:
-        q_pos = qb * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        seen = q_pos >= k_pos
-        if window is not None:
-            seen = seen & (k_pos > q_pos - window)
-        s = jnp.where(seen, s, _NEG_INF)
-    m = jnp.max(s, axis=1, keepdims=True)
-    p = jnp.exp(s - m)
-    denom = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
+    where = (pl.program_id(2) * block_q, 0, window) if causal else ()
+    s = _scores(q_ref[0, 0], k_ref[0, 0], scale, *where)
+    m, _, p = _softmax_block(
+        s, jnp.full((s.shape[0], _LANES), _NEG_INF, jnp.float32))
     acc = jax.lax.dot_general(
         p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    o_ref[0, 0] = (acc / denom).astype(o_ref.dtype)
-    l_ref[0, 0] = m + jnp.log(denom)
+    o, lse = _normalised(acc, _lane_sums(p), m)
+    o_ref[0, 0] = o.astype(o_ref.dtype)
+    l_ref[0, 0] = lse
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -172,14 +225,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         delta = delta_ref[0, 0]  # [BQ, 1]
         k_blk = k_ref[0, 0]
         v_blk = v_ref[0, 0]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        where = (qb * block_q, kb * block_k) if causal else ()
+        s = _scores(q, k_blk, scale, *where)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -224,14 +271,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0, 0]  # [BQ, 1]
         k_blk = k_ref[0, 0]
         v_blk = v_ref[0, 0]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        where = (0, kb * block_k) if causal else ()
+        s = _scores(q, k_blk, scale, *where)
         p = jnp.exp(s - lse)  # [BQ, BK]
         dv_ref[0, 0] = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -279,14 +320,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0, 0]
         lse = lse_ref[0, 0]  # [BQ, 1]
         delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        where = (qb * block_q, kb * block_k) if causal else ()
+        s = _scores(q, k_blk, scale, *where)
         p = jnp.exp(s - lse)  # [BQ, BK]
         dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),

@@ -432,3 +432,109 @@ def test_single_kblock_causal_forward_sq_gt_sk():
                                        attn_mask=_diag_mask(sq, sk))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
+
+
+# -- the forward's lane-wide carries and the mask on cut blocks alone --------
+
+def _dense_reference(q, k, v, causal, window=None):
+    """float32 attention of [B, H, Sq, D] over [B, KVH, Sk, D] under the
+    kernel's diagonal-aligned mask; (out, logsumexp rows)."""
+    g = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        qp = jnp.arange(q.shape[2])[:, None]
+        kp = jnp.arange(k.shape[2])[None, :]
+        seen = qp >= kp
+        if window is not None:
+            seen = seen & (kp > qp - window)
+        s = jnp.where(seen, s, -jnp.inf)
+    return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v),
+            jax.nn.logsumexp(s, -1))
+
+
+# (sq, sk, block_q, block_k): one K block; a 3 x 3 grid of blocks; Q
+# blocks twice as tall as K blocks; queries longer than keys
+_LAYOUTS = {"nk1": (128, 128, 128, 128), "nk3": (384, 384, 128, 128),
+            "bq_ne_bk": (512, 512, 256, 128), "sq_gt_sk": (256, 128, 128, 128)}
+
+
+@pytest.mark.parametrize("group,d", [(1, 128), (7, 64), (8, 256)])
+@pytest.mark.parametrize("window", [None, 200, 128, 1024],
+                         ids=["no_window", "edge_in_tile", "one_block",
+                              "wider_than_seq"])
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+def test_forward_blocks_match_dense(layout, window, group, d):
+    """Every kind of block the forward meets — wholly visible, cut by
+    the diagonal, cut by the window's lower edge, skipped — over grouped
+    heads and the three head sizes, in the streaming and the
+    single-block kernel: output AND log-sum-exp rows against the dense
+    float32 attention."""
+    sq, sk, bq, bk = _LAYOUTS[layout]
+    if window is not None and window <= sq - sk:
+        window = sq - sk + 1  # the last row still sees the last key
+    rng = np.random.default_rng(hash((layout, window, group)) % 2 ** 31)
+    q = jnp.asarray(rng.standard_normal((1, group, sq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, 1, sk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, 1, sk, d)), jnp.float32)
+    out, lse = fa._flash_fwd(q, k, v, d ** -0.5, True, bq, bk, group=group,
+                             window=window)
+    ref, ref_lse = _dense_reference(q, k, v, True, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(lse[..., 0]), np.asarray(ref_lse),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("causal", [False, True])
+def test_lse_rows_and_cotangent_match_dense(causal, d):
+    """flash_attention_lse over a 3 x 3 grid of blocks: the rows of the
+    log-sum-exp and the gradients THROUGH them (the lse cotangent folds
+    into delta) are what the dense attention gives."""
+    rng = np.random.default_rng(17 + d)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 384, 2, d)), jnp.float32)
+               for _ in range(3))
+    w = jnp.asarray(rng.standard_normal((1, 384, 2)), jnp.float32)
+
+    def flash(q_, k_, v_):
+        o, lse = fa.flash_attention_lse(q_, k_, v_, causal=causal,
+                                        block_q=128, block_k=128)
+        return jnp.sum(o ** 2) + jnp.sum(lse * w), lse
+
+    def dense(q_, k_, v_):
+        o, lse = _dense_reference(*(jnp.swapaxes(x, 1, 2)
+                                    for x in (q_, k_, v_)), causal)
+        lse = jnp.swapaxes(lse, 1, 2)
+        return jnp.sum(o ** 2) + jnp.sum(lse * w), lse
+
+    (_, lse), g = jax.value_and_grad(flash, argnums=(0, 1, 2),
+                                     has_aux=True)(q, k, v)
+    (_, ref_lse), g_ref = jax.value_and_grad(dense, argnums=(0, 1, 2),
+                                             has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=2e-5, atol=2e-5)
+    for got, want, name in zip(g, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=5e-3, atol=5e-3,
+                                   err_msg=f"d{name} mismatch")
+
+
+def test_relevant_never_skips_a_visible_key():
+    """The scalar test that guards a grid step off: a block it skips
+    holds no key any of its queries sees, and a block above the
+    diagonal or behind the window is skipped (a block it passes may
+    still hide every key of SOME rows)."""
+    for bq, bk, window in [(128, 128, None), (128, 128, 200),
+                           (128, 128, 128), (256, 128, None),
+                           (128, 256, 300)]:
+        for qb in range(4):
+            for kb in range(4):
+                qp = np.arange(qb * bq, (qb + 1) * bq)[:, None]
+                kp = np.arange(kb * bk, (kb + 1) * bk)[None, :]
+                seen = qp >= kp
+                if window is not None:
+                    seen = seen & (kp > qp - window)
+                relevant = fa._relevant(qb, kb, bq, bk, window)
+                assert bool(relevant) == bool(seen.any()), (bq, bk, window,
+                                                            qb, kb)

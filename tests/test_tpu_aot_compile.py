@@ -103,6 +103,17 @@ def _named(compiled, *names):
         assert f"pt.kernel.{n}" in txt, n
 
 
+def _named_once(compiled, *names):
+    """Each kernel is ONE custom call of the program (the roofline
+    readers of the benchmark sum a name's calls a layer: a kernel split
+    into several calls, or a second copy, would skew them)."""
+    _named(compiled, *names)
+    txt = compiled.as_text()
+    for n in names:
+        calls = re.findall(rf"%{n}[.\d]* = [^\n]*tpu_custom_call", txt)
+        assert len(calls) == 1, (n, len(calls))
+
+
 def _fits_one_v5e(compiled):
     mem = compiled.memory_analysis()
     live = (int(mem.argument_size_in_bytes) + int(mem.temp_size_in_bytes)
@@ -226,8 +237,10 @@ def test_flash_fwd_bwd_compiles_train_shape(one_chip):
     q = jax.ShapeDtypeStruct((2, 2048, H, D), jnp.bfloat16,
                              sharding=one_chip)
     compiled = _compile(_grad_of_attention(fa.flash_attention), q)
-    assert _kernel_calls(compiled) >= 2  # forward + backward kernels
-    _named(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    # forward + the two backward kernels, once each, and no other; a
+    # body that outgrew the scoped VMEM limit would not have compiled
+    assert _kernel_calls(compiled) == 3
+    _named_once(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
     _fits_one_v5e(compiled)
 
 
@@ -343,7 +356,20 @@ def test_flash_grouped_window_compiles(one_chip, s, window):
         lambda q, k, v: fa.flash_attention_grouped(q, k, v, window=window),
         q, kv, kv)
     assert _kernel_calls(compiled) == 1
-    _named(compiled, "flash_fwd_single" if s == 512 else "flash_fwd")
+    _named_once(compiled, "flash_fwd_single" if s == 512 else "flash_fwd")
+
+
+@pytest.mark.parametrize("s", [1024, 32768])
+def test_flash_grouped_solar_compiles(one_chip, s):
+    """Solar Open 2's softmax layer: 64 query heads over 8 KV heads of
+    128, no window, the smallest and the largest prefill bucket (64 x 64
+    grid steps a head): one ``flash_fwd`` call whose two step bodies
+    (whole blocks, blocks the diagonal cuts) fit the scoped VMEM."""
+    q = _bf16(one_chip, 1, s, 64, 128)
+    kv = _bf16(one_chip, 1, s, 8, 128)
+    compiled = _compile(fa.flash_attention_grouped, q, kv, kv)
+    assert _kernel_calls(compiled) == 1
+    _named_once(compiled, "flash_fwd")
 
 
 @pytest.mark.parametrize("tokens", [16, 8192], ids=["decode", "prefill"])
