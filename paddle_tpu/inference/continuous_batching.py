@@ -529,7 +529,9 @@ class ContinuousBatchingEngine:
         # allocator's pages and the one page table; a window layer
         # holds a ring of `ring_pages` pages a slot in a pool of its
         # own; a state layer a row a slot of its state pool, which no
-        # page table addresses. Everything below that differs between
+        # page table addresses; a latent layer one row a position for
+        # all heads, in the allocator's pages: ONE pool (under "k") and
+        # no V pool. Everything below that differs between
         # decoders comes from here, never from the model's class.
         self._layout = list(model.cache_layout())
         self._has_state = any(lc.state is not None for lc in self._layout)
@@ -540,12 +542,16 @@ class ContinuousBatchingEngine:
         # layers whose memory is a row a SLOT: their programs are told
         # which slot each batch row is
         self._slot_rows = self._has_rings or self._has_state
-        if not all(lc.plain for lc in self._layout):
+        self._plain_layout = all(lc.plain for lc in self._layout)
+        if not self._plain_layout:
             # a ring holds a window's worth of ONE sequence's own keys,
             # a state layer what the whole sequence left (no snapshot
-            # of an earlier position), and a heads-major page is not
-            # the page the spill codecs, the int8 scales and the verify
-            # path read: what would have to restore, share, rewind or
+            # of an earlier position), and a heads-major page or a
+            # latent page (one pool, no heads) is not the page the
+            # spill codecs, the int8 scales, a mesh's head sharding and
+            # the verify path read; a prompt over latent pages attends
+            # to its own positions alone. What would have to restore,
+            # share, rewind or
             # re-enter such a cache is refused here, typed, until it
             # has a parity test. (Spill and handoff move a prefix
             # cache's pages: refused with it. Resurrection replays
@@ -565,8 +571,8 @@ class ContinuousBatchingEngine:
                 if asked:
                     raise UnsupportedCacheLayout(
                         f"a cache layout with window layers, grouped "
-                        f"heads or state layers does not support "
-                        f"{what} yet")
+                        f"heads, state layers or latent pages does not "
+                        f"support {what} yet")
         # tensor-parallel serving (mesh=None = single-device, the
         # byte-for-byte pre-r10 behavior): weights shard per their
         # mp_layers pspecs, KV pools shard over heads, page table and
@@ -688,10 +694,17 @@ class ContinuousBatchingEngine:
         self._pools = {
             name: [p[j] for p in per_layer] for j, name in
             enumerate(("k", "v", "ks", "vs", "state", "tail"))}
-        self.state_pool_bytes = sum(
-            int(x.size) * x.dtype.itemsize
-            for kind in ("state", "tail") for x in self._pools[kind]
-            if x is not None)
+        def nbytes(xs):
+            return sum(int(x.size) * x.dtype.itemsize
+                       for x in xs if x is not None)
+
+        self.state_pool_bytes = nbytes(self._pools["state"]
+                                       + self._pools["tail"])
+        # the latent layers' pools as they lie in memory: a row's
+        # padding to whole lane tiles counts
+        self.latent_pool_bytes = nbytes(
+            x for lc, x in zip(self._layout, self._pools["k"])
+            if lc.latent is not None)
         # host-owned scheduler state. The three mirrors of the decode
         # step's inputs are views of ONE packed int32 array
         # ``[num_slots, max_pages + 2]`` (page table | length | current
@@ -1060,17 +1073,21 @@ class ContinuousBatchingEngine:
         """One cache a layer over the pools. A window layer's table is
         its slots' rings (``rows``: the slot of each batch row; the
         decode step's rows are the slots in order)."""
-        from ..models.cache_layout import StateCache, ring_table
+        from ..models.cache_layout import (LatentCache, StateCache,
+                                           ring_table)
         from ..models.gpt import PagedKVCache
         if rows is None and self._slot_rows:
             rows = self._jnp.arange(table.shape[0], dtype=self._jnp.int32)
         return [StateCache(pools["state"][i], pools["tail"][i], rows, lens)
-                if pools["state"][i] is not None
+                if lc.state is not None
+                else LatentCache(pools["k"][i], table, lens)
+                if lc.latent is not None
                 else PagedKVCache(pools["k"][i], pools["v"][i],
                                   pools["ks"][i], pools["vs"][i],
                                   table if ring is None
                                   else ring_table(rows, ring), lens)
-                for i, ring in enumerate(self._rings)]
+                for i, (lc, ring) in enumerate(zip(self._layout,
+                                                   self._rings))]
 
     def _take_stats(self):
         """At trace time, after the model's call: the counters of the
@@ -1535,10 +1552,11 @@ class ContinuousBatchingEngine:
             entry[k] = round(v, 4)
         if self._tl_decode is not None:
             entry["decode_h2d"], entry["decode_ahead"] = self._tl_decode
-        if self._slot_rows:
+        if not self._plain_layout:
             # pages in use by kind of layer, a layer of each: the
-            # allocator's (every position kept) and the rings' (a
-            # sequence never holds more than its ring)
+            # allocator's (every position kept: K/V pages or latent
+            # rows) and the rings' (a sequence never holds more than
+            # its ring)
             entry["kv_pages"] = {
                 "global": self.num_pages - entry["free_pages"]}
             if self._has_rings:
@@ -1592,6 +1610,7 @@ class ContinuousBatchingEngine:
             "decode_steps_ahead": int(self.decode_steps_ahead),
             "decode_rows_dropped": int(self.decode_rows_dropped),
             "state_pool_bytes": int(self.state_pool_bytes),
+            "latent_pool_bytes": int(self.latent_pool_bytes),
             "state_rows_overwritten": int(self.state_rows_overwritten),
             "model_counters": {k: dict(v) for k, v in
                                self.model_counters.items()},
@@ -1872,8 +1891,11 @@ class ContinuousBatchingEngine:
         def take(name, only=True):
             return [_raw(getattr(c, name)) if only and hasattr(c, name)
                     else None for c in nc]
+        # a latent layer's one pool rides under "k"
+        k = [a if a is not None else b
+             for a, b in zip(take("k_pages"), take("pages"))]
         pools = self._constrain_pools({
-            "k": take("k_pages"), "v": take("v_pages"),
+            "k": k, "v": take("v_pages"),
             "ks": take("k_scale", self.kv_int8),
             "vs": take("v_scale", self.kv_int8)})
         return dict(pools, state=take("state"), tail=take("tail"))

@@ -29,6 +29,21 @@ step computed twice moves it twice, and what it was at an earlier
 position is gone. What has to restore, share, rewind or re-enter a
 sequence's memory is therefore refused for such a layer
 (``UnsupportedCacheLayout``) until it keeps snapshots.
+
+A latent layer (``latent`` = (rank, rope): multi-head latent attention)
+keeps every position in the allocator's pages, under the one page
+table, but what a position leaves is ONE row for all heads: the
+normed latent of ``rank`` values and the rotated key of ``rope``
+shared by the heads. It has one pool and no V pool, ``[P, page,
+latent_width]``: a page is at once the keys and the values of the
+absorbed decode (ops/pallas/paged_attention.py
+``paged_attention_latent``). A row is stored ``latent_width`` wide,
+``rank + rope`` rounded up to whole lane tiles of 128 with zeros
+behind: the chip's tiling of a pool's minor axis would pad it so in
+memory anyway, and the kernel then reads whole tiles. The page codecs
+(prefix cache, spill, handoff), int8 scales, the verify path and a
+mesh's head sharding all read ``[page, H, D]`` pairs: refused for this
+layout like the others.
 """
 
 from __future__ import annotations
@@ -48,12 +63,18 @@ class LayerCache:
     heads_major: bool = False
     state: Optional[Tuple[int, int, int]] = None  # a state layer's
     conv: Optional[Tuple[int, int]] = None
+    latent: Optional[Tuple[int, int]] = None  # a latent layer's
 
     @property
     def plain(self) -> bool:
         """GPT's layout: what every engine option was written for."""
         return self.window is None and not self.heads_major \
-            and self.state is None
+            and self.state is None and self.latent is None
+
+    @property
+    def latent_width(self) -> int:
+        """Values a position's row is stored as: whole lane tiles."""
+        return -(-sum(self.latent) // 128) * 128
 
 
 class StateCache(NamedTuple):
@@ -65,6 +86,17 @@ class StateCache(NamedTuple):
     state: Any
     tail: Any
     rows: Any
+    seq_lens: Any
+
+
+class LatentCache(NamedTuple):
+    """A latent layer's memory as the model's forward sees it: the one
+    pool (``pages`` [P + 1, page, width]: a position's row is its
+    latent, the shared rotated key, zeros up to ``width``), the
+    allocator's page table ``[B, max_pages]`` and the lengths stored so
+    far (``seq_lens`` [B]: 0 is a parked slot)."""
+    pages: Any
+    page_table: Any
     seq_lens: Any
 
 
@@ -80,7 +112,11 @@ def create_pools(lc: LayerCache, pages: int, page_size: int,
                  max_pages: int = 1, quantized: bool = False,
                  kv_sharding=None):
     """``(k_pages, v_pages, k_scale, v_scale)`` of one layer: ``pages``
-    pages and the scratch page behind them, zero-filled."""
+    pages and the scratch page behind them, zero-filled. A latent layer
+    has the first alone."""
+    if lc.latent is not None:
+        return (jnp.zeros((pages + 1, page_size, lc.latent_width),
+                          lc.dtype), None, None, None)
     if lc.heads_major:
         shape = (pages + 1, lc.kv_heads, page_size, lc.head_dim)
         return (jnp.zeros(shape, lc.dtype), jnp.zeros(shape, lc.dtype),

@@ -271,6 +271,46 @@ def causal_conv(x, tail, w):
     return out, seen
 
 
+def sigmoid_moe(blk, y, valid, *, eps, top_k, scaling, segment, held=None):
+    """``y + MoE(RMSNorm_2(y))`` of a layer whose router scores every
+    expert by a sigmoid and which has one shared expert (``blk``: its
+    ``ln2``, ``router``, ``router_bias``, ``w_gate`` / ``w_up`` /
+    ``w_down`` by expert and ``ws_*`` of the shared one), and the picks
+    computed per held expert. A prompt goes through ``segment``
+    positions at a time: the rows laid out by expert are sized for
+    EVERY pick of the rows given (none is dropped), eight times what a
+    chip that holds an eighth of the experts gets of a long prompt."""
+    from ..distributed.moe import (dropless_experts, gated_ffn,
+                                   route_sigmoid_top_k)
+    shape = y.shape
+    y = y.reshape(-1, shape[-1])
+    valid = valid.reshape(-1)
+    out, cnt = [], 0
+    for lo in range(0, y.shape[0], segment):
+        ys = y[lo:lo + segment]
+        if out:
+            # one segment's rows at a time
+            ys, _ = jax.lax.optimization_barrier((ys, out[-1]))
+        u32 = rms_norm32(ys, _raw(blk.ln2), eps)
+        with jax.named_scope("pt.moe.router"):
+            idx, gates = route_sigmoid_top_k(
+                u32, _raw(blk.router), _raw(blk.router_bias), top_k,
+                scaling)
+        u = u32.astype(y.dtype)
+        with jax.named_scope("pt.moe.experts"):
+            m, n = dropless_experts(
+                u, idx, gates, _raw(blk.w_gate), _raw(blk.w_up),
+                _raw(blk.w_down), held=held,
+                valid=valid[lo:lo + segment], activation="silu")
+        with jax.named_scope("pt.moe.shared"):
+            m = m + gated_ffn(u, _raw(blk.ws_gate), _raw(blk.ws_up),
+                              _raw(blk.ws_down), "silu")
+        out.append(ys + m)
+        cnt = cnt + n
+    y = out[0] if len(out) == 1 else jnp.concatenate(out, axis=0)
+    return y.reshape(shape), cnt
+
+
 class SolarOpen2ForCausalLM(ServedDecoderLM):
     body = SolarOpen2Model
 
@@ -398,42 +438,11 @@ class SolarOpen2ForCausalLM(ServedDecoderLM):
     # -- the expert layer -----------------------------------------------------
 
     def _moe(self, blk, y, valid):
-        """``y + MoE(RMSNorm_2(y))`` and the picks computed per held
-        expert. A prompt goes through ``prefill_segment`` positions at
-        a time: the rows laid out by expert are sized for EVERY pick of
-        the rows given (none is dropped), eight times what a chip that
-        holds an eighth of the experts gets of a long prompt."""
-        from ..distributed.moe import (dropless_experts, gated_ffn,
-                                       route_sigmoid_top_k)
         c = self.config
-        shape = y.shape
-        y = y.reshape(-1, shape[-1])
-        valid = valid.reshape(-1)
-        out, cnt = [], 0
-        for lo in range(0, y.shape[0], c.prefill_segment):
-            ys = y[lo:lo + c.prefill_segment]
-            if out:
-                # one segment's rows at a time
-                ys, _ = jax.lax.optimization_barrier((ys, out[-1]))
-            u32 = rms_norm32(ys, _raw(blk.ln2), c.rms_norm_eps)
-            with jax.named_scope("pt.moe.router"):
-                idx, gates = route_sigmoid_top_k(
-                    u32, _raw(blk.router), _raw(blk.router_bias),
-                    c.num_experts_per_tok, c.routed_scaling_factor)
-            u = u32.astype(y.dtype)
-            with jax.named_scope("pt.moe.experts"):
-                m, n = dropless_experts(
-                    u, idx, gates, _raw(blk.w_gate), _raw(blk.w_up),
-                    _raw(blk.w_down), held=c.experts_held,
-                    valid=valid[lo:lo + c.prefill_segment],
-                    activation="silu")
-            with jax.named_scope("pt.moe.shared"):
-                m = m + gated_ffn(u, _raw(blk.ws_gate), _raw(blk.ws_up),
-                                  _raw(blk.ws_down), "silu")
-            out.append(ys + m)
-            cnt = cnt + n
-        y = out[0] if len(out) == 1 else jnp.concatenate(out, axis=0)
-        return y.reshape(shape), cnt
+        return sigmoid_moe(blk, y, valid, eps=c.rms_norm_eps,
+                           top_k=c.num_experts_per_tok,
+                           scaling=c.routed_scaling_factor,
+                           segment=c.prefill_segment, held=c.experts_held)
 
     # -- the forward ----------------------------------------------------------
 

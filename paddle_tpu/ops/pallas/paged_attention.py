@@ -605,6 +605,199 @@ def paged_attention_grouped(q, k_pages, v_pages, page_table, seq_lens,
 
 
 # --------------------------------------------------------------------------
+# Latent pages (multi-head latent attention, the absorbed form)
+# --------------------------------------------------------------------------
+#
+# A latent layer's pool is ``[P, page, W]`` (models/cache_layout.py
+# ``latent``): a position's row holds the normed latent ``c`` (``rank``
+# values), the ONE rotated key all heads share (``rope`` values) and
+# zeros up to ``W`` (whole lane tiles). In the absorbed form a head's
+# query is ``[q_nope W_UK^T | q_rope]``, also ``rank + rope`` long, so
+# every head scores against the page's rows as they lie, and the
+# output is the probability-weighted sum of the SAME rows' first
+# ``rank`` columns: a page is fetched once and is the keys and the
+# values of all heads. ``H`` query rows against ``[page, W]``: both
+# products go to the MXU. Pages are fetched ``block`` at a time into
+# one buffer (their copies in flight together, one pair of products
+# over ``block * page`` rows), because one page's 80 KB arrive faster
+# than a loop iteration turns round: at 48 slots of 8,192 positions a
+# call reads its required bytes at 35 % of the chip's bandwidth with 2
+# pages a block, 52 % with 4, 69 % with 8, 75 % with 16 and no more
+# with 32, while a sequence's last, partly filled block costs a whole
+# one (0.5, 0.9, 1.3, 2.0 us a live slot; tools/latent_decode_report.py,
+# PERF.md section 6, PR 34). So a block is 16 pages where sequences can
+# be long, and an eighth of the table where they cannot.
+
+LATENT_BLOCK_PAGES = 16
+
+
+def _decode_latent_kernel(pt_ref, len_ref, q_ref, pool_ref, o_ref, buf, sems,
+                          *, page: int, scale: float, rank: int, block: int):
+    """One grid step = one sequence; a parked slot (length 0) walks no
+    page and returns zeros. ``q_ref`` is ``[1, Hp, W]``: the heads
+    padded to a sublane tile with zero rows, whose outputs the caller
+    drops. ``buf`` ``[2, block * page, W]``: what a block's copies do
+    not fill (pages past the sequence's last) keeps older rows, which
+    the mask hides; it is zeroed once a call so that nothing read from
+    it was never written."""
+    b = pl.program_id(0)
+    seq_len = len_ref[b]
+    n_pages = pl.cdiv(seq_len, page)
+    n_blocks = pl.cdiv(n_pages, block)
+    mp = pt_ref.shape[1]
+    hp = q_ref.shape[1]
+    rows = block * page
+
+    @pl.when(b == 0)
+    def _():
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+    def each_copy(i, slot, fn):
+        for j in range(block):
+            pg = i * block + j
+
+            @pl.when(pg < n_pages)
+            def _():
+                fn(pltpu.make_async_copy(
+                    pool_ref.at[pt_ref[b, jnp.minimum(pg, mp - 1)]],
+                    buf.at[slot, pl.ds(j * page, page)],
+                    sems.at[slot, j]))
+
+    @pl.when(n_blocks > 0)
+    def _():
+        each_copy(0, 0, lambda c: c.start())
+
+    def body(i, carry):
+        m, l, acc = carry  # noqa: E741
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _():
+            each_copy(i + 1, 1 - slot, lambda c: c.start())
+
+        each_copy(i, slot, lambda c: c.wait())
+        s = jax.lax.dot_general(
+            q_ref[0], buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [Hp, rows]
+        kpos = i * rows + jax.lax.broadcasted_iota(jnp.int32, (hp, rows), 1)
+        seen = kpos < seq_len
+        s = jnp.where(seen, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)  # noqa: E741
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(buf.dtype), buf[slot, :, :rank],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [Hp, rank]
+        return m_new, l, acc
+
+    init = (jnp.full((hp, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((hp, 1), jnp.float32),
+            jnp.zeros((hp, rank), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)  # noqa: E741
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_decode_latent_pallas(q, pages, page_table, seq_lens, rank, scale,
+                                block, interpret=False):
+    """q: [B, H, <= W]; pages [P, page, W]; returns [B, H, rank]."""
+    b, h, qw = q.shape
+    page, w = pages.shape[1:]
+    hp = _pad_to_sublane_tile(h, pages.dtype)
+    qp = jnp.pad(q.astype(pages.dtype),
+                 ((0, 0), (0, hp - h), (0, w - qw)))
+    mp = page_table.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, hp, w), lambda i, *_: (i, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, hp, rank), lambda i, *_: (i, 0, 0),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, block * page, w), pages.dtype),
+            pltpu.SemaphoreType.DMA((2, block)),
+        ],
+    )
+    out = named_pallas_call(
+        "paged_decode_latent",
+        functools.partial(_decode_latent_kernel, page=page, scale=scale,
+                          rank=rank, block=block),
+        grid_spec=grid_spec, interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((b, hp, rank), q.dtype),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * int(b) * h * page * mp * (w + rank),
+            bytes_accessed=int(b) * mp * page * w * pages.dtype.itemsize,
+            transcendentals=int(b) * h * page * mp),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )(page_table, seq_lens, qp, pages)
+    return out[:, :h]
+
+
+def paged_attention_latent_reference(q, pages, page_table, seq_lens,
+                                     rank: int, scale: float):
+    """Dense-gather reference of :func:`paged_attention_latent`."""
+    b, h, qw = q.shape
+    page = pages.shape[1]
+    mp = page_table.shape[1]
+    rows = pages[page_table].astype(jnp.float32).reshape(
+        b, mp * page, -1)
+    logits = jnp.einsum("bhw,btw->bht", q.astype(jnp.float32),
+                        rows[..., :qw]) * scale
+    seen = (jnp.arange(mp * page, dtype=jnp.int32)[None]
+            < seq_lens[:, None])[:, None]
+    logits = jnp.where(seen, logits, _NEG_INF)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    p = jnp.where(seen, jnp.exp(logits - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)  # noqa: E741
+    out = jnp.einsum("bht,btr->bhr", p / jnp.maximum(l, 1e-30),
+                     rows[..., :rank])
+    return out.astype(q.dtype)
+
+
+def paged_latent_supported(pool_shape, rank: int,
+                           backend: Optional[str] = None) -> bool:
+    """Gate for the latent kernel: rows and their value part in whole
+    lane tiles, whole sublane tiles a page."""
+    from .flash_attention import _FORCE_DEPTH
+    if backend is None:
+        backend = jax.default_backend()
+    if backend != "tpu" and _FORCE_DEPTH == 0:
+        return False
+    page, w = pool_shape[1:]
+    return w % 128 == 0 and rank % 128 == 0 and page % 16 == 0
+
+
+def paged_attention_latent(q, pages, page_table, seq_lens, rank: int,
+                           scale: float, block: Optional[int] = None,
+                           interpret: bool = False):
+    """Single-token absorbed latent attention over a latent pool. q:
+    ``[B, H, rank + rope]``, a head's ``[q_nope W_UK^T | rotated
+    q_rope]``; pages ``[P, page, W]``, a position's row ``[latent
+    (rank) | rotated shared key (rope) | zeros]``; seq_lens [B] lengths
+    INCLUDING the appended token, 0 for a parked slot (zeros out).
+    Returns ``[B, H, rank]``: softmax over the context of ``q . row *
+    scale``, times the rows' first ``rank`` columns. Not under head
+    sharding (one latent serves every head)."""
+    if get_head_sharding() is not None:
+        raise NotImplementedError("latent pages under head sharding")
+    if block is None:
+        block = min(LATENT_BLOCK_PAGES, max(1, page_table.shape[1] // 8))
+    if interpret or paged_latent_supported(pages.shape, rank):
+        return _paged_decode_latent_pallas(
+            q, pages, page_table.astype(jnp.int32),
+            seq_lens.astype(jnp.int32), int(rank), float(scale), int(block),
+            interpret)
+    return paged_attention_latent_reference(q, pages, page_table, seq_lens,
+                                            int(rank), float(scale))
+
+
+# --------------------------------------------------------------------------
 # Head sharding (tensor-parallel serving over a `model` mesh axis)
 # --------------------------------------------------------------------------
 

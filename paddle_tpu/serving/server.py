@@ -2189,6 +2189,8 @@ def client_request(host: str, port: int, payload: Dict,
 
 def _build_model(name: str):
     import paddle_tpu as pt
+    from ..models.glm4_moe_lite import (Glm4MoeLiteForCausalLM,
+                                        glm4_7_flash, glm4_moe_lite_tiny)
     from ..models.gpt import (GPTForCausalLM, gpt_125m, gpt_1p3b,
                               gpt_350m, gpt_tiny)
     from ..models.smallthinker import (SmallThinkerForCausalLM,
@@ -2211,7 +2213,14 @@ def _build_model(name: str):
                "solar_open2_250b_cut": (
                    SolarOpen2ForCausalLM, lambda: solar_open2_250b(
                        4, experts_held=(0, 40), vocab_size=24576,
-                       dtype="bfloat16"))}
+                       dtype="bfloat16")),
+               "glm4_moe_lite_tiny": (Glm4MoeLiteForCausalLM,
+                                      glm4_moe_lite_tiny),
+               # the first of eight pipeline stages at the published
+               # widths: the dense layer and five expert layers
+               "glm4_7_flash_cut": (
+                   Glm4MoeLiteForCausalLM,
+                   lambda: glm4_7_flash(6, dtype="bfloat16"))}
     if name not in configs:
         raise SystemExit(f"unknown --model {name!r}; choose from "
                          f"{sorted(configs)}")

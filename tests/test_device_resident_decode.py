@@ -54,9 +54,10 @@ from paddle_tpu.distributed import fault_inject as fi
 from paddle_tpu.distributed.topology import make_serving_mesh
 from paddle_tpu.inference import SpeculativeConfig, create_decode_engine
 from paddle_tpu.inference import continuous_batching as cb
-from paddle_tpu.models import (SmallThinkerForCausalLM,
-                               SolarOpen2ForCausalLM, smallthinker_tiny,
-                               solar_open2_tiny)
+from paddle_tpu.models import (Glm4MoeLiteForCausalLM,
+                               SmallThinkerForCausalLM,
+                               SolarOpen2ForCausalLM, glm4_moe_lite_tiny,
+                               smallthinker_tiny, solar_open2_tiny)
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 from paddle_tpu.serving.prefix_cache import PrefixCache
 
@@ -68,8 +69,9 @@ ENGINE_KW = dict(num_slots=2, page_size=PAGE, max_seq_len=64,
 # and the speculative one builds its own arguments and never runs the
 # single-step program: they must come out the same all the more.
 # `rings` is the second decoder (window rings, grouped heads), `state`
-# the third (a state a slot updated in place beside the pages), at the
-# tiny size
+# the third (a state a slot updated in place beside the pages),
+# `latent` the fourth (one latent row a position in the allocator's
+# pages, one pool a layer), at the tiny size
 VARIANTS = {
     "plain": lambda: {},
     "prefix_cache": lambda: {"prefix_cache": PrefixCache(PAGE)},
@@ -80,6 +82,7 @@ VARIANTS = {
     "int8": lambda: {"kv_int8": True},
     "rings": lambda: {},
     "state": lambda: {},
+    "latent": lambda: {},
     "speculative": lambda: {
         "speculative": SpeculativeConfig(k=2, draft="ngram")},
 }
@@ -116,11 +119,17 @@ def state_model():
     return SolarOpen2ForCausalLM(solar_open2_tiny(), seed=3)
 
 
+@pytest.fixture(scope="module")
+def latent_model():
+    return Glm4MoeLiteForCausalLM(glm4_moe_lite_tiny(), seed=3)
+
+
 @pytest.fixture
-def build(model, rings_model, state_model):
+def build(model, rings_model, state_model, latent_model):
     """An engine of a variant, on the model the variant serves."""
     def make(variant, **kw):
-        m = {"rings": rings_model, "state": state_model}.get(variant, model)
+        m = {"rings": rings_model, "state": state_model,
+             "latent": latent_model}.get(variant, model)
         return _engine(m, **VARIANTS[variant](), **kw)
     return make
 

@@ -64,7 +64,7 @@ FLIGHT_KEYS = {
     "decode_ema_ms", "prefill_chunk_ema_ms", "prefill_debt_tokens",
     "prefill_chunk_tokens", "fused_step", "decode_steps_resident",
     "decode_steps_uploaded", "decode_steps_ahead", "decode_rows_dropped",
-    "state_pool_bytes", "state_rows_overwritten",
+    "state_pool_bytes", "state_rows_overwritten", "latent_pool_bytes",
     "model_counters", "window_ring_pages", "speculative", "mesh",
     "programs_launched", "step_programs", "ledger_events"}
 HEALTH_KEYS = {

@@ -372,6 +372,82 @@ def test_flash_grouped_solar_compiles(one_chip, s):
     _named_once(compiled, "flash_fwd")
 
 
+def test_paged_decode_latent_compiles(one_chip):
+    """The absorbed latent decode at the GLM-4.7-Flash cell's shapes: 48
+    slots of 20 heads against rows of 512 + 64 stored 640 wide, a table
+    of 544 pages. A pool whose rows are 576 wide is refused by the gate
+    (Mosaic refuses the page copy: the chip tiles the minor axis to 640
+    whatever the array says)."""
+    specs = (_bf16(one_chip, 48, 20, 576),
+             _bf16(one_chip, 9217, 64, 640),
+             _bf16(one_chip, 48, 544, dt=jnp.int32),
+             _bf16(one_chip, 48, dt=jnp.int32))
+    assert pa.paged_latent_supported(specs[1].shape, 512, backend="tpu")
+    assert not pa.paged_latent_supported((9217, 64, 576), 512, backend="tpu")
+    compiled = _compile(
+        lambda q, p, t, n: pa.paged_attention_latent(q, p, t, n, 512, 1 / 16),
+        *specs)
+    assert _kernel_calls(compiled) == 1
+    _named_once(compiled, "paged_decode_latent")
+
+
+@pytest.mark.parametrize("s", [1024, 32768])
+def test_flash_grouped_latent_prefill_compiles(one_chip, s):
+    """Latent attention's expanded prefill: 20 heads of 256, group 1,
+    the smallest and the largest bucket."""
+    q = _bf16(one_chip, 1, s, 20, 256)
+    compiled = _compile(
+        lambda q, k, v: fa.flash_attention_grouped(q, k, v, scale=1 / 16),
+        q, q, q)
+    assert _kernel_calls(compiled) == 1
+    _named_once(compiled, "flash_fwd")
+
+
+def test_latent_cell_decode_program_compiles(one_chip, monkeypatch):
+    """The GLM-4.7-Flash cell's whole decode program (the engine's
+    `_build_decode` over an abstract model at the published widths, 6
+    layers, 48 slots, 9,216 latent pages of 64): it fits the chip, the
+    page walk is ONE named kernel a layer and no V pool is an argument."""
+    from paddle_tpu.inference.continuous_batching import \
+        ContinuousBatchingEngine
+    from paddle_tpu.models import (Glm4MoeLiteForCausalLM, cache_layout,
+                                   glm4_7_flash)
+    from paddle_tpu.nn.layer import functional_state
+
+    def S(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    class Shapes:  # `jnp` whose zeros are shapes: no pool is allocated
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def zeros(shape, dtype):
+            return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+
+    monkeypatch.setattr(cache_layout, "jnp", Shapes())
+    model = Glm4MoeLiteForCausalLM(glm4_7_flash(6, dtype="bfloat16"),
+                                   abstract=True)
+    model.eval()
+    eng = ContinuousBatchingEngine(model, num_slots=48, page_size=64,
+                                   max_seq_len=34816, num_pages=9216)
+    monkeypatch.undo()
+    assert eng.latent_pool_bytes == 6 * 9217 * 64 * 640 * 2
+    state = jax.tree_util.tree_map(S, functional_state(model))
+    pools = jax.tree_util.tree_map(S, eng._pools)
+    assert pools["v"] == [None] * 6
+    packed = jax.ShapeDtypeStruct((48, eng.max_pages + 2), jnp.int32,
+                                  sharding=one_chip)
+    with fa.force_flash_for_aot():
+        compiled = eng._build_decode().lower(state, pools, packed).compile()
+    _fits_one_v5e(compiled)
+    _named(compiled, "paged_decode_latent", "moe_ffn_in", "moe_ffn_out",
+           "fused_argmax")
+    txt = compiled.as_text()
+    assert len(re.findall(
+        r"%paged_decode_latent[.\d]* = [^\n]*tpu_custom_call", txt)) == 6
+
+
 @pytest.mark.parametrize("tokens", [16, 8192], ids=["decode", "prefill"])
 def test_dropless_experts_compile(one_chip, tokens):
     """The expert layer's two regimes of one code path: 16 rows x 6
