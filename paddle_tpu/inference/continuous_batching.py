@@ -754,6 +754,13 @@ class ContinuousBatchingEngine:
         # its finish and the next admission, and a prompt writes the
         # whole row from zero (tests/test_solar_open2.py pins it)
         self.state_rows_overwritten = 0
+        # what whole-prompt prefills ran: the prompts' own positions
+        # and the buckets they were padded to (their quotient is the
+        # share of a prefill program's rows that are a prompt's; the
+        # flash forward skips the rest where a model hands it the
+        # lengths, the matmuls run them)
+        self.prefill_positions = 0
+        self.prefill_positions_padded = 0
         self._slots: List[Optional[DecodeRequest]] = \
             [None] * self.num_slots
         self._queue: List[DecodeRequest] = []
@@ -1612,6 +1619,8 @@ class ContinuousBatchingEngine:
             "state_pool_bytes": int(self.state_pool_bytes),
             "latent_pool_bytes": int(self.latent_pool_bytes),
             "state_rows_overwritten": int(self.state_rows_overwritten),
+            "prefill_positions": int(self.prefill_positions),
+            "prefill_positions_padded": int(self.prefill_positions_padded),
             "model_counters": {k: dict(v) for k, v in
                                self.model_counters.items()},
             "window_ring_pages": max(
@@ -2644,6 +2653,7 @@ class ContinuousBatchingEngine:
             tr.end(sp_admit, cached_pages=len(shared),
                    restored_pages=req.stats.restored_pages)
         sp_pref = (tr.begin("prefill", parent=tr.anchor, bucket=bucket,
+                            fill=round(len(suffix) / bucket, 4),
                             chained=chained)
                    if tr is not None else None)
 
@@ -2692,6 +2702,8 @@ class ContinuousBatchingEngine:
             self._unwind_prefill_failure(slot, req)
             raise
         self._pools = pools
+        self.prefill_positions += len(suffix)
+        self.prefill_positions_padded += bucket
         with self._phase("wait"):
             # blocks until the prefill program has run; the model's
             # counters, where it reports any, ride behind the token

@@ -327,7 +327,8 @@ class Glm4MoeLiteForCausalLM(ServedDecoderLM):
                 axis=-1)
             v = jnp.einsum("bsr,hrv->bshv", lat, _raw(blk.w_uv))
             q = jnp.concatenate([q_nope, q_rope], axis=-1)
-            o = chunk_attention(q, k, v, None, scale)
+            o = chunk_attention(q, k, v, None, scale,
+                                valid_len=prefill_lens)
         else:
             # absorbed: the slot's pages are keys and values at once
             qa = jnp.concatenate([

@@ -187,11 +187,18 @@ def dense_attention(q, k, v, window: Optional[int], scale: float):
     return out.reshape(b, s, hq, d).astype(q.dtype)
 
 
-def chunk_attention(q, k, v, window: Optional[int], scale: float):
+def chunk_attention(q, k, v, window: Optional[int], scale: float,
+                    valid_len=None):
+    """Causal attention of a right-padded prompt over itself.
+    ``valid_len`` ([B] int32): the prompts' true lengths, with which
+    the flash kernel skips the blocks of queries past a prompt's end
+    (those rows come back zero); the rows before it do not depend on
+    it, and the dense fallback takes no notice of it."""
     from ..ops.pallas.flash_attention import (flash_attention_grouped,
                                               flash_attention_supported)
     if flash_attention_supported(q.shape, k.shape):
-        return flash_attention_grouped(q, k, v, window=window, scale=scale)
+        return flash_attention_grouped(q, k, v, window=window, scale=scale,
+                                       lengths=valid_len)
     return dense_attention(q, k, v, window, scale)
 
 
@@ -510,7 +517,8 @@ class SmallThinkerForCausalLM(ServedDecoderLM):
                     cache, k, v, valid_len=prefill_lens,
                     ring=window is not None)
                 if prefill_lens is not None:
-                    att = chunk_attention(q, k, v, window, scale)
+                    att = chunk_attention(q, k, v, window, scale,
+                                          valid_len=prefill_lens)
                 elif window is None:
                     att = paged_attention_grouped(
                         q, nc.k_pages, nc.v_pages, nc.page_table,

@@ -343,7 +343,8 @@ class SolarOpen2ForCausalLM(ServedDecoderLM):
             nc = None if cache is None else kv_append(
                 cache, k, v, valid_len=prefill_lens)
             if prefill_lens is not None:
-                att = chunk_attention(q, k, v, None, scale)
+                att = chunk_attention(q, k, v, None, scale,
+                                      valid_len=prefill_lens)
             else:
                 att = paged_attention_grouped(
                     q, nc.k_pages, nc.v_pages, nc.page_table, nc.seq_lens,

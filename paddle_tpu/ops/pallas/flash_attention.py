@@ -128,19 +128,50 @@ def _normalised(acc, l_lanes, m):
             (m + jnp.log(denom))[:, :1])
 
 
-def _relevant(qb, kb, block_q, block_k, window):
+def _relevant(qb, kb, block_q, block_k, window, live=None):
     """Does the K block hold a key that some query of the Q block sees:
-    not wholly above the diagonal, nor wholly behind the window."""
+    not wholly above the diagonal, nor wholly behind the window.
+    ``live`` (``_live_blocks`` of a sequence): nor does it start at or
+    past the sequence's true length, queries or keys (no LIVE query sees
+    such a key: one at p < length sees keys <= p)."""
     relevant = kb * block_k <= (qb + 1) * block_q - 1
     if window is not None:
         # a query at p sees keys p - window + 1 .. p
         relevant = relevant & (
             (kb + 1) * block_k - 1 >= qb * block_q - (window - 1))
+    if live is not None:
+        q_blocks, last_k = live
+        relevant = relevant & (qb < q_blocks) & (kb <= last_k)
     return relevant
 
 
+def _live_blocks(lengths, block_q, block_k):
+    """What the kernel reads of the true lengths, a pair a sequence
+    ([2, B] int32): the Q blocks that start before the length (``qb <
+    it`` is ``qb * block_q < length``) and the last K block that does
+    (``kb <= it`` is ``kb * block_k < length``; 0 for an empty
+    sequence, whose Q blocks are all past the end). Worked out once a
+    call and not once a grid step: the compiler schedules 6 scalar
+    bundles a step for the pair, 12 for the length itself (PR 35)."""
+    return jnp.stack([-(-lengths // block_q),
+                      jnp.maximum(lengths - 1, 0) // block_k])
+
+
+def _fwd_kernel_ragged(live_ref, q_ref, k_ref, v_ref, o_ref, *scratch,
+                       **static):
+    """``_fwd_kernel`` under a scalar-prefetch grid: ``_live_blocks``
+    arrives first, in scalar memory, and no log-sum-exp rows leave (the
+    serving prefill keeps none, and their ``[block_q, 1]`` store and
+    copy cost 1.2 us a row of blocks at d 256, 0.4 at d 128: 1.5 ms of
+    a 32,768 bucket's call, PR 35)."""
+    b = pl.program_id(0)
+    _fwd_kernel(q_ref, k_ref, v_ref, o_ref, None, *scratch,
+                live=(live_ref[0, b], live_ref[1, b]), **static)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
-                *, scale, causal, block_q, block_k, nk, window=None):
+                *, scale, causal, block_q, block_k, nk, window=None,
+                live=None):
     qb = pl.program_id(2)
     kb = pl.program_id(3)
 
@@ -162,7 +193,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
     # pay either: no change with 256-wide sub-tiles, 6 % slower with
     # 128-wide ones (narrow bands reload the MXU's weights as often as
     # they stream rows); folding ``scale`` into the exponent: 0.4 %.
-    @pl.when(_relevant(qb, kb, block_q, block_k, window) if causal else True)
+    # With ``live`` a Q block past the sequence's end runs no step at
+    # all: _init and _finish alone, which write zeros (0 / 1e-30), so
+    # the padded rows that flow on through the row-wise matmuls and
+    # norms after the attention are finite and never uninitialised.
+    @pl.when(_relevant(qb, kb, block_q, block_k, window, live)
+             if causal else True)
     def _step():
         v_blk = v_ref[0, 0]
         where = (qb * block_q, kb * block_k, window) if causal else ()
@@ -184,7 +220,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc_ref, m_ref, l_run_ref,
         o_ref[0, 0] = o.astype(o_ref.dtype)
         # logsumexp per row, stored [BQ, 1] (lane-1 layout keeps the
         # block spec legal on TPU: last dim equals the array dim)
-        l_ref[0, 0] = lse
+        if l_ref is not None:
+            l_ref[0, 0] = lse
 
 
 def _fwd_single_block_kernel(q_ref, k_ref, v_ref, o_ref, l_ref,
@@ -350,7 +387,8 @@ def _spec_outer(block, d):
     block dim must divide 128 or span the array), and at d=128 the
     strided block DMA cost more than the transposes it saved (GPT-1.3B
     step 254.0 vs 251.7 ms)."""
-    return pl.BlockSpec((1, 1, block, d), lambda b, h, i, j: (b, h, i, 0),
+    return pl.BlockSpec((1, 1, block, d),
+                        lambda b, h, i, j, *_: (b, h, i, 0),
                         memory_space=pltpu.VMEM)
 
 
@@ -378,7 +416,7 @@ def _spec_inner(block, d, clamp=None, group=1):
 
 def _spec_lane1_outer(block):
     return pl.BlockSpec((1, 1, block, 1),
-                        lambda b, h, i, j: (b, h, i, 0),
+                        lambda b, h, i, j, *_: (b, h, i, 0),
                         memory_space=pltpu.VMEM)
 
 
@@ -431,6 +469,24 @@ def _kv_clamp(causal, block_q, block_k, window=None):
         ((i + 1) * block_q - 1) // block_k)
 
 
+def _spec_inner_ragged(block_q, block_k, d, window, group):
+    """``_spec_inner`` of a causal Q-outer kernel that was handed
+    ``_live_blocks`` (the last argument of a scalar-prefetch index map).
+    A live Q block streams what it streams without, capped by the last
+    K block that holds a live key; a Q block past its sequence's end
+    maps EVERY step to that one block, which the edge Q block before it
+    left in VMEM: nothing is fetched for it."""
+    visible = _kv_clamp(True, block_q, block_k, window)
+
+    def index(b, h, i, j, live):
+        last_k = live[1, b]
+        j = jnp.where(i < live[0, b],
+                      jnp.minimum(visible(i, j), last_k), last_k)
+        return (b, h // group, j, 0)
+
+    return pl.BlockSpec((1, 1, block_k, d), index, memory_space=pltpu.VMEM)
+
+
 def _q_clamp(causal, block_q, block_k):
     """For K-outer kernels: the first Q block that sees K block i."""
     if not causal:
@@ -439,12 +495,24 @@ def _q_clamp(causal, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, group=1,
-               window=None):
+               window=None, lengths=None):
     """``group`` > 1: k, v are [B, H // group, S, D] and query head h
     reads KV head h // group. ``window``: with ``causal``, a query at p
     sees keys p - window + 1 .. p; blocks behind the window are skipped
-    (neither fetched nor computed), the edge block is masked. Both
-    default to the plain kernel, whose program they leave untouched."""
+    (neither fetched nor computed), the edge block is masked.
+    ``lengths`` ([B] int32, with ``causal``): positions from a
+    sequence's length on are padding. They reach the kernel as
+    prefetched scalars (``_live_blocks``), and a Q block that starts at
+    or past the length runs no step, fetches no K or V block and writes
+    zeros; no key is masked for it (a live query at p sees keys <= p <
+    length), so every live row runs the blocks, in the order, it runs
+    without. The log-sum-exp rows are not written then (None comes back
+    in their place): forward only. On a v5e (PR 35,
+    tools/flash_report.py) a [1, 20, 32768, 256] call takes 76.1 ms
+    without lengths, 76.9 at a length of 32,768, 49.5 at 24,576 and
+    30.8 at 16,896: the steps of the rows past the length are still
+    grid steps, 0.1-0.2 us each. All three default to the plain kernel,
+    whose program they leave untouched."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     nk = sk // block_k
@@ -452,6 +520,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, group=1,
     if nk == 1:
         # one K block: plain softmax kernel, no streaming axis — every
         # grid dim is parallel and the online-softmax scratch vanishes
+        # (a prompt of the shortest bucket: ``lengths`` has no block to
+        # save there)
         out, lse = named_pallas_call(
             "flash_fwd_single",
             functools.partial(_fwd_single_block_kernel, scale=scale,
@@ -477,37 +547,51 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, group=1,
                                      "parallel")),
         )(q, k, v)
         return out, lse
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, nk=nk, **extra)
     grid = (b, h, sq // block_q, nk)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, nk=nk,
-                               **extra)
-    kvc = _kv_clamp(causal, block_q, block_k, window)
-    out, lse = named_pallas_call(
+    out_specs = [_spec_outer(block_q, d), _spec_lane1_outer(block_q)]
+    out_shape = [
+        jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
+    ]
+    scratch_shapes = [
+        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, 128), jnp.float32),
+        pltpu.VMEM((block_q, 128), jnp.float32),
+    ]
+    if lengths is None:
+        kvc = _kv_clamp(causal, block_q, block_k, window)
+        kernel = functools.partial(_fwd_kernel, **static)
+        layout = dict(grid=grid,
+                      in_specs=[_spec_outer(block_q, d),
+                                _spec_inner(block_k, d, kvc, group),
+                                _spec_inner(block_k, d, kvc, group)],
+                      out_specs=out_specs, scratch_shapes=scratch_shapes)
+        args = (q, k, v)
+    else:
+        if not causal:
+            raise ValueError("lengths: the causal forward alone takes them")
+        kernel = functools.partial(_fwd_kernel_ragged, **static)
+        kv = _spec_inner_ragged(block_q, block_k, d, window, group)
+        out_specs, out_shape = out_specs[:1], out_shape[:1]
+        layout = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[_spec_outer(block_q, d), kv, kv],
+            out_specs=out_specs, scratch_shapes=scratch_shapes))
+        args = (_live_blocks(lengths.astype(jnp.int32), block_q, block_k),
+                q, k, v)
+    out, *lse = named_pallas_call(
         "flash_fwd", kernel,
-        grid=grid,
-        in_specs=[_spec_outer(block_q, d),
-                  _spec_inner(block_k, d, kvc, group),
-                  _spec_inner(block_k, d, kvc, group)],
-        out_specs=[
-            _spec_outer(block_q, d),
-            _spec_lane1_outer(block_q),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
+        out_shape=out_shape,
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * sq * sk * d,
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
             transcendentals=b * h * sq * sk),
         compiler_params=_GRID_SEMANTICS,
-    )(q, k, v)
-    return out, lse
+        **layout,
+    )(*args)
+    return out, (lse[0] if lse else None)
 
 
 def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
@@ -664,11 +748,16 @@ def flash_attention_lse(q, k, v, causal: bool = False,
 def flash_attention_grouped(q, k, v, window: Optional[int] = None,
                             scale: Optional[float] = None,
                             block_q: int = DEFAULT_BLOCK_Q,
-                            block_k: int = DEFAULT_BLOCK_K):
+                            block_k: int = DEFAULT_BLOCK_K,
+                            lengths=None):
     """Causal forward for serving prefill, layout [B, S, H, D] with
     k, v [B, S, KVH, D], KVH dividing H: query head h reads KV head
     h // (H // KVH) through the K/V block index maps (K and V are not
     repeated). ``window``: a query at p sees keys p - window + 1 .. p.
+    ``lengths`` ([B] int32): the right-padded sequences' true lengths;
+    the rows of a Q block wholly past one come back zero and run no
+    attention (``_flash_fwd``), every row before one is what it is
+    without them.
     Forward only (no vjp): the same kernels as ``flash_attention``."""
     b, sq, h, d = q.shape
     group = h // k.shape[2]
@@ -676,7 +765,8 @@ def flash_attention_grouped(q, k, v, window: Optional[int] = None,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     out, _ = _flash_fwd(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
                         jnp.swapaxes(v, 1, 2), float(scale), True,
-                        block_q, block_k, group=group, window=window)
+                        block_q, block_k, group=group, window=window,
+                        lengths=lengths)
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -196,6 +196,59 @@ def module_compile_cache(tmp_path_factory):
 
 
 @pytest.fixture
+def check_padded_prefill_through_flash(monkeypatch):
+    """``check(model, flash_calls)``: two right-padded prompts (300 and
+    640 positions in a bucket of 640: with heads of 64 the flash kernel
+    in blocks of 128, 3 of the shorter prompt's 5 Q blocks live) through
+    a served decoder's prefill, first with the dense attention the CPU
+    takes, then with the flash kernel forced through the Pallas
+    interpreter. Each of the ``flash_calls`` calls was handed the
+    prompts' true lengths, the logits at a prompt's last position are
+    the dense path's, and what the rows of the skipped Q blocks (zeros)
+    become in the matmuls and norms after is finite."""
+    import functools
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    def check(model, flash_calls):
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        lens = jnp.asarray([300, 640], jnp.int32)
+        ids = np.random.default_rng(2).integers(
+            0, model.config.vocab_size, (2, 640))
+        ids[0, 300:] = 0  # right-padded, as the engine's
+        ids = jnp.asarray(ids, jnp.int32)
+
+        def logits():
+            hidden, _ = model.decode_hidden(ids, None, prefill_lens=lens)
+            return np.asarray(model.logits(hidden))
+
+        dense = logits()
+        handed = []
+        grouped = fa.flash_attention_grouped
+
+        def spy(*args, lengths=None, **kw):
+            handed.append(lengths)
+            return grouped(*args, lengths=lengths, **kw)
+
+        with monkeypatch.context() as m:
+            m.setattr(fa.pl, "pallas_call", functools.partial(
+                fa.pl.pallas_call, interpret=True))
+            m.setattr(fa, "flash_attention_supported", lambda *a, **k: True)
+            m.setattr(fa, "flash_attention_grouped", spy)
+            flash = logits()
+        assert len(handed) == flash_calls
+        for got in handed:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(lens))
+        for i, n in enumerate(np.asarray(lens)):
+            np.testing.assert_allclose(flash[i, n - 1], dense[i, n - 1],
+                                       atol=2e-5, rtol=0)
+        assert np.isfinite(flash).all()
+
+    return check
+
+
+@pytest.fixture
 def cpu_mesh_json():
     """Run a mesh payload in a FRESH subprocess pinned to an N-device
     CPU host platform (core/cpu_mesh.py): the child prints its result

@@ -520,14 +520,19 @@ def test_lse_rows_and_cotangent_match_dense(causal, d):
                                    err_msg=f"d{name} mismatch")
 
 
-def test_relevant_never_skips_a_visible_key():
+@pytest.mark.parametrize("length", [None, 512, 385, 129, 128, 1, 0])
+def test_relevant_never_skips_a_visible_key(length):
     """The scalar test that guards a grid step off: a block it skips
     holds no key any of its queries sees, and a block above the
     diagonal or behind the window is skipped (a block it passes may
-    still hide every key of SOME rows)."""
+    still hide every key of SOME rows). With a true length: a block of
+    queries or of keys that starts at or past it is skipped too, and no
+    LIVE query (one before the length) loses a key it sees."""
     for bq, bk, window in [(128, 128, None), (128, 128, 200),
                            (128, 128, 128), (256, 128, None),
                            (128, 256, 300)]:
+        live = (None if length is None else tuple(
+            int(x) for x in fa._live_blocks(jnp.int32(length), bq, bk)))
         for qb in range(4):
             for kb in range(4):
                 qp = np.arange(qb * bq, (qb + 1) * bq)[:, None]
@@ -535,6 +540,126 @@ def test_relevant_never_skips_a_visible_key():
                 seen = qp >= kp
                 if window is not None:
                     seen = seen & (kp > qp - window)
-                relevant = fa._relevant(qb, kb, bq, bk, window)
-                assert bool(relevant) == bool(seen.any()), (bq, bk, window,
-                                                            qb, kb)
+                relevant = bool(fa._relevant(qb, kb, bq, bk, window, live))
+                if length is None:
+                    assert relevant == bool(seen.any()), (bq, bk, window,
+                                                          qb, kb)
+                    continue
+                assert relevant == bool(seen.any() and qb * bq < length
+                                        and kb * bk < length), (
+                    bq, bk, window, length, qb, kb)
+                # what a live query sees lies in a block that runs
+                assert relevant or not (seen & (qp < length)).any()
+
+
+# -- the true lengths of right-padded sequences, as prefetched scalars -------
+
+def _ragged_lengths(kind, sq, bq):
+    """Two different lengths a case, the first as the case names it."""
+    first = {"full": sq, "past_a_block_edge": bq + 1, "one_block": bq,
+             "one": 1}[kind]
+    return first, sq - bq // 2
+
+
+@pytest.mark.parametrize("kind", ["full", "past_a_block_edge", "one_block",
+                                  "one"])
+@pytest.mark.parametrize("group,d", [(1, 128), (7, 64), (8, 256)])
+@pytest.mark.parametrize("window", [None, 200, 128],
+                         ids=["no_window", "edge_in_tile", "one_block"])
+@pytest.mark.parametrize("layout", ["nk3", "bq_ne_bk"])
+def test_forward_with_lengths_leaves_live_rows_as_they_were(layout, window,
+                                                            group, d, kind):
+    """Two right-padded sequences of different true lengths: every row
+    before a sequence's length is BIT-equal to the call without lengths
+    (it runs the same blocks in the same order), the rows of a Q block
+    wholly past the length are zero, nothing is left uninitialised or
+    infinite, and no log-sum-exp rows are written (forward only)."""
+    sq, sk, bq, bk = _LAYOUTS[layout]
+    rng = np.random.default_rng(hash((layout, window, group, kind)) % 2 ** 31)
+    q = jnp.asarray(rng.standard_normal((2, group, sq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, 1, sk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, 1, sk, d)), jnp.float32)
+    lengths = _ragged_lengths(kind, sq, bq)
+    want, _ = fa._flash_fwd(q, k, v, d ** -0.5, True, bq, bk, group=group,
+                            window=window)
+    out, lse = fa._flash_fwd(q, k, v, d ** -0.5, True, bq, bk, group=group,
+                             window=window,
+                             lengths=jnp.asarray(lengths, jnp.int32))
+    assert lse is None
+    assert np.isfinite(np.asarray(out)).all()
+    for b, n in enumerate(lengths):
+        np.testing.assert_array_equal(np.asarray(out[b, :, :n]),
+                                      np.asarray(want[b, :, :n]))
+        assert not np.asarray(out[b, :, -(-n // bq) * bq:]).any()
+
+
+def test_an_empty_sequence_comes_back_zero():
+    """A length of 0 (an empty slot of a batch): every Q block is past
+    the end, no block index leaves the array, the rows are zero."""
+    rng = np.random.default_rng(5)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, 256, 2, 64)), jnp.float32)
+               for _ in range(3))
+    out = fa.flash_attention_grouped(q, k, v, block_q=128, block_k=128,
+                                     lengths=jnp.asarray([0, 256]))
+    want = fa.flash_attention_grouped(q, k, v, block_q=128, block_k=128)
+    assert not np.asarray(out[0]).any()
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(want[1]))
+
+
+def test_lengths_are_for_the_causal_forward_alone():
+    q = jnp.zeros((1, 2, 256, 64))
+    with pytest.raises(ValueError, match="causal"):
+        fa._flash_fwd(q, q, q, 0.125, False, 128, 128,
+                      lengths=jnp.asarray([7]))
+
+
+def _pallas_calls(fn, *args):
+    """The ``pallas_call`` equations of ``fn``'s jaxpr, custom_vjp
+    bodies included: (kernel name, operands, scalar-prefetch operands)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((
+                    eqn.params["name"], len(eqn.invars),
+                    eqn.params["grid_mapping"].num_index_operands))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("entry", ["flash_attention", "flash_attention_lse",
+                                   "flash_attention_grouped", "grad",
+                                   "with_lengths"])
+def test_without_lengths_the_call_has_no_scalar_prefetch_operand(entry):
+    """``lengths=None`` builds the call it always built: q, k, v and no
+    scalar-prefetch operand, in the trainer's forward and backward, the
+    GPT prefill's entry and the grouped one (their programs are not this
+    mechanism's to change); with lengths the one forward call gains
+    exactly one."""
+    q = jnp.zeros((1, 256, 2, 64), jnp.float32)
+    kw = dict(block_q=128, block_k=128)
+    fns = {
+        "flash_attention": lambda x: fa.flash_attention(x, x, x, causal=True,
+                                                        **kw),
+        "flash_attention_lse": lambda x: fa.flash_attention_lse(
+            x, x, x, causal=True, **kw),
+        "flash_attention_grouped": lambda x: fa.flash_attention_grouped(
+            x, x, x, **kw),
+        "grad": jax.grad(lambda x: fa.flash_attention(
+            x, x, x, causal=True, **kw).sum()),
+        "with_lengths": lambda x: fa.flash_attention_grouped(
+            x, x, x, lengths=jnp.asarray([100]), **kw),
+    }
+    calls = _pallas_calls(fns[entry], q)
+    fwd = [c for c in calls if c[0] == "flash_fwd"]
+    assert len(fwd) == 1, calls
+    if entry == "with_lengths":
+        assert fwd == [("flash_fwd", 4, 1)]
+    else:
+        assert fwd == [("flash_fwd", 3, 0)]
+        assert all(c[2] == 0 for c in calls), calls
+        assert len(calls) == (3 if entry == "grad" else 1), calls

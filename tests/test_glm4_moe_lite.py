@@ -384,3 +384,16 @@ def test_a_planted_fault_fails_the_rehearsals_limit(rehearsed, fault):
         assert mean <= limit / 10
     else:
         assert mean > 2 * limit, (fault, mean)
+
+
+def test_a_padded_prompt_hands_flash_its_true_lengths(
+        check_padded_prefill_through_flash):
+    """Latent attention's expanded prefill (keys of 48 + 16, values of
+    64, a bucket of 640: the flash kernel in blocks of 128) hands it
+    ``prefill_lens`` in every layer, and the logits at a prompt's last
+    position are the dense path's."""
+    cfg = glm4_moe_lite_tiny(qk_nope_head_dim=48, qk_rope_head_dim=16,
+                             v_head_dim=64, max_position_embeddings=1024,
+                             prefill_segment=1024)
+    check_padded_prefill_through_flash(Glm4MoeLiteForCausalLM(cfg, seed=11),
+                                       flash_calls=cfg.num_hidden_layers)

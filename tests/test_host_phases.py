@@ -65,6 +65,7 @@ FLIGHT_KEYS = {
     "prefill_chunk_tokens", "fused_step", "decode_steps_resident",
     "decode_steps_uploaded", "decode_steps_ahead", "decode_rows_dropped",
     "state_pool_bytes", "state_rows_overwritten", "latent_pool_bytes",
+    "prefill_positions", "prefill_positions_padded",
     "model_counters", "window_ring_pages", "speculative", "mesh",
     "programs_launched", "step_programs", "ledger_events"}
 HEALTH_KEYS = {

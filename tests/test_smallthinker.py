@@ -296,6 +296,18 @@ def test_no_token_is_dropped_under_a_skewed_router(rows):
     assert not np.asarray(out2)[1::2].any()
 
 
+def test_a_padded_prompt_hands_flash_its_true_lengths(
+        check_padded_prefill_through_flash):
+    """Head size 64 and a bucket of 640 take the flash kernel in blocks
+    of 128: every layer, window or global, hands it ``prefill_lens``,
+    and the logits at a prompt's last position are the dense path's
+    (the Q blocks past the shorter prompt's end come back zero and run
+    on through the matmuls and norms after: finite everywhere)."""
+    cfg = smallthinker_tiny(head_dim=64, max_position_embeddings=1024)
+    check_padded_prefill_through_flash(SmallThinkerForCausalLM(cfg, seed=11),
+                                       flash_calls=cfg.num_hidden_layers)
+
+
 # -- the kernels against jax.numpy, in interpret mode --------------------------
 
 @pytest.fixture

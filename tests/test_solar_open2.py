@@ -309,3 +309,15 @@ def test_the_server_builds_the_presets_and_serves_one():
     with pytest.raises(UnsupportedCacheLayout):
         ServingServer(model, port=0, prefix_cache=True, num_slots=2,
                       page_size=PAGE, max_seq_len=64)
+
+
+def test_a_padded_prompt_hands_flash_its_true_lengths(
+        check_padded_prefill_through_flash):
+    """The softmax layer of a period (head size 64, a bucket of 640: the
+    flash kernel in blocks of 128) hands it ``prefill_lens``; the KDA
+    layers after it read the rows of the skipped Q blocks as zeros and
+    the logits at a prompt's last position are the dense path's."""
+    cfg = solar_open2_tiny(head_dim=64, max_position_embeddings=1024,
+                           prefill_segment=1024)
+    check_padded_prefill_through_flash(SolarOpen2ForCausalLM(cfg, seed=11),
+                                       flash_calls=1)

@@ -403,6 +403,25 @@ def test_flash_grouped_latent_prefill_compiles(one_chip, s):
     _named_once(compiled, "flash_fwd")
 
 
+@pytest.mark.parametrize("s,h,kvh,d,window", [
+    (32768, 20, 20, 256, None), (32768, 64, 8, 128, None),
+    (8192, 28, 4, 128, 4096)], ids=["latent", "solar", "window"])
+def test_flash_grouped_with_lengths_compiles(one_chip, s, h, kvh, d, window):
+    """The serving prefill's forward handed the prompts' true lengths
+    (scalar prefetch: a pair of block counts a sequence in scalar
+    memory, read by the K and V index maps and the step's guard), at
+    the three routed cells' largest buckets: still ONE call named
+    ``flash_fwd`` (the roofline readers match the stem)."""
+    q = _bf16(one_chip, 1, s, h, d)
+    kv = _bf16(one_chip, 1, s, kvh, d)
+    compiled = _compile(
+        lambda q, k, v, n: fa.flash_attention_grouped(
+            q, k, v, window=window, lengths=n),
+        q, kv, kv, _bf16(one_chip, 1, dt=jnp.int32))
+    assert _kernel_calls(compiled) == 1
+    _named_once(compiled, "flash_fwd")
+
+
 def test_latent_cell_decode_program_compiles(one_chip, monkeypatch):
     """The GLM-4.7-Flash cell's whole decode program (the engine's
     `_build_decode` over an abstract model at the published widths, 6

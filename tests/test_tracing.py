@@ -273,6 +273,28 @@ class TestEngineTracing:
             < names.index("complete")
         _lint_ok(traces)
 
+    def test_prefill_says_how_full_its_bucket_is(self, model):
+        """A whole-prompt prefill pads its prompt to a bucket: the span
+        carries the bucket and the share of it that is the prompt's,
+        ``flight_summary()`` the sums of both over the engine's life
+        (what the flash forward skips of a bucket and what the matmuls
+        still run of it)."""
+        tr = SpanTracer(sample_rate=1.0)
+        eng = _engine(model, tracer=tr)
+        assert eng.flight_summary()["prefill_positions_padded"] == 0
+        eng.submit(np.arange(1, 7, dtype=np.int32), 2)     # 6 of 8
+        eng.submit(np.arange(20, 31, dtype=np.int32), 2)   # 11 of 16
+        eng.run()
+        eng.close()
+        spans = sorted((s["args"] for t in tr.finished()
+                        for s in t["spans"] if s["name"] == "prefill"),
+                       key=lambda a: a["bucket"])
+        assert [(a["bucket"], a["fill"]) for a in spans] == \
+            [(8, 0.75), (16, 0.6875)]
+        card = eng.flight_summary()
+        assert card["prefill_positions"] == 6 + 11
+        assert card["prefill_positions_padded"] == 8 + 16
+
     def test_chunked_prefill_tree_has_chunk_spans(self, model):
         tr = SpanTracer(sample_rate=1.0)
         eng = _engine(model, tracer=tr, prefill_chunk_tokens=8)
