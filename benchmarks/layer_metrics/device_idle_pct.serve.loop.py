@@ -1,0 +1,15 @@
+"""Server loop: share of the traced window the device idled, outside every
+program, while the engine thread was in the server's loop between two
+`step()` calls (`[t_us - gap_us, t_us]`: the inbox drain and the swap check;
+also its sleep when nothing was left to do).
+Share of the TRACED WINDOW (the denominator of `device_idle_pct.serve`);
+read from the record's `phases` and the first device plane's ops and
+programs, the host's clock laid on the trace's by
+`benchmarks/host_clock.py`; off by at most what `host_clock_bracket_us`
+allows. Nothing to pair (an older program's records carry no `phases`,
+no single shift, an empty bracket): nothing returned."""
+from benchmarks import host_clock
+
+
+def read(art):
+    return host_clock.read(art, "loop")

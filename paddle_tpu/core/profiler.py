@@ -15,10 +15,13 @@ op, the benchmark's ``--trace 1``) it is an event on the profiler's
 host plane, in the same ``.xplane.pb`` and on the same clock as the
 device's ``XLA Ops`` / ``XLA Modules`` lines; with no session it costs
 a flag check. Given a ``HostPhases`` accumulator, the same enter and
-exit also read ``time.monotonic`` once each and add the phase's own
-time to it — the engine's step timeline is fed from there
-(``host_us``), always on. ``RecordEvent`` holds the same annotation, so
-the marker API and the engine reach the profiler by one path.
+exit also read ``time.monotonic`` once each, add the phase's own time
+to it and keep the stretch itself, ``(name, start, end, kind)`` — the
+engine's step timeline is fed from there (``host_us``: how long;
+``phases``: when, which is what lays a phase against the device's idle
+gaps once the trace's clock is tied to ``time.monotonic``), always on.
+``RecordEvent`` holds the same annotation, so the marker API and the
+engine reach the profiler by one path.
 """
 
 from __future__ import annotations
@@ -62,44 +65,58 @@ class HostPhases:
     """One thread's per-step accumulator of host-phase time.
 
     ``us[name]`` holds the seconds spent in phase ``name`` since the
-    owner last emptied it (``take()``); ``t`` is the newest stamp any
-    phase read, so a caller that needs "now" at a phase boundary reads
-    no clock of its own. Phases never overlap: entering one inside
-    another pauses the outer (its annotation closes, its time stops)
-    and resumes it on exit, so the sum over names is wall time spent
-    inside phases, each second counted once. Not thread-safe: one
-    accumulator belongs to one thread at a time (the engine's)."""
+    owner last emptied it (``take()``), and ``segs`` the stretches
+    themselves in the order they ended: ``(name, start, end, kind)`` on
+    ``time.monotonic``, one for every stretch a phase ran, so a phase
+    paused by an inner one leaves two. ``kind`` is what the caller gave
+    ``phase(...)``: the program a ``launch`` dispatched or a ``wait``
+    fetched, else ``None``. ``t`` is the newest stamp any phase read,
+    so a caller that needs "now" at a phase boundary reads no clock of
+    its own. Phases never overlap: entering one inside another pauses
+    the outer (its annotation closes, its time stops) and resumes it
+    on exit, so the sum over names is wall time spent inside phases,
+    each second counted once, and the segments are disjoint. Not
+    thread-safe: one accumulator belongs to one thread at a time (the
+    engine's)."""
 
-    __slots__ = ("us", "t", "_open")
+    __slots__ = ("us", "segs", "t", "_open")
 
     def __init__(self):
         self.us: dict = {}
+        self.segs: list = []
         self.t = 0.0
         self._open: Optional["_Phase"] = None
 
-    def phase(self, name: str) -> "_Phase":
+    def phase(self, name: str, kind: Optional[str] = None) -> "_Phase":
         """``with acc.phase(name):`` — ``host_phase(name)`` whose own
-        time is also added to ``acc.us[name]``."""
-        return _Phase(self, name)
+        time is also added to ``acc.us[name]`` and whose stretches go
+        to ``acc.segs``, tagged ``kind``."""
+        return _Phase(self, name, kind)
 
-    def take(self) -> dict:
-        """The accumulated ``{phase: seconds}``, and start afresh."""
-        out, self.us = self.us, {}
+    def take(self) -> tuple:
+        """The accumulated ``({phase: seconds}, [segments])``, and
+        start afresh."""
+        out, self.us, self.segs = (self.us, self.segs), {}, []
         return out
 
 
 class _Phase:
-    """One ``with acc.phase(name):`` block. ``t0`` / ``t1`` are its
-    entry and exit on ``time.monotonic`` (for callers that feed an
-    older counter or a span from the same stamps). The annotation's
+    """One ``with acc.phase(name, kind):`` block. ``t0`` / ``t1`` are
+    its entry and exit on ``time.monotonic`` (for callers that feed an
+    older counter or a span from the same stamps); every stretch it
+    ran goes to ``acc.segs`` from the stamps it reads anyway. The
+    annotation's
     own enter and exit fall inside the stamps, so what a phase costs
     is counted as that phase's."""
 
-    __slots__ = ("name", "t0", "t1", "_acc", "_outer", "_since", "_ann")
+    __slots__ = ("name", "kind", "t0", "t1", "_acc", "_outer", "_since",
+                 "_ann")
 
-    def __init__(self, acc: HostPhases, name: str):
+    def __init__(self, acc: HostPhases, name: str,
+                 kind: Optional[str] = None):
         self._acc = acc
         self.name = name
+        self.kind = kind
 
     def _run(self, now: float) -> None:
         """Start, or resume after an inner phase: a new annotation."""
@@ -114,6 +131,7 @@ class _Phase:
         acc = self._acc
         acc.t = now = _monotonic()
         acc.us[self.name] = acc.us.get(self.name, 0.0) + (now - self._since)
+        acc.segs.append((self.name, self._since, now, self.kind))
         return now
 
     def __enter__(self) -> "_Phase":
@@ -194,26 +212,6 @@ def reset_profiler() -> None:
 def profiler_events() -> List[_Event]:
     with _STATE.lock:
         return list(_STATE.events)
-
-
-def profiler_active() -> bool:
-    """Cheap enabled-check for external event sources (the serving
-    span tracer bridges through this before paying any work)."""
-    return _STATE.enabled or bool(get_flag("profiler_enabled"))
-
-
-def external_event(name: str, start_us: float, end_us: float,
-                   annotation: Optional[str] = None) -> None:
-    """Inject an externally-timed host event (perf_counter/monotonic
-    microseconds — the same clock domain on Linux). The serving span
-    tracer (serving/tracing.py) uses this so request spans land in the
-    same ``export_chrome_trace`` as RecordEvent markers."""
-    if not profiler_active():
-        return
-    evt = _Event(name, float(start_us), float(end_us),
-                 threading.get_ident(), annotation)
-    with _STATE.lock:
-        _STATE.events.append(evt)
 
 
 def export_chrome_trace(path: str) -> None:

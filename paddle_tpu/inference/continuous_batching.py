@@ -1461,6 +1461,24 @@ class ContinuousBatchingEngine:
           ``emit``) and ``other``, what they leave of ``ms``. Phases
           never overlap. ``launch`` is every jit CALL (it returns
           futures), ``wait`` every blocking read of a device result.
+        - ``phases``: the same phases as the stretches they ran in, in
+          order: ``[name, start_us, us]`` with ``start_us`` counted
+          from ``t_us``, one entry for every stretch (a phase paused
+          by an inner one leaves two), inside ``[0, ms]``, disjoint,
+          and summed by name equal to ``host_us`` to the rounding
+          (``other`` is what they leave). A ``launch`` entry carries
+          a fourth element, the kind of program it dispatched, under
+          the name ``programs`` counts it by (``prefill``,
+          ``prefill_chained`` — a chunk is one of the two —,
+          ``decode``, ``verify``, ``restore``; ``spill`` for the
+          gather of a page on its way to a spill tier, which
+          ``programs`` does not count), and a ``wait`` entry the kind
+          it fetched. ``commit`` and ``loop`` need no entry: they are
+          ``[t_us + ms, + commit_us]`` and ``[t_us - gap_us, t_us]``.
+          A sum says how long; only a place on the clock can be laid
+          against the device's idle gaps (benchmarks/host_clock.py
+          ties the trace's clock to this one from the ``launch`` and
+          ``wait`` entries and the programs they bracket).
         - ``commit_us``: this method itself (page accounting for every
           slot, ``occupancy()``, the record) — what the always-on
           timeline costs. It runs after ``ms`` is taken.
@@ -1529,13 +1547,24 @@ class ContinuousBatchingEngine:
         for r in self._slots:
             if r is not None:
                 self._account_req_pages(r, now)
-        host = {k: round(v * 1e6, 1) for k, v in self._host.take().items()}
+        us, segs = self._host.take()
+        host = {k: round(v * 1e6, 1) for k, v in us.items()}
         host["other"] = round((now - t_step) * 1e6 - sum(host.values()), 1)
+        # the same stretches with their places: both ends rounded on
+        # the step's own scale, so neighbours that share a stamp still
+        # touch and none overlaps
+        phases = []
+        for name, a, b, kind in segs:
+            a = round((a - t_step) * 1e6, 1)
+            us = round(round((b - t_step) * 1e6, 1) - a, 1)
+            phases.append((name, a, us) if kind is None
+                          else (name, a, us, kind))
         entry: Dict[str, Any] = {
             "step": self.steps,
             "t_us": t_step * 1e6,
             "ms": round((now - t_step) * 1e3, 4),
             "host_us": host,
+            "phases": phases,
             "gap_us": 0.0 if self._tl_end is None
             else round((t_step - self._tl_end) * 1e6, 1),
             "programs": self._tl_programs,
@@ -1788,10 +1817,10 @@ class ContinuousBatchingEngine:
             # spill-side device IO: the page's KV is leaving the
             # device for a spill tier (the cache decides which)
             self.ledger.record("spill", None, pages=[int(page)])
-        with self._phase("launch"):
+        with self._phase("launch", "spill"):
             k, v, ks, vs = self._gather_jit(
                 self._pools, jnp.asarray(page, jnp.int32))
-        with self._phase("wait"):
+        with self._phase("wait", "spill"):
             k, v = np.asarray(k), np.asarray(v)
             ks = None if ks is None else np.asarray(ks)
             vs = None if vs is None else np.asarray(vs)
@@ -1857,7 +1886,7 @@ class ContinuousBatchingEngine:
                                pages=[int(p) for p in pages])
         with self._phase("upload"):
             args = (self._pools, jnp.asarray(page_idx), k, v, ks, vs)
-        with self._phase("launch") as launch:
+        with self._phase("launch", "restore") as launch:
             with count_op_calls() as c:
                 self._pools = self._splice_jit(*args)
         self._tl_add_ms("splice_ms", launch.t1 - launch.t0)
@@ -2648,6 +2677,7 @@ class ContinuousBatchingEngine:
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :len(suffix)] = suffix
         chained = cached_len > 0
+        kind = "prefill_chained" if chained else "prefill"
         jit = self._get_prefill(chained)
         if tr is not None:
             tr.end(sp_admit, cached_pages=len(shared),
@@ -2662,7 +2692,6 @@ class ContinuousBatchingEngine:
             from ..distributed.fault_inject import fault_point
             self._check_pools_live("prefill")
             fault_point("serving.prefill")
-            kind = "prefill_chained" if chained else "prefill"
             with self._phase("upload"):
                 args = (self._fresh_state(refresh=True), self._pools,
                         jnp.asarray(row[None]),
@@ -2673,7 +2702,7 @@ class ContinuousBatchingEngine:
                     # whose rings the window layers write, whose row
                     # the state layers
                     args += (jnp.asarray([slot], jnp.int32),)
-            with self._phase("launch"):
+            with self._phase("launch", kind):
                 with count_op_calls() as c:
                     out = jit(*args)
             self._record_programs(kind, c.count)
@@ -2704,14 +2733,13 @@ class ContinuousBatchingEngine:
         self._pools = pools
         self.prefill_positions += len(suffix)
         self.prefill_positions_padded += bucket
-        with self._phase("wait"):
+        with self._phase("wait", kind):
             # blocks until the prefill program has run; the model's
             # counters, where it reports any, ride behind the token
             got = np.asarray(nxt).reshape(-1)
             tok = int(got[0])
             if got.size > 1:
-                self._fold_stats(
-                    "prefill_chained" if chained else "prefill", got[1:])
+                self._fold_stats(kind, got[1:])
         # the first token exists from here: `now` is the end of that
         # wait, and prefill_ms the argument build, the dispatch and
         # the wait together, from the phases' own stamps
@@ -2821,6 +2849,7 @@ class ContinuousBatchingEngine:
         # prefill program (chained=False), so a suffix that fits in one
         # chunk is byte-for-byte the whole-prefill admission
         chained = done > 0
+        kind = "prefill_chained" if chained else "prefill"
         jit = self._get_prefill(chained)
         row = self._table[slot]  # the live row: insert() retargets it
 
@@ -2829,7 +2858,6 @@ class ContinuousBatchingEngine:
             from ..distributed.fault_inject import fault_point
             self._check_pools_live("prefill")
             fault_point("serving.prefill")
-            kind = "prefill_chained" if chained else "prefill"
             with self._phase("upload"):
                 # a copy of the row: the upload may alias what it is
                 # given (a CPU device does), and the mirrors are written
@@ -2844,7 +2872,7 @@ class ContinuousBatchingEngine:
                     # whose rings the window layers write, whose row
                     # the state layers
                     args += (jnp.asarray([slot], jnp.int32),)
-            with self._phase("launch"):
+            with self._phase("launch", kind):
                 with count_op_calls() as c:
                     out = jit(*args)
             self._record_programs(kind, c.count)
@@ -2909,7 +2937,7 @@ class ContinuousBatchingEngine:
             return True
         # last chunk: its logits ARE the whole prefill's logits — emit
         # the first token and promote the slot to the decode batch
-        with self._phase("wait"):
+        with self._phase("wait", kind):
             tok = int(nxt)
         with self._phase("emit"):
             req.stats.prefill_attempts += 1
@@ -3075,7 +3103,7 @@ class ContinuousBatchingEngine:
                         jnp.asarray(self._table), jnp.asarray(self._lens),
                         jnp.asarray(tokens), jnp.asarray(valid), key)
             stamps.append(upload.t0)
-            with self._phase("launch"):
+            with self._phase("launch", "verify"):
                 with count_op_calls() as c:
                     out = self._verify_jit(*args)
             self._record_programs("verify", c.count)
@@ -3092,7 +3120,7 @@ class ContinuousBatchingEngine:
         t0v, t1v = stamps[0], self._host.t
         self._tl_add_ms("verify_ms", t1v - t0v)
         self._pools = pools
-        with self._phase("wait"):
+        with self._phase("wait", "verify"):
             accept = np.asarray(accept)
             resid = np.asarray(resid)
             full = np.asarray(full)
@@ -3341,7 +3369,7 @@ class ContinuousBatchingEngine:
             if send is not None:
                 held = self._place_resident(send)
             args = (self._fresh_state(), self._pools, held)
-        with self._phase("launch") as launch:
+        with self._phase("launch", "decode") as launch:
             with count_op_calls() as c:
                 nxt, self._pools, held = self._decode_jit(*args)
             # the newest launch's outputs; a masked step leaves nothing
@@ -3388,7 +3416,7 @@ class ContinuousBatchingEngine:
         (the step's one fetch), advance the mirrors, emit, finish.
         Rows of requests that left their slot since the launch (a
         finish in the step before, under this one) are dropped."""
-        with self._phase("wait"):
+        with self._phase("wait", "decode"):
             nxt = np.asarray(pend.pop("nxt"))
             if nxt.size > self.num_slots:
                 # the model's counters came back behind the tokens

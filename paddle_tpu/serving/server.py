@@ -1894,8 +1894,8 @@ class ServingServer:
         if sp is not None:
             g["step_programs"] = sp.get("decode", 0)
         # step timeline (r16): per-kind program LAUNCH totals, the
-        # engine step count, and the latest step's decode wall ms;
-        # new entries since the last scrape feed the serving_step_ms
+        # engine step count, and the latest step's wall ms; new
+        # entries since the last scrape feed the serving_step_ms
         # histogram (ServingMetrics.step_ms)
         for kind, n in dict(getattr(eng, "programs_launched", {})
                             or {}).items():
@@ -1904,7 +1904,6 @@ class ServingServer:
         tl = getattr(eng, "step_timeline", lambda: [])()
         if tl:
             g["step_last_ms"] = tl[-1].get("ms", 0.0)
-            g["step_last_decode_ms"] = tl[-1].get("decode_ms", 0.0)
             self._feed_step_histogram(tl)
         # program-cost gauges (r16 satellite): flops / bytes-accessed
         # per program kind from jit cost_analysis at build time

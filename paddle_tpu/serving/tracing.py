@@ -38,11 +38,12 @@ links into one tree.
 Export: ``to_dict`` span trees (the ``trace`` server op / bench
 input, validated by tools/trace_lint.py) and Chrome trace-event JSON
 (``to_chrome`` / ``chrome_events``) mergeable with ``jax.profiler``
-device traces via tools/merge_traces.py. When core/profiler.py is
-enabled, finished spans are also injected as RecordEvent-compatible
-host events, so ``export_chrome_trace`` shows serving spans next to
-the jitted-step markers (which trace under ``jax.named_scope`` — see
-the engine's step builders — and therefore appear inside XLA traces).
+device traces via tools/merge_traces.py (which lines sources up at
+their first events: merged spans and device ops share no clock). The
+jitted steps trace under ``jax.named_scope`` — see the engine's step
+builders — and therefore appear inside XLA traces. Spans are stamped
+on ``time.monotonic`` (``now_us``), the clock of the step timeline's
+``t_us`` and ``phases``.
 
 Debug mode: PT_SERVING_DEBUG=1 (see server.py) is now this tracer at
 ``sample_rate=1.0`` with the ``stderr_span_sink`` — one event
@@ -204,22 +205,17 @@ class SpanTracer:
 
     ``sample_rate`` in [0, 1]: deterministic accumulator sampling.
     ``on_span(kind, trace_id, span_dict)`` is the optional live sink
-    (``stderr_span_sink`` — the PT_SERVING_DEBUG lifecycle stream);
-    ``profiler_bridge`` additionally injects finished spans into
-    core/profiler.py's host-event buffer whenever that profiler is
-    enabled, so one ``export_chrome_trace`` carries both."""
+    (``stderr_span_sink`` — the PT_SERVING_DEBUG lifecycle stream)."""
 
     def __init__(self, sample_rate: float = 0.0, max_traces: int = 64,
                  max_spans_per_trace: int = 4096,
-                 on_span: Optional[Callable] = None,
-                 profiler_bridge: bool = True):
+                 on_span: Optional[Callable] = None):
         self.sample_rate = float(sample_rate)
         if not 0.0 <= self.sample_rate <= 1.0:
             raise ValueError(
                 f"sample_rate must be in [0, 1], got {self.sample_rate}")
         self.max_spans_per_trace = int(max_spans_per_trace)
         self.on_span = on_span
-        self.profiler_bridge = bool(profiler_bridge)
         self._ring: "deque[Dict]" = deque(maxlen=int(max_traces))
         self._events: "deque[Dict]" = deque(maxlen=256)
         self._acc = 0.0
@@ -300,9 +296,6 @@ class SpanTracer:
                 self.on_span(kind, trace.trace_id, span.to_dict())
             except Exception:
                 pass  # a sink must never break the serving path
-        if kind != "begin" and self.profiler_bridge \
-                and span.t1_us is not None:
-            _bridge_profiler(trace.trace_id, span)
 
     def annotate(self, name: str, **args) -> None:
         """Tracer-level event not tied to one request (resurrection
@@ -429,20 +422,3 @@ def stderr_span_sink(kind: str, trace_id: Optional[str],
     print(f"[pt-serving-trace {time.monotonic():.3f}] {kind} "
           f"{span.get('name')} trace={tid}{dur} {kv}".rstrip(),
           file=sys.stderr, flush=True)
-
-
-def _bridge_profiler(trace_id: str, span: Span) -> None:
-    """Inject a closed span into core/profiler.py's host-event buffer
-    when that profiler is enabled — serving spans then ride the same
-    ``export_chrome_trace`` as the RecordEvent markers."""
-    try:
-        from ..core import profiler
-    except Exception:  # profiler imports jax; never break serving
-        return
-    if not getattr(profiler, "profiler_active", lambda: False)():
-        return
-    try:
-        profiler.external_event(span.name, span.t0_us, span.t1_us,
-                                annotation=trace_id)
-    except Exception:
-        pass

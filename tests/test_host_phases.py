@@ -9,8 +9,17 @@ decode) ``other`` is small. With ``jax.profiler`` running, every phase
 is a ``pt.host.*`` event on the host plane: every program launch lies
 inside a ``pt.host.launch``, every blocking read inside a
 ``pt.host.wait``.
+
+The same phases as stretches with a place on the clock (``segs`` of the
+accumulator, ``phases`` of a record): ordered, disjoint, inside the
+step, summed by name equal to ``host_us``; every ``launch`` names the
+kind of program it dispatched and every ``wait`` the kind it fetched,
+decode steps settled in the order they were launched; and under a
+profiler session one offset lays every ``pt.host.<name>`` event inside
+the segment of that name.
 """
 
+import collections
 import glob
 import json
 import os
@@ -24,6 +33,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.core.monitor import StatRegistry
 from paddle_tpu.core.profiler import HostPhases, RecordEvent, host_phase
+from paddle_tpu.distributed import fault_inject as fi
 from paddle_tpu.inference import SpeculativeConfig, create_decode_engine
 from paddle_tpu.inference.continuous_batching import HOST_PHASES
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
@@ -48,7 +58,8 @@ PATHS = {
 # a step-timeline record: the keys every record carries, then by path
 # (what every record of that path carries besides, what only some do)
 RECORD_ALWAYS = {
-    "step", "t_us", "ms", "host_us", "commit_us", "gap_us", "programs",
+    "step", "t_us", "ms", "host_us", "phases", "commit_us", "gap_us",
+    "programs",
     "slots_active", "slots_decoding", "queued", "free_pages",
     "reserved_pages", "occupancy"}
 _DECODE = {"decode_ms", "decode_h2d", "decode_ahead"}
@@ -117,14 +128,34 @@ class TestHostPhases:
             with acc.phase("launch") as inner:
                 time.sleep(0.004)
             time.sleep(0.002)
-        us = acc.take()
+        us, _ = acc.take()
         assert set(us) == {"admit", "launch"}
         # each second counted once: the sum is the outer's wall time
         assert us["admit"] + us["launch"] == pytest.approx(
             outer.t1 - outer.t0, abs=1e-9)
         assert us["launch"] == pytest.approx(inner.t1 - inner.t0, abs=1e-9)
         assert 0.004 <= us["launch"] < us["admit"] + us["launch"]
-        assert acc.t == outer.t1 and acc.take() == {}
+        assert acc.t == outer.t1 and acc.take() == ({}, [])
+
+    def test_paused_outer_phase_leaves_two_segments(self):
+        acc = HostPhases()
+        with acc.phase("admit") as outer:
+            with acc.phase("launch", "decode") as inner:
+                pass
+            with acc.phase("wait", "decode") as read:
+                pass
+        assert acc.segs == [
+            ("admit", outer.t0, inner.t0, None),
+            ("launch", inner.t0, inner.t1, "decode"),
+            ("admit", inner.t1, read.t0, None),
+            ("wait", read.t0, read.t1, "decode"),
+            ("admit", read.t1, outer.t1, None)]
+        us, segs = acc.take()
+        # the stretches are the sums, placed: no other clock was read
+        for name in us:
+            assert us[name] == pytest.approx(
+                sum(b - a for n, a, b, _ in segs if n == name), abs=1e-12)
+        assert acc.segs == [] and acc.take() == ({}, [])
 
     def test_exception_closes_the_phase(self):
         acc = HostPhases()
@@ -173,6 +204,49 @@ class TestTimelineRecords:
         assert all(0 <= e["cpu_us"] <= e["gap_us"] + e["ms"] * 1e3
                    + e["commit_us"] + 1e3 for e in tl[1:])
         assert seen == NAMES, seen
+
+    @pytest.mark.parametrize("path", sorted(PATHS))
+    def test_phases_are_the_sums_with_their_places(self, model, path):
+        """`phases` (read by `benchmarks/host_clock.py`): the step's
+        stretches in order, disjoint, inside `[0, ms]`, summed by name
+        equal to `host_us`; one `launch` for every program `programs`
+        counts, under its kind, and every `wait` with the kind it
+        fetched."""
+        eng = _engine(model, **PATHS[path])
+        _drive(eng)
+        eng.close()
+        tl = eng.step_timeline()
+        kinds = collections.Counter()
+        for e in tl:
+            end, sums, launched = 0.0, collections.Counter(), \
+                collections.Counter()
+            for seg in e["phases"]:
+                name, start, us = seg[:3]
+                assert name in HOST_PHASES, seg
+                assert start >= end - 1e-6 and us >= 0, (seg, end)
+                end = start + us
+                sums[name] += us
+                # a launch and a wait say what of, nothing else does
+                assert len(seg) == (4 if name in ("launch", "wait")
+                                    else 3), seg
+                if name == "launch":
+                    launched[seg[3]] += 1
+                kinds[name, seg[-1]] += len(seg) == 4
+            assert end <= e["ms"] * 1e3 + 0.1, (end, e["ms"])
+            host = dict(e["host_us"])
+            host.pop("other")
+            assert set(sums) == set(host)
+            for name, us in host.items():  # a tenth a stretch: rounding
+                assert sums[name] == pytest.approx(
+                    us, abs=0.1 * len(e["phases"])), (name, e)
+            assert launched == e["programs"], (launched, e["programs"])
+        want = {"default": {"prefill", "decode"},
+                "chunked": {"prefill", "prefill_chained", "decode"},
+                "speculative": {"prefill", "verify"}}[path]
+        assert {k for (n, k), c in kinds.items()
+                if n == "launch" and c} == want
+        assert {k for (n, k), c in kinds.items()
+                if n == "wait" and c} == want
 
     @pytest.mark.parametrize("path", sorted(RECORD_KEYS))
     def test_record_keys_are_exactly_these(self, model, path):
@@ -244,14 +318,102 @@ class TestTimelineRecords:
                 assert e["prefill_ms"] * 1e3 <= (
                     host["upload"] + host["launch"] + host["wait"] + 0.5)
 
+    @pytest.mark.parametrize("path", ["default", "chunked"])
+    def test_decode_waits_settle_launches_in_order(self, model, path):
+        """A `wait` of kind `decode` for every decode step settled
+        inside a call, in launch order: steps launched ahead (settled
+        by the next call), masked steps (a half-prefilled slot:
+        settled where they are launched), a step dropped in flight by
+        a failed step and one whose launch raised. The rule a reader
+        pairs by (`benchmarks/host_clock.py launches`): waits settle
+        launches first in, first out, and a record's `decode_ahead`
+        says how many launches were still unfetched when its own was
+        made, which forgets a step that never was fetched."""
+        eng = _engine(model, **PATHS[path])
+        ran = []  # ("launch" | "settle", the step), as the engine ran
+        launch, settle = eng._launch_decode, eng._settle_decode
+
+        def launched(ahead):
+            new = launch(ahead=ahead)
+            new["nth"] = sum(1 for what, _ in ran if what == "launch")
+            ran.append(("launch", new["nth"]))
+            return new
+
+        def settled(pend):
+            ran.append(("settle", pend["nth"]))
+            return settle(pend)
+
+        eng._launch_decode, eng._settle_decode = launched, settled
+        for i in range(4):
+            eng.submit(np.arange(1, 20 + 9 * i, dtype=np.int32), 8 + i)
+        for _ in range(40):  # until a step is ahead of the host
+            eng.step()
+            if eng._inflight is not None:
+                break
+        assert eng._inflight is not None
+        fi.get_injector().arm("engine.step", at_calls=[1])
+        with pytest.raises(fi.InjectedFault):
+            eng.step()
+        fi.reset()
+        for _ in range(3):
+            eng.step()
+        real = eng._decode_jit
+
+        def broken(*a):
+            raise RuntimeError("launch failed")
+
+        eng._decode_jit = broken
+        with pytest.raises(RuntimeError, match="launch failed"):
+            eng.step()
+        eng._decode_jit = real
+        eng.run()
+        in_calls = sum(1 for what, _ in ran if what == "settle")
+        eng.close()  # settles what is in flight outside any call
+
+        order = [nth for what, nth in ran if what == "launch"]
+        truth = [nth for what, nth in ran if what == "settle"][:in_calls]
+        flying, read, n = [], [], 0
+        for e in eng.step_timeline():
+            for seg in e["phases"]:
+                if seg[-1] != "decode":
+                    continue
+                if seg[0] == "launch" and "decode" in e["programs"]:
+                    ahead = e["decode_ahead"]
+                    flying = flying[len(flying) - ahead:] if ahead else []
+                    flying.append(n)
+                    n += 1
+                elif seg[0] == "wait":
+                    assert flying, e  # nothing fetched that was not sent
+                    read.append(flying.pop(0))
+        assert n == len(order) and read == truth
+        # ahead, masked (chunked only) and dropped steps all occurred
+        assert any(a != b + 1 for a, b in zip(truth[1:], truth))
+        assert len(set(order)) - len(set(truth)) >= 1
+        if path == "chunked":
+            assert any(
+                [s[0] for s in e["phases"] if s[-1] == "decode"]
+                in (["launch", "wait"], ["launch", "wait", "wait"])
+                for e in eng.step_timeline())
+
     def test_record_size_does_not_depend_on_tokens(self, model):
         def keys(new_tokens):
             eng = _engine(model)
             _drive(eng, rounds=1, new_tokens=new_tokens)
             eng.close()
+            tl = eng.step_timeline()
+            # a call that launches a decode step alone: admit, upload,
+            # launch and one settle (wait, emit), with `admit` cut in
+            # three where it is the admission that settles; a prefill
+            # adds its upload, launch, wait and emit and cuts `admit`
+            # four times more. No stretch a token.
+            for e in tl:
+                prefills = sum(n for k, n in e["programs"].items()
+                               if k != "decode")
+                assert len(e["phases"]) <= 7 + 8 * prefills, e
+                if not prefills:  # 629 bytes as JSON read here, 480 before
+                    assert len(json.dumps(e)) <= 900, e
             return {(k, len(v) if isinstance(v, dict) else 1)
-                    for e in eng.step_timeline()
-                    if e["programs"] == {"decode": 1}
+                    for e in tl if e["programs"] == {"decode": 1}
                     for k, v in e.items() if k != "occupancy"}
         assert keys(4) == keys(40)
 
@@ -360,6 +522,30 @@ def _check_line(evs, launches):
         assert _inside(e, wait), e
 
 
+def _check_segments(evs, records):
+    """Each `pt.host.<name>` event of the stepping thread lies inside
+    the record's segment of that name: the events and the segments
+    (with each record's `commit` behind its step) are the same names
+    in the same order, and ONE offset between the profiler's clock and
+    `time.monotonic` puts every event inside its segment (an event
+    opens after its segment's first stamp and closes before its
+    last). Returns the offsets that do, in microseconds."""
+    events = sorted((e for e in evs if e[0].startswith("pt.host.")
+                     and e[0] not in ("pt.host.loop", "pt.host.inbox")),
+                    key=lambda e: e[1])
+    segs = []
+    for e in records:
+        segs += [(s[0], e["t_us"] + s[1], e["t_us"] + s[1] + s[2])
+                 for s in e["phases"]]
+        end = e["t_us"] + e["ms"] * 1e3
+        segs.append(("commit", end, end + e["commit_us"]))
+    assert [e[0] for e in events] == ["pt.host." + s[0] for s in segs]
+    lo = max(e[2] * 1e-3 - s[2] for e, s in zip(events, segs))
+    hi = min(e[1] * 1e-3 - s[1] for e, s in zip(events, segs))
+    assert lo <= hi + 0.2, (lo, hi)  # the record rounds to 0.1 us
+    return lo, hi
+
+
 class TestProfilerPlane:
     @pytest.mark.parametrize("path", sorted(PATHS))
     def test_engine_phases_on_the_host_plane(self, model, tmp_path, path):
@@ -369,9 +555,11 @@ class TestProfilerPlane:
         lines = _trace(lambda: _drive(eng, rounds=1, new_tokens=6),
                        tmp_path)
         eng.close()
-        launched = sum(sum(e["programs"].values())
-                       for e in eng.step_timeline()[n0:])
-        _check_line(_stepping_line(lines), launched)
+        tl = eng.step_timeline()[n0:]
+        launched = sum(sum(e["programs"].values()) for e in tl)
+        line = _stepping_line(lines)
+        _check_line(line, launched)
+        _check_segments(line, tl)
 
     def test_server_loop_and_inbox(self, model, tmp_path):
         srv = ServingServer(model, port=0, metrics=ServingMetrics(
@@ -395,6 +583,7 @@ class TestProfilerPlane:
         assert loops and len(inbox) == len(loops)
         assert all(_inside(e, loops) for e in inbox)
         _check_line(line, sum(sum(e["programs"].values()) for e in tl))
+        _check_segments(line, tl)
         # gap_us is that loop: a working gap is about one loop event
         assert all(e["gap_us"] >= 0 for e in tl)
 

