@@ -420,56 +420,120 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, seq_lens,
 # [G, D] x [page, D] products and go to the MXU, which wants each KV
 # head's page as a [page, D] tile: these pools are HEADS-MAJOR inside a
 # page, ``[P, KVH, page, D]`` (models/cache_layout.py ``heads_major``),
-# so a page is still one contiguous DMA and KV head ``j`` of it is
+# so a page is still one DMA, which lays KV head ``j``'s rows under
 # ``buf[j]``, no relayout. (A ``[P, page, KVH, D]`` pool with KVH = 4
 # is tiled (4, 128): regrouping it costs a copy of the pool a call.)
+# Pages are fetched a block at a time, as the latent walk's are, and
+# the next sequence's first block during this one's last: fetched one
+# 128 KB page (K and V) a loop turn the walk read 251 GB/s of 819 at 4
+# KV heads and 293 at 8, and a live slot cost 0.5-0.7 us; blocks of 2
+# MB read 757 at both, and a slot's first block no longer waits in the
+# open (1.3-2.8 us a live slot without the look-ahead, about 0 with it;
+# tools/grouped_decode_report.py, PERF.md section 5, PR 37).
+
+GROUPED_BLOCK_PAGES = 16
+GROUPED_BLOCK_BYTES = 2 << 20
+
+
+def grouped_block_pages(kvh: int, page: int, d: int, itemsize: int,
+                        table_width: int) -> int:
+    """Pages a turn of the grouped walk: as many as hold
+    ``GROUPED_BLOCK_BYTES`` of K and V (16 pages of 4 KV heads, 8 of 8:
+    two such buffers are the walk's VMEM), at most 16 and at most a
+    quarter of the table (a sequence's last, partly filled block costs
+    a whole one's products)."""
+    page_bytes = 2 * kvh * page * d * itemsize
+    return max(1, min(GROUPED_BLOCK_PAGES, table_width // 4,
+                      GROUPED_BLOCK_BYTES // page_bytes))
+
 
 def _decode_grouped_kernel(pt_ref, len_ref, lo_ref, q_ref, kp_ref, vp_ref,
-                           o_ref, k_buf, v_buf, sems, *,
-                           page: int, scale: float, kvh: int):
+                           o_ref, k_buf, v_buf, sems, turn, *,
+                           page: int, scale: float, kvh: int, block: int):
     """One grid step = one sequence. ``q_ref`` is ``[1, KVH, Gp, D]``:
     the group padded to a sublane tile with zero rows, whose outputs
     the caller drops. The walk starts at the page that holds
     ``kv_start`` and masks inside it; pages behind the bound are never
-    fetched."""
+    fetched. A loop turn takes a block of ``block`` pages (their copies
+    in flight together) into one buffer ``[KVH, block * page, D]`` and
+    runs one pair of products a KV head over its rows. What a block's
+    copies do not fill keeps older rows, which the mask hides; the
+    buffers are zeroed once a call so that nothing read from them was
+    never written. A parked slot (length 0) walks nothing and returns
+    zeros.
+
+    The next sequence's first block is started during this one's last
+    turn, so only the first sequence of a call waits for its first
+    block in the open. ``turn`` (SMEM) carries across grid steps the
+    count of blocks walked so far (a block's buffer is that count's
+    parity) and whether this step's first block is already in flight."""
     b = pl.program_id(0)
+    n_seq = pt_ref.shape[0]
+    gp, d = q_ref.shape[2], q_ref.shape[3]
+    rows = block * page
+
+    def walk(s):
+        first = lo_ref[s] // page
+        n_pages = pl.cdiv(len_ref[s], page)
+        return first, n_pages, pl.cdiv(jnp.maximum(n_pages - first, 0), block)
+
+    def each_copy(s, i, slot, fn):
+        """``fn`` on the K and V copies of block ``i`` of sequence ``s``
+        into buffer ``slot``: its pages up to the sequence's last."""
+        first, n_pages, _ = walk(s)
+        start = first + i * block
+
+        def one(j, carry):
+            idx = pt_ref[s, start + j]
+            dst = (slot, slice(None),
+                   pl.ds(pl.multiple_of(j * page, page), page))
+            fn(pltpu.make_async_copy(kp_ref.at[idx], k_buf.at[dst],
+                                     sems.at[0, slot, j]))
+            fn(pltpu.make_async_copy(vp_ref.at[idx], v_buf.at[dst],
+                                     sems.at[1, slot, j]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - start, 0, block), one, 0)
+
+    @pl.when(b == 0)
+    def _():
+        k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        turn[0] = 0
+        turn[1] = 0
+
     seq_len = len_ref[b]
     lo = lo_ref[b]
-    first = lo // page
-    n_pages = pl.cdiv(seq_len, page)
-    gp, d = q_ref.shape[2], q_ref.shape[3]
+    first, _, n_blocks = walk(b)
+    base = turn[0]
+    nxt = jnp.minimum(b + 1, n_seq - 1)
+    ahead = (b + 1 < n_seq) & (walk(nxt)[2] > 0)
 
-    def copies(i, slot):
-        idx = pt_ref[b, i]
-        return [pltpu.make_async_copy(kp_ref.at[idx], k_buf.at[slot],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(vp_ref.at[idx], v_buf.at[slot],
-                                      sems.at[1, slot])]
-
-    @pl.when(n_pages > first)
+    @pl.when((n_blocks > 0) & (turn[1] == 0))
     def _():
-        for c in copies(first, jax.lax.rem(first, 2)):
-            c.start()
+        each_copy(b, 0, jax.lax.rem(base, 2), lambda c: c.start())
 
     def body(i, carry):
-        slot = jax.lax.rem(i, 2)
+        slot = jax.lax.rem(base + i, 2)
 
-        @pl.when(i + 1 < n_pages)
+        @pl.when(i + 1 < n_blocks)
         def _():
-            for c in copies(i + 1, jax.lax.rem(i + 1, 2)):
-                c.start()
+            each_copy(b, i + 1, 1 - slot, lambda c: c.start())
 
-        for c in copies(i, slot):
-            c.wait()
-        kpos = i * page + jax.lax.broadcasted_iota(
-            jnp.int32, (gp, page), 1)
+        @pl.when((i + 1 == n_blocks) & ahead)
+        def _():
+            each_copy(nxt, 0, 1 - slot, lambda c: c.start())
+
+        each_copy(b, i, slot, lambda c: c.wait())
+        kpos = (first * page + i * rows
+                + jax.lax.broadcasted_iota(jnp.int32, (gp, rows), 1))
         seen = (kpos < seq_len) & (kpos >= lo)
         out = []
         for j in range(kvh):
             m, l, acc = carry[j]  # noqa: E741
             s = jax.lax.dot_general(
                 q_ref[0, j], k_buf[slot, j], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [Gp, page]
+                preferred_element_type=jnp.float32) * scale  # [Gp, rows]
             s = jnp.where(seen, s, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -485,14 +549,16 @@ def _decode_grouped_kernel(pt_ref, len_ref, lo_ref, q_ref, kp_ref, vp_ref,
     init = tuple((jnp.full((gp, 1), _NEG_INF, jnp.float32),
                   jnp.zeros((gp, 1), jnp.float32),
                   jnp.zeros((gp, d), jnp.float32)) for _ in range(kvh))
-    final = jax.lax.fori_loop(first, n_pages, body, init)
+    final = jax.lax.fori_loop(0, n_blocks, body, init)
+    turn[0] = base + n_blocks
+    turn[1] = ((n_blocks > 0) & ahead).astype(jnp.int32)
     for j in range(kvh):
         _, l, acc = final[j]  # noqa: E741
         o_ref[0, j] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_decode_grouped_pallas(q, k_pages, v_pages, page_table, seq_lens,
-                                 kv_start, scale):
+                                 kv_start, scale, block):
     """q: [B, Hq, D]; pools [P, KVH, page, D]; returns [B, Hq, D]."""
     b, hq, d = q.shape
     kvh, page = k_pages.shape[1:3]
@@ -514,15 +580,16 @@ def _paged_decode_grouped_pallas(q, k_pages, v_pages, page_table, seq_lens,
         out_specs=pl.BlockSpec((1, kvh, gp, d), lambda i, *_: (i, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((2, kvh, page, d), k_pages.dtype),
-            pltpu.VMEM((2, kvh, page, d), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((2, kvh, block * page, d), k_pages.dtype),
+            pltpu.VMEM((2, kvh, block * page, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, block)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     out = named_pallas_call(
         "paged_decode_grouped",
         functools.partial(_decode_grouped_kernel, page=page, scale=scale,
-                          kvh=kvh),
+                          kvh=kvh, block=block),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, gp, d), q.dtype),
         cost_estimate=pl.CostEstimate(
@@ -594,10 +661,13 @@ def paged_attention_grouped(q, k_pages, v_pages, page_table, seq_lens,
     scale = float(1.0 / math.sqrt(d) if scale is None else scale)
     if paged_grouped_supported(q.shape, k_pages.shape):
         lo = jnp.zeros_like(seq_lens) if kv_start is None else kv_start
+        kvh, page = k_pages.shape[1:3]
+        block = grouped_block_pages(kvh, page, d, k_pages.dtype.itemsize,
+                                    page_table.shape[1])
         out = _paged_decode_grouped_pallas(
             q.reshape(b, h, d), k_pages, v_pages,
             page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-            lo.astype(jnp.int32), scale)
+            lo.astype(jnp.int32), scale, block)
         return out.reshape(b, sq, h, d)
     return paged_attention_grouped_reference(
         q, k_pages, v_pages, page_table, seq_lens, kv_start=kv_start,

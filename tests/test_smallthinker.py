@@ -342,6 +342,46 @@ def test_paged_decode_kernel_group_7_window_edge_inside_a_page(
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("kvh,g,width,block,lens,window", [
+    (2, 7, 16, 4, [77, 130], None),
+    (2, 7, 16, 4, [200, 150], 90),
+    (2, 7, 22, 5, [345, 17, 160], None),
+    (2, 7, 16, 4, [70, 0, 120, 0, 33], None),
+    (8, 8, 16, 4, [100, 0, 64, 250], None),
+    (2, 7, 16, 4, [1, 16, 0, 2], None),
+], ids=["ends_mid_block_mid_page", "window_bound_in_an_unaligned_block",
+        "width_not_a_multiple_of_the_block", "parked_between_live",
+        "group_8_over_8_kv_heads", "single_page"])
+def test_paged_decode_kernel_walks_a_block_of_pages_a_turn(
+        interpret, kvh, g, width, block, lens, window):
+    """The walk takes ``block`` pages a turn (the rule's choice for the
+    table's width) and starts the next sequence's first block during
+    this one's last: a block that is partly past a sequence's end or
+    past the table's, a window's first page that does not start a
+    block, parked slots between live ones (zeros out), pages of 8 KV
+    heads."""
+    rng = np.random.default_rng(len(lens) * width + kvh)
+    d, page = 128, 16
+    assert pa.grouped_block_pages(kvh, page, d, 4, width) == block
+    b = len(lens)
+    n_pages = b * width
+    kp = jnp.asarray(rng.standard_normal((n_pages + 1, kvh, page, d)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((n_pages + 1, kvh, page, d)),
+                     jnp.float32)
+    table = jnp.asarray(rng.permutation(n_pages).reshape(b, width),
+                        jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    lo = None if window is None else jnp.maximum(lens - window, 0)
+    q = jnp.asarray(rng.standard_normal((b, 1, kvh * g, d)), jnp.float32)
+    out = pa.paged_attention_grouped(q, kp, vp, table, lens, kv_start=lo)
+    want = pa.paged_attention_grouped_reference(q, kp, vp, table, lens,
+                                                kv_start=lo)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+    assert not np.asarray(out)[np.asarray(lens) == 0].any()
+
+
 @pytest.mark.parametrize("s,window", [(256, None), (256, 100), (512, 130),
                                       (128, 40)])
 def test_flash_prefill_kernel_group_7_and_a_window(interpret, s, window):

@@ -327,17 +327,24 @@ def _bf16(one_chip, *shape, dt=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
 
-@pytest.mark.parametrize("pages,width", [(4097, 256), (16 * 66 + 1, 66)],
-                         ids=["global", "ring"])
-def test_paged_decode_grouped_compiles(one_chip, pages, width):
+@pytest.mark.parametrize("slots,heads,kvh,pages,width",
+                         [(16, 28, 4, 4097, 256), (16, 28, 4, 16 * 66 + 1, 66),
+                          (32, 64, 8, 8193, 545)],
+                         ids=["global", "ring", "solar"])
+def test_paged_decode_grouped_compiles(one_chip, slots, heads, kvh, pages,
+                                       width):
     """Group of 7 on the MXU over heads-major pools, the allocator's
-    table and a window layer's ring with its lower bound."""
-    specs = (_bf16(one_chip, 16, 1, 28, 128),
-             _bf16(one_chip, pages, 4, 64, 128),
-             _bf16(one_chip, pages, 4, 64, 128),
-             _bf16(one_chip, 16, width, dt=jnp.int32),
-             _bf16(one_chip, 16, dt=jnp.int32),
-             _bf16(one_chip, 16, dt=jnp.int32))
+    table and a window layer's ring with its lower bound; Solar's group
+    of 8 over 8 KV heads (models/solar_open2.py: 32 slots, a 545-wide
+    table). The block of pages the walk takes a turn has its two K+V
+    buffers in VMEM: they fit at both widths, and the call is still
+    one kernel."""
+    specs = (_bf16(one_chip, slots, 1, heads, 128),
+             _bf16(one_chip, pages, kvh, 64, 128),
+             _bf16(one_chip, pages, kvh, 64, 128),
+             _bf16(one_chip, slots, width, dt=jnp.int32),
+             _bf16(one_chip, slots, dt=jnp.int32),
+             _bf16(one_chip, slots, dt=jnp.int32))
     assert pa.paged_grouped_supported(specs[0].shape, specs[1].shape,
                                       backend="tpu")
     compiled = _compile(
