@@ -239,12 +239,16 @@ def route_sigmoid_top_k(u, w_router, bias, top_k: int,
     return idx.astype(jnp.int32), gates
 
 
-def gated_ffn(u, w_gate, w_up, w_down, activation: str = "silu"):
-    """``(act(u W_gate) * (u W_up)) W_down``: an expert every token
-    goes through (a shared expert), as plain matmuls."""
+def gated_ffn(u, w_gate, w_up, w_down, activation: str = "silu",
+              gate_scale: float = 1.0):
+    """``(act(gate_scale * u W_gate) * (u W_up)) W_down``: an expert
+    every token goes through (a shared expert), or a dense layer, as
+    plain matmuls."""
     from ..ops.pallas.grouped_matmul import ACTIVATIONS
-    mid = ACTIVATIONS[activation](
-        jnp.matmul(u, w_gate, preferred_element_type=jnp.float32)) * \
+    gate = jnp.matmul(u, w_gate, preferred_element_type=jnp.float32)
+    if gate_scale != 1.0:
+        gate = gate * gate_scale
+    mid = ACTIVATIONS[activation](gate) * \
         jnp.matmul(u, w_up, preferred_element_type=jnp.float32)
     return jnp.matmul(mid.astype(u.dtype), w_down)
 

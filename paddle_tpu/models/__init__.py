@@ -6,6 +6,8 @@ from .gpt import (GPTAttention, GPTBlock, GPTConfig, GPTForCausalLM, GPTMLP,
                   paged_cache_create, paged_kv_append)
 from .cache_layout import (LatentCache, LayerCache, StateCache,
                            UnsupportedCacheLayout, ring_pages)
+from .falcon_h1 import (FalconH1Config, FalconH1ForCausalLM, falcon_h1_34b,
+                        falcon_h1_tiny)
 from .glm4_moe_lite import (Glm4MoeLiteConfig, Glm4MoeLiteForCausalLM,
                             glm4_7_flash, glm4_moe_lite_tiny)
 from .smallthinker import (SmallThinkerConfig, SmallThinkerForCausalLM,

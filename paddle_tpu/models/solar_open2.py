@@ -258,16 +258,18 @@ class SolarOpen2Model(Layer):
         self.norm = make((cfg.hidden_size,), "norm")
 
 
-def causal_conv(x, tail, w):
-    """``out[t] = sum_i w[i] in[t - taps + 1 + i]`` a channel, ``in``
-    the sequence ``x`` [B, T, C] behind the ``tail`` [B, taps - 1, C] of
-    what came before it (zeros at a sequence's start). Returns ``(out
-    [B, T, C] float32, tail + x)``."""
+def causal_conv(x, tail, w, bias=None):
+    """``out[t] = sum_i w[i] in[t - taps + 1 + i] (+ bias)`` a channel,
+    ``in`` the sequence ``x`` [B, T, C] behind the ``tail`` [B, taps - 1,
+    C] of what came before it (zeros at a sequence's start). Returns
+    ``(out [B, T, C] float32, tail + x)``."""
     t = x.shape[1]
     seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     wf = w.astype(F32)
     out = sum(seen[:, i:i + t].astype(F32) * wf[i]
               for i in range(w.shape[0]))
+    if bias is not None:
+        out = out + bias.astype(F32)
     return out, seen
 
 

@@ -2188,6 +2188,8 @@ def client_request(host: str, port: int, payload: Dict,
 
 def _build_model(name: str):
     import paddle_tpu as pt
+    from ..models.falcon_h1 import (FalconH1ForCausalLM, falcon_h1_34b,
+                                    falcon_h1_tiny)
     from ..models.glm4_moe_lite import (Glm4MoeLiteForCausalLM,
                                         glm4_7_flash, glm4_moe_lite_tiny)
     from ..models.gpt import (GPTForCausalLM, gpt_125m, gpt_1p3b,
@@ -2219,7 +2221,14 @@ def _build_model(name: str):
                # widths: the dense layer and five expert layers
                "glm4_7_flash_cut": (
                    Glm4MoeLiteForCausalLM,
-                   lambda: glm4_7_flash(6, dtype="bfloat16"))}
+                   lambda: glm4_7_flash(6, dtype="bfloat16")),
+               "falcon_h1_tiny": (FalconH1ForCausalLM, falcon_h1_tiny),
+               # the first of twelve pipeline stages at the published
+               # widths, an eighth of the vocabulary
+               "falcon_h1_34b_cut": (
+                   FalconH1ForCausalLM, lambda: falcon_h1_34b(
+                       6, vocab_size=32640, prefill_segment=2048,
+                       dtype="bfloat16"))}
     if name not in configs:
         raise SystemExit(f"unknown --model {name!r}; choose from "
                          f"{sorted(configs)}")

@@ -54,10 +54,11 @@ from paddle_tpu.distributed import fault_inject as fi
 from paddle_tpu.distributed.topology import make_serving_mesh
 from paddle_tpu.inference import SpeculativeConfig, create_decode_engine
 from paddle_tpu.inference import continuous_batching as cb
-from paddle_tpu.models import (Glm4MoeLiteForCausalLM,
+from paddle_tpu.models import (FalconH1ForCausalLM, Glm4MoeLiteForCausalLM,
                                SmallThinkerForCausalLM,
-                               SolarOpen2ForCausalLM, glm4_moe_lite_tiny,
-                               smallthinker_tiny, solar_open2_tiny)
+                               SolarOpen2ForCausalLM, falcon_h1_tiny,
+                               glm4_moe_lite_tiny, smallthinker_tiny,
+                               solar_open2_tiny)
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 from paddle_tpu.serving.prefix_cache import PrefixCache
 
@@ -71,7 +72,8 @@ ENGINE_KW = dict(num_slots=2, page_size=PAGE, max_seq_len=64,
 # `rings` is the second decoder (window rings, grouped heads), `state`
 # the third (a state a slot updated in place beside the pages),
 # `latent` the fourth (one latent row a position in the allocator's
-# pages, one pool a layer), at the tiny size
+# pages, one pool a layer), `hybrid` the fifth (every block a state AND
+# pages: two cache entries a block), at the tiny size
 VARIANTS = {
     "plain": lambda: {},
     "prefix_cache": lambda: {"prefix_cache": PrefixCache(PAGE)},
@@ -83,6 +85,7 @@ VARIANTS = {
     "rings": lambda: {},
     "state": lambda: {},
     "latent": lambda: {},
+    "hybrid": lambda: {},
     "speculative": lambda: {
         "speculative": SpeculativeConfig(k=2, draft="ngram")},
 }
@@ -124,12 +127,18 @@ def latent_model():
     return Glm4MoeLiteForCausalLM(glm4_moe_lite_tiny(), seed=3)
 
 
+@pytest.fixture(scope="module")
+def hybrid_model():
+    return FalconH1ForCausalLM(falcon_h1_tiny(), seed=3)
+
+
 @pytest.fixture
-def build(model, rings_model, state_model, latent_model):
+def build(model, rings_model, state_model, latent_model, hybrid_model):
     """An engine of a variant, on the model the variant serves."""
     def make(variant, **kw):
         m = {"rings": rings_model, "state": state_model,
-             "latent": latent_model}.get(variant, model)
+             "latent": latent_model, "hybrid": hybrid_model}.get(variant,
+                                                                 model)
         return _engine(m, **VARIANTS[variant](), **kw)
     return make
 

@@ -538,6 +538,101 @@ def test_kda_decode_compiles_in_place(one_chip):
     assert int(mem.temp_size_in_bytes) < pool // 8
 
 
+# -- the SSD kernels at Falcon-H1-34B's published widths -----------------------
+# (32 heads of 128 in 2 groups, a state of 256, float32 states, 128
+# slots: models/falcon_h1.py)
+
+def test_ssd_chunk_fwd_compiles(one_chip):
+    """The chunked scan over a 2048-position prompt: a group's C B^T
+    once for its 16 heads, every product in float32."""
+    from paddle_tpu.ops.pallas import ssd
+    specs = (_bf16(one_chip, 1, 2048, 4096),
+             _bf16(one_chip, 1, 2048, 32, dt=jnp.float32),
+             _bf16(one_chip, 32, dt=jnp.float32),
+             _bf16(one_chip, 1, 2048, 512), _bf16(one_chip, 1, 2048, 512),
+             _bf16(one_chip, 1, 32, 256, 128, dt=jnp.float32),
+             _bf16(one_chip, 1, dt=jnp.int32))
+    assert ssd.ssd_supported(256, 128, 16, backend="tpu")
+    compiled = _compile(
+        lambda x, dt, a, b, c, s, n: ssd.ssd_chunk_fwd(x, dt, a, b, c, s, n,
+                                                       groups=2), *specs)
+    assert _kernel_calls(compiled) == 1
+    _named(compiled, "ssd_chunk_fwd")
+
+
+def test_ssd_decode_compiles_in_place(one_chip):
+    """One token a slot over the state pool of 128 slots: the pool
+    (537 MB) is the call's input AND output."""
+    from paddle_tpu.ops.pallas import ssd
+    specs = (_bf16(one_chip, 128, 32, 128),
+             _bf16(one_chip, 128, 32, dt=jnp.float32),
+             _bf16(one_chip, 32, dt=jnp.float32),
+             _bf16(one_chip, 128, 2, 256), _bf16(one_chip, 128, 2, 256),
+             _bf16(one_chip, 129, 32, 256, 128, dt=jnp.float32),
+             _bf16(one_chip, 128, dt=jnp.int32),
+             _bf16(one_chip, 128, dt=jnp.bool_))
+    with fa.force_flash_for_aot():
+        compiled = jax.jit(ssd.ssd_decode, donate_argnums=(5,)).lower(
+            *specs).compile()
+    assert _kernel_calls(compiled) == 1
+    _named(compiled, "ssd_decode")
+    pool = 129 * 32 * 256 * 128 * 4
+    mem = compiled.memory_analysis()
+    assert int(mem.alias_size_in_bytes) >= pool
+    assert int(mem.temp_size_in_bytes) < pool // 8
+
+
+def test_hybrid_cell_decode_program_compiles(one_chip, monkeypatch):
+    """The Falcon-H1 cell's whole decode program (the engine's
+    `_build_decode` over an abstract model at the published widths, 6
+    blocks, 128 slots, 3,072 pages of 64): it fits the chip, and each
+    block runs ONE state step and ONE page walk."""
+    from paddle_tpu.inference.continuous_batching import \
+        ContinuousBatchingEngine
+    from paddle_tpu.models import (FalconH1ForCausalLM, cache_layout,
+                                   falcon_h1_34b)
+    from paddle_tpu.nn.layer import functional_state
+
+    def S(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    class Shapes:  # `jnp` whose zeros are shapes: no pool is allocated
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def zeros(shape, dtype):
+            return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+
+    monkeypatch.setattr(cache_layout, "jnp", Shapes())
+    model = FalconH1ForCausalLM(falcon_h1_34b(6, vocab_size=32640,
+                                              dtype="bfloat16"),
+                                abstract=True)
+    model.eval()
+    eng = ContinuousBatchingEngine(model, num_slots=128, page_size=64,
+                                   max_seq_len=4096, num_pages=3072)
+    monkeypatch.undo()
+    assert eng.state_pool_bytes == 6 * 129 * (32 * 256 * 128 * 4
+                                              + 3 * 5120 * 2)
+    state = jax.tree_util.tree_map(S, functional_state(model))
+    pools = jax.tree_util.tree_map(S, eng._pools)
+    packed = jax.ShapeDtypeStruct((128, eng.max_pages + 2), jnp.int32,
+                                  sharding=one_chip)
+    with fa.force_flash_for_aot():
+        compiled = eng._build_decode().lower(state, pools, packed).compile()
+    # the pools come back aliased to their inputs: counted once
+    mem = compiled.memory_analysis()
+    assert int(mem.alias_size_in_bytes) >= eng.state_pool_bytes
+    live = (int(mem.argument_size_in_bytes) + int(mem.temp_size_in_bytes)
+            + int(mem.output_size_in_bytes) - int(mem.alias_size_in_bytes))
+    assert live < 16 * (1 << 30), live
+    _named(compiled, "ssd_decode", "paged_decode_grouped", "fused_argmax")
+    txt = compiled.as_text()
+    for name in ("ssd_decode", "paged_decode_grouped"):
+        assert len(re.findall(
+            rf"%{name}[.\d]* = [^\n]*tpu_custom_call", txt)) == 6, name
+
+
 def test_dropless_experts_with_silu_and_a_share_compile(one_chip):
     """The expert layer as the third decoder calls it: silu, 40 of 320
     experts held, a decode step's 32 rows x 8 picks."""
