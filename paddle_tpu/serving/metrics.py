@@ -381,7 +381,14 @@ class ServingMetrics:
                 "weight_swaps_committed_total",
                 "weight_swaps_rolled_back_total",
                 "weight_swaps_failed_total",
-                "cross_generation_skips_total")
+                "cross_generation_skips_total",
+                # streamed token delivery (server._StreamWriter): the
+                # engine's wakes of the one writer thread, token lines,
+                # sends that left bytes for the selector, lines of
+                # connections that died
+                "stream_writer_wakes_total", "stream_lines_total",
+                "stream_deferred_sends_total",
+                "stream_dropped_lines_total")
 
     # outcome labels for the weight_swaps_total family; index-aligned
     # with the weight_swaps_*_total counters above

@@ -103,10 +103,17 @@ typed (EngineFailed) and stop admitting.
 
 Threading model: the ENGINE THREAD exclusively owns the engine (it is
 not thread-safe) — connection threads parse requests and hand them
-over through an inbox queue; per-token streaming flows back through
-per-request outbox queues, so a slow client can never stall the decode
-step. Graceful drain: stop admitting, finish in-flight work, return
-every page, `engine.close()` (which asserts ``check_no_leak``).
+over through an inbox queue. A streaming generate's tokens and final
+reply flow back through ONE stream writer thread: the engine appends
+each token to the writer's queue (no lock, no wake-up) and wakes the
+writer once after each step; the writer formats the lines as bytes
+and sends each connection's share without blocking, keeping what a
+socket does not take until a selector reports it writable, so a slow
+client delays itself alone — never the decode step nor another
+stream. Every other reply flows back through the request's outbox
+queue to its connection thread. Graceful drain: stop admitting,
+finish in-flight work, return every page, `engine.close()` (which
+asserts ``check_no_leak``).
 
 Fault sites (distributed/fault_inject.py): ``serving.request`` fires
 in the connection thread per request (clients get a retryable typed
@@ -138,8 +145,10 @@ Reference analog: the C serving API / AnalysisPredictor server loop
 
 from __future__ import annotations
 
+import collections
 import json
 import queue as queue_mod
+import selectors
 import socket
 import threading
 import time
@@ -244,13 +253,228 @@ def fetch_page_blobs(host: str, port: int, keys=None, heads=None,
 
 
 class _Pending:
-    """Engine-side record of one in-flight client request."""
+    """Engine-side record of one in-flight client request.
 
-    __slots__ = ("outbox", "stream")
+    A streaming generate's messages (tokens, then the final reply) go
+    through the server's `_StreamWriter`, in the order they were
+    produced, straight to ``sock``; every other request's go through
+    ``outbox`` to its connection thread, which sends them. Either way
+    ``None`` on the outbox releases the connection thread: for a
+    streaming request the writer puts it there once the last byte is
+    sent (or the peer is gone)."""
 
-    def __init__(self, stream: bool):
+    __slots__ = ("outbox", "writer", "sock", "taken",
+                 "wbuf", "closing", "dead")
+
+    def __init__(self, writer: Optional["_StreamWriter"] = None,
+                 sock: Optional[socket.socket] = None):
         self.outbox: "queue_mod.Queue[Optional[Dict]]" = queue_mod.Queue()
-        self.stream = stream
+        self.writer = writer
+        self.sock = sock
+        # set once the engine thread took the request off the inbox:
+        # from then on it owes the request its end
+        self.taken = False
+        # the writer's own: bytes not yet sent, the end seen, the peer gone
+        self.wbuf = bytearray()
+        self.closing = False
+        self.dead = False
+
+    @property
+    def stream(self) -> bool:
+        return self.writer is not None
+
+    def put(self, msg: Optional[Dict]) -> None:
+        """Hand ``msg`` (a reply, or None: the end of the request) on
+        toward the client, behind everything handed on before it."""
+        if self.writer is None:
+            self.outbox.put(msg)
+        else:
+            self.writer.queue.append((self, None, msg, None))
+
+    def finish(self, msg: Dict) -> None:
+        """The final reply, then the end of the request."""
+        self.put(msg)
+        self.put(None)
+
+    def on_token(self):
+        """The engine's per-token callback: for a streaming request one
+        append to the writer's queue (no lock, no wake-up: the engine
+        loop wakes the writer once a step), else None."""
+        if self.writer is None:
+            return None
+        append = self.writer.queue.append
+
+        def on_token(rid, tok, done, _p=self):
+            append((_p, rid, tok, done))
+        return on_token
+
+
+class _StreamWriter:
+    """The one thread that writes every streaming generate's lines.
+
+    The engine thread appends ``(pending, rid, token, done)`` for a
+    token and ``(pending, None, reply_or_None, None)`` for a final
+    reply or the end of a request to ``queue`` (a deque: an append
+    needs no lock) and calls `kick` once after each engine step. The
+    writer formats a token line as bytes (the fields and values of
+    ``json.dumps``, without it), appends it to its connection's buffer
+    and sends with ``MSG_DONTWAIT`` (the connection thread's blocking
+    reads are left alone); what a socket does not take waits in its
+    buffer for a selector to report it writable, so a client that
+    stops reading delays itself alone. Once a request's end is reached
+    and its last byte sent, the writer puts ``None`` into the
+    pending's outbox. A socket error drops that connection's bytes,
+    releases its connection thread and is counted; it never raises
+    here. Sockets must be blocking (no timeout): a socket with a
+    timeout waits inside ``send`` whatever the flags.
+
+    Counters (``metrics``): ``stream_writer_wakes_total`` (the engine's
+    wakes), ``stream_lines_total`` (token lines), ``stream_deferred_
+    sends_total`` (sends that left bytes for the selector),
+    ``stream_dropped_lines_total`` (lines, tokens or replies, of a
+    connection that died)."""
+
+    def __init__(self, metrics: ServingMetrics):
+        self.queue: "collections.deque[tuple]" = collections.deque()
+        self._metrics = metrics
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._wake_w.setblocking(False)
+        self._sel = selectors.DefaultSelector()
+        self._sel.register(self._wake_r, selectors.EVENT_READ, None)
+        self._blocked: set = set()  # pendings waiting to be writable
+        self._stop_at: Optional[float] = None
+        self._thread: Optional[threading.Thread] = None
+        # counts not yet added to the metrics, by counter name
+        self._n = dict.fromkeys(
+            ("stream_writer_wakes_total", "stream_lines_total",
+             "stream_deferred_sends_total", "stream_dropped_lines_total"),
+            0)
+
+    def start(self) -> None:
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="pt-serving-stream-writer")
+        self._thread.start()
+
+    def kick(self) -> None:
+        """Wake the writer if anything is queued (engine thread)."""
+        if self.queue:
+            try:
+                self._wake_w.send(b"\0")
+            except OSError:
+                pass  # the pair is full (a wake is waiting) or closed
+
+    def stop(self, timeout_s: float = 5.0) -> None:
+        """Send what is queued for up to ``timeout_s``, drop the rest
+        (releasing each connection thread), and end the thread."""
+        self._stop_at = time.monotonic() + float(timeout_s)
+        try:
+            self._wake_w.send(b"\0")
+        except OSError:
+            pass
+        self._thread.join(timeout=float(timeout_s) + 5.0)
+
+    def _run(self) -> None:
+        try:
+            while True:
+                timeout = None
+                if self._stop_at is not None:
+                    timeout = max(0.0, self._stop_at - time.monotonic())
+                for key, _ev in self._sel.select(timeout):
+                    if key.data is None:
+                        self._read_wake()
+                    else:
+                        self._flush(key.data)
+                self._take()
+                self._count()
+                if self._stop_at is not None and not self.queue and (
+                        not self._blocked
+                        or time.monotonic() >= self._stop_at):
+                    break
+            for p in list(self._blocked):
+                self._drop(p)
+            self._count()
+        finally:
+            self._close()
+
+    def _read_wake(self) -> None:
+        self._n["stream_writer_wakes_total"] += 1
+        try:
+            while self._wake_r.recv(4096):
+                pass
+        except OSError:
+            pass  # drained
+
+    def _take(self) -> None:
+        """Every queued item into its connection's buffer, then one
+        send for each connection that got bytes."""
+        q, touched = self.queue, {}
+        lines = dropped = 0
+        while q:
+            p, rid, tok, done = q.popleft()
+            if p.dead:
+                dropped += tok is not None  # an end marker is no line
+                continue
+            if rid is not None:
+                p.wbuf += b'{"rid": %d, "token": %d, "done": %s}\n' % (
+                    rid, tok, b"true" if done else b"false")
+                lines += 1
+            elif tok is not None:
+                p.wbuf += json.dumps(tok).encode() + b"\n"
+            else:
+                p.closing = True
+            touched[p] = None
+        self._n["stream_lines_total"] += lines
+        self._n["stream_dropped_lines_total"] += dropped
+        for p in touched:
+            self._flush(p)
+
+    def _flush(self, p: _Pending) -> None:
+        if p.wbuf:
+            try:
+                sent = p.sock.send(p.wbuf, socket.MSG_DONTWAIT)
+            except (BlockingIOError, InterruptedError):
+                sent = 0
+            except OSError:  # reset, closed: this peer alone is gone
+                self._drop(p)
+                return
+            del p.wbuf[:sent]
+            if p.wbuf:
+                self._n["stream_deferred_sends_total"] += 1
+                if p not in self._blocked:
+                    self._sel.register(p.sock, selectors.EVENT_WRITE, p)
+                    self._blocked.add(p)
+                return
+        if p in self._blocked:
+            self._unregister(p)
+        if p.closing:
+            p.outbox.put(None)
+
+    def _drop(self, p: _Pending) -> None:
+        self._n["stream_dropped_lines_total"] += p.wbuf.count(b"\n")
+        p.wbuf.clear()
+        p.dead = True
+        if p in self._blocked:
+            self._unregister(p)
+        p.outbox.put(None)
+
+    def _unregister(self, p: _Pending) -> None:
+        self._blocked.discard(p)
+        self._sel.unregister(p.sock)
+
+    def _count(self) -> None:
+        for name, n in self._n.items():
+            if n:
+                self._metrics.counter(name).add(n)
+                self._n[name] = 0
+
+    def _close(self) -> None:
+        for s in (self._wake_r, self._wake_w):
+            try:
+                s.close()
+            except OSError:
+                pass
+        self._sel.close()
 
 
 class ServingServer:
@@ -438,6 +662,8 @@ class ServingServer:
         self._started = False
         self._listen_sock: Optional[socket.socket] = None
         self._threads = []
+        # the one writer of streamed lines, built and started by start()
+        self._stream_writer: Optional[_StreamWriter] = None
         self._conn_threads = []
         self._conns = []
         self._conns_lock = threading.Lock()
@@ -496,6 +722,8 @@ class ServingServer:
         self._listen_sock.listen(64)
         self.port = self._listen_sock.getsockname()[1]
         self._started = True
+        self._stream_writer = _StreamWriter(self.metrics)
+        self._stream_writer.start()
         for name, fn in (("engine", self._engine_loop),
                          ("accept", self._accept_loop)):
             t = threading.Thread(target=fn, daemon=True,
@@ -520,6 +748,11 @@ class ServingServer:
             if t is threading.current_thread():
                 continue
             t.join(timeout=timeout_s)
+        if self._stream_writer is not None:
+            # the engine has put every request's end on the writer's
+            # queue: let it send what it holds before the conn threads
+            # are waited for
+            self._stream_writer.stop()
         if self._listen_sock is not None:
             try:
                 self._listen_sock.close()
@@ -600,6 +833,8 @@ class ServingServer:
                     eng.step()
                     after = eng.num_queued + eng.num_active
                     self._consec_errors = 0
+                    # the step's tokens and replies: one wake-up for all
+                    self._stream_writer.kick()
                     if after and after == before and not eng.num_active:
                         # queued but nothing admissible and nothing
                         # decoding: don't hot-spin on the free list
@@ -643,8 +878,10 @@ class ServingServer:
                                 self._fail_engine()
                         else:
                             self._fail_engine()
+                    self._stream_writer.kick()
                     time.sleep(self.poll_interval_s)
                 continue
+            self._stream_writer.kick()  # replies the inbox gave
             if self._stopping and self._inbox.empty():
                 self._resolve_swap_pending(
                     {"error": "ServerEvicted",
@@ -656,8 +893,9 @@ class ServingServer:
                     # pending outbox (evicted replies already sent by
                     # close() -> on_complete)
                     for p in self._pending.values():
-                        p.outbox.put(None)
+                        p.put(None)
                     self._pending.clear()
+                    self._stream_writer.kick()
                     self._engine_done.set()
                 return
             self._wake.wait(timeout=self.poll_interval_s)
@@ -784,11 +1022,7 @@ class ServingServer:
             replay_prompt = np.concatenate(
                 [req.prompt, np.asarray(req.generated, np.int32)])
             remaining = req.max_new_tokens - len(req.generated)
-            on_token = None
-            if pending is not None and pending.stream:
-                def on_token(rid, tok, done, _p=pending):
-                    _p.outbox.put({"rid": rid, "token": int(tok),
-                                   "done": bool(done)})
+            on_token = None if pending is None else pending.on_token()
             try:
                 new_rid = self.engine.submit(
                     replay_prompt, max_new_tokens=remaining,
@@ -812,10 +1046,8 @@ class ServingServer:
                              state="replay_failed")
                     self.tracer.finish(tr, state="replay_failed")
                 if pending is not None:
-                    pending.outbox.put(
-                        {"error": "ReplayFailed",
-                         "reason": f"{type(e).__name__}: {e}"})
-                    pending.outbox.put(None)
+                    pending.finish({"error": "ReplayFailed",
+                                    "reason": f"{type(e).__name__}: {e}"})
                 continue
             self.metrics.counter("replayed_requests_total").add()
             self.tracer.annotate(
@@ -847,44 +1079,49 @@ class ServingServer:
         except Exception:
             pass
         for p in self._pending.values():
-            p.outbox.put(dict(err))
-            p.outbox.put(None)
+            p.finish(dict(err))
         self._pending.clear()
         while True:
-            try:
-                _payload, p = self._inbox.get_nowait()
-            except queue_mod.Empty:
+            item = self._take_inbox()
+            if item is None:
                 break
-            p.outbox.put(dict(err))
-            p.outbox.put(None)
+            item[1].finish(dict(err))
+        self._stream_writer.kick()
+
+    def _take_inbox(self) -> Optional[tuple]:
+        """The next ``(payload, pending)`` off the inbox, or None. From
+        here the engine thread owes the pending its end."""
+        try:
+            payload, pending = self._inbox.get_nowait()
+        except queue_mod.Empty:
+            return None
+        pending.taken = True
+        return payload, pending
 
     def _drain_inbox(self) -> None:
         while True:
-            try:
-                payload, pending = self._inbox.get_nowait()
-            except queue_mod.Empty:
+            item = self._take_inbox()
+            if item is None:
                 return
+            payload, pending = item
             if payload.get("ctl") == "leak_check":
                 # page-accounting audit, answered ON the engine thread
                 # so it never races a step's allocator mutations (the
                 # chaos harness's per-replica invariant probe)
-                pending.outbox.put(self._leak_check())
-                pending.outbox.put(None)
+                pending.finish(self._leak_check())
                 continue
             if payload.get("ctl") == "fetch_pages":
                 # r20 handoff serving side: pack/serve chain blobs ON
                 # the engine thread — device reads (pack_page_blob via
                 # _read_page) and tier index walks must not race a
                 # step's pool donation or LRU mutation
-                pending.outbox.put(self._serve_fetch_pages(payload))
-                pending.outbox.put(None)
+                pending.finish(self._serve_fetch_pages(payload))
                 continue
             if payload.get("ctl") == "import_blobs":
                 # r20 handoff/prefetch receiving side: tier puts are
                 # engine-thread state (the conn thread already did the
                 # network pull; this is dict inserts + crc checks)
-                pending.outbox.put(self._import_blobs(payload))
-                pending.outbox.put(None)
+                pending.finish(self._import_blobs(payload))
                 continue
             if payload.get("ctl") == "swap":
                 # weight hot-swap (r24): the conn thread already
@@ -892,22 +1129,16 @@ class ServingServer:
                 # apply until active slots drain (admission pauses,
                 # nothing is dropped — _maybe_apply_swap finishes it)
                 if self._swap_pending is not None:
-                    pending.outbox.put(
+                    pending.finish(
                         {"error": "SwapFailed",
                          "reason": "another weight swap is already "
                                    "pending on this replica"})
-                    pending.outbox.put(None)
                     continue
                 self.engine.pause_admission = True
                 deadline = time.monotonic() + float(
                     payload.get("timeout_s") or 120.0)
                 self._swap_pending = (payload, pending, deadline)
                 continue
-
-            def on_token(rid, tok, done, _p=pending):
-                if _p.stream:
-                    _p.outbox.put({"rid": rid, "token": int(tok),
-                                   "done": bool(done)})
 
             # r20: a generate that rode a wire handoff carries the
             # fetched blobs — import them into the cache tiers NOW so
@@ -937,7 +1168,7 @@ class ServingServer:
                     eos_token=payload.get("eos"),
                     priority=payload.get("priority", Priority.NORMAL),
                     deadline_t=payload.get("deadline_t"),
-                    on_token=on_token,
+                    on_token=pending.on_token(),
                     # a prefill_only job is handoff-blocking: the
                     # router is mid-handoff and a decode replica waits
                     # on this chain (scheduler boost, r20)
@@ -953,9 +1184,8 @@ class ServingServer:
                 # one malformed payload (e.g. prompt [null] -> numpy
                 # TypeError) must cost that client a BadRequest, never
                 # the thread every other client depends on
-                pending.outbox.put({"error": "BadRequest",
-                                    "reason": f"{type(e).__name__}: {e}"})
-                pending.outbox.put(None)
+                pending.finish({"error": "BadRequest",
+                                "reason": f"{type(e).__name__}: {e}"})
                 continue
             self._pending[rid] = pending
 
@@ -970,8 +1200,7 @@ class ServingServer:
         _payload, pending, _deadline = self._swap_pending
         self._swap_pending = None
         self.metrics.counter("weight_swaps_failed_total").add()
-        pending.outbox.put(dict(reply))
-        pending.outbox.put(None)
+        pending.finish(dict(reply))
 
     def _maybe_apply_swap(self, eng) -> None:
         """Engine-thread gate of a parked swap: once active slots
@@ -987,8 +1216,7 @@ class ServingServer:
         reply = self._apply_swap(eng, payload)
         eng.pause_admission = False
         self._wake.set()
-        pending.outbox.put(reply)
-        pending.outbox.put(None)
+        pending.finish(reply)
 
     def _apply_swap(self, eng, payload: Dict) -> Dict:
         """Apply a drained, pre-validated swap (engine thread). Any
@@ -1105,7 +1333,7 @@ class ServingServer:
             payload["rollback"] = True
         if timeout_s is not None:
             payload["timeout_s"] = float(timeout_s)
-        pending = _Pending(stream=False)
+        pending = _Pending()
         self._inbox.put((payload, pending))
         self._wake.set()
         self._await_outbox(pending, send)
@@ -1191,8 +1419,7 @@ class ServingServer:
         else:  # evicted (drain/close)
             msg = {"rid": req.req_id, "error": "ServerEvicted",
                    "reason": "server shutting down"}
-        pending.outbox.put(msg)
-        pending.outbox.put(None)
+        pending.finish(msg)
 
     # -- connection threads ------------------------------------------------
 
@@ -1256,7 +1483,7 @@ class ServingServer:
                     send({"error": "BadRequest", "reason": str(e)})
                     continue
                 try:
-                    self._handle(msg, send)
+                    self._handle(msg, send, conn)
                 except ServerOverloaded as e:
                     # submit-gate rejections get their own counter:
                     # engine-side sheds count under requests_total +
@@ -1279,7 +1506,7 @@ class ServingServer:
                 if conn in self._conns:
                     self._conns.remove(conn)
 
-    def _handle(self, msg: Dict, send) -> None:
+    def _handle(self, msg: Dict, send, conn: socket.socket) -> None:
         from ..distributed.fault_inject import InjectedFault, fault_point
 
         op = msg.get("op", "generate")
@@ -1390,7 +1617,7 @@ class ServingServer:
         if op == "leak_check":
             # answered on the ENGINE thread via the inbox so the audit
             # can't race a step; same outbox plumbing as generate
-            pending = _Pending(stream=False)
+            pending = _Pending()
             self._inbox.put(({"ctl": "leak_check"}, pending))
             self._wake.set()
             self._await_outbox(pending, send)
@@ -1413,7 +1640,7 @@ class ServingServer:
                 send({"error": "BadRequest",
                       "reason": "fetch_pages cursor must be an int"})
                 return
-            pending = _Pending(stream=False)
+            pending = _Pending()
             self._inbox.put(({"ctl": "fetch_pages", "keys": keys,
                               "heads": heads, "cursor": cursor},
                              pending))
@@ -1516,8 +1743,11 @@ class ServingServer:
             if check is not None:
                 check(self.engine.num_queued + self._inbox.qsize())
             handoff = self._handoff_fetch(msg.get("fetch_from"), prompt)
-        pending = _Pending(stream=bool(msg.get("stream", False))
-                           and not prefill_only)
+        # a streamed generate's lines go out through the stream writer,
+        # straight to this connection's socket
+        stream = bool(msg.get("stream", False)) and not prefill_only
+        pending = (_Pending(self._stream_writer, conn) if stream
+                   else _Pending())
         with self._admission_lock:
             # submit-time overload gate, atomic with the enqueue so
             # concurrent connections can't all slip under the depth
@@ -1548,16 +1778,21 @@ class ServingServer:
                       transform=None) -> None:
         """Relay one request's outbox to the client until the None
         sentinel (``transform``, when given, rewrites each message —
-        the prefill-ack path). Closes the submit-vs-shutdown race: if
-        the engine thread has fully EXITED (mere stop() intent is not
-        enough — graceful shutdown still finishes in-flight work and
-        delivers real results), the request can never complete, so
-        answer a typed ServerEvicted instead of hanging."""
+        the prefill-ack path); a streaming request's outbox holds the
+        sentinel alone, put there by the stream writer after the last
+        line. Closes the submit-vs-shutdown race: if the engine thread
+        has fully EXITED (mere stop() intent is not enough — graceful
+        shutdown still finishes in-flight work and delivers real
+        results), the request can never complete, so answer a typed
+        ServerEvicted instead of hanging — unless the engine took a
+        streaming request, whose end the writer still owes and
+        sends."""
         while True:
             try:
                 out = pending.outbox.get(timeout=1.0)
             except queue_mod.Empty:
-                if self._engine_done.is_set():
+                if self._engine_done.is_set() and not (
+                        pending.stream and pending.taken):
                     send({"error": "ServerEvicted",
                           "reason": "server shutting down"})
                     return
@@ -1725,7 +1960,7 @@ class ServingServer:
             return
         self.metrics.counter("handoff_bytes_total").add(nbytes)
         ms = (time.perf_counter() - t0) * 1e3
-        pending = _Pending(stream=False)
+        pending = _Pending()
         self._inbox.put(({"ctl": "import_blobs", "blobs": blobs,
                           "heads": heads}, pending))
         self._wake.set()

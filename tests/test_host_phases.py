@@ -489,6 +489,21 @@ def _trace(work, tmp_path):
     return _host_events(str(tmp_path))
 
 
+def _idle_after(eng, n0, timeout_s=60.0):
+    """Wait for the engine to commit a record past the first ``n0`` that
+    leaves it with nothing active and nothing queued. A reply reaches
+    its client from inside the step that finished the request, before
+    that step's record is committed."""
+    deadline = time.monotonic() + timeout_s
+    while True:
+        tl = eng.step_timeline()
+        if len(tl) > n0 and tl[-1]["slots_active"] == 0 \
+                and tl[-1]["queued"] == 0:
+            return len(tl)
+        assert time.monotonic() < deadline, "the engine never went idle"
+        time.sleep(0.005)
+
+
 def _inside(ev, spans):
     return any(a <= ev[1] and ev[2] <= b for _, a, b in spans)
 
@@ -569,10 +584,14 @@ class TestProfilerPlane:
             client_request("127.0.0.1", port, {
                 "op": "generate", "prompt": [1, 2, 3, 4, 5],
                 "max_new_tokens": 3})  # compiled before the trace
-            n0 = len(srv.engine.step_timeline())
-            lines = _trace(lambda: client_request("127.0.0.1", port, {
-                "op": "generate", "prompt": [1, 2, 3, 4, 5, 6],
-                "max_new_tokens": 5}), tmp_path)
+            n0 = _idle_after(srv.engine, 0)
+
+            def work():
+                client_request("127.0.0.1", port, {
+                    "op": "generate", "prompt": [1, 2, 3, 4, 5, 6],
+                    "max_new_tokens": 5})
+                _idle_after(srv.engine, n0)
+            lines = _trace(work, tmp_path)
             tl = srv.engine.step_timeline()[n0:]
         finally:
             srv.stop()
