@@ -244,12 +244,12 @@ def gated_ffn(u, w_gate, w_up, w_down, activation: str = "silu",
     """``(act(gate_scale * u W_gate) * (u W_up)) W_down``: an expert
     every token goes through (a shared expert), or a dense layer, as
     plain matmuls."""
-    from ..ops.pallas.grouped_matmul import ACTIVATIONS
+    from ..ops.pallas.grouped_matmul import glu
     gate = jnp.matmul(u, w_gate, preferred_element_type=jnp.float32)
     if gate_scale != 1.0:
         gate = gate * gate_scale
-    mid = ACTIVATIONS[activation](gate) * \
-        jnp.matmul(u, w_up, preferred_element_type=jnp.float32)
+    mid = glu(activation)(
+        gate, jnp.matmul(u, w_up, preferred_element_type=jnp.float32))
     return jnp.matmul(mid.astype(u.dtype), w_down)
 
 
@@ -269,7 +269,8 @@ def dropless_experts(u, idx, gates, w_gate, w_up, w_down, *,
                      held=None, valid=None, activation: str = "relu"):
     """The part of ``sum_k gates[t, k] * FFN_{idx[t, k]}(u[t])`` that the
     experts held here give, with ``FFN_e(x) = (act(x @ w_gate[e]) * (x
-    @ w_up[e])) @ w_down[e]`` (``activation``: ``relu`` or ``silu``). No
+    @ w_up[e])) @ w_down[e]`` (``activation``: ``relu``, ``silu`` or
+    ``silu_limit<L>``, ops/pallas/grouped_matmul.py ``glu``). No
     capacity and no drop: every pick of a held expert is computed.
 
     u: [T, H]; idx, gates: [T, k] over ALL experts; w_gate, w_up:

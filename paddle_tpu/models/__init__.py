@@ -10,6 +10,8 @@ from .falcon_h1 import (FalconH1Config, FalconH1ForCausalLM, falcon_h1_34b,
                         falcon_h1_tiny)
 from .glm4_moe_lite import (Glm4MoeLiteConfig, Glm4MoeLiteForCausalLM,
                             glm4_7_flash, glm4_moe_lite_tiny)
+from .glm5_next import (Glm5NextConfig, Glm5NextForCausalLM, glm5_3_flash,
+                        glm5_next_tiny)
 from .smallthinker import (SmallThinkerConfig, SmallThinkerForCausalLM,
                            smallthinker_21b_a3b, smallthinker_tiny)
 from .solar_open2 import (SolarOpen2Config, SolarOpen2ForCausalLM,

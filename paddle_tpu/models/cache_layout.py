@@ -37,10 +37,14 @@ normed latent of ``rank`` values and the rotated key of ``rope``
 shared by the heads. It has one pool and no V pool, ``[P, page,
 latent_width]``: a page is at once the keys and the values of the
 absorbed decode (ops/pallas/paged_attention.py
-``paged_attention_latent``). A row is stored ``latent_width`` wide,
-``rank + rope`` rounded up to whole lane tiles of 128 with zeros
-behind: the chip's tiling of a pool's minor axis would pad it so in
-memory anyway, and the kernel then reads whole tiles. The page codecs
+``paged_attention_latent``). A latent entry with ``pooled`` = n > 1
+keeps ONE row for n consecutive positions (a page holds ``page / n``
+rows, under the same page table): the pooled keys of a sparse
+attention's indexer (models/glm5_next.py). A row is stored
+``latent_width`` wide, ``rank + rope`` rounded up to whole lane tiles
+of 128 with zeros behind: the chip's tiling of a pool's minor axis
+would pad it so in memory anyway, and the kernel then reads whole
+tiles. The page codecs
 (prefix cache, spill, handoff), int8 scales, the verify path and a
 mesh's head sharding all read ``[page, H, D]`` pairs: refused for this
 layout like the others.
@@ -64,6 +68,7 @@ class LayerCache:
     state: Optional[Tuple[int, int, int]] = None  # a state layer's
     conv: Optional[Tuple[int, int]] = None
     latent: Optional[Tuple[int, int]] = None  # a latent layer's
+    pooled: int = 1  # positions a latent row stands for
 
     @property
     def plain(self) -> bool:
@@ -115,8 +120,8 @@ def create_pools(lc: LayerCache, pages: int, page_size: int,
     pages and the scratch page behind them, zero-filled. A latent layer
     has the first alone."""
     if lc.latent is not None:
-        return (jnp.zeros((pages + 1, page_size, lc.latent_width),
-                          lc.dtype), None, None, None)
+        return (jnp.zeros((pages + 1, page_size // lc.pooled,
+                           lc.latent_width), lc.dtype), None, None, None)
     if lc.heads_major:
         shape = (pages + 1, lc.kv_heads, page_size, lc.head_dim)
         return (jnp.zeros(shape, lc.dtype), jnp.zeros(shape, lc.dtype),

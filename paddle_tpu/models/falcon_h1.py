@@ -60,9 +60,9 @@ import jax.numpy as jnp
 from ..nn.container import LayerList
 from ..nn.layer import Layer
 from .cache_layout import LayerCache, StateCache
-from .smallthinker import (ServedDecoderLM, _raw, chunk_attention, kv_append,
-                           rms_norm32, rotate)
-from .solar_open2 import causal_conv, init_a_log, init_conv, init_dt_bias
+from .served import (ServedDecoderLM, _raw, causal_conv, chunk_attention,
+                     kv_append, rms_norm32, rotate)
+from .solar_open2 import init_a_log, init_conv, init_dt_bias
 
 F32 = jnp.float32
 

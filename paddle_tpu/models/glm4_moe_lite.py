@@ -60,9 +60,9 @@ import jax.numpy as jnp
 from ..nn.container import LayerList
 from ..nn.layer import Layer
 from .cache_layout import LatentCache, LayerCache
-from .smallthinker import (ServedDecoderLM, _raw, chunk_attention,
-                           rms_norm32, rotate)
-from .solar_open2 import init_router_bias, sigmoid_moe
+from .served import (ServedDecoderLM, _raw, chunk_attention,
+                     init_router_bias, latent_append, rms_norm32, rotate,
+                     sigmoid_moe)
 
 
 @dataclasses.dataclass
@@ -225,51 +225,6 @@ class Glm4MoeLiteModel(Layer):
             Glm4MoeLiteBlock(cfg, make, i < cfg.first_k_dense_replace)
             for i in range(cfg.num_hidden_layers)])
         self.norm = make((cfg.hidden_size,), "norm")
-
-
-def latent_append(cache: LatentCache, rows, valid_len=None) -> LatentCache:
-    """Write the chunk's rows ([B, S, <= W]: latent, rotated key) into
-    the pool ``[P, page, W]``, zeros behind them, and advance the
-    lengths. A single token a sequence (``valid_len`` None) goes to
-    position ``seq_lens`` of its slot; a prompt (``valid_len`` [B],
-    into FRESH slots: positions from 0) writes whole pages, and what
-    its last page holds past ``valid_len`` is padding that the lengths
-    hide until decoding overwrites it. Empty rows (length 0 at a
-    single-token step), pages past a prompt's end and positions past
-    the table go to the pool's scratch page. Both writes index the
-    pool's leading axis alone, so XLA scatters in place into the donated
-    pool (models/smallthinker.py ``kv_append``)."""
-    b, s, _ = rows.shape
-    n_pool, page, w = cache.pages.shape
-    width = cache.page_table.shape[1]
-    scratch = n_pool - 1
-    rows = jnp.pad(rows.astype(cache.pages.dtype),
-                   ((0, 0), (0, 0), (0, w - rows.shape[-1])))
-    if valid_len is None:
-        pos = cache.seq_lens
-        keep = (pos > 0) & (pos < width * page)
-        pages = jnp.take_along_axis(
-            cache.page_table, jnp.minimum(pos // page, width - 1)[:, None],
-            axis=1)[:, 0]
-        at = jnp.where(keep, pages * page + pos % page, scratch * page)
-        pool = cache.pages.reshape(n_pool * page, w).at[at].set(
-            rows[:, 0]).reshape(cache.pages.shape)
-        new_lens = jnp.minimum(pos + 1, width * page)
-    else:
-        new_lens = jnp.minimum(valid_len.astype(jnp.int32), width * page)
-        n_pg = -(-s // page)
-        entry = jnp.arange(n_pg, dtype=jnp.int32)[None]
-        keep = (entry <= ((new_lens - 1) // page)[:, None]) & \
-            (entry < width)
-        pages = jnp.take_along_axis(
-            cache.page_table,
-            jnp.broadcast_to(jnp.minimum(entry, width - 1), (b, n_pg)),
-            axis=1)
-        pages = jnp.where(keep, pages, scratch).reshape(-1)
-        rows = jnp.pad(rows, ((0, 0), (0, n_pg * page - s), (0, 0)))
-        pool = cache.pages.at[pages].set(
-            rows.reshape(b * n_pg, page, w))
-    return LatentCache(pool, cache.page_table, new_lens)
 
 
 class Glm4MoeLiteForCausalLM(ServedDecoderLM):

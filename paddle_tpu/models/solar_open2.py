@@ -51,8 +51,8 @@ import jax.numpy as jnp
 from ..nn.container import LayerList
 from ..nn.layer import Layer
 from .cache_layout import LayerCache, StateCache
-from .smallthinker import (ServedDecoderLM, _raw, chunk_attention, kv_append,
-                           rms_norm32)
+from .served import (ServedDecoderLM, _raw, causal_conv, chunk_attention,
+                     init_router_bias, kv_append, rms_norm32, sigmoid_moe)
 
 F32 = jnp.float32
 
@@ -202,10 +202,6 @@ def init_conv(key, shape):
     return jax.random.uniform(key, shape, F32, -bound, bound)
 
 
-def init_router_bias(key, shape):
-    return jax.random.normal(key, shape, F32) * 0.01
-
-
 class SolarOpen2Block(Layer):
     def __init__(self, cfg: SolarOpen2Config, make, softmax: bool):
         super().__init__()
@@ -256,61 +252,6 @@ class SolarOpen2Model(Layer):
             SolarOpen2Block(cfg, make, i in cfg.gqa_layers)
             for i in range(cfg.num_hidden_layers)])
         self.norm = make((cfg.hidden_size,), "norm")
-
-
-def causal_conv(x, tail, w, bias=None):
-    """``out[t] = sum_i w[i] in[t - taps + 1 + i] (+ bias)`` a channel,
-    ``in`` the sequence ``x`` [B, T, C] behind the ``tail`` [B, taps - 1,
-    C] of what came before it (zeros at a sequence's start). Returns
-    ``(out [B, T, C] float32, tail + x)``."""
-    t = x.shape[1]
-    seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    wf = w.astype(F32)
-    out = sum(seen[:, i:i + t].astype(F32) * wf[i]
-              for i in range(w.shape[0]))
-    if bias is not None:
-        out = out + bias.astype(F32)
-    return out, seen
-
-
-def sigmoid_moe(blk, y, valid, *, eps, top_k, scaling, segment, held=None):
-    """``y + MoE(RMSNorm_2(y))`` of a layer whose router scores every
-    expert by a sigmoid and which has one shared expert (``blk``: its
-    ``ln2``, ``router``, ``router_bias``, ``w_gate`` / ``w_up`` /
-    ``w_down`` by expert and ``ws_*`` of the shared one), and the picks
-    computed per held expert. A prompt goes through ``segment``
-    positions at a time: the rows laid out by expert are sized for
-    EVERY pick of the rows given (none is dropped), eight times what a
-    chip that holds an eighth of the experts gets of a long prompt."""
-    from ..distributed.moe import (dropless_experts, gated_ffn,
-                                   route_sigmoid_top_k)
-    shape = y.shape
-    y = y.reshape(-1, shape[-1])
-    valid = valid.reshape(-1)
-    out, cnt = [], 0
-    for lo in range(0, y.shape[0], segment):
-        ys = y[lo:lo + segment]
-        if out:
-            # one segment's rows at a time
-            ys, _ = jax.lax.optimization_barrier((ys, out[-1]))
-        u32 = rms_norm32(ys, _raw(blk.ln2), eps)
-        with jax.named_scope("pt.moe.router"):
-            idx, gates = route_sigmoid_top_k(
-                u32, _raw(blk.router), _raw(blk.router_bias), top_k,
-                scaling)
-        u = u32.astype(y.dtype)
-        with jax.named_scope("pt.moe.experts"):
-            m, n = dropless_experts(
-                u, idx, gates, _raw(blk.w_gate), _raw(blk.w_up),
-                _raw(blk.w_down), held=held,
-                valid=valid[lo:lo + segment], activation="silu")
-        with jax.named_scope("pt.moe.shared"):
-            m = m + gated_ffn(u, _raw(blk.ws_gate), _raw(blk.ws_up),
-                              _raw(blk.ws_down), "silu")
-        out.append(ys + m)
-        cnt = cnt + n
-    y = out[0] if len(out) == 1 else jnp.concatenate(out, axis=0)
-    return y.reshape(shape), cnt
 
 
 class SolarOpen2ForCausalLM(ServedDecoderLM):

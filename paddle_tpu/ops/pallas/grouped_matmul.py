@@ -12,7 +12,8 @@ index, so nothing is fetched for them and their bodies are skipped;
 their output rows are never read.
 
 ``grouped_ffn_in`` computes ``act(x @ w_gate[e]) * (x @ w_up[e])`` in
-one pass over ``x`` (``act`` the model's: ``relu`` or ``silu``);
+one pass over ``x`` (``act`` the model's: ``relu``, ``silu`` or a clamped
+``silu``, see :func:`glu`);
 ``grouped_matmul`` is the plain product (the down
 projection). Both accumulate in float32 on the MXU and store in the
 activation's dtype. Off the TPU the same layout runs through a gather
@@ -46,6 +47,20 @@ def _col_block(n: int, cap: int = 256) -> int:
 
 
 ACTIVATIONS = {"relu": lambda a: jnp.maximum(a, 0.0), "silu": jax.nn.silu}
+CLAMPED = "silu_limit"
+
+
+def glu(activation: str):
+    """``f(gate, up)`` of a gated unit: ``act(gate) * up`` for a name of
+    ``ACTIVATIONS``; ``silu_limit<L>`` (``swiglu_limit`` L) is ``silu(min(
+    gate, L)) * clip(up, -L, L)``, gpt-oss's clamp without its alpha and
+    its +1."""
+    if activation.startswith(CLAMPED):
+        lim = float(activation[len(CLAMPED):])
+        return lambda g, u: jax.nn.silu(jnp.minimum(g, lim)) * \
+            jnp.clip(u, -lim, lim)
+    act = ACTIVATIONS[activation]
+    return lambda g, u: act(g) * u
 
 
 def _kernel(te_ref, used_ref, x_ref, *refs, act):
@@ -61,7 +76,7 @@ def _kernel(te_ref, used_ref, x_ref, *refs, act):
             up = jax.lax.dot_general(
                 x, w_refs[1][0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            acc = act(acc) * up
+            acc = act(acc, up)
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -80,13 +95,13 @@ def _reference(x, ws, tile_expert, tm: int, act):
     if act is not None:
         up = jnp.einsum("tmk,tkn->tmn", xt, ws[1][tile_expert],
                         preferred_element_type=jnp.float32)
-        acc = act(acc) * up
+        acc = act(acc, up)
     return acc.astype(x.dtype).reshape(nt * tm, -1)
 
 
 def _call(name, x, ws, tile_expert, used, tm: int, act=None):
-    """``act``: the gate's activation where ``ws`` is (gate, up), None
-    for the plain product."""
+    """``act``: ``f(gate, up)`` (:func:`glu`) where ``ws`` is (gate, up),
+    None for the plain product."""
     if not grouped_supported():
         return _reference(x, ws, tile_expert, tm, act)
     mp, k = x.shape
@@ -123,14 +138,14 @@ def _call(name, x, ws, tile_expert, used, tm: int, act=None):
 
 def grouped_ffn_in(x, w_gate, w_up, tile_expert, used, tm: int,
                    activation: str = "relu"):
-    """``act(x @ w_gate[e]) * (x @ w_up[e])`` per row tile, ``act`` one
-    of ``ACTIVATIONS``.
+    """``act(x @ w_gate[e]) * (x @ w_up[e])`` per row tile, ``activation``
+    a name :func:`glu` knows.
 
     x: [tiles * tm, K]; w_gate, w_up: [E, K, F]; tile_expert: [tiles]
     int32 (each tile's expert; tiles past ``used`` repeat the last
     used tile's); used: [1] int32. Returns [tiles * tm, F]."""
     return _call("moe_ffn_in", x, (w_gate, w_up), tile_expert, used, tm,
-                 ACTIVATIONS[activation])
+                 glu(activation))
 
 
 def grouped_matmul(x, w, tile_expert, used, tm: int):

@@ -701,15 +701,19 @@ def paged_attention_grouped(q, k_pages, v_pages, page_table, seq_lens,
 LATENT_BLOCK_PAGES = 16
 
 
-def _decode_latent_kernel(pt_ref, len_ref, q_ref, pool_ref, o_ref, buf, sems,
-                          *, page: int, scale: float, rank: int, block: int):
+def _decode_latent_kernel(pt_ref, len_ref, q_ref, *refs, page: int,
+                          scale: float, rank: int, block: int, masked: bool):
     """One grid step = one sequence; a parked slot (length 0) walks no
     page and returns zeros. ``q_ref`` is ``[1, Hp, W]``: the heads
     padded to a sublane tile with zero rows, whose outputs the caller
     drops. ``buf`` ``[2, block * page, W]``: what a block's copies do
     not fill (pages past the sequence's last) keeps older rows, which
     the mask hides; it is zeroed once a call so that nothing read from
-    it was never written."""
+    it was never written. ``masked``: ``refs`` begin with ``sel_ref``
+    ``[1, 1, positions]`` int32, and only the positions it marks
+    nonzero are attended to."""
+    sel_ref, (pool_ref, o_ref, buf, sems) = \
+        (refs[0], refs[1:]) if masked else (None, refs)
     b = pl.program_id(0)
     seq_len = len_ref[b]
     n_pages = pl.cdiv(seq_len, page)
@@ -751,6 +755,9 @@ def _decode_latent_kernel(pt_ref, len_ref, q_ref, pool_ref, o_ref, buf, sems,
             preferred_element_type=jnp.float32) * scale  # [Hp, rows]
         kpos = i * rows + jax.lax.broadcasted_iota(jnp.int32, (hp, rows), 1)
         seen = kpos < seq_len
+        if masked:
+            sel = sel_ref[0, :, pl.ds(pl.multiple_of(i * rows, rows), rows)]
+            seen = seen & (sel > 0)
         s = jnp.where(seen, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -770,20 +777,32 @@ def _decode_latent_kernel(pt_ref, len_ref, q_ref, pool_ref, o_ref, buf, sems,
 
 
 def _paged_decode_latent_pallas(q, pages, page_table, seq_lens, rank, scale,
-                                block, interpret=False):
-    """q: [B, H, <= W]; pages [P, page, W]; returns [B, H, rank]."""
+                                block, interpret=False, sel=None):
+    """q: [B, H, <= W]; pages [P, page, W]; returns [B, H, rank]. With
+    ``sel`` [B, >= table positions] the call is ``sparse_decode_latent``
+    (ops/pallas/sparse_attention.py), without it ``paged_decode_latent``."""
     b, h, qw = q.shape
     page, w = pages.shape[1:]
     hp = _pad_to_sublane_tile(h, pages.dtype)
     qp = jnp.pad(q.astype(pages.dtype),
                  ((0, 0), (0, hp - h), (0, w - qw)))
     mp = page_table.shape[1]
+    operands, mask_spec = (qp, pages), []
+    if sel is not None:
+        # the mask a position a sequence, padded to whole blocks of rows
+        n_pos = -(-mp * page // (block * page)) * block * page
+        sel = jnp.pad(sel.astype(jnp.int32),
+                      ((0, 0), (0, n_pos - sel.shape[1])))[:, None]
+        operands = (qp, sel, pages)
+        mask_spec = [pl.BlockSpec((1, 1, n_pos), lambda i, *_: (i, 0, 0),
+                                  memory_space=pltpu.VMEM)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, hp, w), lambda i, *_: (i, 0, 0),
                          memory_space=pltpu.VMEM),
+            *mask_spec,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, hp, rank), lambda i, *_: (i, 0, 0),
@@ -794,9 +813,9 @@ def _paged_decode_latent_pallas(q, pages, page_table, seq_lens, rank, scale,
         ],
     )
     out = named_pallas_call(
-        "paged_decode_latent",
+        "paged_decode_latent" if sel is None else "sparse_decode_latent",
         functools.partial(_decode_latent_kernel, page=page, scale=scale,
-                          rank=rank, block=block),
+                          rank=rank, block=block, masked=sel is not None),
         grid_spec=grid_spec, interpret=interpret,
         out_shape=jax.ShapeDtypeStruct((b, hp, rank), q.dtype),
         cost_estimate=pl.CostEstimate(
@@ -805,12 +824,12 @@ def _paged_decode_latent_pallas(q, pages, page_table, seq_lens, rank, scale,
             transcendentals=int(b) * h * page * mp),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-    )(page_table, seq_lens, qp, pages)
+    )(page_table, seq_lens, *operands)
     return out[:, :h]
 
 
 def paged_attention_latent_reference(q, pages, page_table, seq_lens,
-                                     rank: int, scale: float):
+                                     rank: int, scale: float, sel=None):
     """Dense-gather reference of :func:`paged_attention_latent`."""
     b, h, qw = q.shape
     page = pages.shape[1]
@@ -820,7 +839,10 @@ def paged_attention_latent_reference(q, pages, page_table, seq_lens,
     logits = jnp.einsum("bhw,btw->bht", q.astype(jnp.float32),
                         rows[..., :qw]) * scale
     seen = (jnp.arange(mp * page, dtype=jnp.int32)[None]
-            < seq_lens[:, None])[:, None]
+            < seq_lens[:, None])
+    if sel is not None:
+        seen = seen & (sel[:, :mp * page] > 0)
+    seen = seen[:, None]
     logits = jnp.where(seen, logits, _NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.where(seen, jnp.exp(logits - m), 0.0)
@@ -845,14 +867,17 @@ def paged_latent_supported(pool_shape, rank: int,
 
 def paged_attention_latent(q, pages, page_table, seq_lens, rank: int,
                            scale: float, block: Optional[int] = None,
-                           interpret: bool = False):
+                           interpret: bool = False, sel=None):
     """Single-token absorbed latent attention over a latent pool. q:
     ``[B, H, rank + rope]``, a head's ``[q_nope W_UK^T | rotated
     q_rope]``; pages ``[P, page, W]``, a position's row ``[latent
     (rank) | rotated shared key (rope) | zeros]``; seq_lens [B] lengths
     INCLUDING the appended token, 0 for a parked slot (zeros out).
     Returns ``[B, H, rank]``: softmax over the context of ``q . row *
-    scale``, times the rows' first ``rank`` columns. Not under head
+    scale``, times the rows' first ``rank`` columns. With ``sel`` [B,
+    >= table positions], nonzero at the positions a sequence selected
+    (ops/pallas/sparse_attention.py), the softmax is over those alone;
+    the walk still reads every page of the context. Not under head
     sharding (one latent serves every head)."""
     if get_head_sharding() is not None:
         raise NotImplementedError("latent pages under head sharding")
@@ -862,9 +887,9 @@ def paged_attention_latent(q, pages, page_table, seq_lens, rank: int,
         return _paged_decode_latent_pallas(
             q, pages, page_table.astype(jnp.int32),
             seq_lens.astype(jnp.int32), int(rank), float(scale), int(block),
-            interpret)
+            interpret, sel)
     return paged_attention_latent_reference(q, pages, page_table, seq_lens,
-                                            int(rank), float(scale))
+                                            int(rank), float(scale), sel)
 
 
 # --------------------------------------------------------------------------

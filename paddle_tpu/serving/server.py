@@ -2427,6 +2427,8 @@ def _build_model(name: str):
                                     falcon_h1_tiny)
     from ..models.glm4_moe_lite import (Glm4MoeLiteForCausalLM,
                                         glm4_7_flash, glm4_moe_lite_tiny)
+    from ..models.glm5_next import (Glm5NextForCausalLM, glm5_3_flash,
+                                    glm5_next_tiny)
     from ..models.gpt import (GPTForCausalLM, gpt_125m, gpt_1p3b,
                               gpt_350m, gpt_tiny)
     from ..models.smallthinker import (SmallThinkerForCausalLM,
@@ -2463,6 +2465,15 @@ def _build_model(name: str):
                "falcon_h1_34b_cut": (
                    FalconH1ForCausalLM, lambda: falcon_h1_34b(
                        6, vocab_size=32640, prefill_segment=2048,
+                       dtype="bfloat16")),
+               "glm5_next_tiny": (Glm5NextForCausalLM, glm5_next_tiny),
+               # the first pipeline stage at the published widths
+               # (layers 0 and 3-6; 1 and 2 are of layer 0's kind), one
+               # chip's share of eight: 36 of 288 experts, an eighth of
+               # the head
+               "glm5_3_flash_cut": (
+                   Glm5NextForCausalLM, lambda: glm5_3_flash(
+                       experts_held=(0, 36), vocab_size=19360,
                        dtype="bfloat16"))}
     if name not in configs:
         raise SystemExit(f"unknown --model {name!r}; choose from "

@@ -21,8 +21,8 @@ from paddle_tpu.models import (Glm4MoeLiteConfig, Glm4MoeLiteForCausalLM,
                                LatentCache, UnsupportedCacheLayout,
                                glm4_moe_lite_tiny)
 from paddle_tpu.models.cache_layout import create_pools
-from paddle_tpu.models.glm4_moe_lite import latent_append, split_kv_b
-from paddle_tpu.models.smallthinker import rotate
+from paddle_tpu.models.glm4_moe_lite import split_kv_b
+from paddle_tpu.models.served import latent_append, rotate
 from paddle_tpu.serving.prefix_cache import PrefixCache
 
 PAGE = 4

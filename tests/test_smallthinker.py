@@ -386,7 +386,7 @@ def test_paged_decode_kernel_walks_a_block_of_pages_a_turn(
                                       (128, 40)])
 def test_flash_prefill_kernel_group_7_and_a_window(interpret, s, window):
     """The window's edge inside a K block; blocks behind it skipped."""
-    from paddle_tpu.models.smallthinker import dense_attention
+    from paddle_tpu.models.served import dense_attention
     rng = np.random.default_rng(s)
     kvh, g, d = 2, 7, 64
     q = jnp.asarray(rng.standard_normal((1, s, kvh * g, d)), jnp.float32)
@@ -400,7 +400,7 @@ def test_flash_prefill_kernel_group_7_and_a_window(interpret, s, window):
                                rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.parametrize("act", ["relu", "silu"])
+@pytest.mark.parametrize("act", ["relu", "silu", "silu_limit1"])
 @pytest.mark.parametrize("rows,tm", [(12, 8), (300, 64)])
 def test_grouped_matmul_kernels_match_the_gathered_product(interpret, rows,
                                                            tm, act):
@@ -413,7 +413,7 @@ def test_grouped_matmul_kernels_match_the_gathered_product(interpret, rows,
     x = jnp.asarray(rng.standard_normal((tiles * tm, h)), jnp.float32)
     live = (tiles - 2) * tm
     mid = gm.grouped_ffn_in(x, wg, wu, tile_expert, used, tm, act)
-    want = gm._reference(x, (wg, wu), tile_expert, tm, gm.ACTIVATIONS[act])
+    want = gm._reference(x, (wg, wu), tile_expert, tm, gm.glu(act))
     np.testing.assert_allclose(np.asarray(mid)[:live], np.asarray(want)[:live],
                                rtol=1e-4, atol=1e-4)
     out = gm.grouped_matmul(want, wd, tile_expert, used, tm)

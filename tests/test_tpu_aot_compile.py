@@ -633,6 +633,120 @@ def test_hybrid_cell_decode_program_compiles(one_chip, monkeypatch):
             rf"%{name}[.\d]* = [^\n]*tpu_custom_call", txt)) == 6, name
 
 
+# -- the sparse attention kernels at GLM-5.3-Flash's published widths ----------
+# (64 heads over latent rows of 512, an indexer of 32 heads of 128 over
+# pooled keys of blocks of 4, the top 2,048; 32 slots, pages of 64, a
+# prompt's last segment of 8,192 queries at 32,768: models/glm5_next.py)
+
+def test_sparse_decode_latent_compiles(one_chip):
+    """A decoded token's walk over its slot's pages under the selection
+    mask: 32 slots of 64 heads against rows of 512 (four lane tiles, no
+    padding), a table of 544 pages, the mask a position."""
+    specs = (_bf16(one_chip, 32, 64, 512), _bf16(one_chip, 13313, 64, 512),
+             _bf16(one_chip, 32, 544, dt=jnp.int32),
+             _bf16(one_chip, 32, dt=jnp.int32),
+             _bf16(one_chip, 32, 544 * 64, dt=jnp.bool_))
+    assert pa.paged_latent_supported(specs[1].shape, 512, backend="tpu")
+    compiled = _compile(
+        lambda q, p, t, n, s: pa.paged_attention_latent(q, p, t, n, 512,
+                                                        1 / 16, sel=s),
+        *specs)
+    assert _kernel_calls(compiled) == 1
+    _named_once(compiled, "sparse_decode_latent")
+
+
+def test_sparse_prefill_kernels_compile(one_chip):
+    """The indexer's scores of a segment of 8,192 queries over 8,192
+    pooled keys (never stored per head) and the segment's attention in
+    dense tiles of 16 queries x 64 heads against 512 keys, the selection
+    a mask from the scores and each query's threshold: one call each."""
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+    lo, s, t = 24576, 8192, 32768
+    specs = (_bf16(one_chip, 1, s, 32, 128),
+             _bf16(one_chip, 1, s, 32, dt=jnp.float32),
+             _bf16(one_chip, 1, t // 4, 128),
+             _bf16(one_chip, 1, 64, s, 512), _bf16(one_chip, 1, t, 512),
+             _bf16(one_chip, 1, s, dt=jnp.float32))
+
+    def segment(qi, w, keys, qa, rows, tau):
+        scores = sa.index_scores(qi, w, keys, lo, 4)
+        return sa.sparse_prefill_latent(qa, rows, scores, tau, lo, 4,
+                                        1 / 16)
+    compiled = _compile(segment, *specs)
+    assert _kernel_calls(compiled) == 2
+    _named_once(compiled, "dsa_index_prefill", "sparse_prefill_latent")
+
+
+def test_sparse_cell_decode_program_compiles(one_chip, monkeypatch):
+    """The GLM-5.3-Flash cell's whole decode program (the engine's
+    `_build_decode` over an abstract model at the published widths, 5
+    layers, 32 slots, 13,312 pages of 64): it fits the chip, the DSA
+    layer walks its pages once and each KDA layer runs one state step."""
+    from paddle_tpu.inference.continuous_batching import \
+        ContinuousBatchingEngine
+    from paddle_tpu.models import (Glm5NextForCausalLM, cache_layout,
+                                   glm5_3_flash)
+    from paddle_tpu.nn.layer import functional_state
+
+    def S(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    class Shapes:  # `jnp` whose zeros are shapes: no pool is allocated
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def zeros(shape, dtype):
+            return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+
+    monkeypatch.setattr(cache_layout, "jnp", Shapes())
+    model = Glm5NextForCausalLM(glm5_3_flash(experts_held=(0, 36),
+                                             vocab_size=19360,
+                                             dtype="bfloat16"),
+                                abstract=True)
+    model.eval()
+    eng = ContinuousBatchingEngine(model, num_slots=32, page_size=64,
+                                   max_seq_len=34816, num_pages=13312)
+    monkeypatch.undo()
+    # the latent rows (512 wide) and the pooled keys (16 rows of 128 a
+    # page) under one page table
+    assert eng.latent_pool_bytes == 13313 * (64 * 512 + 16 * 128) * 2
+    state = jax.tree_util.tree_map(S, functional_state(model))
+    pools = jax.tree_util.tree_map(S, eng._pools)
+    packed = jax.ShapeDtypeStruct((32, eng.max_pages + 2), jnp.int32,
+                                  sharding=one_chip)
+    with fa.force_flash_for_aot():
+        compiled = eng._build_decode().lower(state, pools, packed).compile()
+    mem = compiled.memory_analysis()
+    live = (int(mem.argument_size_in_bytes) + int(mem.temp_size_in_bytes)
+            + int(mem.output_size_in_bytes) - int(mem.alias_size_in_bytes))
+    assert live < 16 * (1 << 30), live
+    _named(compiled, "sparse_decode_latent", "kda_decode", "moe_ffn_in",
+           "fused_argmax")
+    txt = compiled.as_text()
+    for name, n in (("sparse_decode_latent", 1), ("kda_decode", 4)):
+        assert len(re.findall(
+            rf"%{name}[.\d]* = [^\n]*tpu_custom_call", txt)) == n, name
+
+
+def test_dropless_experts_with_the_clamped_swiglu_compile(one_chip):
+    """GLM-5.3-Flash's expert layer: ``silu_limit10``, 36 of 288 experts
+    of 4096 x 2048 held, a decode step's 32 rows x 8 picks."""
+    from paddle_tpu.distributed.moe import dropless_experts
+    specs = (_bf16(one_chip, 32, 4096),
+             _bf16(one_chip, 32, 8, dt=jnp.int32),
+             _bf16(one_chip, 32, 8, dt=jnp.float32),
+             _bf16(one_chip, 36, 4096, 2048), _bf16(one_chip, 36, 4096, 2048),
+             _bf16(one_chip, 36, 2048, 4096),
+             _bf16(one_chip, 32, dt=jnp.bool_))
+    compiled = _compile(
+        lambda u, i, g, wg, wu, wd, ok: dropless_experts(
+            u, i, g, wg, wu, wd, held=(0, 36), valid=ok,
+            activation="silu_limit10"), *specs)
+    assert _kernel_calls(compiled) == 2
+    _named(compiled, "moe_ffn_in", "moe_ffn_out")
+
+
 def test_dropless_experts_with_silu_and_a_share_compile(one_chip):
     """The expert layer as the third decoder calls it: silu, 40 of 320
     experts held, a decode step's 32 rows x 8 picks."""

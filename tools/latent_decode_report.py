@@ -77,7 +77,7 @@ def main() -> int:
 
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.models.smallthinker import dense_attention
+    from paddle_tpu.models.served import dense_attention
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas import paged_attention as pa
 
