@@ -577,6 +577,7 @@ class Glm5NextForCausalLM(ServedDecoderLM):
         the normed input: the rows and pooled keys of every position
         first, then each segment's queries over them."""
         from ..ops.pallas.sparse_attention import (index_scores, kth_largest,
+                                                   prefill_tile_pairs,
                                                    select_blocks,
                                                    sparse_prefill_latent)
         c = self.config
@@ -594,7 +595,7 @@ class Glm5NextForCausalLM(ServedDecoderLM):
             nl = latent_append(lat, rows, valid_len=lens)
             ni = latent_append(idx, kbar, valid_len=-(-lens // pool))
             ni = LatentCache(ni.pages, ni.page_table, nl.seq_lens)
-        out, selected, causal, lo = [], 0, 0, 0
+        out, selected, causal, run, grid, lo = [], 0, 0, 0, 0, 0
         for h in hs:
             q, qi, w = self._dsa_queries(blk, h)
             s = q.shape[1]
@@ -605,7 +606,8 @@ class Glm5NextForCausalLM(ServedDecoderLM):
             with jax.named_scope("pt.attn.sparse"):
                 qa = jnp.einsum("bshn,hrn->bhsr", q, _raw(blk.w_uk))
                 oa = sparse_prefill_latent(qa, rows, scores, tau, lo, pool,
-                                           c.qk_head_dim ** -0.5)
+                                           c.qk_head_dim ** -0.5,
+                                           lengths=lens)
             out.append(self._dsa_out(blk, oa.swapaxes(1, 2), h))
             picked = select_blocks(scores, tau, jnp.broadcast_to(qpos, (b, s)),
                                    pool)
@@ -616,8 +618,10 @@ class Glm5NextForCausalLM(ServedDecoderLM):
             selected = selected + jnp.sum(jnp.where(
                 live, pool * jnp.sum(before, -1) + qpos % pool + 1, 0))
             causal = causal + jnp.sum(jnp.where(live, qpos + 1, 0))
+            tiles = prefill_tile_pairs(lens, lo, s)
+            run, grid = run + tiles[0], grid + tiles[1]
             lo += s
-        return out, nl, ni, (selected, causal)
+        return out, nl, ni, (selected, causal, run, grid)
 
     # -- the feed-forward -----------------------------------------------------
 
@@ -751,7 +755,14 @@ class Glm5NextForCausalLM(ServedDecoderLM):
         if counts:
             self._keep_stats(counts, prompt)
         if dsa:
-            self._stats = dict(self._stats or {}, dsa_positions={
-                "selected": sum(a for a, _ in dsa).astype(jnp.int32),
-                "causal": sum(b_ for _, b_ in dsa).astype(jnp.int32)})
+            sums = [sum(v).astype(jnp.int32) for v in zip(*dsa)]
+            stats = {"dsa_positions": dict(zip(("selected", "causal"),
+                                               sums[:2]))}
+            if prompt:
+                # the prompt kernel's (query tile, key tile) pairs: run
+                # at the true lengths, and what the bucket's whole
+                # causal square would run
+                stats["dsa_prefill_tiles"] = dict(zip(("run", "grid"),
+                                                      sums[2:]))
+            self._stats = dict(self._stats or {}, **stats)
         return x, new_caches

@@ -31,7 +31,8 @@ the selected positions. Its kernels:
   indexer's scores and each query's threshold. Inside a tile of keys
   the rows are laid out by position within a block, then by block, so
   that a tile of block scores, repeated once for each position of a
-  block, is the tile's mask.
+  block, is the tile's mask. Handed the prompts' true lengths, it
+  computes no tile of queries or of keys past a prompt's end.
 - ``dsa_index_prefill``: the indexer's scores of a prompt's queries over
   its pooled keys, a tile of queries' heads against a tile of blocks,
   the heads summed in the kernel: ``[T, heads, blocks]`` is never
@@ -49,6 +50,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -219,9 +221,37 @@ def block_major(rows, tk: int, pool: int):
         .reshape(b, t, w)
 
 
+def _prefill_live(lengths, lo: int, s: int):
+    """What the prompt kernel reads of the true lengths for a segment of
+    ``s`` queries at ``lo``, a pair a sequence ([2, B] int32): the query
+    tiles of the segment that start before the length (``q < it``) and
+    the key tile of the last position (0 for an empty sequence), where
+    the query tiles past the length park their reads. Worked out once a
+    call, as flash_attention's ``_live_blocks``."""
+    n = lengths.astype(jnp.int32)
+    # shifts of non-negative values: no floor division's sign fix-ups
+    tiles = jnp.right_shift(jnp.maximum(n - lo, 0) + Q_TILE - 1,
+                            Q_TILE.bit_length() - 1)
+    return jnp.stack([jnp.minimum(tiles, s // Q_TILE),
+                      jnp.right_shift(jnp.maximum(n - 1, 0),
+                                      K_TILE.bit_length() - 1)])
+
+
+def prefill_tile_pairs(lengths, lo: int, s: int):
+    """The (query tile, key tile) pairs the prompt kernel computes for a
+    segment of ``s`` queries at ``lo``: with the true ``lengths`` ([B]),
+    and without them (every query tile up to its diagonal, the padding's
+    too). Returns ``(run, grid)``, int32 scalars."""
+    first = lo + np.arange(-(-s // Q_TILE)) * Q_TILE
+    diag = (first + Q_TILE - 1) // K_TILE
+    run = jnp.where(first < lengths.astype(jnp.int32)[:, None], diag + 1, 0)
+    return (jnp.sum(run).astype(jnp.int32),
+            jnp.int32(lengths.shape[0] * int(np.sum(diag + 1))))
+
+
 def _prefill_kernel(q_ref, k_ref, sc_ref, tau_ref, o_ref, m_sc, l_sc, acc_sc,
                     *, lo: int, tq: int, tk: int, heads: int, pool: int,
-                    scale: float):
+                    scale: float, live=None):
     qi, kt = pl.program_id(1), pl.program_id(2)
     first = lo + qi * tq
     nb = tk // pool
@@ -233,7 +263,18 @@ def _prefill_kernel(q_ref, k_ref, sc_ref, tau_ref, o_ref, m_sc, l_sc, acc_sc,
         l_sc[...] = jnp.zeros(l_sc.shape, F32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
 
-    @pl.when(kt * tk <= first + tq - 1)
+    # a key tile past the diagonal holds no key a query of the tile
+    # sees. With ``live`` (``_prefill_live`` of the sequence) a query
+    # tile past the length runs no step: the init and the last write
+    # alone, which write zeros (0 / 1e-30). A live tile's diagonal never
+    # passes the length's key tile (16 positions never straddle a tile
+    # of 512), so a live row runs the tiles, in the order, it runs
+    # without.
+    run = kt * tk <= first + tq - 1
+    if live is not None:
+        run = run & (qi < live[0])
+
+    @pl.when(run)
     def _():
         w = q_ref.shape[-1]
         q = q_ref[0].reshape(rows, w)
@@ -269,35 +310,65 @@ def _prefill_kernel(q_ref, k_ref, sc_ref, tau_ref, o_ref, m_sc, l_sc, acc_sc,
         o_ref[0] = out.reshape(heads, tq, -1).astype(o_ref.dtype)
 
 
-def _prefill_pallas(qa, rows, scores, tau, lo, pool, scale, interpret):
+def _prefill_kernel_ragged(live_ref, *refs, **static):
+    """``_prefill_kernel`` under a scalar-prefetch grid: ``_prefill_live``
+    arrives first, in scalar memory."""
+    b = pl.program_id(0)
+    _prefill_kernel(*refs, live=(live_ref[0, b], live_ref[1, b]), **static)
+
+
+def _prefill_pallas(qa, rows, scores, tau, lo, pool, scale, interpret,
+                    lengths=None):
     b, h, s, w = qa.shape
     t = rows.shape[1]
     tq, tk = Q_TILE, K_TILE
     nb = tk // pool
     lat = block_major(rows, tk, pool)
 
-    def last(q):  # the last key tile a tile of queries sees
-        return (lo + q * tq + tq - 1) // tk
+    def tiles(i, q, k, live=None):
+        """The query tile and the key tile a grid step reads: a query
+        tile reads up to the last key tile it sees. One past the length
+        reads, every step, the tiles the step before it read (the last
+        live query tile and the length's key tile, that tile's
+        diagonal): nothing is fetched for it."""
+        kt = jnp.minimum(k, (lo + q * tq + tq - 1) // tk)
+        if live is None:
+            return q, kt
+        n, last_k = live[0, i], live[1, i]
+        return (jnp.minimum(q, jnp.maximum(n - 1, 0)),
+                jnp.where(q < n, kt, last_k))
 
-    kern = functools.partial(_prefill_kernel, lo=lo, tq=tq, tk=tk, heads=h,
-                             pool=pool, scale=scale)
+    static = dict(lo=lo, tq=tq, tk=tk, heads=h, pool=pool, scale=scale)
+    in_specs = [
+        pl.BlockSpec((1, h, tq, w),
+                     lambda i, q, k, *lv: (i, 0, tiles(i, q, k, *lv)[0], 0)),
+        pl.BlockSpec((1, tk, w),
+                     lambda i, q, k, *lv: (i, tiles(i, q, k, *lv)[1], 0)),
+        pl.BlockSpec((1, tq, nb),
+                     lambda i, q, k, *lv: (i, *tiles(i, q, k, *lv))),
+        pl.BlockSpec((1, tq, 1),
+                     lambda i, q, k, *lv: (i, tiles(i, q, k, *lv)[0], 0)),
+    ]
+    layout = dict(
+        # the key axis ends at the segment's last key tile
+        grid=(b, s // tq, -(-(lo + s) // tk)), in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, h, tq, w),
+                               lambda i, q, k, *lv: (i, 0, q, 0)),
+        scratch_shapes=[pltpu.VMEM((h * tq, 1), F32),
+                        pltpu.VMEM((h * tq, 1), F32),
+                        pltpu.VMEM((h * tq, w), F32)])
+    args = (qa, lat, scores, tau.astype(F32)[..., None])
+    if lengths is None:
+        kern = functools.partial(_prefill_kernel, **static)
+    else:
+        kern = functools.partial(_prefill_kernel_ragged, **static)
+        layout = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **layout))
+        args = (_prefill_live(lengths, lo, s),) + args
     pairs = min(lo + s // 2, t)  # keys a query sees, the mean
     return named_pallas_call(
         "sparse_prefill_latent", kern,
-        grid=(b, s // tq, t // tk),
-        in_specs=[
-            pl.BlockSpec((1, h, tq, w), lambda i, q, k: (i, 0, q, 0)),
-            pl.BlockSpec((1, tk, w),
-                         lambda i, q, k: (i, jnp.minimum(k, last(q)), 0)),
-            pl.BlockSpec((1, tq, nb),
-                         lambda i, q, k: (i, q, jnp.minimum(k, last(q)))),
-            pl.BlockSpec((1, tq, 1), lambda i, q, k: (i, q, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, tq, w), lambda i, q, k: (i, 0, q, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, w), qa.dtype),
-        scratch_shapes=[pltpu.VMEM((h * tq, 1), F32),
-                        pltpu.VMEM((h * tq, 1), F32),
-                        pltpu.VMEM((h * tq, w), F32)],
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * s * w * pairs, transcendentals=b * h * s * pairs,
@@ -306,12 +377,14 @@ def _prefill_pallas(qa, rows, scores, tau, lo, pool, scale, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
-    )(qa, lat, scores, tau.astype(F32)[..., None])
+        **layout,
+    )(*args)
 
 
 def sparse_prefill_reference(qa, rows, scores, tau, lo: int, pool: int,
-                             scale: float):
-    """Dense reference of :func:`sparse_prefill_latent`."""
+                             scale: float, lengths=None):
+    """Dense reference of :func:`sparse_prefill_latent`; with
+    ``lengths``, zeros at the queries from each length on."""
     b, h, s, w = qa.shape
     t = rows.shape[1]
     qpos = lo + jnp.arange(s, dtype=jnp.int32)
@@ -326,23 +399,34 @@ def sparse_prefill_reference(qa, rows, scores, tau, lo: int, pool: int,
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.where(seen, jnp.exp(logits - m), 0.0)
     p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    return jnp.einsum("bhst,btw->bhsw", p, rows.astype(F32)).astype(qa.dtype)
+    out = jnp.einsum("bhst,btw->bhsw", p, rows.astype(F32))
+    if lengths is not None:
+        live = qpos[None] < lengths.astype(jnp.int32)[:, None]
+        out = jnp.where(live[:, None, :, None], out, 0.0)
+    return out.astype(qa.dtype)
 
 
 def sparse_prefill_latent(qa, rows, scores, tau, lo: int, pool: int,
-                          scale: float, interpret: bool = False):
+                          scale: float, interpret: bool = False,
+                          lengths=None):
     """A prompt's queries at positions ``lo .. lo + S - 1`` against its
     latent rows, each query over the positions of the blocks it
     selects (:func:`select_blocks`) and no later one. qa [B, H, S, W]
     (absorbed queries); rows [B, T, W]; scores [B, S, T / pool] (the
     indexer's); tau [B, S] (each query's threshold). Returns [B, H, S,
     W]: the softmax over the selected positions of ``qa . row * scale``,
-    times the rows."""
+    times the rows.
+
+    ``lengths`` ([B] int32): positions from a sequence's length on are
+    padding. The kernel then computes no tile of queries that starts at
+    or past the length (zeros come out) and no tile of keys that does;
+    a live query's row is the one it gets without. The rows of padded
+    queries inside the tile that holds the length are not defined."""
     b, h, s, w = qa.shape
     t = rows.shape[1]
     if _on_tpu(interpret) and s % Q_TILE == 0 and t % K_TILE == 0 and \
             w % 128 == 0 and scores.shape[-1] == t // pool and _pow2(pool):
         return _prefill_pallas(qa, rows, scores, tau, int(lo), int(pool),
-                               float(scale), interpret)
+                               float(scale), interpret, lengths)
     return sparse_prefill_reference(qa, rows, scores, tau, int(lo), int(pool),
-                                    float(scale))
+                                    float(scale), lengths)

@@ -20,6 +20,7 @@ moves the logits by 0.1 or more.
 """
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -371,6 +372,43 @@ def test_the_counters_count_the_positions_each_query_attends_to(tiny):
     want = sum(t + 1 if t // 4 <= 4 else 16 + t % 4 + 1 for t in range(30))
     assert int(got["causal"]) == 30 * 31 // 2
     assert int(got["selected"]) == want
+
+
+def test_the_prompt_kernel_handed_the_lengths_computes_the_plain_path(
+        monkeypatch):
+    """The rehearsal widths but a latent row of 128 (the prompt kernel's
+    lane tile), the DSA layer alone (id 3), over a bucket of 1,024 in
+    two segments of 512: the kernel (interpreted) gives the plain
+    path's logits at a prompt's last position; it runs fewer tiles than
+    the bucket's causal square for a prompt shorter than the bucket, and
+    all of them for one that fills it."""
+    cfg = glm5_next_tiny(kv_lora_rank=128, prefill_segment=512,
+                         num_hidden_layers=1, layer_ids=(3,))
+    model = Glm5NextForCausalLM(cfg, seed=5)
+    fwd = jax.jit(lambda ids, lens: _last_logits(model, ids, lens))
+    ids = jnp.asarray(_prompts(cfg, (1024,), seed=8)[0][None])
+    want = {n: fwd(ids, jnp.asarray([n], jnp.int32))[0] for n in (700, 1024)}
+    monkeypatch.setattr(sa, "sparse_prefill_latent", functools.partial(
+        sa.sparse_prefill_latent, interpret=True))
+    fwd = jax.jit(lambda ids, lens: _last_logits(model, ids, lens))
+    for n in (700, 1024):
+        got, tiles = fwd(ids, jnp.asarray([n], jnp.int32))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want[n]),
+                                   atol=ATOL, rtol=0)
+        # a query tile of 16 sees the key tiles up to its diagonal: 32
+        # tiles see one, 32 see two
+        assert int(tiles["grid"]) == 32 + 64
+        if n == 1024:
+            assert int(tiles["run"]) == int(tiles["grid"])
+        else:  # 44 query tiles start before 700
+            assert int(tiles["run"]) == 32 + 2 * 12 < int(tiles["grid"])
+
+
+def _last_logits(model, ids, lens):
+    hidden, _ = model.decode_hidden(ids, None, prefill_lens=lens)
+    last = jnp.take_along_axis(hidden, (lens - 1)[:, None, None], axis=1)
+    return (model.logits(last)[:, 0],
+            model.pop_step_stats()["dsa_prefill_tiles"])
 
 
 @pytest.mark.parametrize("option", [

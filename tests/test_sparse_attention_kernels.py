@@ -142,32 +142,87 @@ def test_a_decoded_token_attends_to_its_selected_positions(interpret, k):
         np.testing.assert_allclose(got[i], want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "pallas"])
-@pytest.mark.parametrize("lo,s,k", [(0, 64, 8), (960, 64, 16),
-                                    (960, 64, 300)],
-                         ids=["first_segment", "later", "sees_all"])
-def test_a_prompt_attends_to_its_selected_positions(interpret, lo, s, k):
+def _prompt_case(lo, s, k, b, seed):
     """A segment of ``s`` queries at ``lo ..`` over a prompt of 1024 rows
-    (two key tiles of 512), 4 heads."""
-    rng = np.random.default_rng(lo + k)
-    b, t, w, heads, hi, di = 1, 1024, 128, 4, 2, 16
+    (two key tiles of 512), 4 heads, the selection from the indexer's
+    scores and the ``k``-th largest."""
+    rng = np.random.default_rng(seed)
+    t, w, heads, hi, di = 1024, 128, 4, 2, 16
     rows = jnp.asarray(rng.standard_normal((b, t, w)), jnp.float32)
     qa = jnp.asarray(rng.standard_normal((b, heads, s, w)), jnp.float32)
     qi = jnp.asarray(rng.standard_normal((b, s, hi, di)), jnp.float32)
     wi = jnp.asarray(np.abs(rng.standard_normal((b, s, hi))), jnp.float32)
     keys = jnp.asarray(rng.standard_normal((b, t // POOL, di)), jnp.float32)
     scores = sa.index_scores(qi, wi, keys, lo, POOL)
-    tau = sa.kth_largest(scores, k)
-    got = np.asarray(sa.sparse_prefill_latent(qa, rows, scores, tau, lo,
-                                              POOL, 0.05,
-                                              interpret=interpret))
-    qpos = lo + np.arange(s)[None]
-    seen = _dense_selected(np.asarray(scores), qpos, k)
-    for j in (0, 1, s // 2, s - 1):
-        want = _attend(np.asarray(qa)[0, :, j], np.asarray(rows)[0],
-                       seen[0, j, :t], 0.05)
-        np.testing.assert_allclose(got[0, :, j], want, rtol=2e-5,
-                                   atol=2e-5)
+    return qa, rows, scores, sa.kth_largest(scores, k)
+
+
+# each a function of the segment's first position and size
+_PROMPT_LENGTHS = {
+    "none": lambda lo, s: None,
+    "in_a_tile": lambda lo, s: [lo + s // 2 + 5],
+    "key_tile_edge": lambda lo, s: [512],
+    "past_the_end": lambda lo, s: [max(lo - 3, 0)],
+    "two_unequal": lambda lo, s: [lo + 21, 1024],
+}
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "pallas"])
+@pytest.mark.parametrize("lo,s,k", [(0, 64, 8), (960, 64, 16),
+                                    (960, 64, 300), (480, 64, 8)],
+                         ids=["first_segment", "later", "sees_all",
+                              "across_key_tiles"])
+@pytest.mark.parametrize("lengths", list(_PROMPT_LENGTHS),
+                         ids=list(_PROMPT_LENGTHS))
+def test_a_prompt_attends_to_its_selected_positions(interpret, lo, s, k,
+                                                    lengths):
+    """Live queries (all of them without lengths) attend to what they
+    select; a tile of 16 queries that starts at or past its sequence's
+    length comes out zero."""
+    lengths = _PROMPT_LENGTHS[lengths](lo, s)
+    b = 1 if lengths is None else len(lengths)
+    qa, rows, scores, tau = _prompt_case(lo, s, k, b, lo + k)
+    got = np.asarray(sa.sparse_prefill_latent(
+        qa, rows, scores, tau, lo, POOL, 0.05, interpret=interpret,
+        lengths=None if lengths is None else jnp.asarray(lengths, jnp.int32)))
+    qpos = lo + np.arange(s)
+    seen = _dense_selected(np.asarray(scores), np.broadcast_to(qpos, (b, s)),
+                           k)
+    for i in range(b):
+        n = 1024 if lengths is None else lengths[i]
+        live = [j for j in (0, 1, s // 2, s - 1, n - 1 - lo) if 0 <= j < s
+                and qpos[j] < n]
+        for j in live:
+            want = _attend(np.asarray(qa)[i, :, j], np.asarray(rows)[i],
+                           seen[i, j, :1024], 0.05)
+            np.testing.assert_allclose(got[i, :, j], want, rtol=2e-5,
+                                       atol=2e-5)
+        past = lo + np.arange(s) // sa.Q_TILE * sa.Q_TILE >= n
+        assert not got[i][:, past].any()
+
+
+def test_live_rows_are_the_same_bits_with_and_without_lengths():
+    """The kernel (interpreted) handed lengths computes a live query's row
+    from the same tiles in the same order as without them."""
+    lo, s = 480, 64
+    qa, rows, scores, tau = _prompt_case(lo, s, 8, 3, 7)
+    lengths = np.asarray([500, 1024, 517], np.int32)
+
+    def call(n):
+        return np.asarray(sa.sparse_prefill_latent(
+            qa, rows, scores, tau, lo, POOL, 0.05, interpret=True,
+            lengths=n))
+
+    plain, ragged = call(None), call(jnp.asarray(lengths))
+    for i, n in enumerate(lengths):
+        live = lo + np.arange(s) < n
+        np.testing.assert_array_equal(ragged[i][:, live], plain[i][:, live])
+    # and the tiles counted: the segment's 4 query tiles each see key
+    # tiles 0 and 1 (its diagonal lies in the second) but the first two
+    # (0 only); with lengths a tile past its sequence's end sees none
+    run, grid = sa.prefill_tile_pairs(jnp.asarray(lengths), lo, s)
+    assert int(grid) == 3 * (1 + 1 + 2 + 2)
+    assert int(run) == (1 + 1 + 0 + 0) + (1 + 1 + 2 + 2) + (1 + 1 + 2 + 0)
 
 
 def test_the_prompt_kernels_layout_of_a_key_tile():

@@ -659,7 +659,11 @@ def test_sparse_prefill_kernels_compile(one_chip):
     """The indexer's scores of a segment of 8,192 queries over 8,192
     pooled keys (never stored per head) and the segment's attention in
     dense tiles of 16 queries x 64 heads against 512 keys, the selection
-    a mask from the scores and each query's threshold: one call each."""
+    a mask from the scores and each query's threshold: one call each.
+    Handed the prompt's true length, the attention is still one call of
+    the same name, and the program holds nothing of the prompt's size
+    more: the length's scalar arithmetic alone (a [2, B] int32 operand
+    and a few tile-padded scalars, 62.5 KiB at this size)."""
     from paddle_tpu.ops.pallas import sparse_attention as sa
     lo, s, t = 24576, 8192, 32768
     specs = (_bf16(one_chip, 1, s, 32, 128),
@@ -668,13 +672,26 @@ def test_sparse_prefill_kernels_compile(one_chip):
              _bf16(one_chip, 1, 64, s, 512), _bf16(one_chip, 1, t, 512),
              _bf16(one_chip, 1, s, dt=jnp.float32))
 
-    def segment(qi, w, keys, qa, rows, tau):
+    def segment(qi, w, keys, qa, rows, tau, lengths=None):
         scores = sa.index_scores(qi, w, keys, lo, 4)
         return sa.sparse_prefill_latent(qa, rows, scores, tau, lo, 4,
-                                        1 / 16)
+                                        1 / 16, lengths=lengths)
+
+    def live_bytes(compiled):
+        mem = compiled.memory_analysis()
+        return (int(mem.argument_size_in_bytes)
+                + int(mem.temp_size_in_bytes)
+                + int(mem.output_size_in_bytes)
+                - int(mem.alias_size_in_bytes))
+
     compiled = _compile(segment, *specs)
     assert _kernel_calls(compiled) == 2
     _named_once(compiled, "dsa_index_prefill", "sparse_prefill_latent")
+    ragged = _compile(segment, *specs, _bf16(one_chip, 1, dt=jnp.int32))
+    assert _kernel_calls(ragged) == 2
+    _named_once(ragged, "dsa_index_prefill", "sparse_prefill_latent")
+    assert live_bytes(ragged) <= live_bytes(compiled) + 64 * 1024, \
+        (live_bytes(ragged), live_bytes(compiled))
 
 
 def test_sparse_cell_decode_program_compiles(one_chip, monkeypatch):

@@ -18,7 +18,13 @@ of 4, the top 2,048; 32 slots, pages of 64).
   (dense tiles in the absorbed form, the selection a mask) and the
   selected rows gathered (``jax.numpy``: a query's 8,192 rows of 512,
   then the absorbed products), timed over ``--gather-queries`` queries
-  and scaled to the segment.
+  and scaled to the segment;
+- a whole prompt in the 32,768 bucket (its four segments of 8,192, as
+  the model runs them) through ``sparse_prefill_latent`` without the
+  prompt's length and with it, at fills of 0.5, 0.75 and 1.0 of the
+  bucket: device µs a prompt, the share of (query tile, key tile)
+  pairs the length skips, and the largest difference on live query
+  rows between the two calls (0: the same tiles in the same order).
 
 A call's time is the device time of its ops in a profiler trace (read
 with the benchmark's reader, ``benchmarks/xplane.py``), the median over
@@ -47,9 +53,10 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
 
-def _device_us(fn, reps, name=None):
-    """Device µs a call: of the custom calls named ``name``, or of every
-    op (their union) where ``name`` is None; the median over ``reps``."""
+def _device_us(fn, reps, name=None, calls=1):
+    """Device µs a call: of the custom calls named ``name`` (``calls`` of
+    them a call of ``fn``, summed), or of every op (their union) where
+    ``name`` is None; the median over ``reps``."""
     import jax
     from benchmarks import xplane
     jax.block_until_ready(fn())  # compiles
@@ -62,8 +69,10 @@ def _device_us(fn, reps, name=None):
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
     if name is not None:
-        us = [e["dur"] * 1e6 for e in xplane.leaf_ops(ev)
+        us = [e["dur"] * 1e6 for e in sorted(
+            xplane.leaf_ops(ev), key=lambda e: e["start"])
               if name in e["name"] and xplane.op_kind(e) == "custom-call"]
+        us = [sum(us[i:i + calls]) for i in range(0, len(us), calls)]
         return statistics.median(us) if us else None
     return xplane.busy_seconds(ev) * 1e6 / reps
 
@@ -263,6 +272,55 @@ def main() -> int:
                     100 * work / peak / attn_us * 1e6, 1),
                 gathered_us_scaled=round(gat_us, 1),
                 gathered_queries_timed=g)
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
+    # -- a whole prompt, with and without its length ---------------------------
+    t_len = prompts[-1]
+    segs = list(range(0, t_len, seg))
+    lat_rows = jnp.asarray(rng.standard_normal((1, t_len, rank)), dt)
+    keys = jnp.asarray(rng.standard_normal((1, t_len // pool, di)), dt)
+    pq = jnp.asarray(rng.standard_normal((1, seg, hi, di)), dt)
+    pw = jnp.asarray(np.abs(rng.standard_normal((1, seg, hi))), jnp.float32)
+    qa = jnp.asarray(rng.standard_normal((1, heads, seg, rank)), dt)
+    selection = []
+    for lo in segs:
+        scores = jax.jit(lambda pq, pw, keys, lo=lo: sa.index_scores(
+            pq, pw, keys, lo, pool, interpret=a.tiny))(pq, pw, keys)
+        selection.append((scores, tau_fn(scores)))
+
+    @jax.jit
+    def prompt_fn(qa, r, selection, lengths=None):
+        return [sa.sparse_prefill_latent(qa, r, sc, tau, lo, pool, scale,
+                                         interpret=a.tiny, lengths=lengths)
+                for lo, (sc, tau) in zip(segs, selection)]
+
+    @jax.jit
+    def live_diff(a_, b_, lengths):
+        return jnp.max(jnp.stack([jnp.max(jnp.where(
+            (lo + jnp.arange(seg) < lengths[:, None])[:, None, :, None],
+            jnp.abs(x.astype(jnp.float32) - y.astype(jnp.float32)), 0.0))
+            for lo, x, y in zip(segs, a_, b_)]))
+
+    plain = prompt_fn(qa, lat_rows, selection)
+    plain_us = _device_us(lambda: prompt_fn(qa, lat_rows, selection),
+                          a.reps, "sparse_prefill_latent",
+                          calls=len(segs)) if kernel else None
+    for fill in (0.5, 0.75, 1.0):
+        n = jnp.asarray([int(fill * t_len)], jnp.int32)
+        run, grid = (sum(int(x) for x in v) for v in zip(
+            *(sa.prefill_tile_pairs(n, lo, seg) for lo in segs)))
+        ragged = prompt_fn(qa, lat_rows, selection, n)
+        row = {"whole_prompt": t_len, "length": int(n[0]),
+               "tile_pairs": {"run": run, "grid": grid},
+               "skipped_pct": round(100 * (1 - run / grid), 2),
+               "max_live_diff": float(live_diff(plain, ragged, n))}
+        if kernel:
+            us = _device_us(lambda: prompt_fn(qa, lat_rows, selection, n),
+                            a.reps, "sparse_prefill_latent", calls=len(segs))
+            row.update(us_without_length=round(plain_us, 1),
+                       us_with_length=round(us, 1),
+                       time_saved_pct=round(100 * (1 - us / plain_us), 2))
         rows.append(row)
         print(json.dumps(row), flush=True)
     print(json.dumps({"max_err": err}), flush=True)
