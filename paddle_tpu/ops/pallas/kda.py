@@ -25,8 +25,17 @@ block's start: every exponent is then at most ``SUB`` steps of decay.
 The triangular system is solved by forward substitution (a block of
 rows at a time, the rows of a block one after another): a series in
 powers of the strictly lower part loses every digit where keys repeat
-and ``beta`` nears 2. Rows past a sequence's length get ``beta = 0``
-and ``g = 0``: they leave the state alone.
+and ``beta`` nears 2. Inside a block it runs in column form: once a row
+is final, its outer product with its column of ``N = Diag(beta) A`` is
+taken from the rows below it. The columns do not depend on the solve,
+so a row's serial chain is a sublane broadcast, a product and a
+difference; the row form (each row a sum over the rows solved so far,
+reduced across sublanes, from a transposed ``N`` made by a product with
+the identity) compiled to 2,616 bundles a grid step for a v5e at 64
+heads of 128, the column form to 1,885. Inverting the blocks' diagonals
+together and merging them by products saved 4 %: the float32 products
+it adds run one after another. Rows past a sequence's length get
+``beta = 0`` and ``g = 0``: they leave the state alone.
 
 Off the TPU both run the same mathematics through plain ``jax.numpy``
 (the chunk's body is one function, used by the kernel and by the
@@ -128,26 +137,27 @@ def chunk_body(q, k, v, g, beta_row, s, n_valid, scale: float):
     a = jnp.where(lower & ~_eye(c), jnp.concatenate(a_rows, 0), 0.0)
     b = jnp.where(lower, jnp.concatenate(b_rows, 0), 0.0)
     n = a * beta_col                                     # N[r, i], i < r
-    n_t = _dot_nt(_eye(c).astype(F32), a) * beta_row     # N^T
     rhs = beta_col * (v - _dot(k * jnp.exp(gam), s))
-    sub_rows = _iota((sub, 1), 0)
     u_blocks = []
     for i in range(c // sub):
         lo, hi = i * sub, (i + 1) * sub
-        r_i = rhs[lo:hi]
+        blk = rhs[lo:hi]
         if i:
             # the rows solved so far, zeros below them: N's entries
             # inside this block meet zeros
             so_far = jnp.concatenate(
                 u_blocks + [jnp.zeros((c - lo, dv), F32)], 0)
-            r_i = r_i - _dot(n[lo:hi], so_far)
-        nt = n_t[lo:hi]
-        blk = jnp.zeros((sub, dv), F32)
-        for r in range(sub):
-            got = jnp.sum(nt[:, lo + r:lo + r + 1] * blk, axis=0,
-                          keepdims=True)
-            blk = jnp.where(sub_rows == r, r_i[r:r + 1] - got, blk)
-        u_blocks.append(blk)
+            blk = blk - _dot(n[lo:hi], so_far)
+        # column form: once row r is final it leaves the rows below it
+        # (N is zero on and above the diagonal, so the rows above stay);
+        # a row touches only the tiles of 8 rows that hold rows below it
+        tiles = [blk[j:j + 8] for j in range(0, sub, 8)]
+        for r in range(sub - 1):
+            row = tiles[r // 8][r % 8:r % 8 + 1]
+            for t in range((r + 1) // 8, len(tiles)):
+                at = lo + 8 * t
+                tiles[t] = tiles[t] - n[at:at + 8, lo + r:lo + r + 1] * row
+        u_blocks.append(jnp.concatenate(tiles, 0))
     u = jnp.concatenate(u_blocks, 0)
     o = _dot(q * jnp.exp(gam), s) + _dot(b, u)
     s_new = _col(jnp.exp(gam_end)) * s + _dot_tn(

@@ -60,12 +60,16 @@ def _check(got, want, lens, atol):
                                rtol=0)
 
 
+_EDGES = (15, 16, 17, 48, 63)  # either side of a 16-row block's edge
+
+
 @pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel"])
 @pytest.mark.parametrize("t,lens", [
     (64, (64,)),          # one whole chunk
     (70, (70, 9)),        # not a multiple of the chunk; a row inside one
     (200, (130, 200)),    # padding past a row's length over whole chunks
     (5, (5, 2)),          # shorter than a chunk of any size
+    (64, _EDGES),
 ])
 def test_chunked_scan_equals_the_recurrence(t, lens, interpret):
     h, d = (1, 128) if interpret else (2, 16)
@@ -76,20 +80,24 @@ def test_chunked_scan_equals_the_recurrence(t, lens, interpret):
     _check(got, _recurrence(q, k, v, g, beta, s0, lens, h), lens, 5e-6)
 
 
-@pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel"])
-def test_beta_near_two_over_repeated_keys_and_hard_decay(interpret):
+@pytest.mark.parametrize("interpret,lens", [
+    (False, (96,)), (True, (96,)), (False, _EDGES), (True, _EDGES),
+], ids=["jnp", "kernel", "jnp-block_edges", "kernel-block_edges"])
+def test_beta_near_two_over_repeated_keys_and_hard_decay(interpret, lens):
     """``I - beta k k^T`` nearly a reflection at every position, every
     key the same, and a decay whose running sum leaves float32's range
     inside a chunk (64 x 1.6 = 102 > 88): the blocked exponents and the
-    forward substitution hold."""
+    forward substitution hold, also where a prompt ends beside the edge
+    of a block of the solve."""
     h, d = (1, 128) if interpret else (2, 16)
-    q, k, v, g, beta, s0 = _inputs(1, 96, h, d, d, seed=1, beta_lo=1.9,
+    b = len(lens)
+    q, k, v, g, beta, s0 = _inputs(b, 96, h, d, d, seed=1, beta_lo=1.9,
                                    decay=0.02, same_key=True)
-    lens = jnp.asarray([96], jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
     got = kda.kda_chunk_fwd(q, k, v, g, beta, s0, lens, heads=h,
                             interpret=interpret)
     _check(got, _recurrence(q, k, v, g, beta, s0, lens, h), lens, 2e-4)
-    q, k, v, g, beta, s0 = _inputs(1, 96, h, d, d, seed=2, decay=1.6)
+    q, k, v, g, beta, s0 = _inputs(b, 96, h, d, d, seed=2, decay=1.6)
     g = jnp.full_like(g, -1.6)
     got = kda.kda_chunk_fwd(q, k, v, g, beta, s0, lens, heads=h,
                             interpret=interpret)
